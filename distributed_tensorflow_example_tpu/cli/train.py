@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import os
 import sys
+from typing import Any, NamedTuple
 
 from ..cluster import ClusterSpec, WORKER_JOB
 from ..config import (CheckpointConfig, DataConfig, MeshShape,
@@ -688,7 +689,26 @@ def load_dataset(cfg: TrainConfig, model=None, eval_only: bool = False):
             {"x": d["test_x"], "y": d["test_y"]})
 
 
+class TrainRun(NamedTuple):
+    """What one CLI invocation built and produced (:func:`run`)."""
+    trainer: Any
+    state: Any
+    summary: dict
+
+
 def main(argv: list[str] | None = None) -> int:
+    run(argv)
+    return 0
+
+
+def run(argv: list[str] | None = None, *, mesh=None) -> TrainRun | None:
+    """Everything ``python -m ...cli.train`` does for ``argv``, returning
+    the trainer, the final state and the summary to an in-process caller
+    (``chip_smoke.py`` trains through here and then serves the very
+    parameters it got back). ``None`` for the ``ps`` role, which only
+    prints its notice. ``mesh`` replaces the mesh the Trainer would
+    build from ``--mesh`` over all devices — the one thing a flag
+    cannot say, since a mesh is made of device objects."""
     parser = build_parser()
     args = parser.parse_args(argv)
     if args.eval_only and not args.ckpt_dir:
@@ -807,12 +827,14 @@ def main(argv: list[str] | None = None) -> int:
             WORKER_JOB: parse_hosts(args.worker_hosts) or ["localhost:0"],
         })
 
+    from ..runtime.device import enable_compilation_cache
     from ..runtime.server import Server
+    enable_compilation_cache()
     server = Server(cluster, args.job_name, args.task_index,
                     profiler_port=args.profiler_port or None)
     if not server.role.should_run:          # ps branch: notice + exit 0
         server.join()
-        return 0
+        return None
 
     if cfg.obs.debug_nans:
         import jax
@@ -844,7 +866,7 @@ def main(argv: list[str] | None = None) -> int:
         from ..data.cifar import make_augment_transform
         train_transform = make_augment_transform(cfg.data.seed)
     ctx = server.context
-    trainer = Trainer(model, cfg, train_arrays, eval_arrays,
+    trainer = Trainer(model, cfg, train_arrays, eval_arrays, mesh=mesh,
                       process_index=ctx.process_index if ctx else 0,
                       num_processes=ctx.num_processes if ctx else 1,
                       train_transform=train_transform)
@@ -893,7 +915,7 @@ def main(argv: list[str] | None = None) -> int:
         # export-from-checkpoint: the natural serving path (restore,
         # optionally eval, ship the artifact)
         _maybe_export(args, cfg, model, state, ctx)
-        return 0
+        return TrainRun(trainer, state, {"eval": metrics})
 
     with trainer:
         state, summary = trainer.train()
@@ -907,7 +929,7 @@ def main(argv: list[str] | None = None) -> int:
              summary["steps_per_sec"])
 
     _maybe_export(args, cfg, model, state, ctx)
-    return 0
+    return TrainRun(trainer, state, summary)
 
 
 def _maybe_export(args, cfg, model, state, ctx) -> None:

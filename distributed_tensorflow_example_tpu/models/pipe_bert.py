@@ -50,7 +50,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..config import TrainConfig
 from ..ops.attention import multi_head_attention
-from ..parallel.collectives import axis_size
 from ..parallel.mesh import AxisNames
 from ..parallel.pipeline import make_pipeline, sequential_blocks
 from ..parallel.sharding import ShardingRules
@@ -137,7 +136,7 @@ class PipeBert(Bert):
         tensor: every TP member draws the full mask from the shared key
         and slices its own seq chunk (mask generation is cheap replicated
         compute; the values stream stays sharded)."""
-        t = axis_size(tp_axis)
+        t = lax.axis_size(tp_axis)
         m = lax.axis_index(tp_axis)
         b, sl, hd = x_local.shape
         keep = 1.0 - self.cfg.dropout

@@ -50,7 +50,6 @@ from jax.sharding import PartitionSpec as P
 
 from ..config import TrainConfig
 from ..ops import moe
-from ..parallel.collectives import axis_size
 from ..parallel.mesh import AxisNames
 from ..parallel.pipeline import make_pipeline, sequential_blocks
 from ..parallel.sharding import ShardingRules
@@ -132,7 +131,7 @@ class PipeMoeBert(PipeBert):
         if ep_axis is not None:
             return moe.moe_ffn_ep_body(
                 lp_moe, h, n_experts=c.n_experts,
-                n_ranks=axis_size(ep_axis), top_k=c.top_k,
+                n_ranks=lax.axis_size(ep_axis), top_k=c.top_k,
                 capacity_factor=c.capacity_factor, dtype=self.dtype,
                 axis_name=ep_axis, stat_axes=stat_axes)
         return moe.moe_ffn(lp_moe, h, n_experts=c.n_experts,

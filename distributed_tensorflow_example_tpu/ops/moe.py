@@ -47,7 +47,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from . import nn
-from ..parallel.collectives import shard_map
 
 Params = Any
 
@@ -330,6 +329,6 @@ def moe_ffn_shard_map(params: Params, x: jax.Array, mesh, *,
     }
     aux_spec = {"lb_loss": P(), "z_loss": P(), "dropped_fraction": P(),
                 "expert_load": P()}
-    fn = shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
                        out_specs=(xspec, aux_spec), check_vma=False)
     return fn(params, x)

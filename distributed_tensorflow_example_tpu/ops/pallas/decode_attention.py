@@ -17,12 +17,18 @@ exactly 0 — slot ``pos`` is always valid, so no fully-masked rows
 exist), probabilities cast to the value dtype before the context matmul
 with f32 accumulation.
 
-Scope: the kernel path needs Mosaic-friendly tiles — the score row's
-lane dim is the cache length T (T % 128 == 0) and the head dim must be
-MXU-aligned (D == 64 or D % 128 == 0). Anything else falls back to the
-XLA path (which the ``"loop"`` decode impl uses anyway). On non-TPU
-backends the kernel runs in Pallas interpret mode so CPU tests exercise
-the same code path (same recipe as flash_attention).
+Scope: the kernel path needs tiles the TPU compiler accepts
+(:func:`tile_friendly`). Every K/V block is carved from the
+[B, T, H·D] view of the cache, so its lane width must be a multiple of
+128: a head dim that is one (D % 128 == 0) is one block per head; at
+D == 64 — GPT-2's — one block holds a PAIR of adjacent heads (the head
+count must be even) and the kernel keeps the two apart with a lane mask
+on the query rows. The score row's lane dim is the cache length
+(T % 128 == 0). Anything else takes the XLA path (which the ``"loop"``
+decode impl uses anyway). Off-TPU the kernel runs in Pallas interpret
+mode so CPU tests exercise the same code path (same recipe as
+flash_attention); tests/test_tpu_compile.py compiles it for a described
+v5e at GPT-2 widths.
 """
 
 from __future__ import annotations
@@ -38,29 +44,63 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import NEG_INF, multi_head_attention
 
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# repo meets (sandbox 0.4.x vs the chip runtime); take whichever exists
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def tile_friendly(total: int, head_dim: int) -> bool:
-    """Kernel-path tile constraints: the [1, T] score row puts T in the
-    lane dim (128-multiples) and the context matmul wants an MXU-aligned
-    head dim — the same D rule as flash_attention."""
-    return total % 128 == 0 and (head_dim == 64 or head_dim % 128 == 0)
+def _group(head_dim: int) -> int:
+    """Heads per K/V block: as many as fill one 128-lane tile."""
+    return max(1, 128 // head_dim)
+
+
+def _head_dim_ok(heads: int, head_dim: int) -> bool:
+    return head_dim % 128 == 0 or (head_dim == 64 and heads % 2 == 0)
+
+
+def tile_friendly(total: int, heads: int, head_dim: int) -> bool:
+    """Exactly the slab shapes the TPU compiler accepts for this kernel:
+    the [g, T] score rows put T in the lane dim (128-multiples) and each
+    [T, g·D] K/V block must be 128 lanes wide — D a multiple of 128, or
+    D == 64 with an even head count (two heads per block)."""
+    return total % 128 == 0 and _head_dim_ok(heads, head_dim)
+
+
+def _own_lanes(group: int, head_dim: int):
+    """[g, g·D] bool: row r owns the lanes of the r-th head in the
+    block. Comparisons only — no vector integer division."""
+    shape = (group, group * head_dim)
+    lo = lax.broadcasted_iota(jnp.int32, shape, 0) * head_dim
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+    return (lane >= lo) & (lane < lo + head_dim)
+
+
+def _split_heads(q, group: int, head_dim: int):
+    """[1, g·D] query lanes -> [g, g·D] rows, row r zero outside head
+    r's lanes, so ONE lane-aligned matmul against the [T, g·D] K block
+    yields each head's own scores (the zeros contribute exact 0)."""
+    if group == 1:
+        return q
+    return jnp.where(_own_lanes(group, head_dim), q, 0.0)
+
+
+def _merge_heads(ctx, group: int, head_dim: int):
+    """[g, g·D] per-row contexts -> the [1, g·D] output lanes: row r's
+    matmul against the whole V block is only meaningful in head r's
+    lanes; keep those."""
+    if group == 1:
+        return ctx
+    return jnp.sum(jnp.where(_own_lanes(group, head_dim), ctx, 0.0),
+                   axis=0, keepdims=True)
 
 
 def _kernel(pos_ref, pad_ref, q_ref, k_ref, v_ref, o_ref, *,
-            total: int, sm_scale: float):
+            total: int, head_dim: int, sm_scale: float):
     b = pl.program_id(0)
-    q = q_ref[0].astype(jnp.float32)                    # [1, D]
-    k = k_ref[0].astype(jnp.float32)                    # [T, D]
-    v = v_ref[0]                                        # [T, D]
+    g = _group(head_dim)
+    q = _split_heads(q_ref[0].astype(jnp.float32), g, head_dim)  # [g, W]
+    k = k_ref[0].astype(jnp.float32)                    # [T, W]
+    v = v_ref[0]                                        # [T, W]
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
     # ragged pad/pos mask fused in: slot j of row b is live iff
@@ -73,43 +113,45 @@ def _kernel(pos_ref, pad_ref, q_ref, k_ref, v_ref, o_ref, *,
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)                                  # masked -> exact 0
     probs = (p / jnp.sum(p, axis=-1, keepdims=True)).astype(v.dtype)
-    o_ref[0] = lax.dot_general(
-        probs, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32).astype(o_ref.dtype)
+    ctx = lax.dot_general(probs, v, (((1,), (0,)), ((), ())),
+                          preferred_element_type=jnp.float32)
+    o_ref[0] = _merge_heads(ctx, g, head_dim).astype(o_ref.dtype)
 
 
 def _dispatch(q, k, v, pos, pad):
-    """Grid (B, H); per program ONE [T, D] K/V plane of the cache slab.
+    """Grid (B, H/g); per program ONE [T, g·D] K/V block of the cache
+    slab — g = 1 head where D fills the lanes, 2 heads at D == 64.
 
-    Mosaic tiling note: the per-head plane is carved out of the
-    [B, T, H·D] *view* of the slab (a free, contiguous reshape), so
-    every block's trailing 2-D tile is [T, D] (sublane T — a 128-
-    multiple — by lane D) or a [1, D] row whose singleton matches its
-    array dim. Blocking the 4-D [B, T, H, D] layout directly would put
-    a size-1 tile against the H dim (neither 8-divisible nor the array
-    dim) — the interpret-passes-but-Mosaic-fails shape documented in
-    the verify notes."""
+    Mosaic tiling note: the block is carved out of the [B, T, H·D]
+    *view* of the slab (a free, contiguous reshape), so its trailing
+    2-D tile is [T, g·D]: sublane T equals the array dim and lane g·D
+    is a 128-multiple — the two things the TPU lowering asks of a
+    block. q and the output ride the same view as [B, 1, H·D] rows.
+    Blocking the 4-D [B, T, H, D] layout directly would put a size-1
+    tile against the H dim (neither 8-divisible nor the array dim),
+    and a bare [T, 64] block of the view a 64-lane tile against
+    H·D: both pass interpret mode and are refused by the compiler."""
     b, t, h, d = k.shape
-    q3 = q.reshape(b * h, 1, d)
-    k3 = k.reshape(b, t, h * d)
-    v3 = v.reshape(b, t, h * d)
+    g = _group(d)
+    w = g * d
+    row = pl.BlockSpec((1, 1, w), lambda bb, hh: (bb, 0, hh))
+    slab = pl.BlockSpec((1, t, w), lambda bb, hh: (bb, 0, hh))
     out = pl.pallas_call(
-        functools.partial(_kernel, total=t, sm_scale=1.0 / math.sqrt(d)),
-        grid=(b, h),
+        functools.partial(_kernel, total=t, head_dim=d,
+                          sm_scale=1.0 / math.sqrt(d)),
+        grid=(b, h // g),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),               # pos [B]
             pl.BlockSpec(memory_space=pltpu.SMEM),               # pad [B]
-            pl.BlockSpec((1, 1, d), lambda bb, hh: (bb * h + hh, 0, 0)),
-            pl.BlockSpec((1, t, d), lambda bb, hh: (bb, 0, hh)),
-            pl.BlockSpec((1, t, d), lambda bb, hh: (bb, 0, hh)),
+            row, slab, slab,
         ],
-        out_specs=pl.BlockSpec((1, 1, d),
-                               lambda bb, hh: (bb * h + hh, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, 1, d), v.dtype),
-        compiler_params=_CompilerParams(
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((b, 1, h * d), v.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=_interpret(),
-    )(pos, pad, q3, k3, v3)
+    )(pos, pad, q.reshape(b, 1, h * d), k.reshape(b, t, h * d),
+      v.reshape(b, t, h * d))
     return out.reshape(b, h, d)
 
 
@@ -146,12 +188,13 @@ def xla_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # tests/test_paged_serving.py.
 # ---------------------------------------------------------------------------
 
-def paged_tile_friendly(block_size: int, head_dim: int) -> bool:
-    """Paged-kernel tile constraints: each score row is [1, block_size]
-    (block_size in the lane dim — 128-multiples) and the context matmul
-    wants the same MXU-aligned head dim as the slab kernel."""
-    return block_size % 128 == 0 and (head_dim == 64
-                                      or head_dim % 128 == 0)
+def paged_tile_friendly(block_size: int, heads: int,
+                        head_dim: int) -> bool:
+    """Exactly the pool shapes the TPU compiler accepts for the paged
+    kernel: each score row is [g, block_size] (block_size in the lane
+    dim — 128-multiples) over the same 128-lane K/V blocks as the slab
+    kernel (:func:`tile_friendly`)."""
+    return block_size % 128 == 0 and _head_dim_ok(heads, head_dim)
 
 
 def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
@@ -185,20 +228,22 @@ def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
 
 
 def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
-                  block_size: int, sm_scale: float, quant: bool):
-    """Grid (B, H, NB): one [block_size, D] K/V block per step, gathered
-    through the block table by the index maps (scalar prefetch). The
-    softmax runs online over the NB dimension (m/l/acc scratch persists
-    across the revisited output block); masked slots are zeroed
-    explicitly so never-written pool blocks (incl. the engine's null
-    block) contribute exact 0 regardless of their bytes.
+                  block_size: int, head_dim: int, sm_scale: float,
+                  quant: bool):
+    """Grid (B, H/g, NB): one [block_size, g·D] K/V block per step,
+    gathered through the block table by the index maps (scalar
+    prefetch). The softmax runs online over the NB dimension (per-head
+    m/l/acc scratch persists across the revisited output block);
+    masked slots are zeroed explicitly so never-written pool blocks
+    (incl. the engine's null block) contribute exact 0 regardless of
+    their bytes.
 
     ``quant=True`` (int8 pools): two extra [1, 1, Bs] scale-row inputs
     follow v. The dequant is fused ALGEBRAICALLY — K's per-row scale
     multiplies the score COLUMNS (q·(k·s)ᵀ = (q·kᵀ)·s, broadcast along
-    the [1, Bs] score row) and V's scale folds into the probabilities
+    the [g, Bs] score rows) and V's scale folds into the probabilities
     before the context matmul (p·(v·s) = (p·s)·v) — so no dequantized
-    [Bs, D] tile is ever materialized and no transpose of the scale
+    [Bs, g·D] tile is ever materialized and no transpose of the scale
     row is needed."""
     if quant:
         ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
@@ -206,15 +251,16 @@ def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
     j = pl.program_id(2)
+    g = _group(head_dim)
 
     @pl.when(j == 0)
     def _init():
-        m_ref[0, 0] = NEG_INF
-        l_ref[0, 0] = 0.0
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)                    # [1, D]
-    k = k_ref[0].astype(jnp.float32)                    # [Bs, D]
+    q = _split_heads(q_ref[0].astype(jnp.float32), g, head_dim)  # [g, W]
+    k = k_ref[0].astype(jnp.float32)                    # [Bs, W]
     s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
     if quant:
@@ -223,13 +269,13 @@ def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
         jnp.int32, (1, block_size), 1)
     live = (kpos <= pos_ref[b]) & (kpos >= pad_ref[b])
     s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    m_prev = m_ref[...]                                 # [g, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     # explicit zeroing (not exp underflow): with the finite NEG_INF fill
     # an all-masked block would otherwise see exp(NEG_INF - NEG_INF) = 1
-    p = jnp.where(live, jnp.exp(s - m_new), 0.0)        # [1, Bs]
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)        # [g, Bs]
     alpha = jnp.exp(m_prev - m_new)
-    l_new = l_ref[0, 0] * alpha + jnp.sum(p)
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
     if quant:
         pv = p * vs_ref[0]                              # fold V scales
         vblk = v_ref[0].astype(jnp.float32)
@@ -241,30 +287,28 @@ def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
                         pv, vblk,
                         (((1,), (0,)), ((), ())),
                         preferred_element_type=jnp.float32))
-    m_ref[0, 0] = m_new
-    l_ref[0, 0] = l_new
+    m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _finalize():
         # slot `pos` is always live, so l >= exp(0) > 0
-        o_ref[0] = (acc_ref[...] / l_ref[0, 0]).astype(o_ref.dtype)
+        o_ref[0] = _merge_heads(acc_ref[...] / l_ref[...], g,
+                                head_dim).astype(o_ref.dtype)
 
 
 def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
                     k_scale=None, v_scale=None):
-    """Grid (B, H, NB); per program ONE [Bs, D] K/V plane of the pool,
-    selected by the block table via scalar-prefetch index maps. Same
-    [N, Bs, H·D]-view trick as the slab kernel so every tile is
-    Mosaic-friendly. int8 pools additionally stream the matching
-    [1, Bs] scale row per block ([N, 1, Bs] view so the singleton tile
-    dim matches its array dim — the Mosaic tiling rule the slab
-    kernel's docstring records)."""
+    """Grid (B, H/g, NB); per program ONE [Bs, g·D] K/V block of the
+    pool, selected by the block table via scalar-prefetch index maps.
+    Same [N, Bs, H·D]-view blocks as the slab kernel so every tile is
+    one the compiler accepts. int8 pools additionally stream the
+    matching [1, Bs] scale row per block ([N, 1, Bs] view so the
+    singleton tile dim matches its array dim)."""
     n, bs, h, d = k_pool.shape
     b, nb = block_tables.shape
     quant = k_scale is not None
-    q3 = q.reshape(b * h, 1, d)
-    k3 = k_pool.reshape(n, bs, h * d)
-    v3 = v_pool.reshape(n, bs, h * d)
+    g = _group(d)
+    w = g * d
 
     def kv_map(bb, hh, jj, bt, pos_s, pad_s):
         return (bt[bb, jj], 0, hh)
@@ -273,36 +317,37 @@ def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
         return (bt[bb, jj], 0, 0)
 
     def q_map(bb, hh, jj, bt, pos_s, pad_s):
-        return (bb * h + hh, 0, 0)
+        return (bb, 0, hh)
 
     in_specs = [
-        pl.BlockSpec((1, 1, d), q_map),
-        pl.BlockSpec((1, bs, d), kv_map),
-        pl.BlockSpec((1, bs, d), kv_map),
+        pl.BlockSpec((1, 1, w), q_map),
+        pl.BlockSpec((1, bs, w), kv_map),
+        pl.BlockSpec((1, bs, w), kv_map),
     ]
-    operands = [q3, k3, v3]
+    operands = [q.reshape(b, 1, h * d), k_pool.reshape(n, bs, h * d),
+                v_pool.reshape(n, bs, h * d)]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, bs), scale_map)] * 2
         operands += [k_scale.reshape(n, 1, bs).astype(jnp.float32),
                      v_scale.reshape(n, 1, bs).astype(jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # block_tables, pos, pad
-        grid=(b, h, nb),
+        grid=(b, h // g, nb),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), q_map),
+        out_specs=pl.BlockSpec((1, 1, w), q_map),
         scratch_shapes=[
-            pltpu.SMEM((1, 1), jnp.float32),            # running max
-            pltpu.SMEM((1, 1), jnp.float32),            # running sum
-            pltpu.VMEM((1, d), jnp.float32),            # context acc
+            pltpu.VMEM((g, 1), jnp.float32),            # running max
+            pltpu.VMEM((g, 1), jnp.float32),            # running sum
+            pltpu.VMEM((g, w), jnp.float32),            # context acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=bs,
+        functools.partial(_paged_kernel, block_size=bs, head_dim=d,
                           sm_scale=1.0 / math.sqrt(d), quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (b * h, 1, d), q.dtype if quant else v_pool.dtype),
-        compiler_params=_CompilerParams(
+            (b, 1, h * d), q.dtype if quant else v_pool.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(block_tables, pos, pad, *operands)
@@ -362,14 +407,16 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     bt = jnp.asarray(block_tables, jnp.int32)
     if bt.ndim != 2 or bt.shape[0] != b:
         raise ValueError(f"block_tables shape {bt.shape} != ({b}, NB)")
-    use_kernel = (impl == "pallas"
-                  or (impl == "auto" and jax.default_backend() == "tpu"
-                      and paged_tile_friendly(bs, d)))
-    if use_kernel and not paged_tile_friendly(bs, d):
+    friendly = paged_tile_friendly(bs, h, d)
+    if impl == "pallas" and not friendly:
         raise ValueError(
             f"paged decode_attention kernel needs block_size % 128 == 0 "
-            f"and an MXU-aligned head dim, got block_size={bs} D={d} "
-            "(use impl='auto' for the XLA fallback)")
+            f"and a head dim of 64 (even head count) or a multiple of "
+            f"128, got block_size={bs} H={h} D={d} (use impl='auto' for "
+            "the XLA fallback)")
+    use_kernel = friendly and (
+        impl == "pallas"
+        or (impl == "auto" and jax.default_backend() == "tpu"))
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     padb = jnp.broadcast_to(jnp.asarray(pad, jnp.int32).reshape(-1), (b,))
     if not use_kernel:
@@ -404,14 +451,15 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          f"{k.shape}")
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown decode attention impl {impl!r}")
-    use_kernel = (impl == "pallas"
-                  or (impl == "auto" and jax.default_backend() == "tpu"
-                      and tile_friendly(t, d)))
-    if use_kernel and not tile_friendly(t, d):
+    friendly = tile_friendly(t, h, d)
+    if impl == "pallas" and not friendly:
         raise ValueError(
-            f"decode_attention kernel needs T % 128 == 0 and an "
-            f"MXU-aligned head dim, got T={t} D={d} (use impl='auto' "
-            "for the XLA fallback)")
+            f"decode_attention kernel needs T % 128 == 0 and a head dim "
+            f"of 64 (even head count) or a multiple of 128, got T={t} "
+            f"H={h} D={d} (use impl='auto' for the XLA fallback)")
+    use_kernel = friendly and (
+        impl == "pallas"
+        or (impl == "auto" and jax.default_backend() == "tpu"))
     if not use_kernel:
         return xla_decode_attention(q, k, v, pos=pos, pad=pad)
     # kernel reads one pos per row from SMEM; broadcast a scalar pos

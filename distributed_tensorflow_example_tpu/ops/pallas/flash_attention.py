@@ -59,11 +59,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import NEG_INF
 
-# jax renamed TPUCompilerParams -> CompilerParams across the versions this
-# repo meets (sandbox 0.4.x vs the chip runtime); take whichever exists
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 DEFAULT_BLOCK = 128
 
 #: fused-bwd dq slab budget: the [S, head_dim] f32 accumulator must share
@@ -219,7 +214,7 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
         scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -408,7 +403,7 @@ def _bwd_fused(q3, k3, v3, do3, L, Dsum, mask2, *, heads: int, blk_q: int,
         scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
                         pltpu.VMEM((blk_k, d), jnp.float32),
                         pltpu.VMEM((blk_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -448,7 +443,7 @@ def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
         out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -479,7 +474,7 @@ def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
                    jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
         scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
                         pltpu.VMEM((blk_k, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*args)
@@ -553,6 +548,35 @@ def kernel_engages(seq: int, head_dim: int, *,
             and _tile_friendly(seq, head_dim, bwd_q, bwd_k))
 
 
+def _partitioned(amesh, q, k, v, mask, **kw):
+    """The kernel under a mesh that jit partitions automatically.
+
+    The TPU compiler refuses to partition a Mosaic kernel by itself
+    ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map"). Attention is independent per (batch row,
+    head), so the call is wrapped in ``shard_map`` over the ambient
+    mesh — batch dim over the framework's batch axes, head dim over the
+    tensor-parallel axis where it divides the head count — and each
+    shard runs the whole kernel on its slice; no collective. The mesh is
+    the trace context's (``jax.sharding.use_abstract_mesh``, entered by
+    whoever jits a model over a multi-device mesh: SyncReplicas' step,
+    the Trainer's eval pass). No ambient mesh — a single-device trace
+    such as an export — or one already manual (``sync_mode="shard_map"``,
+    pipeline stages) calls the kernel bare."""
+    from jax.sharding import PartitionSpec as P
+
+    from ...parallel.mesh import AxisNames
+    heads = (AxisNames.MODEL
+             if q.shape[2] % amesh.shape[AxisNames.MODEL] == 0 else None)
+    qkv = P(AxisNames.BATCH, None, heads, None)
+    masks = () if mask is None else (mask,)
+    return jax.shard_map(
+        lambda q_, k_, v_, *m: flash_attention(
+            q_, k_, v_, mask=m[0] if m else None, **kw),
+        in_specs=(qkv, qkv, qkv) + (P(AxisNames.BATCH, None),) * len(masks),
+        out_specs=qkv, check_vma=False)(q, k, v, *masks)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     mask: jax.Array | None = None, causal: bool = False,
                     block_q: int = DEFAULT_BLOCK,
@@ -588,10 +612,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             m4 = mask if mask.ndim == 4 else mask[:, None, None, :]
         return multi_head_attention(q, k, v, mask=m4, causal=causal,
                                     impl="xla")
-    bwd_variant = effective_bwd_variant(s, d, bwd_variant)
-
     if mask is not None and mask.ndim == 4:
         mask = mask[:, 0, 0, :]
+    amesh = jax.sharding.get_abstract_mesh()
+    if any(amesh.shape[a] > 1 for a in amesh.axis_names
+           if a not in amesh.manual_axes):
+        return _partitioned(amesh, q, k, v, mask, causal=causal,
+                            block_q=block_q, block_k=block_k,
+                            bwd_block=bwd_block, bwd_variant=bwd_variant)
+    bwd_variant = effective_bwd_variant(s, d, bwd_variant)
 
     def fold(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)

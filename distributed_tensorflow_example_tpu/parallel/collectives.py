@@ -17,34 +17,10 @@ from __future__ import annotations
 
 from typing import Any, Sequence
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
 AxisName = Any  # str | tuple[str, ...]
-
-# shard_map graduated from jax.experimental to a top-level jax API
-# (and renamed check_rep -> check_vma) between the jax this sandbox
-# pins and the chip runtime's; resolve whichever exists so every
-# shard_map call site — all written against the graduated API — works
-# on both
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                            # pre-graduation jax
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, /, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_exp(f, **kw)
-
-
-def axis_size(axis_name: AxisName) -> jax.Array:
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    # pre-graduation jax: psum of the literal 1 is the classic
-    # statically-folded axis-size idiom
-    return lax.psum(1, axis_name)
 
 
 def all_reduce_sum(x, axis_name: AxisName):
@@ -73,7 +49,7 @@ def reduce_scatter_mean(x, axis_name: AxisName, *, scatter_axis: int = 0):
     sharded, since each host only materializes its own shard."""
     summed = lax.psum_scatter(x, axis_name, scatter_dimension=scatter_axis,
                               tiled=True)
-    return summed / axis_size(axis_name)
+    return summed / lax.axis_size(axis_name)
 
 
 def ppermute_ring_shift(x, axis_name: AxisName, *, shift: int = 1):
@@ -83,7 +59,7 @@ def ppermute_ring_shift(x, axis_name: AxisName, *, shift: int = 1):
     (SURVEY.md §5.7): each step passes KV blocks to the ring neighbor over
     ICI while the MXU overlaps compute on the resident block.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis_name, perm)
 

@@ -43,7 +43,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .collectives import axis_size, shard_map
 from .mesh import AxisNames
 
 # stage_fn(stage_params, x, mb_idx) -> y with the same pytree
@@ -72,7 +71,7 @@ def pipeline_spmd(stage_fn: StageFn, stage_params, microbatches,
     Returns the same pytree with the final stage's outputs, identical on
     every pipe member.
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     m = jax.tree_util.tree_leaves(microbatches)[0].shape[0]
 
@@ -162,7 +161,7 @@ def make_pipeline(mesh: Mesh, stage_fn: StageFn, *,
                    else _tmap(lambda _: P(pipe_axis), stacked_params))
         a_specs = (x_specs if x_specs is not None
                    else _tmap(lambda _: P(batch_axes), x))
-        return shard_map(
+        return jax.shard_map(
             body, mesh=mesh,
             in_specs=(p_specs, a_specs),
             out_specs=a_specs, check_vma=False)(stacked_params, x)

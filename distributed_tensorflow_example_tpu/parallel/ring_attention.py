@@ -27,7 +27,6 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..ops.attention import NEG_INF as _NEG, apply_mask, attention_scores
-from .collectives import axis_size, shard_map
 from .mesh import AxisNames
 
 
@@ -62,7 +61,7 @@ def ring_attention_local(q, k, v, *, axis_name: str = AxisNames.SEQ,
     Args are the LOCAL shards [B, S/n, H, D] (+ optional kv_mask [B, S/n]).
     Returns the local context shard [B, S/n, H, D].
     """
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     me = lax.axis_index(axis_name)
     b, sq, h, d = q.shape
     sk = k.shape[1]
@@ -119,13 +118,13 @@ def make_ring_attention(mesh: Mesh, *, causal: bool = False,
         if mask is not None:
             fn = partial(ring_attention_local, axis_name=seq_axis,
                          causal=bound_causal)
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 lambda q_, k_, v_, m_: fn(q_, k_, v_, kv_mask=m_),
                 mesh=mesh,
                 in_specs=(qkv_spec, qkv_spec, qkv_spec, mask_spec),
                 out_specs=qkv_spec, check_vma=False)
             return sharded(q, k, v, mask)
-        sharded = shard_map(
+        sharded = jax.shard_map(
             lambda q_, k_, v_: ring_attention_local(
                 q_, k_, v_, axis_name=seq_axis, causal=bound_causal,
                 kv_mask=None),

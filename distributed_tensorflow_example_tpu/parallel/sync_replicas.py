@@ -51,6 +51,7 @@ when unused) and ``aux_metrics`` is a dict of scalars.
 
 from __future__ import annotations
 
+import time
 from functools import partial
 from typing import Any, Callable
 
@@ -62,7 +63,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..config import SyncConfig
 from ..train.state import TrainState
-from .collectives import shard_map
 from .mesh import AxisNames, batch_axis_size
 from .sharding import ShardingRules, batch_pspec, state_shardings
 
@@ -155,6 +155,7 @@ class SyncReplicas:
             fsdp_axis_size=mesh.shape[AxisNames.FSDP])
         self.num_replicas = batch_axis_size(mesh)
         self.last_cost_analysis: dict | None = None   # set by precompile()
+        self.last_compile_seconds: float | None = None     # likewise
         if (self.sync.replicas_to_aggregate is not None
                 and self.sync.replicas_to_aggregate != self.num_replicas):
             raise ValueError(
@@ -230,7 +231,13 @@ class SyncReplicas:
         fn = getattr(self, name)
         if not hasattr(fn, "lower"):        # checkify wrapper: no AOT path
             return {}
-        compiled = fn.lower(state, batch).compile()
+        lowered = fn.lower(state, batch)
+        t0 = time.perf_counter()
+        compiled = lowered.compile()
+        # set-up time, reported apart from the step times: the XLA
+        # compile alone (tracing is not in it), so a warm persistent
+        # compilation cache shows here as a short compile
+        self.last_compile_seconds = time.perf_counter() - t0
         setattr(self, name, compiled)
         raw = compiled.cost_analysis() or {}
         if isinstance(raw, (list, tuple)):  # older jax: one dict per device
@@ -329,9 +336,12 @@ class SyncReplicas:
         replicated/fsdp-sharded. One fused program = SURVEY.md §3.3 steps
         1-4 plus the chief aggregation loop."""
         rng = jax.random.fold_in(state.rng, state.step)
-        grads, loss, aux, new_extras = _grads_and_metrics(
-            self.loss_fn, state.params, state.extras, batch, rng,
-            self.sync.accum_steps)
+        # the mesh is the trace's ambient one: what jit cannot partition
+        # by itself (a Mosaic kernel) shard_maps itself over it
+        with jax.sharding.use_abstract_mesh(self.mesh.abstract_mesh):
+            grads, loss, aux, new_extras = _grads_and_metrics(
+                self.loss_fn, state.params, state.extras, batch, rng,
+                self.sync.accum_steps)
         return self._update(state, grads, loss, aux, new_extras)
 
     def _shard_map_step(self, state: TrainState, batch):
@@ -340,7 +350,7 @@ class SyncReplicas:
         replicated (fsdp/tp rules are the auto path's job)."""
         axes = AxisNames.BATCH
 
-        @partial(shard_map, mesh=self.mesh,
+        @partial(jax.shard_map, mesh=self.mesh,
                  in_specs=(P(), jax.tree_util.tree_map(
                      lambda _: batch_pspec(), batch)),
                  out_specs=P(),

@@ -9,8 +9,10 @@ gRPC RecvTensor push/pull.
 
 from .device import (
     available_devices,
+    chip_peak_flops,
     cpu_devices,
     default_device_kind,
+    enable_compilation_cache,
     local_device_count,
 )
 from .distributed import DistributedContext, initialize
@@ -18,8 +20,10 @@ from .server import Server
 
 __all__ = [
     "available_devices",
+    "chip_peak_flops",
     "cpu_devices",
     "default_device_kind",
+    "enable_compilation_cache",
     "local_device_count",
     "DistributedContext",
     "initialize",

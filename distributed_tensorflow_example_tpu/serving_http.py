@@ -185,6 +185,7 @@ from .obs import trace as obs_trace
 from .obs.flightrec import FlightRecorder
 from .obs.registry import Registry
 from .runtime import faults
+from .runtime.device import enable_compilation_cache
 from .serving import ServableModel, has_stepwise, load_servable
 from .serving_batch import (DeadlineExceededError, DrainingError,
                             EngineStalledError, GenerationEngine,
@@ -1422,6 +1423,7 @@ def main(argv=None) -> int:
     ap.add_argument("--fault_seed", type=int, default=0,
                     help="seed for p= fault rules in --fault_spec")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     if args.fault_spec:
         faults.install(faults.parse_spec(args.fault_spec,
                                          seed=args.fault_seed))

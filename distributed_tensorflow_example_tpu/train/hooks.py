@@ -521,6 +521,7 @@ class StepTimingHook(Hook):
             cost = getattr(trainer.sync, "last_cost_analysis", None)
             if cost:
                 rec["step_cost_analysis"] = cost
+                rec["compile_seconds"] = trainer.sync.last_compile_seconds
                 self._cost_logged = True
         self.last_record = rec
         self._times_ms.clear()

@@ -266,6 +266,9 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=20,
                     help="training steps per scenario run (>= 10)")
     args = ap.parse_args(argv)
+    from distributed_tensorflow_example_tpu.runtime.device import (
+        enable_compilation_cache)
+    enable_compilation_cache()
     names = (list(SCENARIOS) if args.scenario == "all"
              else [s.strip() for s in args.scenario.split(",") if s.strip()])
     unknown = [n for n in names if n not in SCENARIOS]

@@ -604,6 +604,9 @@ def main(argv=None) -> int:
                     "(the fleets are already CPU-tiny; --smoke changes "
                     "nothing today)")
     args = ap.parse_args(argv)
+    from distributed_tensorflow_example_tpu.runtime.device import (
+        enable_compilation_cache)
+    enable_compilation_cache()
     names = (list(SCENARIOS) if args.scenario == "all"
              else [s.strip() for s in args.scenario.split(",")
                    if s.strip()])

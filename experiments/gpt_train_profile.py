@@ -19,11 +19,12 @@ Fresh process per cell; one JSON line per cell; findings in BASELINE.md.
 
 import json
 import os
-import subprocess
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from _cells import fail, run_cells  # noqa: E402
 
 BATCH, SEQ = 32, 512
 
@@ -111,18 +112,13 @@ def trace(outdir: str, chunk: int = 512) -> dict:
 
 def main() -> None:
     if sys.argv[1:2] == ["--all"]:
-        env = dict(os.environ,
-                   DTX_JAX_CACHE=os.environ.get("DTX_JAX_CACHE",
-                                                "/tmp/dtx_jax_cache"))
-        me = os.path.abspath(__file__)
-        for c in (512, 0, 128, 256):
-            subprocess.run([sys.executable, me, "time", str(c)],
-                           env=env, check=False)
+        run_cells(os.path.abspath(__file__),
+                  [("time", c) for c in (512, 0, 128, 256)])
         return
     mode, arg = sys.argv[1], sys.argv[2]
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("DTX_JAX_CACHE", "/tmp/dtx_jax_cache"))
+    from distributed_tensorflow_example_tpu.runtime.device import (
+        enable_compilation_cache)
+    enable_compilation_cache()
     try:
         if mode == "time":
             out = timed_cell(int(arg))
@@ -132,9 +128,7 @@ def main() -> None:
             raise SystemExit(f"unknown mode {mode!r}")
         print(json.dumps(out), flush=True)
     except Exception as e:  # noqa: BLE001 — OOM at compile is a finding
-        print(json.dumps({"mode": mode, "arg": arg,
-                          "error": f"{type(e).__name__}: {str(e)[:250]}"}),
-              flush=True)
+        fail({"mode": mode, "arg": arg}, e)
 
 
 if __name__ == "__main__":

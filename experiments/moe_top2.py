@@ -18,11 +18,12 @@ Usage: python experiments/moe_top2.py TOPK CAPACITY
 
 import json
 import os
-import subprocess
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from _cells import fail, run_cells  # noqa: E402
 
 CELLS = [(1, 1.25), (2, 1.0), (2, 1.25), (2, 2.0)]
 
@@ -86,23 +87,16 @@ def measure(top_k: int, capacity: float, *, batch=64, steps=20,
 
 def main() -> None:
     if sys.argv[1:2] == ["--all"]:
-        env = dict(os.environ,
-                   DTX_JAX_CACHE=os.environ.get("DTX_JAX_CACHE",
-                                                "/tmp/dtx_jax_cache"))
-        for k, c in CELLS:
-            subprocess.run([sys.executable, os.path.abspath(__file__),
-                            str(k), str(c)], env=env, check=False)
+        run_cells(os.path.abspath(__file__), CELLS)
         return
     k, c = int(sys.argv[1]), float(sys.argv[2])
-    import jax
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("DTX_JAX_CACHE", "/tmp/dtx_jax_cache"))
+    from distributed_tensorflow_example_tpu.runtime.device import (
+        enable_compilation_cache)
+    enable_compilation_cache()
     try:
         print(json.dumps(measure(k, c)), flush=True)
     except Exception as e:  # noqa: BLE001
-        print(json.dumps({"top_k": k, "capacity_factor": c,
-                          "error": f"{type(e).__name__}: {str(e)[:200]}"}),
-              flush=True)
+        fail({"top_k": k, "capacity_factor": c}, e)
 
 
 if __name__ == "__main__":

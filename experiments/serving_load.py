@@ -1218,6 +1218,9 @@ def main(argv=None) -> int:
                     "always runs its own armed leg + seeded-violation "
                     "probe)")
     args = ap.parse_args(argv)
+    from distributed_tensorflow_example_tpu.runtime.device import (
+        enable_compilation_cache)
+    enable_compilation_cache()
     if args.smoke and (args.weight_quant != "off"
                        or args.kv_cache_dtype != "auto"):
         ap.error("--smoke already runs its own fully quantized int8 "

@@ -122,14 +122,16 @@ def test_force_direction_overrides(tmp_path, capsys):
 
 
 def test_real_capture_round_trip():
-    """The actual BENCH_r04 -> r05 captures must load and compare
-    clean (they did not regress — that is why r05 landed)."""
-    old = os.path.join(ROOT, "BENCH_r04.json")
-    new = os.path.join(ROOT, "BENCH_r05.json")
-    if not (os.path.exists(old) and os.path.exists(new)):
-        pytest.skip("BENCH captures not present")
-    rows = bench_diff.diff(bench_diff.load_metrics(old),
-                           bench_diff.load_metrics(new),
-                           tolerance=0.2)
+    """A pair of driver-shaped captures (tests/fixtures: the round-4 and
+    round-5 chip records' metric lines behind a log line the parser
+    must skip) must load and compare clean — r05 did not regress on
+    r04, that is why it landed."""
+    fixtures = os.path.join(ROOT, "tests", "fixtures")
+    rows = bench_diff.diff(
+        bench_diff.load_metrics(
+            os.path.join(fixtures, "bench_capture_old.json")),
+        bench_diff.load_metrics(
+            os.path.join(fixtures, "bench_capture_new.json")),
+        tolerance=0.2)
     assert rows
     assert not [r for r in rows if r["status"] == "regression"]

@@ -320,3 +320,47 @@ def test_flash_kwargs_rejected_by_xla_impl():
     with pytest.raises(ValueError, match="impl='flash'"):
         multi_head_attention(q, k, v, impl="xla",
                              flash_kwargs={"block_q": 256})
+
+
+# ---------------------------------------------------------------------------
+# under a mesh jit partitions: the kernel shard_maps itself (PR 21)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_kernel_under_an_ambient_mesh_matches_unsharded(cpu8, masked):
+    """Batch over data=2, heads over model=2: the per-shard kernel is
+    the whole computation, so values and gradients equal the unsharded
+    call's (the TPU-side reason — Mosaic kernels cannot be
+    auto-partitioned — is compiled in tests/test_tpu_compile.py)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from distributed_tensorflow_example_tpu.config import MeshShape
+    from distributed_tensorflow_example_tpu.parallel.mesh import (
+        AxisNames, build_mesh)
+
+    mesh = build_mesh(MeshShape(data=2, model=2), devices=cpu8[:4])
+    rs = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rs.randn(4, 128, 2, 64).astype(np.float32) * 0.4)
+               for _ in range(3))
+    mask = np.ones((4, 128), np.int32)
+    mask[1, 100:] = 0
+    mask = jnp.asarray(mask) if masked else None
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, mask=mask, causal=True)
+        return jnp.sum(out * out)
+
+    def on_mesh(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    sh = NamedSharding(mesh, P(AxisNames.BATCH, None, AxisNames.MODEL))
+    got = jax.jit(on_mesh)(*(jax.device_put(x, sh) for x in (q, k, v)))
+    # the manual region shard_map lowers to is there (not a silent
+    # bare call that interpret mode would let the partitioner take)
+    assert "manual_computation" in jax.jit(on_mesh).lower(q, k, v).as_text()
+    for g, w in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-5, atol=1e-5)

@@ -78,7 +78,7 @@ def test_paged_kernel_matches_gather_reference():
     table holds null/stale entries past its pos."""
     rs = np.random.RandomState(1)
     b, h, d, bs, nb = 2, 2, 64, 128, 3
-    assert paged_tile_friendly(bs, d)
+    assert paged_tile_friendly(bs, h, d)
     n = 1 + b * nb
     q = rs.randn(b, h, d).astype(np.float32)
     kp, vp = _rand_pool(rs, n, bs, h, d)
@@ -105,7 +105,7 @@ def test_paged_kernel_matches_gather_on_verify_expanded_rows():
     reference sees them as ordinary independent rows."""
     rs = np.random.RandomState(2)
     b, kk, h, d, bs, nb = 2, 4, 2, 64, 128, 3
-    assert paged_tile_friendly(bs, d)
+    assert paged_tile_friendly(bs, h, d)
     n = 1 + b * nb
     kp, vp = _rand_pool(rs, n, bs, h, d)
     q = rs.randn(b * kk, h, d).astype(np.float32)
@@ -384,6 +384,24 @@ def test_paged_cold_greedy_parity(paged_dir, tiny_model):
         eng.close()
     for p, g in zip(prompts, got):
         assert g == _oracle(m, params, p)
+
+
+def test_each_program_compiles_once_across_requests(paged_dir):
+    """Sequential requests ride ONE executable per program: the pool
+    the engine starts from must key the jit cache exactly like the
+    pool a program hands back. (Found on the chip in PR 21: the fresh
+    pool was uncommitted, so the second request ever served paid a
+    second full compile of the prefill program — 33 s of
+    time-to-first-token at GPT-2-small.)"""
+    sw = load_stepwise(paged_dir)
+    eng = GenerationEngine(sw, prefix_cache=False).start()
+    try:
+        for p in _prompts(3, seed=21):
+            eng.generate(p, timeout=120)
+    finally:
+        eng.close()
+    assert sw._prefill._cache_size() == 1
+    assert sw._decode._cache_size() == 1
 
 
 def test_exact_prefix_hit_skips_prefill_and_keeps_parity(paged_dir,

@@ -121,7 +121,7 @@ def test_int8_paged_kernel_matches_gather_reference():
     mode on CPU — tier-1 covers both int8 impls (CI satellite)."""
     rs = np.random.RandomState(2)
     b, h, d, bs, nb = 2, 2, 64, 128, 3
-    assert paged_tile_friendly(bs, d)
+    assert paged_tile_friendly(bs, h, d)
     n = 1 + b * nb
     kq, ks = _quantized_pool(rs, n, bs, h, d)
     vq, vs = _quantized_pool(rs, n, bs, h, d)

@@ -1,0 +1,224 @@
+"""The main path's Pallas kernels, compiled for a described v5e.
+
+Interpret mode (what every other CPU test runs) cannot see what the TPU
+compiler refuses — a block whose lane width is not a 128-multiple, a
+Mosaic kernel handed to the automatic partitioner. The compiler is
+installed here and compiles for a chip that is described and not
+attached (on-chip-measurement guide §2 step 3), so the kernels of the
+main path are compiled at GPT-2-small widths (12 heads x 64) as tier-1
+tests: a refusal shows here at no chip time. Nothing runs; a compile
+that passes is not a chip run.
+"""
+
+import functools
+import importlib
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # compiler logs off /tmp
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from distributed_tensorflow_example_tpu.parallel.mesh import AxisNames
+
+# the modules, not the same-named functions ops.pallas re-exports
+decode_mod = importlib.import_module(
+    "distributed_tensorflow_example_tpu.ops.pallas.decode_attention")
+flash_mod = importlib.import_module(
+    "distributed_tensorflow_example_tpu.ops.pallas.flash_attention")
+
+H, D = 12, 64                      # GPT-2-small heads x head_dim
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def chip():
+    """Devices of a described v5e 2x2 host; kernels lower for the chip
+    (``_interpret`` held false — the code under test asks
+    ``jax.default_backend()``, which is the CPU here) with the
+    persistent compilation cache off: such a compile can be written to
+    it but never read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler installed
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    mp = pytest.MonkeyPatch()
+    mp.setattr(decode_mod, "_interpret", lambda: False)
+    mp.setattr(flash_mod, "_interpret", lambda: False)
+    yield list(topo.devices)
+    mp.undo()
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def compile_text(fn, *specs) -> str:
+    """Compile ``fn`` for the shardings its specs carry; the text of the
+    compiled program. Raises what the chip's compiler would raise."""
+    return jax.jit(fn).lower(*specs).compile().as_text()
+
+
+def on(device_or_sharding, shape, dtype=BF16):
+    sh = device_or_sharding
+    if not isinstance(sh, jax.sharding.Sharding):
+        sh = SingleDeviceSharding(sh)
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+
+
+# ---------------------------------------------------------------------------
+# flash attention: the train step's kernels
+# ---------------------------------------------------------------------------
+
+def _flash_loss(q, k, v, mask, *, causal, bwd_variant):
+    out = flash_mod.flash_attention(
+        q, k, v, mask=mask if not causal else None, causal=causal,
+        bwd_variant=bwd_variant)
+    return jnp.sum(out.astype(jnp.float32))
+
+
+@pytest.mark.parametrize("name,batch,seq,causal,bwd", [
+    ("fwd_causal_s512", 32, 512, True, None),
+    ("bwd_split_causal_s512", 32, 512, True, "split"),
+    ("bwd_fused_causal_s512", 32, 512, True, "fused"),
+    ("fwd_masked_s4096", 4, 4096, False, None),
+])
+def test_flash_kernels_compile(chip, name, batch, seq, causal, bwd):
+    assert flash_mod.kernel_engages(seq, D)
+    fn = functools.partial(_flash_loss, causal=causal,
+                           bwd_variant=bwd or "split")
+    if bwd:
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    qkv = on(chip[0], (batch, seq, H, D))
+    text = compile_text(fn, qkv, qkv, qkv,
+                        on(chip[0], (batch, seq), jnp.int32))
+    # fwd alone is one kernel; fwd + split bwd three, fused bwd two
+    assert text.count("tpu_custom_call") >= {None: 1, "split": 3,
+                                             "fused": 2}[bwd]
+
+
+def test_flash_partitions_itself_over_a_four_chip_mesh(chip):
+    """What ``cli.train --mesh data=4 --attention flash`` compiles: the
+    batch sharded over four chips under the mesh SyncReplicas makes
+    ambient. Bare, the TPU lowering refuses ("Mosaic kernels cannot be
+    automatically partitioned"); the kernel must shard_map itself."""
+    mesh = Mesh(np.asarray(chip).reshape(4, 1, 1, 1, 1, 1), AxisNames.ALL)
+    batch_sh = NamedSharding(mesh, P(AxisNames.BATCH))
+
+    def step(q, k, v):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return jax.grad(functools.partial(
+                _flash_loss, mask=None, causal=True,
+                bwd_variant="split"), argnums=(0, 1, 2))(q, k, v)
+
+    qkv = on(batch_sh, (32, 512, H, D))
+    text = compile_text(step, qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
+    # each chip runs the kernel on its own 8 rows: [8*12, 512, 64]
+    assert f"bf16[{8 * H},512,{D}]" in text
+
+
+# ---------------------------------------------------------------------------
+# decode attention: the serving step's kernels
+# ---------------------------------------------------------------------------
+
+SLOTS, T, POOL_BLOCKS, BS = 8, 256, 64, 128
+
+
+def _slab_specs(dev, heads=H, head_dim=D):
+    kv = on(dev, (SLOTS, T, heads, head_dim))
+    row = on(dev, (SLOTS,), jnp.int32)
+    return on(dev, (SLOTS, heads, head_dim)), kv, kv, row, row
+
+
+def _paged_specs(dev, heads=H, head_dim=D, dtype=BF16):
+    pool = on(dev, (POOL_BLOCKS, BS, heads, head_dim), dtype)
+    row = on(dev, (SLOTS,), jnp.int32)
+    specs = (on(dev, (SLOTS, heads, head_dim)), pool, pool,
+             on(dev, (SLOTS, T // BS), jnp.int32), row, row)
+    if dtype == jnp.int8:
+        scale = on(dev, (POOL_BLOCKS, BS), jnp.float32)
+        specs += (scale, scale)
+    return specs
+
+
+@pytest.mark.parametrize("name", ["slab", "paged", "paged_int8"])
+def test_decode_kernels_compile(chip, name):
+    if name == "slab":
+        assert decode_mod.tile_friendly(T, H, D)
+        text = compile_text(decode_mod._dispatch, *_slab_specs(chip[0]))
+    else:
+        assert decode_mod.paged_tile_friendly(BS, H, D)
+        dtype = jnp.int8 if name == "paged_int8" else BF16
+        text = compile_text(decode_mod._paged_dispatch,
+                            *_paged_specs(chip[0], dtype=dtype))
+    assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# the predicates say what the compiler says
+# ---------------------------------------------------------------------------
+
+def _compiles(fn, *specs) -> bool:
+    try:
+        compile_text(fn, *specs)
+    except Exception:  # noqa: BLE001 — any refusal of the TPU lowering
+        return False
+    return True
+
+
+def _raw_flash_fwd(chip, block_k):
+    q3 = on(chip[0], (4 * H, 512, D))
+    return _compiles(
+        functools.partial(flash_mod._fwd, heads=H, blk_q=128,
+                          blk_k=block_k, causal=False),
+        q3, q3, q3, on(chip[0], (4, 512), jnp.int32))
+
+
+@pytest.mark.parametrize("kernel,shape,accepted", [
+    ("slab", dict(heads=12, head_dim=64), True),
+    ("slab", dict(heads=12, head_dim=96), False),
+    ("paged", dict(heads=12, head_dim=64), True),
+    ("paged", dict(heads=12, head_dim=96), False),
+    ("flash", dict(block_k=128), True),
+    ("flash", dict(block_k=64), False),
+])
+def test_predicates_agree_with_the_compiler(chip, kernel, shape, accepted):
+    """Each predicate against the RAW kernel (its dispatcher bypassed):
+    what it admits compiles, what it refuses the compiler refuses too —
+    and a forced ``impl="pallas"`` on a refused shape raises the
+    predicate's ValueError before any lowering is tried."""
+    if kernel == "flash":
+        assert flash_mod.kernel_engages(512, D, **shape) is accepted
+        assert _raw_flash_fwd(chip, **shape) is accepted
+        return
+    h, d = shape["heads"], shape["head_dim"]
+    if kernel == "slab":
+        assert decode_mod.tile_friendly(T, h, d) is accepted
+        assert _compiles(decode_mod._dispatch,
+                         *_slab_specs(chip[0], h, d)) is accepted
+    else:
+        assert decode_mod.paged_tile_friendly(BS, h, d) is accepted
+        assert _compiles(decode_mod._paged_dispatch,
+                         *_paged_specs(chip[0], h, d)) is accepted
+    if not accepted:
+        q = jnp.zeros((1, h, d))
+        with pytest.raises(ValueError, match="head dim"):
+            if kernel == "slab":
+                decode_mod.decode_attention(
+                    q, jnp.zeros((1, 128, h, d)), jnp.zeros((1, 128, h, d)),
+                    pos=jnp.int32(0), pad=jnp.zeros((1,), jnp.int32),
+                    impl="pallas")
+            else:
+                decode_mod.paged_decode_attention(
+                    q, jnp.zeros((2, BS, h, d)), jnp.zeros((2, BS, h, d)),
+                    block_tables=jnp.zeros((1, 1), jnp.int32),
+                    pos=jnp.zeros((1,), jnp.int32),
+                    pad=jnp.zeros((1,), jnp.int32), impl="pallas")

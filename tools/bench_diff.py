@@ -1,18 +1,19 @@
 """bench_diff — machine-checkable comparison of two bench result files.
 
-The BENCH_r01–r05 trajectory (and the bench gate itself) had no tool
+The driver's bench captures (and the bench gate itself) had no tool
 answering "did anything regress between these two runs?" — reviewers
 eyeballed JSON tails. This compares a baseline and a candidate file
 key by key with a per-key relative tolerance and exits 1 on any
 regression, so a TPU-window re-base (ROADMAP item 5) can gate on it:
 
-    python tools/bench_diff.py BENCH_r04.json BENCH_r05.json
+    python tools/bench_diff.py tests/fixtures/bench_capture_old.json \
+        tests/fixtures/bench_capture_new.json
     python tools/bench_diff.py old.json new.json --tolerance 0.15 \
         --key gpt_serving_tps=0.3 --json
 
 Accepted file shapes (auto-detected):
 
-- a ``BENCH_rNN.json`` capture: ``{"n", "cmd", "rc", "tail"}`` where
+- a driver capture: ``{"n", "cmd", "rc", "tail"}`` where
   ``tail`` holds bench.py's JSON lines (``{"metric", "value",
   "extra": {...}}``) — metrics and their ``extra`` keys are flattened
   into one ``{key: value}`` table;
