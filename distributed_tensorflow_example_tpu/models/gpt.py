@@ -931,10 +931,13 @@ class GPT:
         if alive is None:
             alive = jnp.ones((b,), bool)
         alive = jnp.asarray(alive) != 0
+        # named scopes: metadata only (the HLO's op_name), so a profile
+        # or a dump of the served decode program reads by part
         pos_ids = jnp.clip(pos - pad, 0, c.max_len - 1)
-        h, _ = self._embed(params, tok[:, None], pos_ids[:, None],
-                           rng=None, train=False)
-        h = h[:, 0]                                       # [B, hid]
+        with jax.named_scope("embed"):
+            h, _ = self._embed(params, tok[:, None], pos_ids[:, None],
+                               rng=None, train=False)
+            h = h[:, 0]                                   # [B, hid]
         rows = jnp.arange(b)
         pbid = bt[rows, pos // bs]                        # [B] physical
         off = pos % bs
@@ -945,43 +948,53 @@ class GPT:
                 lp, ck, cv, cks, cvs = xs
             else:
                 lp, ck, cv = xs
-            qkv = nn.dense(self._dequant(lp["qkv"]),
-                           nn.layernorm(lp["ln1"], h), dtype=self.dtype)
-            q, k, v = [x.reshape(b, c.heads, self.head_dim)
-                       for x in jnp.split(qkv, 3, axis=-1)]
+            with jax.named_scope("qkv"):
+                qkv = nn.dense(self._dequant(lp["qkv"]),
+                               nn.layernorm(lp["ln1"], h),
+                               dtype=self.dtype)
+                q, k, v = [x.reshape(b, c.heads, self.head_dim)
+                           for x in jnp.split(qkv, 3, axis=-1)]
             if quant:
                 # quantize-on-write: the new row's int8 bytes + scale,
                 # gated like the float write (dead rows rewrite old)
-                kq, ksc = quantize_kv_rows(k)
-                vq, vsc = quantize_kv_rows(v)
-                ck = ck.at[pbid, off].set(jnp.where(
-                    alive[:, None, None], kq, ck[pbid, off]))
-                cv = cv.at[pbid, off].set(jnp.where(
-                    alive[:, None, None], vq, cv[pbid, off]))
-                cks = cks.at[pbid, off].set(jnp.where(
-                    alive, ksc, cks[pbid, off]))
-                cvs = cvs.at[pbid, off].set(jnp.where(
-                    alive, vsc, cvs[pbid, off]))
-                ctx = paged_decode_attention(
-                    q, ck, cv, block_tables=bt, pos=pos, pad=pad,
-                    k_scale=cks, v_scale=cvs, impl=impl)
+                with jax.named_scope("cache_write"):
+                    kq, ksc = quantize_kv_rows(k)
+                    vq, vsc = quantize_kv_rows(v)
+                    ck = ck.at[pbid, off].set(jnp.where(
+                        alive[:, None, None], kq, ck[pbid, off]))
+                    cv = cv.at[pbid, off].set(jnp.where(
+                        alive[:, None, None], vq, cv[pbid, off]))
+                    cks = cks.at[pbid, off].set(jnp.where(
+                        alive, ksc, cks[pbid, off]))
+                    cvs = cvs.at[pbid, off].set(jnp.where(
+                        alive, vsc, cvs[pbid, off]))
+                with jax.named_scope("attention"):
+                    ctx = paged_decode_attention(
+                        q, ck, cv, block_tables=bt, pos=pos, pad=pad,
+                        k_scale=cks, v_scale=cvs, impl=impl)
             else:
-                k_w = jnp.where(alive[:, None, None],
-                                k.astype(ck.dtype), ck[pbid, off])
-                v_w = jnp.where(alive[:, None, None],
-                                v.astype(cv.dtype), cv[pbid, off])
-                ck = ck.at[pbid, off].set(k_w)
-                cv = cv.at[pbid, off].set(v_w)
-                ctx = paged_decode_attention(q, ck, cv, block_tables=bt,
-                                             pos=pos, pad=pad, impl=impl)
-            a = nn.dense(self._dequant(lp["o"]), ctx.reshape(b, c.hidden),
-                         dtype=self.dtype)
-            h = h + a.astype(h.dtype)
-            f = nn.dense(self._dequant(lp["ffn_in"]),
-                         nn.layernorm(lp["ln2"], h), dtype=self.dtype)
-            f = jax.nn.gelu(f.astype(jnp.float32)).astype(self.dtype)
-            f = nn.dense(self._dequant(lp["ffn_out"]), f, dtype=self.dtype)
-            h = h + f.astype(h.dtype)
+                with jax.named_scope("cache_write"):
+                    k_w = jnp.where(alive[:, None, None],
+                                    k.astype(ck.dtype), ck[pbid, off])
+                    v_w = jnp.where(alive[:, None, None],
+                                    v.astype(cv.dtype), cv[pbid, off])
+                    ck = ck.at[pbid, off].set(k_w)
+                    cv = cv.at[pbid, off].set(v_w)
+                with jax.named_scope("attention"):
+                    ctx = paged_decode_attention(
+                        q, ck, cv, block_tables=bt, pos=pos, pad=pad,
+                        impl=impl)
+            with jax.named_scope("projection"):
+                a = nn.dense(self._dequant(lp["o"]),
+                             ctx.reshape(b, c.hidden), dtype=self.dtype)
+                h = h + a.astype(h.dtype)
+            with jax.named_scope("mlp"):
+                f = nn.dense(self._dequant(lp["ffn_in"]),
+                             nn.layernorm(lp["ln2"], h), dtype=self.dtype)
+                f = jax.nn.gelu(f.astype(jnp.float32)).astype(self.dtype)
+                f = nn.dense(self._dequant(lp["ffn_out"]), f,
+                             dtype=self.dtype)
+                h = h + f.astype(h.dtype)
             return h, (ck, cv, cks, cvs) if quant else (ck, cv)
 
         if quant:
@@ -994,8 +1007,10 @@ class GPT:
             h, (ks, vs) = lax.scan(body, h,
                                    (stacked, pools["k"], pools["v"]))
             out_pools = {"k": ks, "v": vs}
-        h = nn.layernorm(params["ln_f"], h)
-        return self.lm_logits(params, h[:, None])[:, 0], out_pools
+        with jax.named_scope("head"):
+            h = nn.layernorm(params["ln_f"], h)
+            logits = self.lm_logits(params, h[:, None])[:, 0]
+        return logits, out_pools
 
     def decode_verify_batched_paged(self, params, stacked, pools,
                                     block_tables, tok, pos, pad,

@@ -58,6 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import secrets
+import sys
 import threading
 import time
 from collections import deque
@@ -299,8 +300,31 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+_annotation_cls = None
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation`` once this process has imported
+    JAX's profiler, else None: a process that never did (the fleet
+    router) has no profiler session a span could land in, and must not
+    pay JAX's import for one."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        mod = sys.modules.get("jax.profiler")
+        _annotation_cls = getattr(mod, "TraceAnnotation", None)
+    return _annotation_cls
+
+
 class _LiveSpan:
-    __slots__ = ("_rec", "_process", "_lane", "_name", "_args", "_t0")
+    """One live span: ring stamps on ``perf_counter`` (flight recorder,
+    ``/trace/export``, the stitcher), and for its duration a
+    ``jax.profiler.TraceAnnotation`` of the same name and args, so
+    inside a profiler session the span lands on the capture's
+    ``/host:CPU`` plane on the device planes' timebase. Outside a
+    session the annotation is one atomic check."""
+
+    __slots__ = ("_rec", "_process", "_lane", "_name", "_args", "_t0",
+                 "_ann")
 
     def __init__(self, rec, process, lane, name, args):
         self._rec = rec
@@ -309,14 +333,22 @@ class _LiveSpan:
         self._name = name
         self._args = args
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        cls = _annotation_cls or _profiler_annotation()
+        if cls is not None:
+            self._ann = cls(self._name, **self._args)
+            self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         self._rec.add(self._process, self._lane, self._name, self._t0,
-                      time.perf_counter(), self._args or None)
+                      t1, self._args or None)
         return False
 
 

@@ -149,6 +149,7 @@ def _dispatch(q, k, v, pos, pad):
         out_shape=jax.ShapeDtypeStruct((b, 1, h * d), v.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
+        name="decode_attn",
         interpret=_interpret(),
     )(pos, pad, q.reshape(b, 1, h * d), k.reshape(b, t, h * d),
       v.reshape(b, t, h * d))
@@ -349,6 +350,7 @@ def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
             (b, 1, h * d), q.dtype if quant else v_pool.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="paged_decode_attn",
         interpret=_interpret(),
     )(block_tables, pos, pad, *operands)
     return out.reshape(b, h, d)

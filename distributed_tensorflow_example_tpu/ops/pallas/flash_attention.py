@@ -216,6 +216,7 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
                         pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_fwd",
         interpret=_interpret(),
     )(*args)
     return o, L
@@ -405,6 +406,7 @@ def _bwd_fused(q3, k3, v3, do3, L, Dsum, mask2, *, heads: int, blk_q: int,
                         pltpu.VMEM((blk_k, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        name="flash_bwd_fused",
         interpret=_interpret(),
     )(*args)
     return dq, dk, dv
@@ -445,6 +447,7 @@ def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dq",
         interpret=_interpret(),
     )(*args)
 
@@ -476,6 +479,7 @@ def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
                         pltpu.VMEM((blk_k, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
+        name="flash_bwd_dkv",
         interpret=_interpret(),
     )(*args)
     return dq, dk, dv

@@ -904,20 +904,21 @@ class StepwiseGenerator:
         # donate ONLY the pool (the multi-megabyte operand): donating
         # the whole feature dict would warn per-call about the small
         # int arrays XLA can't alias into the outputs
-        def split(call):
+        # each wrapper carries its program's name, so the executed
+        # programs read jit_prefill / jit_decode / jit_verify /
+        # jit_prefill_chunk in a profiler capture and in compile events
+        # (the exported artifacts are untouched)
+        def split(call, name):
             def fn(pool, rest):
                 return call({**rest, **pool})
-            return fn
+            fn.__name__ = fn.__qualname__ = name
+            return jax.jit(fn, donate_argnums=(0,))
 
-        self._prefill = jax.jit(split(self._prefill_exp.call),
-                                donate_argnums=(0,))
-        self._decode = jax.jit(split(self._decode_exp.call),
-                               donate_argnums=(0,))
-        self._verify = (jax.jit(split(self._verify_exp.call),
-                                donate_argnums=(0,))
+        self._prefill = split(self._prefill_exp.call, "prefill")
+        self._decode = split(self._decode_exp.call, "decode")
+        self._verify = (split(self._verify_exp.call, "verify")
                         if self._verify_exp is not None else None)
-        self._chunk = (jax.jit(split(self._chunk_exp.call),
-                               donate_argnums=(0,))
+        self._chunk = (split(self._chunk_exp.call, "prefill_chunk")
                        if self._chunk_exp is not None else None)
 
     def make_pool(self) -> dict:

@@ -231,6 +231,7 @@ import uuid
 
 import numpy as np
 
+from .obs import compiles as obs_compiles
 from .obs.registry import SERVING_LATENCY_BUCKETS, Registry
 from .obs.trace import add_span, span
 from .runtime import faults
@@ -390,6 +391,35 @@ class _NoopCM:
 
 _SNAPSHOT_READS = _SnapshotReads()
 _NOOP_CM = _NoopCM()
+
+#: the phases that tile a working scheduler iteration, in order; each
+#: is one ``sched_<phase>`` span on lane ``scheduler`` and one
+#: ``serving_sched_<phase>_seconds_total`` counter
+SCHED_PHASES = ("housekeeping", "admit", "secure_blocks", "build_feats",
+                "dispatch", "wait_logits", "sample_emit")
+
+
+class _Phase:
+    """One scheduler phase: its span (entered inside, so the counter
+    covers the span) and its seconds counter, which counts with the
+    recorder off too."""
+
+    __slots__ = ("_counter", "_span", "_t0")
+
+    def __init__(self, counter, span_cm):
+        self._counter = counter
+        self._span = span_cm
+        self._t0 = 0.0
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._span.__exit__(*exc)
+        self._counter.inc(time.perf_counter() - self._t0)
+        return False
 
 
 class _GuardedAttr:
@@ -1285,6 +1315,25 @@ class GenerationEngine:
         # perf_counter stamp of the previous shared dispatch while any
         # slot survived it (scheduler-thread-only scalar)
         self._last_dispatch_t: float = 0.0
+        # where a working scheduler iteration's wall time goes, by
+        # phase (SCHED_PHASES tile it): wait_logits is the chip's
+        # share, the rest is the host's: what an operator without a
+        # profiler reads to see which of the two sets the pace
+        self._c_phase = {
+            f"sched_{ph}": reg.counter(
+                f"serving_sched_{ph}_seconds_total",
+                f"scheduler-thread seconds in phase {ph!r} of the "
+                "working iterations (the phases tile an iteration)")
+            for ph in SCHED_PHASES}
+        self._c_decode_kv_bytes = reg.counter(
+            "serving_decode_kv_bytes_total",
+            "bytes of K and V the live rows held, summed over shared "
+            "decode/verify dispatches (sum of pos x bytes per token): "
+            "the least the decode-attention kernels had to read")
+        # the compilations this engine's scheduler thread asks for
+        # (counted from _loop's first line on; here so that /metrics
+        # shows them at zero before that)
+        obs_compiles.counters(reg)
         # request-phase histograms register the AUDITED bucket set
         # (obs/registry.py SERVING_LATENCY_BUCKETS): sub-ms bounds for
         # the µs-scale queue/prefill phases the 1ms-floored default
@@ -1530,18 +1579,18 @@ class GenerationEngine:
         else:
             self.prefix_cache = None
         # bytes one cached token costs at this artifact's kv dtype
-        # (K+V payload + scale rows) — the /metrics-visible dtype
-        # signal next to the string in /stats
-        shape = m["pool_shape"]
-        tok_bytes = 2 * int(np.prod([shape[0], shape[3], shape[4]])) \
-            * np.dtype(m["cache_dtype"]).itemsize
-        if self.kv_cache_dtype == "int8":
-            tok_bytes += 2 * int(shape[0]) * 4       # f32 scale rows
+        # (K+V payload + int8 scale rows), taken from the pool this
+        # engine holds (every cache_* array is [L, rows, tokens, ...]):
+        # the /metrics-visible dtype signal next to the string in
+        # /stats, and what one live token costs a decode step to read
+        self._kv_token_bytes = sum(
+            int(v.nbytes) // (int(v.shape[1]) * int(v.shape[2]))
+            for v in self._pool.values())
         self._g_kv_bytes_per_token = reg.gauge(
             "serving_kv_cache_bytes_per_token",
             "bytes one cached token occupies at the artifact's "
             "kv_cache_dtype (K+V payload plus int8 scale rows)")
-        self._g_kv_bytes_per_token.set(tok_bytes)
+        self._g_kv_bytes_per_token.set(self._kv_token_bytes)
         # ---- thread-ownership sanitizer (debug): swap onto the
         # guarded subclass LAST so __init__'s own stores stay plain.
         # The owner tid arms when the scheduler thread starts; until
@@ -2044,6 +2093,9 @@ class GenerationEngine:
     def _loop(self) -> None:
         self._san_tid = threading.get_ident()
         self._heartbeat = time.monotonic()
+        # every served program is called from this thread: its
+        # compilations are this engine's (jit_compiles_total)
+        obs_compiles.count_on_this_thread(self.registry, self.process)
         while True:
             self._heartbeat = time.monotonic()
             with self._cond:
@@ -2066,11 +2118,13 @@ class GenerationEngine:
                 if not self._running:
                     return
             try:
-                self._apply_cancellations()
-                self._expire_deadlines()
-                self._update_pressure()
-                self._admit()
-                self._prefill_chunk_step()
+                with self._phase(span_name="sched_housekeeping"):
+                    self._apply_cancellations()
+                    self._expire_deadlines()
+                    self._update_pressure()
+                with self._phase(span_name="sched_admit"):
+                    self._admit()
+                    self._prefill_chunk_step()
                 if self._live:
                     self._shared_step()
             except Exception as e:
@@ -2129,6 +2183,14 @@ class GenerationEngine:
                         self.prefix_cache = PrefixCache(
                             self.blocks, self.block_size,
                             registry=self.registry)
+
+    def _phase(self, span_name: str) -> _Phase:
+        """``with self._phase(span_name="sched_admit"):`` — the phase's
+        span on the scheduler lane and its seconds counter around one
+        block (the keyword is what graftlint TRC01 reads the name by)."""
+        return _Phase(self._c_phase[span_name],
+                      span(span_name, process=self.process,
+                           lane="scheduler"))
 
     @scheduler_thread
     def _apply_cancellations(self) -> None:
@@ -3046,10 +3108,15 @@ class GenerationEngine:
                     # rules stay one-shot transients, p-rules resample
                     reg.raise_if_armed("engine.decode_step", index=idx,
                                        attempt=attempt)
+                # what the step's live rows hold in K and V (a dead
+                # row's pos is 0): the decode kernels' least traffic
+                kv_bytes = int(feats["pos"].sum()) * self._kv_token_bytes
                 with span(span_name, process=self.process,
                           lane="scheduler",
-                          slots=int(feats["alive"].sum())):
-                    out = call(feats)
+                          slots=int(feats["alive"].sum()),
+                          kv_bytes=kv_bytes):
+                    with self._phase(span_name="sched_dispatch"):
+                        out = call(feats)
                     # blocks on the result BEFORE adopting the returned
                     # pool: an async device fault surfaces here, and
                     # self._pool must still name the donated (deleted)
@@ -3057,10 +3124,12 @@ class GenerationEngine:
                     # engine-fatal rebuild — adopting first would judge
                     # the FAILED call's outputs alive and re-dispatch
                     # feats whose buffers were consumed
-                    logits = np.asarray(out["logits"])
-                    self._pool = {k: v for k, v in out.items()
-                                  if k.startswith("cache_")}
-                    return logits
+                    with self._phase(span_name="sched_wait_logits"):
+                        logits = np.asarray(out["logits"])
+                        self._pool = {k: v for k, v in out.items()
+                                      if k.startswith("cache_")}
+                self._c_decode_kv_bytes.inc(kv_bytes)
+                return logits
             except Exception as e:
                 if not self._pool_alive():
                     raise          # donated pool consumed: engine-fatal
@@ -3126,69 +3195,90 @@ class GenerationEngine:
     def _shared_step(self) -> None:
         """ONE batched dispatch for every live slot: the single-token
         decode step, or — when speculation is on and any slot drafted —
-        the K-token verify program (draftless slots ride at width 1)."""
+        the K-token verify program (draftless slots ride at width 1).
+        Four phases tile it on the scheduler lane (``_loop`` holds the
+        two before): sched_secure_blocks, sched_build_feats, the
+        dispatch (decode_step / verify_step with sched_dispatch and
+        sched_wait_logits inside) and sched_sample_emit."""
         if self.paged:
             if self._verify_width:
-                self._propose_drafts()
-            # secure every live row's write span first: allocate-on-
-            # write at block boundaries, copy-on-write on shared blocks.
-            # A row that cannot get a block fails ALONE — its neighbors
-            # still step; a SPEC row that cannot get its draft span
-            # drops the drafts first (degrading to the normal step is
-            # strictly better than dying for an optimization).
-            for s in list(self._live.values()):
-                try:
-                    try:
-                        self._ensure_write_block(s, 1 + len(s.draft))
-                    except BlocksExhaustedError:
-                        if not s.draft:
-                            raise
-                        span_end = s.pos + len(s.draft)
-                        s.draft = []
-                        self._release_trailing_blocks(s, span_end)
-                        self._ensure_write_block(s, 1)
-                except BlocksExhaustedError as e:
-                    self._fail_slot(s, BlocksExhaustedError(
-                        f"out of cache blocks mid-decode after "
-                        f"{len(s.tokens)} tokens: {e}"))
-                except Exception as e:
-                    # e.g. an injected pool.alloc fault: quarantine the
-                    # one row whose write target failed (the pool-
-                    # consuming case — a failed COW copy — escalates)
-                    if not self._pool_alive():
-                        raise
-                    self._fail_slot(s, PoisonedRequestError(
-                        f"request {s.req.request_id}: cache write-"
-                        f"block allocation failed "
-                        f"({type(e).__name__}: {e})"))
+                with self._phase(span_name="sched_build_feats"):
+                    self._propose_drafts()
+            with self._phase(span_name="sched_secure_blocks"):
+                self._secure_write_blocks()
             if not self._live:
                 self._last_dispatch_t = 0.0
                 return
-        # decode-stall accounting: slots that survived the previous
-        # shared dispatch experienced everything since its end —
-        # monolithic prefills, prefill chunks, admissions — as stall;
-        # chunked prefill exists to bound this histogram's tail
-        if self._last_dispatch_t:
-            self._h_decode_stall.observe(
-                time.perf_counter() - self._last_dispatch_t)
-        use_verify = any(s.draft for s in self._live.values())
+        with self._phase(span_name="sched_build_feats"):
+            # decode-stall accounting: slots that survived the previous
+            # shared dispatch experienced everything since its end —
+            # monolithic prefills, prefill chunks, admissions — as
+            # stall; chunked prefill exists to bound this histogram's
+            # tail
+            if self._last_dispatch_t:
+                self._h_decode_stall.observe(
+                    time.perf_counter() - self._last_dispatch_t)
+            use_verify = any(s.draft for s in self._live.values())
+            if use_verify:
+                self._c_spec_proposed.inc(
+                    sum(len(s.draft) for s in self._live.values()))
+                feats = self._build_verify_feats()
+            else:
+                feats = self._build_step_feats()
+        t0 = time.perf_counter()
         if use_verify:
-            self._c_spec_proposed.inc(
-                sum(len(s.draft) for s in self._live.values()))
-            feats = self._build_verify_feats()
-            t0 = time.perf_counter()
             logits = self._dispatch_decode(
                 feats, call=self.sw.verify,
                 rebuild=self._build_verify_feats,
                 span_name="verify_step")
         else:
-            feats = self._build_step_feats()
-            t0 = time.perf_counter()
             logits = self._dispatch_decode(feats)
         if logits is None:
             self._last_dispatch_t = 0.0
             return
-        self._retry.observe(time.perf_counter() - t0)
+        with self._phase(span_name="sched_sample_emit"):
+            self._retry.observe(time.perf_counter() - t0)
+            self._sample_emit(logits, use_verify)
+
+    @scheduler_thread
+    def _secure_write_blocks(self) -> None:
+        """Secure every live row's write span before the dispatch:
+        allocate-on-write at block boundaries, copy-on-write on shared
+        blocks. A row that cannot get a block fails ALONE — its
+        neighbors still step; a SPEC row that cannot get its draft span
+        drops the drafts first (degrading to the normal step is
+        strictly better than dying for an optimization)."""
+        for s in list(self._live.values()):
+            try:
+                try:
+                    self._ensure_write_block(s, 1 + len(s.draft))
+                except BlocksExhaustedError:
+                    if not s.draft:
+                        raise
+                    span_end = s.pos + len(s.draft)
+                    s.draft = []
+                    self._release_trailing_blocks(s, span_end)
+                    self._ensure_write_block(s, 1)
+            except BlocksExhaustedError as e:
+                self._fail_slot(s, BlocksExhaustedError(
+                    f"out of cache blocks mid-decode after "
+                    f"{len(s.tokens)} tokens: {e}"))
+            except Exception as e:
+                # e.g. an injected pool.alloc fault: quarantine the
+                # one row whose write target failed (the pool-
+                # consuming case — a failed COW copy — escalates)
+                if not self._pool_alive():
+                    raise
+                self._fail_slot(s, PoisonedRequestError(
+                    f"request {s.req.request_id}: cache write-"
+                    f"block allocation failed "
+                    f"({type(e).__name__}: {e})"))
+
+    @scheduler_thread
+    def _sample_emit(self, logits: np.ndarray, use_verify: bool) -> None:
+        """After a shared dispatch: the step counters, each live row's
+        sample (or its accepted draft run) through :meth:`_emit`, and
+        the hint and stall stamps the next iteration reads."""
         with self.registry.atomic():
             if use_verify:
                 self._c_verify_steps.inc()
@@ -3377,6 +3467,16 @@ class GenerationEngine:
             "slo_served": c("serving_slo_served_total"),
             "slo_good": c("serving_slo_good_total"),
             "goodput_tokens": c("serving_goodput_tokens_total"),
+            # where the scheduler thread's working time went, by phase
+            # (wait_logits is the chip's share), the K/V bytes the
+            # decode steps' live rows held, and this engine's compilations
+            "sched_phase_seconds": {
+                ph: round(c(f"serving_sched_{ph}_seconds_total"), 6)
+                for ph in SCHED_PHASES},
+            "decode_kv_bytes": c("serving_decode_kv_bytes_total"),
+            "jit_compiles": c("jit_compiles_total"),
+            "jit_compile_s": round(
+                c("jit_compile_seconds_total"), 3),
             "latency_p50_ms": round(percentile(lat, 50) * 1e3, 2),
             "latency_p95_ms": round(percentile(lat, 95) * 1e3, 2),
             "latency_p99_ms": round(percentile(lat, 99) * 1e3, 2),

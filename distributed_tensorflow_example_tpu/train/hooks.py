@@ -150,16 +150,11 @@ class CheckpointSaverHook(Hook):
         return False
 
     def _save(self, trainer, step: int) -> None:
-        """One checkpoint save: span on the trainer's checkpoint trace
-        lane + the registry counter (hooks read the counter through
-        ``trainer.registry`` — get-or-create, so a bare-mock trainer in
-        tests simply skips it)."""
+        """One checkpoint save, a span on the trainer's checkpoint
+        trace lane."""
         with span("checkpoint_save", process="training",
                   lane="checkpoint", step=step):
             self.manager.save(trainer.state, step)
-        reg = getattr(trainer, "registry", None)
-        if reg is not None:
-            reg.counter("train_checkpoints_saved_total").inc()
 
     def after_step(self, trainer, step, metrics):
         if self._due(step):
@@ -241,12 +236,6 @@ class AnomalyPolicyHook(Hook):
         if metrics is None or not self.wants_metrics(step):
             return
         count = int(metrics.get("anomaly_count", 0))
-        reg = getattr(trainer, "registry", None)
-        if reg is not None:
-            # the device-cumulative count, surfaced at the cadence the
-            # metrics were materialized anyway — /metrics-visible
-            # without adding a host sync
-            reg.gauge("train_anomaly_count").set(count)
         if count <= self.observed:
             # every step up to here verified finite: a future rollback
             # must not land past this point, or the anomalous window

@@ -109,31 +109,15 @@ class Trainer:
                                  debug_checks=config.obs.debug_checks,
                                  anomaly_policy=config.on_anomaly)
 
-        # telemetry registry (obs/registry.py): the trainer-side
-        # counters live here — hooks reach them through
-        # ``trainer.registry`` (counter() is get-or-create), and the
-        # tier-1 dead-counter lint sees them process-wide. Registered
-        # up front so a run that never checkpoints still EXPOSES the
-        # checkpoint counter at zero instead of hiding it.
+        # telemetry registry (obs/registry.py). The trainer serves no
+        # /metrics page, so it keeps only what something reads: the
+        # data-wait histogram (the benchmark's train_data_wait_ms, a
+        # snapshot at each edge of its window). Steps, saves, rollbacks
+        # and anomalies are in the JSONL log and on the trace lanes.
         self.registry = Registry(namespace="training")
-        self._c_steps = self.registry.counter(
-            "train_steps_total", "optimizer steps completed")
-        self._c_ckpt_saves = self.registry.counter(
-            "train_checkpoints_saved_total", "checkpoint saves issued")
-        self._c_rollbacks = self.registry.counter(
-            "train_rollbacks_total",
-            "anomaly rollbacks performed (--on_anomaly rollback)")
-        self._g_anomalies = self.registry.gauge(
-            "train_anomaly_count",
-            "cumulative on-device anomaly count (observed at the "
-            "metrics cadence)")
         self._h_data_wait = self.registry.histogram(
             "train_data_wait_seconds",
             "host time blocked on the data loader per dispatch")
-        self._h_dispatch = self.registry.histogram(
-            "train_dispatch_seconds",
-            "host time to enqueue one step dispatch (async — device "
-            "time only with --step_timing)")
 
         self.ckpt_manager = (
             CheckpointManager(config.checkpoint.directory,
@@ -144,8 +128,7 @@ class Trainer:
                               sharded=config.checkpoint.sharded)
             if config.checkpoint.directory else None)
         self.metrics_logger = MetricsLogger(config.obs.metrics_path,
-                                            tb_logdir=config.obs.tb_logdir,
-                                            registry=self.registry)
+                                            tb_logdir=config.obs.tb_logdir)
 
         self.process_index = (jax.process_index() if process_index is None
                               else process_index)
@@ -435,13 +418,11 @@ class Trainer:
                     state, device_metrics = self.sync.step(state, batch)
                     t_s1 = time.perf_counter()
                     step += 1
-                # dispatch-side span/histogram: host time to ENQUEUE the
-                # step (the loop is async — device time only shows here
+                # dispatch-side span: host time to ENQUEUE the step
+                # (the loop is async — device time only shows here
                 # under --step_timing, where the block lands below)
-                self._h_dispatch.observe(t_s1 - t_s0)
                 add_span("step_dispatch", t_s0, t_s1,
                          process="training", lane="step", step=step)
-                self._c_steps.inc(step - step_before)
                 if timing:
                     jax.block_until_ready(state.params)
                     self.last_dispatch_ms = (time.perf_counter() - t0) * 1e3
@@ -608,7 +589,6 @@ class Trainer:
             log.warning("rollback: discarded rejected-trajectory "
                         "checkpoint step(s) %s", discarded)
         loader = self._loader(start_step=target)
-        self._c_rollbacks.inc()
         log.warning("rollback: restored verified checkpoint step %d "
                     "(training was at step %d); data stream "
                     "fast-forwarded to match", target, step)

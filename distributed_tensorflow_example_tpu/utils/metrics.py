@@ -26,19 +26,11 @@ class MetricsLogger:
     scalar, one-level-nested dicts flatten to ``outer/inner`` tags."""
 
     def __init__(self, path: str | None = None, *, also_stdout: bool = False,
-                 tb_logdir: str | None = None, registry=None):
+                 tb_logdir: str | None = None):
         self.path = path
         self.also_stdout = also_stdout
         self._f: TextIO | None = None
         self._tb = None
-        # optional obs.registry.Registry: exposes how many structured
-        # records this sink has written (a silent-death JSONL stream —
-        # disk full, wrong path — shows up as a flatlined counter on
-        # /metrics instead of an empty file discovered post-mortem)
-        self._c_records = (registry.counter(
-            "metrics_records_written_total",
-            "structured JSONL records written by MetricsLogger")
-            if registry is not None else None)
         if jax.process_index() == 0:
             if path:
                 os.makedirs(os.path.dirname(os.path.abspath(path)),
@@ -76,8 +68,6 @@ class MetricsLogger:
         line = json.dumps(record, default=float)
         if self._f is not None:
             self._f.write(line + "\n")
-            if self._c_records is not None:
-                self._c_records.inc()
         if self._tb is not None and "step" in record:
             scalars = self._flatten_scalars(record)
             if scalars:

@@ -345,24 +345,33 @@ def test_arm_always_on_never_clears_an_active_capture():
         set_recorder(old)
 
 
+#: per-call ceiling of the armed span path, generous on purpose: the
+#: path measures ~3 us (one lock, a deque append, and since PR 24 a
+#: profiler annotation's inactive check) on an idle core and is timed
+#: here under six xdist workers; what guards the engine's cost per step
+#: is deterministic (tests/test_sched_tracing.py: spans per step do not
+#: depend on the live slots), this only catches a path grown 10x
+ARMED_SPAN_CEILING_S = 40e-6
+
+
 def test_armed_recorder_overhead_within_budget(fresh_recorder):
     """The sampled-ON twin of the disabled-path guard: with the
-    always-on flight-recorder ring armed, span()/add_span() must stay
-    under the same 2 µs/call budget (measured ~1.7 µs here — one lock
-    + deque append; best-of-5 loops reject scheduler noise)."""
+    always-on flight-recorder ring armed, span()/add_span() stay under
+    a per-call ceiling that holds on a loaded CPU (best of 7 loops
+    rejects scheduler noise), and every call is recorded."""
     rec = fresh_recorder
     rec.start()
-    n = 5000
+    n = 2000
     best = float("inf")
-    for _ in range(5):
+    for _ in range(7):
         t0 = time.perf_counter()
         for _ in range(n):
             with span("prefill", lane="slot0", request_id="r"):
                 pass
             add_span("decode", 0.0, 1.0, lane="slot0")
         best = min(best, time.perf_counter() - t0)
-    assert rec.spans_recorded == 5 * 2 * n
-    assert best / (2 * n) < 2e-6, \
+    assert rec.spans_recorded == 7 * 2 * n
+    assert best / (2 * n) < ARMED_SPAN_CEILING_S, \
         f"armed span path too slow: {best / (2 * n) * 1e6:.2f} µs/call"
 
 
@@ -489,8 +498,8 @@ def test_quantile_edge_cases_pinned():
 def test_trainer_registry_and_trace_lanes(tmp_path):
     """The trainer side of the telemetry story: train() with
     --trace_path dumps a Perfetto-loadable timeline with data/step/
-    checkpoint lanes, and the trainer registry holds the step /
-    checkpoint / JSONL-record counters."""
+    checkpoint lanes, and the trainer registry holds the one metric
+    something reads, the data-wait histogram."""
     from distributed_tensorflow_example_tpu.config import (
         CheckpointConfig, DataConfig, MeshShape, ObservabilityConfig,
         OptimizerConfig, TrainConfig)
@@ -522,13 +531,12 @@ def test_trainer_registry_and_trace_lanes(tmp_path):
     finally:
         tr.close()
 
+    # the trainer serves no /metrics page: its registry keeps only what
+    # something reads (the benchmark's train_data_wait_ms); steps, saves
+    # and rollbacks are in the JSONL log and on the trace lanes below
     snap = tr.registry.snapshot()
-    assert snap["train_steps_total"]["value"] == 4
-    assert snap["train_checkpoints_saved_total"]["value"] >= 2
-    assert snap["metrics_records_written_total"]["value"] > 0
+    assert set(snap) == {"train_data_wait_seconds"}
     assert snap["train_data_wait_seconds"]["count"] == 4
-    assert snap["train_dispatch_seconds"]["count"] == 4
-    assert snap["train_rollbacks_total"]["value"] == 0  # registered
 
     with open(trace_path) as f:
         trace = json.load(f)
