@@ -127,10 +127,10 @@ def _flash_step_flops(cfg, model, model_name: str, batch: int,
     return attention_train_flops(
         batch, seq, mc.hidden, mc.layers,
         causal=model_name.startswith("gpt"),
-        # count what EXECUTES: fused silently degrades to split past
-        # the VMEM slab limit
+        # count what EXECUTES: an unset lever is the schedule's choice,
+        # and fused silently degrades to split past the VMEM slab limit
         bwd_variant=effective_bwd_variant(
-            seq, head_dim, fkw.get("bwd_variant", "split")))
+            seq, head_dim, fkw.get("bwd_variant"), cfg.dtype))
 
 
 def robust_time(timed_pass, *, steps: int, flops=None, peak=None,

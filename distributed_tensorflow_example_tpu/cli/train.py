@@ -277,23 +277,27 @@ def build_parser() -> argparse.ArgumentParser:
                         "(flash = Pallas kernel, wins at long sequences)")
     p.add_argument("--attention_block_q", type=int, default=0,
                    help="flash kernel fwd Q-tile rows (multiple of 8; "
-                        "0 = kernel default 128); requires --attention "
-                        "flash — experiments/flash_sweep.py sweeps this")
+                        "0 = chosen by the kernel from S, D and dtype); "
+                        "requires --attention flash — "
+                        "experiments/flash_sweep.py sweeps this")
     p.add_argument("--attention_block_k", type=int, default=0,
                    help="flash kernel fwd K-tile columns (multiple of "
-                        "128; 0 = kernel default 128); requires "
+                        "128; 0 = chosen by the kernel); requires "
                         "--attention flash")
     p.add_argument("--attention_bwd_block", type=int, default=0,
                    help="flash kernel bwd tile for both streamed dims "
-                        "(multiple of 128; 0 = inherit the fwd tiles); "
-                        "requires --attention flash")
-    p.add_argument("--attention_bwd", default="split",
-                   choices=["split", "fused"],
-                   help="flash backward variant: split = two-kernel "
-                        "FA-2 decomposition; fused = one kernel "
-                        "computing dq+dk+dv (scores recomputed once, "
-                        "~29%% fewer bwd matmul FLOPs); requires "
+                        "(multiple of 128; 0 = the fwd tiles where one "
+                        "is set, else chosen by the kernel); requires "
                         "--attention flash")
+    p.add_argument("--attention_bwd", default="auto",
+                   choices=["auto", "split", "fused"],
+                   help="flash backward variant: auto = chosen by the "
+                        "kernel (fused while its dq slab fits VMEM); "
+                        "split = two-kernel FA-2 decomposition; fused = "
+                        "one kernel computing dq+dk+dv (scores "
+                        "recomputed once, ~29%% fewer bwd matmul FLOPs; "
+                        "runs split where the slab does not fit); split "
+                        "and fused require --attention flash")
     p.add_argument("--prng_impl", default="threefry2x32",
                    choices=["threefry2x32", "rbg", "unsafe_rbg"],
                    help="PRNG key implementation for the training rng "

@@ -304,17 +304,23 @@ class TrainConfig:
     bn_stats_dtype: str = "float32"  # BN batch-statistic reduction dtype
                                      # (conv models; running stats stay f32)
     attention_impl: str = "xla"      # xla | flash (pallas kernel; long-seq)
-    # flash-kernel tuning levers (attention_impl="flash" only; 0 = the
-    # kernel default). Sweepable from flags — experiments/flash_sweep.py
-    # — so block-size findings are reproducible, not folklore:
+    # flash-kernel tuning levers (attention_impl="flash" only). Left at
+    # their defaults the kernel chooses tiles and backward variant from
+    # (S, D, dtype) — ops/pallas/flash_attention.flash_schedule; a lever
+    # that is set overrides the choice. Sweepable from flags —
+    # experiments/flash_sweep.py — so findings are reproducible:
     attention_block_q: int = 0       # fwd Q-tile rows (multiple of 8)
     attention_block_k: int = 0       # fwd K-tile cols (multiple of 128)
     attention_bwd_block: int = 0     # bwd tile for BOTH streamed dims
-                                     # (multiple of 128; 0 = inherit fwd)
-    attention_bwd: str = "split"     # split (two-kernel FA-2 bwd) |
+                                     # (multiple of 128; 0 = the set fwd
+                                     # tiles, else the schedule's)
+    attention_bwd: str = "auto"      # auto (the schedule's choice) |
+                                     # split (two-kernel FA-2 bwd) |
                                      # fused (one kernel: s/p/ds computed
                                      # once for dq+dk+dv — ~29% fewer bwd
-                                     # matmul FLOPs, no K/V re-stream)
+                                     # matmul FLOPs, no K/V re-stream;
+                                     # runs split where its dq slab
+                                     # outgrows VMEM)
     remat: str = "none"              # none | full | dots — jax.checkpoint
                                      # each transformer layer (HBM for
                                      # recompute; long-context enabler)
@@ -343,11 +349,11 @@ def flash_attention_kwargs(cfg: TrainConfig) -> dict:
     levers = dict(block_q=cfg.attention_block_q,
                   block_k=cfg.attention_block_k,
                   bwd_block=cfg.attention_bwd_block)
-    if cfg.attention_bwd not in ("split", "fused"):
-        raise ValueError(f"attention_bwd must be 'split' or 'fused', "
-                         f"got {cfg.attention_bwd!r}")
+    if cfg.attention_bwd not in ("auto", "split", "fused"):
+        raise ValueError(f"attention_bwd must be 'auto', 'split' or "
+                         f"'fused', got {cfg.attention_bwd!r}")
     set_levers = {k: v for k, v in levers.items() if v != 0}
-    if cfg.attention_bwd != "split":
+    if cfg.attention_bwd != "auto":
         set_levers["bwd_variant"] = cfg.attention_bwd
     if not set_levers:
         return {}

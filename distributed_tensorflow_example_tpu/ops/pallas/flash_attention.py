@@ -10,26 +10,52 @@ logsumexp ``L``.
 
 Backward variants (``bwd_variant``):
 
-- ``"split"`` (default, the round-2 kernel): the standard
-  flash-attention-2 decomposition — a dq kernel streaming K blocks and a
-  dk/dv kernel streaming Q/dO blocks, both operand streams O(block). Each
-  kernel recomputes the score block ``s = qk^T`` and the ``dp = do v^T``
-  block, so the pair does 7 block matmuls per (q, k) block pair and
-  streams every operand twice.
-- ``"fused"``: ONE kernel (grid walks k blocks outer, q blocks inner)
-  computes dk, dv AND dq in a single pass — s/p/dp/ds are computed once
-  and feed all three gradients (5 block matmuls per pair, ~29% fewer bwd
-  matmul FLOPs, and K/V are not re-streamed by a second kernel). The dq
-  accumulator is a full [S, head_dim] f32 VMEM slab (contributions for a
-  q block arrive once per OUTER k step, so no O(block) scratch can hold
-  them); the variant therefore engages only while the slab fits VMEM
-  (``_FUSED_SLAB_LIMIT``) and falls back to ``"split"`` beyond — at
-  S=4096, D=64 the slab is 1 MiB.
+- ``"split"`` (the round-2 kernel): the standard flash-attention-2
+  decomposition — a dq kernel streaming K blocks and a dk/dv kernel
+  streaming Q/dO blocks, both operand streams O(block). Each kernel
+  recomputes the score block ``s = qk^T`` and the ``dp = do v^T`` block,
+  so the pair does 7 block matmuls per (q, k) block pair and streams
+  every operand twice.
+- ``"fused"`` (what the schedule picks wherever it fits): ONE kernel
+  (grid walks k blocks outer, q blocks inner) computes dk, dv AND dq in
+  a single pass — s/p/dp/ds are computed once and feed all three
+  gradients (5 block matmuls per pair, ~29% fewer bwd matmul FLOPs, and
+  K/V are not re-streamed by a second kernel). The dq accumulator is a
+  full [S, head_dim] f32 VMEM slab (contributions for a q block arrive
+  once per OUTER k step, so no O(block) scratch can hold them) and the
+  dq output block is the whole [S, head_dim] too; the variant therefore
+  engages only while the two fit VMEM (``_fused_dq_bytes`` against
+  ``_FUSED_SLAB_LIMIT``: S <= 16384 in bf16, S <= 8192 in f32) and runs
+  ``"split"`` beyond, forced or chosen.
 
-Block sizes are levers, not constants: ``block_q``/``block_k`` set the
-forward tiles, ``bwd_block`` (one value for both streamed dims) the
-backward tiles; ``config.TrainConfig`` exposes all of them next to
-``attention_impl`` and ``experiments/flash_sweep.py`` sweeps them.
+The tile schedule (PR 25). Tiles are chosen, not constant: the defaults
+come from ``flash_schedule(seq, head_dim, dtype)``, a rule set
+from a chip sweep (``experiments/flash_sweep.py kernels``), and the
+levers override it — ``block_q``/``block_k`` the forward tiles,
+``bwd_block`` (one value for both streamed dims) the backward tiles,
+``bwd_variant`` the backward kernel; ``config.TrainConfig`` exposes all
+of them next to ``attention_impl``, where 0 / ``auto`` means "the
+schedule's". What the sweep taught about v5e: at 128x128 a kernel is
+its grid steps (0.45 us each, 12,288 of them a call), and beyond that
+its per-row work — the [blk_q, 1] running statistics occupy one lane of
+a vreg and the row reductions cross lanes — which is paid once per live
+step, so wide K tiles win even where causal masking wastes half of
+them. Three more things belong to the schedule:
+
+- MXU operands stay in the input's dtype (bf16 in training): q, k, v, do
+  are never up-cast, p and ds are cast to the operand dtype for their
+  matmuls as ``ops/attention.py`` does with ``probs.astype(v.dtype)``,
+  and every dot accumulates in f32. Scores after scaling, the running
+  max, exp, the normaliser, ``lse``, ``Dsum`` and all accumulators are
+  f32. With float32 inputs nothing is cast.
+- Dead causal steps fetch nothing: a step strictly above the diagonal
+  names, in its index maps, the block that is already resident (the
+  row's last live K block; the first live Q block in the kernels that
+  stream Q), so Pallas issues no DMA for it (``_k_stream``,
+  ``_q_stream``).
+- The fully-masked-row guard ``p * (s > NEG_INF / 2)`` runs only under a
+  key mask: causal rows all see key 0 in the first block they visit, so
+  ``exp(NEG_INF - m)`` is already an exact 0 for them.
 
 Layout: inputs [B, S, H, D] (the framework's BSHD convention) are folded to
 [B*H, S, D] so the grid is (batch·head, q/k block, k/q block) and every
@@ -50,6 +76,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -59,14 +86,15 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..attention import NEG_INF
 
+#: the smallest tile the kernels take (one lane width), and what a
+#: sequence no larger candidate divides falls back to
 DEFAULT_BLOCK = 128
 
-#: fused-bwd dq slab budget: the [S, head_dim] f32 accumulator must share
-#: VMEM (~16 MiB less operand blocks) with the streamed tiles; past this
-#: the fused variant silently degrades to "split" (same math, same
-#: gradients — an availability boundary like ``_tile_friendly``, not an
-#: error)
-_FUSED_SLAB_LIMIT = 8 * 2**20
+#: fused-bwd dq budget: what ``_fused_dq_bytes`` may reach. The slab and
+#: the dq output block share VMEM with the streamed tiles; past this the
+#: fused variant silently degrades to "split" (same math, same gradients
+#: — an availability boundary like ``_tile_friendly``, not an error)
+_FUSED_SLAB_LIMIT = 16 * 2**20
 
 BWD_VARIANTS = ("split", "fused")
 
@@ -77,16 +105,116 @@ BWD_VARIANTS = ("split", "fused")
 _FWD_MATMULS = 2
 _BWD_MATMULS = {"split": 7, "fused": 5}
 
+#: f32 [blk_q, blk_k] temporaries live at once in a kernel body, as the
+#: VMEM estimate counts them: forward s, p, the causal select and p in
+#: the operand dtype; backward also dp, ds and their casts
+_FWD_TMPS = 4
+_BWD_TMPS = 7
+#: the compiler's default scoped-VMEM limit, and what a kernel may ask
+#: for (v5e: 128 MiB physical)
+_VMEM_DEFAULT = 16 * 2**20
+_VMEM_MAX = 96 * 2**20
+#: what ``flash_schedule`` lets a kernel's estimate reach
+_VMEM_BUDGET = 32 * 2**20
+#: the largest tile ``flash_schedule`` picks, forward and backward. From
+#: the chip sweep (PR 25, v5e, S=1024..4096, D=64, bf16 and f32): a
+#: kernel's time is mostly its per-ROW work ([blk_q, 1] statistics one
+#: lane wide, the row reductions) paid once per live grid step, so the
+#: forward wants the widest K tile there is even where causal skips
+#: nothing of it (1024x1024 0.65 ms, 512x512 1.15 ms a layer at the
+#: gpt2s-train shape); the backward has five matmuls a block pair and no
+#: running max, and the causal skip wins there (512x512 1.34 ms,
+#: 1024x1024 1.55 ms)
+_FWD_TILE_MAX = 1024
+_BWD_TILE_MAX = 512
+
+
+def _vmem_bytes(blk_q: int, blk_k: int, d: int, tmps: int) -> int:
+    """Estimate of a kernel's VMEM: ``tmps`` f32 [blk_q, blk_k]
+    temporaries, plus the operand, output and scratch blocks — at most
+    five Q-side and three K-side [blk, D] blocks, double-buffered,
+    counted at 4 bytes and a full 128-lane width (D = 64 and the
+    [blk_q, 1] statistics pad to it). The fused backward holds
+    ``_fused_dq_bytes`` more."""
+    lanes = max(d, 128)
+    return 4 * (tmps * blk_q * blk_k + 2 * lanes * (5 * blk_q + 3 * blk_k))
+
+
+def _fused_dq_bytes(seq: int, d: int, itemsize: int) -> int:
+    """What the fused backward holds in VMEM for dq besides its tiles:
+    the [S, D] f32 slab and the double-buffered (1, S, D) output block
+    in the input's dtype, both at a full 128-lane width."""
+    return seq * max(d, 128) * (4 + 2 * itemsize)
+
+
+class FlashSchedule(NamedTuple):
+    """Tiles and backward variant of one ``flash_attention`` call."""
+    blk_q: int
+    blk_k: int
+    bwd_q: int
+    bwd_k: int
+    bwd_variant: str
+
+    def grid_steps(self, seq: int) -> tuple[int, int]:
+        """Grid steps per batch-head of (the forward call, the backward
+        call or calls together)."""
+        bwd = (seq // self.bwd_q) * (seq // self.bwd_k)
+        return ((seq // self.blk_q) * (seq // self.blk_k),
+                bwd * (1 if self.bwd_variant == "fused" else 2))
+
+    def tileable(self, seq: int, head_dim: int) -> bool:
+        """Whether the kernels take these tiles (``_tile_friendly``,
+        forward and backward); the call falls back to XLA otherwise."""
+        return (_tile_friendly(seq, head_dim, self.blk_q, self.blk_k)
+                and _tile_friendly(seq, head_dim, self.bwd_q, self.bwd_k))
+
+
+def _largest_tile(seq: int, head_dim: int, tmps: int, cap: int) -> int:
+    """The largest square tile <= ``cap`` that divides ``seq`` and whose
+    kernel fits ``_VMEM_BUDGET``; ``DEFAULT_BLOCK`` (clamped to ``seq``)
+    where none does — ``_tile_friendly`` then decides the fallback."""
+    tile = cap
+    while tile > DEFAULT_BLOCK:
+        if (seq % tile == 0
+                and _vmem_bytes(tile, tile, head_dim, tmps) <= _VMEM_BUDGET):
+            return tile
+        tile //= 2
+    return min(DEFAULT_BLOCK, seq)
+
+
+def flash_schedule(seq: int, head_dim: int,
+                   dtype=jnp.bfloat16) -> FlashSchedule:
+    """The tiles and backward variant the kernels run at when no lever
+    says otherwise: a rule on what the kernel observes, set from the
+    chip sweep on record (``experiments/flash_sweep.py kernels``;
+    PERF.md section 6, PR 25). Square tiles, the largest that divide S
+    and fit VMEM up to ``_FWD_TILE_MAX`` / ``_BWD_TILE_MAX``, and the
+    fused backward wherever its dq slab and output block fit: it won at
+    every shape swept (S <= 4096; by 25-40 % of the split pair's time).
+    ``dtype`` counts through its itemsize (the dq block is in it); the
+    operands' dtype and causal masking did not move the winner in the
+    sweep (float32 operands cost 5-25 % at equal tiles)."""
+    fwd = _largest_tile(seq, head_dim, _FWD_TMPS, _FWD_TILE_MAX)
+    bwd = _largest_tile(seq, head_dim, _BWD_TMPS, _BWD_TILE_MAX)
+    return FlashSchedule(fwd, fwd, bwd, bwd, effective_bwd_variant(
+        seq, head_dim, "fused", dtype))
+
 
 def effective_bwd_variant(seq: int, head_dim: int,
-                          bwd_variant: str = "split") -> str:
+                          bwd_variant: str | None = None,
+                          dtype=jnp.bfloat16) -> str:
     """The backward variant that actually EXECUTES for these shapes:
-    "fused" degrades to "split" when the dq slab would not fit VMEM
-    (``_FUSED_SLAB_LIMIT``). Shared with the MFU accounting — counting
-    5 fused matmuls while the 7-matmul split runs would understate
-    analytic FLOPs by ~22% exactly where long-S comparability matters.
+    ``None`` is the schedule's own choice, and "fused" degrades to
+    "split" when its dq slab and output block would not fit VMEM
+    (``_fused_dq_bytes`` past ``_FUSED_SLAB_LIMIT``).
+    Shared with the MFU accounting — counting 5 fused matmuls while the
+    7-matmul split runs would understate analytic FLOPs by ~22% exactly
+    where long-S comparability matters.
     """
-    if bwd_variant == "fused" and seq * head_dim * 4 > _FUSED_SLAB_LIMIT:
+    if bwd_variant is None:
+        return flash_schedule(seq, head_dim, dtype).bwd_variant
+    if bwd_variant == "fused" and _fused_dq_bytes(
+            seq, head_dim, jnp.dtype(dtype).itemsize) > _FUSED_SLAB_LIMIT:
         return "split"
     return bwd_variant
 
@@ -129,6 +257,65 @@ def _block_mask(s, mask_row, causal: bool, q_start, k_start,
     return s
 
 
+def _live(qi, ki, blk_q: int, blk_k: int, causal: bool):
+    """causal: blocks strictly above the diagonal contribute nothing."""
+    return ((qi + 1) * blk_q - 1 >= ki * blk_k) if causal else True
+
+
+def _k_stream(causal: bool, blk_q: int, blk_k: int):
+    """Which K-side block step ``j`` of q block ``i`` names (forward,
+    dq). A dead causal step names the row's LAST LIVE block: that one is
+    already resident, so Pallas issues no DMA for a step that reads
+    nothing."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, ((i + 1) * blk_q - 1) // blk_k)
+
+
+def _own(i, j):
+    """The block a kernel holds across its inner walk: grid index i."""
+    return i
+
+
+def _q_stream(causal: bool, blk_q: int, blk_k: int):
+    """The same for the kernels that stream Q-side blocks past a k block
+    ``i`` (dkv, fused): dead steps come first there, and name the FIRST
+    LIVE q block, fetched once and still resident when its step comes."""
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.maximum(j, (i * blk_k) // blk_q)
+
+
+def _scores(q_ref, k_ref, mask_ref, *, causal: bool, q_start, k_start,
+            sm_scale: float):
+    """The scaled, masked f32 score block [blk_q, blk_k]. MXU operands
+    stay in the input's dtype; the accumulator is f32."""
+    s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32) * sm_scale
+    mrow = mask_ref[0] if mask_ref is not None else None  # [1, blk_k]
+    return _block_mask(s, mrow, causal, q_start, k_start, *s.shape)
+
+
+def _maskless(kernel, n_inputs: int):
+    """``kernel`` for a call without the mask operand: its refs arrive
+    without one, the kernel gets ``None`` in its place."""
+    def wrapped(*refs, **kw):
+        return kernel(*refs[:n_inputs], None, *refs[n_inputs:], **kw)
+    return wrapped
+
+
+def _compiler_params(semantics: tuple, blk_q: int, blk_k: int, d: int,
+                     tmps: int, more: int = 0):
+    """Grid semantics, and a raised scoped-VMEM limit where the tiles'
+    f32 [blk_q, blk_k] temporaries (``tmps`` of them live at once), the
+    double-buffered operand blocks and ``more`` bytes (the fused
+    backward's dq slab and block) outgrow the compiler's default."""
+    need = _vmem_bytes(blk_q, blk_k, d, tmps) + more
+    limit = min(2 * need, _VMEM_MAX) if need > _VMEM_DEFAULT // 2 else None
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
+
+
 # ---------------------------------------------------------------------------
 # forward kernel: grid (BH, nq, nk) — nk innermost, sequential, carries the
 # online-softmax state in scratch
@@ -147,27 +334,25 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    # causal: blocks strictly above the diagonal contribute nothing
-    live = ((qi + 1) * blk_q - 1 >= ki * blk_k) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(qi, ki, blk_q, blk_k, causal))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        mrow = mask_ref[0] if mask_ref is not None else None  # [1, blk_k]
-        s = _block_mask(s, mrow, causal, qi * blk_q, ki * blk_k,
-                        blk_q, blk_k)
+        s = _scores(q_ref, k_ref, mask_ref, causal=causal,
+                    q_start=qi * blk_q, k_start=ki * blk_k,
+                    sm_scale=sm_scale)
+        v = v_ref[0]
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new) * (s > NEG_INF / 2)
+        p = jnp.exp(s - m_new)
+        if mask_ref is not None:
+            # a row with no valid key yet has m_new = NEG_INF and would
+            # read exp(0) = 1; only a key mask can make one (causal rows
+            # all see key 0, in the block every row visits first)
+            p = p * (s > NEG_INF / 2)
         corr = jnp.exp(m_prev - m_new)
         m_scr[...] = m_new
         l_scr[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * corr + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -186,22 +371,23 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
     sm_scale = 1.0 / math.sqrt(d)
     nq, nk = s // blk_q, s // blk_k
     grid = (bh, nq, nk)
+    kblk = _k_stream(causal, blk_q, blk_k)
 
     in_specs = [pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0))]
+                pl.BlockSpec((1, blk_k, d),
+                             lambda b, i, j: (b, kblk(i, j), 0)),
+                pl.BlockSpec((1, blk_k, d),
+                             lambda b, i, j: (b, kblk(i, j), 0))]
     args = [q3, k3, v3]
     kw = dict(blk_q=blk_q, blk_k=blk_k, nk=nk, causal=causal,
               sm_scale=sm_scale)
     if mask2 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, blk_k), lambda b, i, j: (b // heads, 0, j)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, blk_k), lambda b, i, j: (b // heads, 0, kblk(i, j))))
         args.append(mask2[:, None, :])
         kernel = functools.partial(_fwd_kernel, **kw)
     else:
-        kernel = functools.partial(
-            lambda qr, kr, vr, o, lr, m, l, a, **k: _fwd_kernel(
-                qr, kr, vr, None, o, lr, m, l, a, **k), **kw)
+        kernel = functools.partial(_maskless(_fwd_kernel, 3), **kw)
 
     o, L = pl.pallas_call(
         kernel,
@@ -214,8 +400,9 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
         scratch_shapes=[pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, 1), jnp.float32),
                         pltpu.VMEM((blk_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), blk_q, blk_k, d,
+            _FWD_TMPS),
         name="flash_fwd",
         interpret=_interpret(),
     )(*args)
@@ -225,6 +412,27 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
 # ---------------------------------------------------------------------------
 # backward kernels
 # ---------------------------------------------------------------------------
+
+def _bwd_block(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref, *,
+               causal: bool, q_start, k_start, sm_scale: float):
+    """What every backward kernel recomputes for one (q, k) block pair:
+    the probabilities ``p`` and the score gradient ``ds`` (f32,
+    [blk_q, blk_k])."""
+    s = _scores(q_ref, k_ref, mask_ref, causal=causal, q_start=q_start,
+                k_start=k_start, sm_scale=sm_scale)
+    p = jnp.exp(s - L_ref[0])                             # L: [blk_q, 1]
+    if mask_ref is not None:
+        p = p * (s > NEG_INF / 2)       # key-masked rows: L is the floor
+    dp = lax.dot_general(do_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
+                         preferred_element_type=jnp.float32)
+    return p, p * (dp - D_ref[0]) * sm_scale
+
+
+def _tdot(a, b):
+    """a.T @ b with ``a`` cast to ``b``'s dtype, f32 accumulation."""
+    return lax.dot_general(a.astype(b.dtype), b, (((0,), (0,)), ((), ())),
+                           preferred_element_type=jnp.float32)
+
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref,
                    dq_ref, dq_scr, *, blk_q: int, blk_k: int, nk: int,
@@ -236,25 +444,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref,
     def _init():
         dq_scr[...] = jnp.zeros_like(dq_scr)
 
-    live = ((qi + 1) * blk_q - 1 >= ki * blk_k) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(qi, ki, blk_q, blk_k, causal))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        Lrow, Drow = L_ref[0], D_ref[0]                   # [blk_q, 1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        mrow = mask_ref[0] if mask_ref is not None else None
-        s = _block_mask(s, mrow, causal, qi * blk_q, ki * blk_k,
-                        blk_q, blk_k)
-        p = jnp.exp(s - Lrow) * (s > NEG_INF / 2)         # [blk_q, blk_k]
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - Drow) * sm_scale
-        dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        _, ds = _bwd_block(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref,
+                           mask_ref, causal=causal, q_start=qi * blk_q,
+                           k_start=ki * blk_k, sm_scale=sm_scale)
+        k = k_ref[0]
+        dq_scr[...] += jnp.dot(ds.astype(k.dtype), k,
+                               preferred_element_type=jnp.float32)
 
     @pl.when(ki == nk - 1)
     def _finalize():
@@ -272,30 +469,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = ((qi + 1) * blk_q - 1 >= ki * blk_k) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(qi, ki, blk_q, blk_k, causal))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        Lrow, Drow = L_ref[0], D_ref[0]                   # [blk_q, 1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        mrow = mask_ref[0] if mask_ref is not None else None
-        s = _block_mask(s, mrow, causal, qi * blk_q, ki * blk_k,
-                        blk_q, blk_k)
-        p = jnp.exp(s - Lrow) * (s > NEG_INF / 2)         # [blk_q, blk_k]
-        dv_scr[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # p.T @ do
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - Drow) * sm_scale
-        dk_scr[...] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # ds.T @ q
+        p, ds = _bwd_block(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref,
+                           mask_ref, causal=causal, q_start=qi * blk_q,
+                           k_start=ki * blk_k, sm_scale=sm_scale)
+        dv_scr[...] += _tdot(p, do_ref[0])                # p.T @ do
+        dk_scr[...] += _tdot(ds, q_ref[0])                # ds.T @ q
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -332,32 +512,16 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref,
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
-    live = ((qi + 1) * blk_q - 1 >= ki * blk_k) if causal else True
-
-    @pl.when(live)
+    @pl.when(_live(qi, ki, blk_q, blk_k, causal))
     def _compute():
-        q = q_ref[0].astype(jnp.float32)
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        do = do_ref[0].astype(jnp.float32)
-        Lrow, Drow = L_ref[0], D_ref[0]                   # [blk_q, 1]
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * sm_scale
-        mrow = mask_ref[0] if mask_ref is not None else None
-        s = _block_mask(s, mrow, causal, q_start, ki * blk_k,
-                        blk_q, blk_k)
-        p = jnp.exp(s - Lrow) * (s > NEG_INF / 2)         # [blk_q, blk_k]
-        dv_scr[...] += lax.dot_general(
-            p, do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # p.T @ do
-        dp = lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - Drow) * sm_scale
-        dk_scr[...] += lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)           # ds.T @ q
+        p, ds = _bwd_block(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref,
+                           mask_ref, causal=causal, q_start=q_start,
+                           k_start=ki * blk_k, sm_scale=sm_scale)
+        k = k_ref[0]
+        dv_scr[...] += _tdot(p, do_ref[0])                # p.T @ do
+        dk_scr[...] += _tdot(ds, q_ref[0])                # ds.T @ q
         dq_slab[pl.dslice(q_start, blk_q), :] += jnp.dot(
-            ds, k, preferred_element_type=jnp.float32)
+            ds.astype(k.dtype), k, preferred_element_type=jnp.float32)
 
     @pl.when(qi == nq - 1)
     def _finalize_kv():
@@ -370,46 +534,25 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, L_ref, D_ref, mask_ref,
             pl.dslice(q_start, blk_q), :].astype(dq_ref.dtype)
 
 
-def _bwd_fused(q3, k3, v3, do3, L, Dsum, mask2, *, heads: int, blk_q: int,
-               blk_k: int, causal: bool):
-    bh, s, d = q3.shape
-    sm_scale = 1.0 / math.sqrt(d)
-    nq, nk = s // blk_q, s // blk_k
-
-    qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, j, 0))
-    kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0))
-    rowspec = pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, j, 0))
+def _bwd_specs(q3, k3, v3, do3, L, Dsum, mask2, *, heads: int, blk_q: int,
+               blk_k: int, q_index, k_index):
+    """in_specs and operands shared by the three backward kernels;
+    ``q_index(i, j)`` / ``k_index(i, j)`` give the q-side and k-side
+    block a grid step (b, i, j) names."""
+    d = q3.shape[-1]
+    qspec = pl.BlockSpec((1, blk_q, d),
+                         lambda b, i, j: (b, q_index(i, j), 0))
+    kspec = pl.BlockSpec((1, blk_k, d),
+                         lambda b, i, j: (b, k_index(i, j), 0))
+    rowspec = pl.BlockSpec((1, blk_q, 1),
+                           lambda b, i, j: (b, q_index(i, j), 0))
     in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
     args = [q3, k3, v3, do3, L, Dsum]
-    kw = dict(blk_q=blk_q, blk_k=blk_k, nq=nq, nk=nk, causal=causal,
-              sm_scale=sm_scale)
     if mask2 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, blk_k), lambda b, i, j: (b // heads, 0, i)))
+        in_specs.append(pl.BlockSpec(
+            (1, 1, blk_k), lambda b, i, j: (b // heads, 0, k_index(i, j))))
         args.append(mask2[:, None, :])
-        kernel = functools.partial(_bwd_fused_kernel, **kw)
-    else:
-        kernel = functools.partial(
-            lambda qr, kr, vr, dor, lr, dr, dq, dk, dv, s0, s1, s2, **k:
-            _bwd_fused_kernel(qr, kr, vr, dor, lr, dr, None, dq, dk, dv,
-                              s0, s1, s2, **k), **kw)
-    dq, dk, dv = pl.pallas_call(
-        kernel, grid=(bh, nk, nq), in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, s, d), lambda b, i, j: (b, 0, 0)),
-                   pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
-                   jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
-                        pltpu.VMEM((blk_k, d), jnp.float32),
-                        pltpu.VMEM((blk_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
-        name="flash_bwd_fused",
-        interpret=_interpret(),
-    )(*args)
-    return dq, dk, dv
+    return in_specs, args
 
 
 def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
@@ -419,66 +562,65 @@ def _bwd(q3, k3, v3, o3, do3, L, mask2, *, heads: int, blk_q: int,
     nq, nk = s // blk_q, s // blk_k
     Dsum = jnp.sum(do3.astype(jnp.float32) * o3.astype(jnp.float32),
                    axis=-1, keepdims=True)                # [BH, S, 1]
+    operands = (q3, k3, v3, do3, L, Dsum, mask2)
+    kw = dict(blk_q=blk_q, blk_k=blk_k, causal=causal, sm_scale=sm_scale)
+    qstream = _q_stream(causal, blk_q, blk_k)
+    kv_out = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0))
+    kv_shape = [jax.ShapeDtypeStruct(k3.shape, k3.dtype),
+                jax.ShapeDtypeStruct(v3.shape, v3.dtype)]
+    kv_scratch = [pltpu.VMEM((blk_k, d), jnp.float32),
+                  pltpu.VMEM((blk_k, d), jnp.float32)]
+
+    def bind(kernel, **more):
+        kernel = kernel if mask2 is not None else _maskless(kernel, 6)
+        return functools.partial(kernel, **kw, **more)
+
     if variant == "fused":
-        return _bwd_fused(q3, k3, v3, do3, L, Dsum, mask2, heads=heads,
-                          blk_q=blk_q, blk_k=blk_k, causal=causal)
+        # grid (BH, nk, nq): Q/dO/L/D streamed innermost, both block
+        # dims sequential (the dq slab lives across the outer k walk)
+        in_specs, args = _bwd_specs(*operands, heads=heads, blk_q=blk_q,
+                                    blk_k=blk_k, q_index=qstream,
+                                    k_index=_own)
+        return pl.pallas_call(
+            bind(_bwd_fused_kernel, nq=nq, nk=nk), grid=(bh, nk, nq),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, s, d), lambda b, i, j: (b, 0, 0)),
+                       kv_out, kv_out],
+            out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype)] + kv_shape,
+            scratch_shapes=[pltpu.VMEM((s, d), jnp.float32)] + kv_scratch,
+            compiler_params=_compiler_params(
+                ("parallel", "arbitrary", "arbitrary"), blk_q, blk_k, d,
+                _BWD_TMPS, _fused_dq_bytes(s, d, q3.dtype.itemsize)),
+            name="flash_bwd_fused",
+            interpret=_interpret(),
+        )(*args)
 
     # dq: grid (BH, nq, nk) — K/V streamed innermost
-    qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, j, 0))
-    rowspec = pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, i, 0))
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
-    args = [q3, k3, v3, do3, L, Dsum]
-    kw = dict(blk_q=blk_q, blk_k=blk_k, nk=nk, causal=causal,
-              sm_scale=sm_scale)
-    if mask2 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, blk_k), lambda b, i, j: (b // heads, 0, j)))
-        args.append(mask2[:, None, :])
-        dq_kernel = functools.partial(_bwd_dq_kernel, **kw)
-    else:
-        dq_kernel = functools.partial(
-            lambda qr, kr, vr, dor, lr, dr, dq, scr, **k: _bwd_dq_kernel(
-                qr, kr, vr, dor, lr, dr, None, dq, scr, **k), **kw)
+    in_specs, args = _bwd_specs(*operands, heads=heads, blk_q=blk_q,
+                                blk_k=blk_k, q_index=_own,
+                                k_index=_k_stream(causal, blk_q, blk_k))
     dq = pl.pallas_call(
-        dq_kernel, grid=(bh, nq, nk), in_specs=in_specs,
+        bind(_bwd_dq_kernel, nk=nk), grid=(bh, nq, nk), in_specs=in_specs,
         out_specs=pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q3.shape, q3.dtype),
         scratch_shapes=[pltpu.VMEM((blk_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), blk_q, blk_k, d,
+            _BWD_TMPS),
         name="flash_bwd_dq",
         interpret=_interpret(),
     )(*args)
 
     # dk/dv: grid (BH, nk, nq) — Q/dO/L/D streamed innermost
-    qspec = pl.BlockSpec((1, blk_q, d), lambda b, i, j: (b, j, 0))
-    kspec = pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0))
-    rowspec = pl.BlockSpec((1, blk_q, 1), lambda b, i, j: (b, j, 0))
-    in_specs = [qspec, kspec, kspec, qspec, rowspec, rowspec]
-    args = [q3, k3, v3, do3, L, Dsum]
-    kw = dict(blk_q=blk_q, blk_k=blk_k, nq=nq, causal=causal,
-              sm_scale=sm_scale)
-    if mask2 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, 1, blk_k), lambda b, i, j: (b // heads, 0, i)))
-        args.append(mask2[:, None, :])
-        dkv_kernel = functools.partial(_bwd_dkv_kernel, **kw)
-    else:
-        dkv_kernel = functools.partial(
-            lambda qr, kr, vr, dor, lr, dr, dk, dv, s1, s2, **k:
-            _bwd_dkv_kernel(qr, kr, vr, dor, lr, dr, None, dk, dv, s1, s2,
-                            **k), **kw)
+    in_specs, args = _bwd_specs(*operands, heads=heads, blk_q=blk_q,
+                                blk_k=blk_k, q_index=qstream, k_index=_own)
     dk, dv = pl.pallas_call(
-        dkv_kernel, grid=(bh, nk, nq), in_specs=in_specs,
-        out_specs=[pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, blk_k, d), lambda b, i, j: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct(k3.shape, k3.dtype),
-                   jax.ShapeDtypeStruct(v3.shape, v3.dtype)],
-        scratch_shapes=[pltpu.VMEM((blk_k, d), jnp.float32),
-                        pltpu.VMEM((blk_k, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        bind(_bwd_dkv_kernel, nq=nq), grid=(bh, nk, nq), in_specs=in_specs,
+        out_specs=[kv_out, kv_out], out_shape=kv_shape,
+        scratch_shapes=kv_scratch,
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"), blk_q, blk_k, d,
+            _BWD_TMPS),
         name="flash_bwd_dkv",
         interpret=_interpret(),
     )(*args)
@@ -526,30 +668,57 @@ def _tile_friendly(s: int, d: int, blk_q: int, blk_k: int) -> bool:
             and (d == 64 or d % 128 == 0))
 
 
-def _resolve_blocks(s: int, block_q: int, block_k: int,
-                    bwd_block: int) -> tuple[int, int, int, int]:
-    """(fwd_q, fwd_k, bwd_q, bwd_k) clamped to the sequence length; a
-    zero ``bwd_block`` inherits the forward tiles."""
-    blk_q, blk_k = min(block_q, s), min(block_k, s)
+def resolve_schedule(seq: int, head_dim: int, dtype=jnp.bfloat16, *,
+                     block_q: int | None = None,
+                     block_k: int | None = None, bwd_block: int = 0,
+                     bwd_variant: str | None = None) -> FlashSchedule:
+    """``flash_schedule`` with the levers laid over it: a lever that is
+    set wins (clamped to the sequence length), one left unset (``None``;
+    0 for ``bwd_block``) takes the schedule's. A set forward tile also
+    tiles the backward unless ``bwd_block`` says otherwise, as it always
+    has. The variant is the one that executes
+    (``effective_bwd_variant``)."""
+    auto = flash_schedule(seq, head_dim, dtype)
+    blk_q = min(block_q, seq) if block_q else auto.blk_q
+    blk_k = min(block_k, seq) if block_k else auto.blk_k
     if bwd_block:
-        bwd_q = bwd_k = min(bwd_block, s)
-    else:
+        bwd_q = bwd_k = min(bwd_block, seq)
+    elif block_q or block_k:
         bwd_q, bwd_k = blk_q, blk_k
-    return blk_q, blk_k, bwd_q, bwd_k
+    else:
+        bwd_q, bwd_k = auto.bwd_q, auto.bwd_k
+    return FlashSchedule(blk_q, blk_k, bwd_q, bwd_k, effective_bwd_variant(
+        seq, head_dim, bwd_variant or auto.bwd_variant, dtype))
 
 
 def kernel_engages(seq: int, head_dim: int, *,
-                   block_q: int = DEFAULT_BLOCK,
-                   block_k: int = DEFAULT_BLOCK,
+                   block_q: int | None = None,
+                   block_k: int | None = None,
                    bwd_block: int = 0) -> bool:
     """True iff these shapes/blocks take the Pallas kernel path (vs the
     XLA fallback). Shared with bench.py's MFU accounting: analytic
     attention FLOPs must be added exactly when the custom call (which
-    XLA cost analysis cannot see into) actually runs."""
-    blk_q, blk_k, bwd_q, bwd_k = _resolve_blocks(seq, block_q, block_k,
-                                                 bwd_block)
-    return (_tile_friendly(seq, head_dim, blk_q, blk_k)
-            and _tile_friendly(seq, head_dim, bwd_q, bwd_k))
+    XLA cost analysis cannot see into) actually runs. The dtype moves
+    the backward variant, never a tile, so it does not count here."""
+    return resolve_schedule(seq, head_dim, block_q=block_q, block_k=block_k,
+                            bwd_block=bwd_block).tileable(seq, head_dim)
+
+
+def describe_attention(seq: int, head_dim: int, dtype=jnp.bfloat16,
+                       **levers) -> str:
+    """What a flash call of this shape runs, for a start-up log line and
+    the sweep's rows: the tiles and variant chosen (or set by
+    ``levers``) and the grid steps they make, or the XLA fallback."""
+    sch = resolve_schedule(seq, head_dim, dtype, **levers)
+    if not sch.tileable(seq, head_dim):
+        return (f"flash attention falls back to XLA at S={seq} "
+                f"D={head_dim} (no tile the kernels take)")
+    fwd, bwd = sch.grid_steps(seq)
+    return (f"flash kernels at S={seq} D={head_dim} "
+            f"{jnp.dtype(dtype).name}: "
+            f"fwd {sch.blk_q}x{sch.blk_k}, bwd {sch.bwd_variant} "
+            f"{sch.bwd_q}x{sch.bwd_k}; grid steps per batch-head "
+            f"fwd {fwd}, bwd {bwd}")
 
 
 def _partitioned(amesh, q, k, v, mask, **kw):
@@ -583,33 +752,36 @@ def _partitioned(amesh, q, k, v, mask, **kw):
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     mask: jax.Array | None = None, causal: bool = False,
-                    block_q: int = DEFAULT_BLOCK,
-                    block_k: int = DEFAULT_BLOCK,
+                    block_q: int | None = None,
+                    block_k: int | None = None,
                     bwd_block: int = 0,
-                    bwd_variant: str = "split") -> jax.Array:
+                    bwd_variant: str | None = None) -> jax.Array:
     """Drop-in for ``multi_head_attention(impl="xla")``: [B,S,H,D] in/out.
 
     ``mask``: [B,S] key-validity (1 = attend) or broadcastable [B,1,1,S].
-    ``block_q``/``block_k`` tile the forward grid; ``bwd_block`` (0 =
-    inherit the forward tiles) tiles BOTH streamed dims of the backward;
-    ``bwd_variant`` picks the split (two-kernel) or fused (one-kernel)
-    backward — see the module docstring. Falls back to the XLA path for
-    tile-unfriendly shapes (see ``_tile_friendly``); nonsensical lever
-    values (non-positive blocks, unknown variant) raise instead of
-    silently falling back.
+    The levers override ``flash_schedule``'s choice for this shape and
+    dtype: ``block_q``/``block_k`` tile the forward grid (and the
+    backward's, unless ``bwd_block`` is set); ``bwd_block`` tiles BOTH
+    streamed dims of the backward; ``bwd_variant`` picks the split
+    (two-kernel) or fused (one-kernel) backward — see the module
+    docstring. Left at ``None`` (0 for ``bwd_block``) each is the
+    schedule's. Falls back to the XLA path for tile-unfriendly shapes
+    (see ``_tile_friendly``); nonsensical lever values (non-positive
+    blocks, unknown variant) raise instead of silently falling back.
     """
-    if block_q <= 0 or block_k <= 0 or bwd_block < 0:
+    if ((block_q is not None and block_q <= 0)
+            or (block_k is not None and block_k <= 0) or bwd_block < 0):
         raise ValueError(
             f"block_q/block_k must be positive and bwd_block >= 0, got "
             f"block_q={block_q} block_k={block_k} bwd_block={bwd_block}")
-    if bwd_variant not in BWD_VARIANTS:
+    if bwd_variant is not None and bwd_variant not in BWD_VARIANTS:
         raise ValueError(f"bwd_variant must be one of {BWD_VARIANTS}, "
                          f"got {bwd_variant!r}")
     b, s, h, d = q.shape
-    blk_q, blk_k, bwd_q, bwd_k = _resolve_blocks(s, block_q, block_k,
-                                                 bwd_block)
-    if not (_tile_friendly(s, d, blk_q, blk_k)
-            and _tile_friendly(s, d, bwd_q, bwd_k)):
+    sch = resolve_schedule(s, d, q.dtype, block_q=block_q,
+                           block_k=block_k, bwd_block=bwd_block,
+                           bwd_variant=bwd_variant)
+    if not sch.tileable(s, d):
         from ..attention import multi_head_attention
         m4 = None
         if mask is not None:
@@ -624,13 +796,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
         return _partitioned(amesh, q, k, v, mask, causal=causal,
                             block_q=block_q, block_k=block_k,
                             bwd_block=bwd_block, bwd_variant=bwd_variant)
-    bwd_variant = effective_bwd_variant(s, d, bwd_variant)
 
     def fold(x):
         return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
 
-    fn = _make_flash(h, blk_q, blk_k, bwd_q, bwd_k, bwd_variant, causal,
-                     mask is not None)
+    fn = _make_flash(h, *sch, causal, mask is not None)
     mask2 = (mask.astype(jnp.int32) if mask is not None
              else jnp.ones((b, s), jnp.int32))
     o3 = fn(fold(q), fold(k), fold(v), mask2)

@@ -282,7 +282,25 @@ class Trainer:
                 self.state = state
                 log.info("%s (from %s)", report,
                          self.config.checkpoint.warm_start)
+        self._log_attention()
         return state
+
+    def _log_attention(self) -> None:
+        """One start-up line: the model's attention path and, for the
+        flash kernels, the schedule they chose for this run's shape."""
+        impl = getattr(self.model, "attention_impl", None)
+        if impl is None:
+            return
+        detail = ""
+        if impl == "flash":
+            from ..ops.pallas.flash_attention import describe_attention
+            ids = self.train_arrays.get("input_ids")
+            seq = (ids.shape[1] if getattr(ids, "ndim", 0) == 2
+                   else self.config.data.seq_len)
+            detail = ": " + describe_attention(
+                seq, self.model.head_dim, self.config.dtype,
+                **self.model.attention_kwargs)
+        log.info("model %s: attention %s%s", self.config.model, impl, detail)
 
     def _loader(self, start_step: int | None = None
                 ) -> Iterator[dict[str, np.ndarray]]:

@@ -26,6 +26,10 @@ another's device memory):
                      one measured cell on the current backend: step
                      time, eps/chip, temp/peak MiB, MFU (analytic basis
                      when the kernel engages). IMPL: xla | flash.
+                     BLOCK sets both forward tiles (0 = the kernel's
+                     choice); VARIANT auto (the kernel's choice) |
+                     split | fused, as in TrainConfig; the row records
+                     the variant that ran.
                      Records OOM as an error line — the flash-vs-XLA
                      crossover table needs the OOM rows too.
   --all              the committed grid: MODEL x S in {512, 1024, 2048,
@@ -33,6 +37,18 @@ another's device memory):
                      bwd {split, fused}); b=4 long-context batch.
   --trace DIR MODEL  5-step profiler capture of the S=4096 b4 gate
                      step (reduce with utils.trace_summary).
+  kernels [OUT]      the kernels alone, one process (PR 25): one layer's
+                     attention forward + backward at the gpt2s-train
+                     cell's shape (B=16, S=1024, H=12, D=64, causal) for
+                     tiles {256, 512, 1024}^2 x {split, fused} x {bf16,
+                     f32 operands}, device ms BY KERNEL NAME from a
+                     profiler capture of each row, plus the parent's
+                     128 tiles, --attention xla, the schedule's own
+                     choice, one ablation (dead causal steps fetching
+                     again) and the other shapes the schedule serves
+                     (the serving prefill, GPT-small at S=512, D=128,
+                     S=2048 and 4096). Rows also go to OUT (JSON lines).
+                     ``flash_schedule`` is what the rows chose from.
 
 The measured columns are TPU columns: off-TPU the kernels run in Pallas
 interpret mode (orders of magnitude slow, numbers meaningless), so
@@ -217,7 +233,7 @@ def measure(model_name: str, seq: int, impl: str, block: int,
                       attention_block_q=block if impl == "flash" else 0,
                       attention_block_k=block if impl == "flash" else 0,
                       attention_bwd_block=bwd_block,
-                      attention_bwd=variant if impl == "flash" else "split",
+                      attention_bwd=variant if impl == "flash" else "auto",
                       lm_loss_chunk=512 if model_name == "gpt" else None)
     model = get_model(model_name, cfg)
     mesh = build_mesh()
@@ -238,16 +254,18 @@ def measure(model_name: str, seq: int, impl: str, block: int,
     # fallback (non-tileable shape) cost_analysis already counts the
     # attention einsums and adding the analytic number would double-count
     # (and over-raise robust_time's impossibility floor)
+    ran = None
     if impl == "flash" and kernel_engages(
             seq, ms["head_dim"], block_q=block, block_k=block,
             bwd_block=bwd_block):
+        # count what EXECUTES: auto is the schedule's choice, and fused
+        # degrades to split past the VMEM slab limit
+        ran = effective_bwd_variant(
+            seq, ms["head_dim"], None if variant == "auto" else variant,
+            cfg.dtype)
         flops += attention_train_flops(
             batch, seq, ms["hidden"], ms["layers"],
-            causal=model_name == "gpt",
-            # count what EXECUTES: fused degrades to split past the
-            # VMEM slab limit
-            bwd_variant=effective_bwd_variant(seq, ms["head_dim"],
-                                              variant))
+            causal=model_name == "gpt", bwd_variant=ran)
         basis = "analytic"
 
     # one untimed priming step binds the metrics for loss_finite even at
@@ -275,7 +293,7 @@ def measure(model_name: str, seq: int, impl: str, block: int,
     return {
         "model": model_name, "seq": seq, "impl": impl,
         "block": block if impl == "flash" else None,
-        "bwd_variant": variant if impl == "flash" else None,
+        "bwd_variant": ran,
         "bwd_block": bwd_block or None,
         "step_ms": round(step_ms, 1),
         "eps_chip": round(batch / (dt / steps), 2),
@@ -289,6 +307,190 @@ def measure(model_name: str, seq: int, impl: str, block: int,
             m_["loss"])))),
         "suspect": bool(suspect),
     }
+
+
+# ---------------------------------------------------------------------------
+# the kernels alone (PR 25): device ms by kernel name from a capture
+# ---------------------------------------------------------------------------
+
+CELL_SHAPE = (16, 1024, 12, 64)         # gpt2s-train: B, S, H, D
+TILES = (256, 512, 1024)
+
+
+def kernel_rows() -> list[dict]:
+    """What ``kernels`` measures, in order. A row: ``shape`` (B, S, H, D),
+    ``dtype``, ``impl``, forward tiles = backward tiles ``blk_q`` x
+    ``blk_k`` (None = the schedule's), ``variant``, ``masked``, ``grad``,
+    ``ablate`` (names of module functions replaced for the row)."""
+    base = dict(shape=CELL_SHAPE, dtype="bfloat16", impl="flash",
+                causal=True, masked=False, grad=True, ablate=())
+    rows = [dict(base, blk_q=None, blk_k=None, variant=None),
+            dict(base, impl="xla", blk_q=None, blk_k=None, variant=None)]
+    for dtype in ("bfloat16", "float32"):
+        for bq in TILES:
+            for bk in TILES:
+                rows += [dict(base, dtype=dtype, blk_q=bq, blk_k=bk,
+                              variant=v) for v in VARIANTS]
+    rows += [dict(base, blk_q=128, blk_k=128, variant=v) for v in VARIANTS]
+    # dead causal steps fetching a new block again, as before PR 25
+    rows += [dict(base, blk_q=512, blk_k=512, variant="fused",
+                  ablate=("no_clamp",)),
+             dict(base, blk_q=128, blk_k=128, variant="split",
+                  ablate=("no_clamp",))]
+    # D=128 at the same hidden size
+    for blk in (512, 1024):
+        rows += [dict(base, shape=(16, 1024, 6, 128), blk_q=blk, blk_k=blk,
+                      variant=v) for v in VARIANTS]
+    # the serving prefill: one prompt, S=512, key mask + causal, no grad
+    for blk in (128, 256, 512):
+        rows.append(dict(base, shape=(1, 512, 12, 64), masked=True,
+                         grad=False, blk_q=blk, blk_k=blk, variant="split"))
+    # ROADMAP S5's flip rule: GPT-small at S=512, batch 32, against XLA
+    s512 = dict(base, shape=(32, 512, 12, 64), blk_q=None, blk_k=None,
+                variant=None)
+    rows += [s512, dict(s512, impl="xla"),
+             dict(s512, blk_q=128, blk_k=128, variant="split")]
+    # longer sequences at the long-context batch
+    for seq in (2048, 4096):
+        for blk in (512, 1024):
+            rows += [dict(base, shape=(BATCH, seq, 12, 64), blk_q=blk,
+                          blk_k=blk, variant=v) for v in VARIANTS]
+    return rows
+
+
+def measure_kernels(row: dict, *, iters: int = 5) -> dict:
+    """One row: compile, warm up, capture ``iters`` calls, and read the
+    device ms per call of each kernel by name (``flash_fwd``,
+    ``flash_bwd_*``), of everything else in the program, and of the
+    whole program."""
+    import importlib
+    import re
+    import shutil
+    import tempfile
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace_reduce
+    from distributed_tensorflow_example_tpu.ops.attention import (
+        multi_head_attention)
+    fm = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.pallas.flash_attention")
+
+    b, s, h, d = row["shape"]
+    dtype = jnp.dtype(row["dtype"])
+    rs = np.random.RandomState(0)
+    q, k, v = (jnp.asarray(rs.randn(b, s, h, d) * 0.5, dtype)
+               for _ in range(3))
+    mask = None
+    if row["masked"]:
+        m = np.ones((b, s), np.int32)
+        m[:, s - s // 4:] = 0
+        mask = jnp.asarray(m)
+    saved = {n: getattr(fm, n) for n in ("_k_stream", "_q_stream")}
+    if "no_clamp" in row["ablate"]:
+        fm._k_stream = fm._q_stream = lambda *a: (lambda i, j: j)
+    fm._make_flash.cache_clear()
+    sch = fm.resolve_schedule(s, d, dtype)
+    if row["blk_q"]:
+        sch = fm.FlashSchedule(row["blk_q"], row["blk_k"], row["blk_q"],
+                               row["blk_k"], row["variant"])
+    try:
+        if row["impl"] == "xla":
+            def attend(q, k, v):
+                m4 = None if mask is None else mask[:, None, None, :]
+                return multi_head_attention(q, k, v, mask=m4,
+                                            causal=row["causal"])
+        else:
+            fn = fm._make_flash(h, *sch, row["causal"], mask is not None)
+            mask2 = mask if mask is not None else jnp.ones((b, s),
+                                                           jnp.int32)
+
+            def fold(x):
+                return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+            def attend(q, k, v):
+                return fn(fold(q), fold(k), fold(v), mask2)
+
+        def loss(q, k, v):
+            return jnp.sum(attend(q, k, v).astype(jnp.float32) ** 2)
+
+        call = jax.jit(jax.grad(loss, argnums=(0, 1, 2)) if row["grad"]
+                       else attend)
+        for _ in range(2):
+            jax.block_until_ready(call(q, k, v))
+        tmp = tempfile.mkdtemp(prefix="flash_sweep_")
+        try:
+            jax.profiler.start_trace(tmp)
+            try:
+                for _ in range(iters):
+                    out = call(q, k, v)
+                jax.block_until_ready(out)
+            finally:
+                jax.profiler.stop_trace()
+            red = trace_reduce.reduce(trace_reduce.find_xplane(tmp))
+        finally:
+            shutil.rmtree(tmp, ignore_errors=True)
+    finally:
+        for n, f in saved.items():
+            setattr(fm, n, f)
+        fm._make_flash.cache_clear()
+
+    def ms(pattern):
+        return round(trace_reduce.op_seconds(red, pattern=pattern)
+                     / iters * 1e3, 4)
+
+    kernels = {n: round(o["seconds"] / iters * 1e3, 4)
+               for n, o in sorted(red["all_ops"].items())
+               if re.search("flash_", n)}
+    busy = red["busy_s"] / iters * 1e3
+    out = {k_: row[k_] for k_ in ("shape", "dtype", "impl", "masked",
+                                  "grad")}
+    out["ablate"] = list(row["ablate"])
+    if row["impl"] == "flash":
+        out.update(fwd=f"{sch.blk_q}x{sch.blk_k}",
+                   bwd=f"{sch.bwd_variant} {sch.bwd_q}x{sch.bwd_k}",
+                   chosen=row["blk_q"] is None,
+                   flash_fwd_ms=ms("flash_fwd"), flash_bwd_ms=ms("flash_bwd"),
+                   kernels_ms=kernels)
+    out.update(program_ms=round(busy, 4),
+               other_ms=round(busy - sum(kernels.values()), 4))
+    return out
+
+
+def kernels(out_path: str | None) -> None:
+    import jax
+
+    on_tpu = jax.devices()[0].platform == "tpu"
+    if not on_tpu and not os.environ.get("FLASH_SWEEP_CPU"):
+        raise SystemExit("kernel rows are TPU rows (interpret-mode Pallas "
+                         "timings are meaningless); set FLASH_SWEEP_CPU=1 "
+                         "for a CI smoke run")
+    rows = kernel_rows()
+    if not on_tpu:                       # smoke: the control flow only
+        rows = [dict(r, shape=(1, 256, 2, 64), blk_q=r["blk_q"] and 128,
+                     blk_k=r["blk_k"] and 128) for r in rows[:4]]
+    sink = open(out_path, "w") if out_path else None
+    failed = 0
+    for row in rows:
+        try:
+            line = measure_kernels(row)
+        except Exception as e:  # noqa: BLE001 — a refused tile is a row
+            failed += 1
+            line = {**{k: (list(v) if isinstance(v, tuple) else v)
+                       for k, v in row.items()},
+                    "error": f"{type(e).__name__}: {str(e)[:300]}"}
+        line["device"] = jax.devices()[0].device_kind
+        text = json.dumps(line)
+        print(text, flush=True)
+        if sink:
+            sink.write(text + "\n")
+            sink.flush()
+    if sink:
+        sink.close()
+    if failed:
+        sys.exit(f"{failed} row(s) failed")
 
 
 def trace(outdir: str, model_name: str) -> dict:
@@ -326,6 +528,12 @@ def main() -> None:
                     cells.append(("cell", mn, s, "flash", 128, "split",
                                   512))
         run_cells(os.path.abspath(__file__), cells)
+        return
+    if sys.argv[1:2] == ["kernels"]:
+        if len(sys.argv) > 2:
+            os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])),
+                        exist_ok=True)
+        kernels(sys.argv[2] if len(sys.argv) > 2 else None)
         return
     if sys.argv[1:2] == ["--trace"]:
         outdir, mn = sys.argv[2], sys.argv[3]

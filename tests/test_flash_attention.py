@@ -364,3 +364,242 @@ def test_kernel_under_an_ambient_mesh_matches_unsharded(cpu8, masked):
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w),
                                    rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# PR 25: tiles chosen from the shape, MXU operands in the input's dtype
+# ---------------------------------------------------------------------------
+
+BF16_EPS = float(jnp.finfo(jnp.bfloat16).eps)          # 2**-7
+
+
+@pytest.mark.parametrize("seq,masked", [(1024, False), (512, True)])
+def test_bf16_parity_at_the_schedules_own_tiles(seq, masked):
+    """bfloat16 in, no lever set: the kernels run at ``flash_schedule``'s
+    tiles with bf16 MXU operands (q, k, v, do, and p / ds cast for their
+    matmuls) and f32 statistics and accumulators — the precision of the
+    XLA path on the same inputs. Shapes: the gpt2s-train cell's (S=1024,
+    causal) and the serving prefill's (S=512, causal + key mask).
+
+    Tolerance, from bf16's epsilon: both results are ROUNDED to bf16 (half
+    an ulp each) and round p at different points (XLA the normalised
+    probabilities, the kernel the unnormalised ones), so they may differ
+    by two ulps of the largest magnitude: 2 * eps * max|want|."""
+    from distributed_tensorflow_example_tpu.ops.pallas.flash_attention \
+        import flash_schedule
+
+    sch = flash_schedule(seq, KD, jnp.bfloat16)
+    assert sch.tileable(seq, KD)
+    assert min(sch[:4]) > 128                # retiled, not the old constant
+    rs = np.random.RandomState(seq)
+    q, k, v = (jnp.asarray(rs.randn(1, seq, H, KD) * 0.5, jnp.bfloat16)
+               for _ in range(3))
+    w = jnp.asarray(rs.randn(1, seq, H, KD), jnp.float32)
+    valid = seq - seq // 4
+    mask = None
+    if masked:
+        m = np.ones((1, seq), np.int32)
+        m[:, valid:] = 0
+        mask = jnp.asarray(m)
+        w = w.at[:, valid:].set(0.0)         # padded rows: no contract
+
+    def xla(q, k, v):
+        m4 = None if mask is None else mask[:, None, None, :]
+        return multi_head_attention(q, k, v, mask=m4, causal=True)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, mask=mask, causal=True)
+
+    def close(got, want):
+        got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=2 * BF16_EPS * np.abs(want).max())
+
+    got, want = flash(q, k, v), xla(q, k, v)
+    assert got.dtype == jnp.bfloat16
+    close(got[:, :valid], want[:, :valid])
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32) * w)
+
+    g_flash = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    g_xla = jax.grad(loss(xla), argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(g_flash, g_xla):
+        assert a.dtype == jnp.bfloat16
+        close(a, b)
+
+
+@pytest.mark.parametrize("seq,d,dtype,want", [
+    # the gpt2s-train cell: one forward step a batch-head, four backward
+    (1024, 64, jnp.bfloat16, (1024, 1024, 512, 512, "fused")),
+    # float32 operands: the sweep found the same winner
+    (1024, 64, jnp.float32, (1024, 1024, 512, 512, "fused")),
+    # the serving prefill (forward only): the whole prompt in one tile
+    (512, 64, jnp.bfloat16, (512, 512, 512, 512, "fused")),
+    (4096, 64, jnp.bfloat16, (1024, 1024, 512, 512, "fused")),
+    # the itemsize counts: the fused backward holds an [S, D] f32 dq
+    # slab and a double-buffered [S, D] dq block in the input's dtype,
+    # and past their VMEM limit the choice degrades to split
+    (16384, 64, jnp.bfloat16, (1024, 1024, 512, 512, "fused")),
+    (32768, 64, jnp.bfloat16, (1024, 1024, 512, 512, "split")),
+    (8192, 64, jnp.float32, (1024, 1024, 512, 512, "fused")),
+    (16384, 64, jnp.float32, (1024, 1024, 512, 512, "split")),
+    (65536, 64, jnp.bfloat16, (1024, 1024, 512, 512, "split")),
+    # only the largest candidate that divides S: 768 = 3 x 256
+    (768, 64, jnp.bfloat16, (256, 256, 256, 256, "fused")),
+    (384, 128, jnp.bfloat16, (128, 128, 128, 128, "fused")),
+    # S=128 keeps the one tile it always had
+    (128, 64, jnp.bfloat16, (128, 128, 128, 128, "fused")),
+])
+def test_flash_schedule_is_a_rule_on_the_shape(seq, d, dtype, want):
+    from distributed_tensorflow_example_tpu.ops.pallas.flash_attention \
+        import FlashSchedule, effective_bwd_variant, flash_schedule
+
+    sch = flash_schedule(seq, d, dtype)
+    assert sch == FlashSchedule(*want)
+    assert kernel_engages(seq, d)
+    # what the MFU accounting counts is what the schedule runs, and a
+    # forced fused degrades where the chosen one does
+    assert effective_bwd_variant(seq, d, None, dtype) == sch.bwd_variant
+    assert effective_bwd_variant(seq, d, "fused", dtype) == sch.bwd_variant
+    assert effective_bwd_variant(seq, d, "split", dtype) == "split"
+
+
+def test_flash_schedule_grid_steps_and_log_line():
+    from distributed_tensorflow_example_tpu.ops.pallas.flash_attention \
+        import describe_attention, flash_schedule
+
+    sch = flash_schedule(1024, 64, jnp.bfloat16)
+    # per batch-head: 1 + 4 steps where block 128 made 64 + 2 * 64
+    assert sch.grid_steps(1024) == (1, 4)
+    line = describe_attention(1024, 64, "bfloat16")
+    assert "fwd 1024x1024" in line and "bwd fused 512x512" in line
+    assert "fwd 1, bwd 4" in line
+    # a set lever shows in the line, as it reaches the kernel
+    assert "bwd split 256x256" in describe_attention(
+        1024, 64, "bfloat16", bwd_block=256, bwd_variant="split")
+    assert "falls back to XLA" in describe_attention(250, 64)
+
+
+def test_levers_override_the_schedule():
+    from distributed_tensorflow_example_tpu.ops.pallas.flash_attention \
+        import resolve_schedule
+
+    def resolved(**levers):
+        return tuple(resolve_schedule(1024, 64, jnp.bfloat16, **levers))
+
+    assert resolved() == (1024, 1024, 512, 512, "fused")
+    # a set forward tile tiles the backward too, as it always has
+    assert resolved(block_q=256) == (256, 1024, 256, 1024, "fused")
+    assert resolved(block_q=256, block_k=512, bwd_block=128) == \
+        (256, 512, 128, 128, "fused")
+    assert resolved(bwd_block=256) == (1024, 1024, 256, 256, "fused")
+    assert resolved(bwd_variant="split") == (1024, 1024, 512, 512, "split")
+    # levers clamp to the sequence, as before
+    assert tuple(resolve_schedule(256, 64, block_q=512, block_k=512)) == \
+        (256, 256, 256, 256, "fused")
+
+
+@pytest.mark.parametrize("flag,want", [
+    ([], {}),
+    (["--attention_bwd", "auto"], {}),
+    (["--attention_bwd", "split"], {"bwd_variant": "split"}),
+    (["--attention_bwd", "fused"], {"bwd_variant": "fused"}),
+])
+def test_attention_bwd_flag_forces_or_leaves_the_choice(flag, want):
+    """``--attention_bwd`` keeps its meaning: split and fused both FORCE
+    their kernel (fused with its VMEM degrade); left at its default
+    (auto) the lever is unset and the schedule chooses."""
+    from distributed_tensorflow_example_tpu.cli.train import (
+        build_parser, config_from_args)
+    from distributed_tensorflow_example_tpu.config import (
+        flash_attention_kwargs)
+    from distributed_tensorflow_example_tpu.ops.pallas.flash_attention \
+        import resolve_schedule
+
+    cfg = config_from_args(build_parser().parse_args(
+        ["--model", "gpt", "--attention", "flash", *flag]))
+    kw = flash_attention_kwargs(cfg)
+    assert kw == want
+    assert resolve_schedule(1024, 64, **kw).bwd_variant == \
+        want.get("bwd_variant", "fused")
+
+
+@pytest.mark.parametrize("seq,d", [(64, 64), (250, 64), (1000, 64),
+                                   (256, 32), (1024, 96)])
+def test_unfriendly_shapes_still_fall_back(seq, d):
+    """No tile of the schedule turns a shape the kernels never took into
+    one they take: short, odd or MXU-unaligned shapes go to XLA."""
+    assert not kernel_engages(seq, d)
+    rs = np.random.RandomState(seq + d)
+    q, k, v = (jnp.asarray(rs.randn(1, seq, 2, d).astype(np.float32) * 0.4)
+               for _ in range(3))
+    np.testing.assert_allclose(
+        np.asarray(flash_attention(q, k, v, causal=True)),
+        np.asarray(multi_head_attention(q, k, v, causal=True)),
+        rtol=1e-5, atol=1e-6)
+
+
+def test_default_call_runs_the_chosen_schedule(monkeypatch):
+    """No lever set: the tiles that reach the kernels are
+    ``flash_schedule``'s for the call's own (S, D, dtype)."""
+    import importlib
+    fa_mod = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.pallas.flash_attention")
+    seen = []
+    real = fa_mod._make_flash
+
+    def spy(*args):
+        seen.append(args)
+        return real(*args)
+
+    monkeypatch.setattr(fa_mod, "_make_flash", spy)
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv_kernel(16))
+    flash_attention(q, k, v, causal=True)
+    flash_attention(q, k, v, causal=True, bwd_variant="split")
+    want = tuple(fa_mod.flash_schedule(KS, KD, jnp.bfloat16))
+    assert seen[0] == (H, *want, True, False)
+    assert seen[1] == (H, *want[:4], "split", True, False)
+
+
+@pytest.mark.parametrize("seq,levers,want", [
+    (128, {}, "attention flash: flash attention falls back to XLA"),
+    (64, dict(attention_impl="xla"), "attention xla"),
+])
+def test_trainer_logs_the_attention_path_once(seq, levers, want):
+    """The start-up line beside the parameter count: which attention
+    runs and, for flash, the schedule the kernels chose (gpt_tiny's
+    head dim is 32, so its flash call reads as the XLA fallback; the
+    chosen-tiles text is ``describe_attention``'s, tested above)."""
+    import logging
+
+    from distributed_tensorflow_example_tpu.config import (DataConfig,
+                                                           MeshShape,
+                                                           TrainConfig)
+    from distributed_tensorflow_example_tpu.models import get_model
+    from distributed_tensorflow_example_tpu.train.trainer import Trainer
+
+    cfg = TrainConfig(**{"model": "gpt_tiny", "attention_impl": "flash",
+                         "mesh": MeshShape(data=8),
+                         "data": DataConfig(batch_size=8, seq_len=seq),
+                         **levers})
+    model = get_model("gpt_tiny", cfg)
+    batch = model.dummy_batch(8)
+    trainer = Trainer(model, cfg, {k: v[:, :seq] for k, v in batch.items()})
+    # the dtx logger does not propagate to root: a handler of our own
+    records = []
+
+    class _Grab(logging.Handler):
+        def emit(self, record):
+            records.append(record)
+
+    lg, grab = logging.getLogger("dtx.trainer"), _Grab(logging.INFO)
+    lg.addHandler(grab)
+    try:
+        trainer.initialize()
+    finally:
+        lg.removeHandler(grab)
+    lines = [r.getMessage() for r in records
+             if "attention" in r.getMessage()]
+    assert len(lines) == 1 and lines[0].startswith("model gpt_tiny: ")
+    assert want in lines[0]

@@ -104,6 +104,63 @@ def test_flash_kernels_compile(chip, name, batch, seq, causal, bwd):
                                              "fused": 2}[bwd]
 
 
+@pytest.mark.parametrize("name,batch,seq,masked,grad", [
+    ("gpt2s_train_cell", 16, 1024, False, True),
+    ("serving_prefill", 1, 512, True, False),
+])
+def test_flash_chosen_schedule_compiles(chip, name, batch, seq, masked,
+                                        grad):
+    """No lever set: the tiles ``flash_schedule`` chooses for the
+    gpt2s-train cell's shape (B=16, S=1024, bf16, causal; forward and
+    the backward variant it picked) and for the serving prefill's (one
+    prompt, S=512, key mask + causal, forward only) are tiles Mosaic
+    takes, with the VMEM their kernels ask for."""
+    sch = flash_mod.flash_schedule(seq, D, BF16)
+    assert flash_mod.kernel_engages(seq, D) and min(sch[:4]) > 128
+
+    def fn(q, k, v, mask):
+        out = flash_mod.flash_attention(
+            q, k, v, mask=mask if masked else None, causal=True)
+        return jnp.sum(out.astype(jnp.float32)) if grad else out
+
+    if grad:
+        fn = jax.grad(fn, argnums=(0, 1, 2))
+    qkv = on(chip[0], (batch, seq, H, D))
+    text = compile_text(fn, qkv, qkv, qkv,
+                        on(chip[0], (batch, seq), jnp.int32))
+    assert "flash_fwd" in text
+    if grad:
+        # the names train_flash_bwd_ms reads by pattern, whatever variant
+        wanted = {"fused": ["flash_bwd_fused"],
+                  "split": ["flash_bwd_dq", "flash_bwd_dkv"]}
+        for kernel in wanted[sch.bwd_variant]:
+            assert kernel in text
+    # the kernel's operands at the folded [B*H, S, D] shape, in bf16
+    assert f"bf16[{batch * H},{seq},{D}]" in text
+
+
+@pytest.mark.parametrize("seq,dtype,want", [
+    # the longest sequence whose fused backward the schedule still
+    # picks, by itemsize: slab + dq block are 16 MiB / 12 MiB of VMEM
+    (16384, BF16, "fused"), (8192, jnp.float32, "fused"),
+    # past it the default call runs the split pair, as it did when
+    # split was the default (fused there: RESOURCE_EXHAUSTED in vmem)
+    (32768, BF16, "split"), (16384, jnp.float32, "split"),
+])
+def test_flash_long_sequence_backward_fits_vmem(chip, seq, dtype, want):
+    """No lever set, long S: the fused backward's [S, D] f32 dq slab and
+    double-buffered [S, D] dq block are in the VMEM the kernel asks for,
+    and where they outgrow ``_FUSED_SLAB_LIMIT`` the schedule runs the
+    split pair instead of failing to compile."""
+    assert flash_mod.flash_schedule(seq, D, dtype).bwd_variant == want
+    fn = jax.grad(functools.partial(_flash_loss, mask=None, causal=True,
+                                    bwd_variant=None), argnums=(0, 1, 2))
+    qkv = on(chip[0], (1, seq, 2, D), dtype)
+    text = compile_text(fn, qkv, qkv, qkv)
+    assert ("flash_bwd_fused" in text) == (want == "fused")
+    assert ("flash_bwd_dq" in text) == (want == "split")
+
+
 def test_flash_partitions_itself_over_a_four_chip_mesh(chip):
     """What ``cli.train --mesh data=4 --attention flash`` compiles: the
     batch sharded over four chips under the mesh SyncReplicas makes
