@@ -45,7 +45,11 @@ def build_parser() -> argparse.ArgumentParser:
                         "bert | bert_large | bert_tiny | moe_bert | "
                         "moe_bert_tiny | pipe_bert | pipe_bert_tiny | "
                         "pipe_moe_bert | pipe_moe_bert_tiny | "
-                        "gpt | gpt_tiny")
+                        "gpt | gpt_tiny | sdar_moe | sdar_moe_tiny "
+                        "(served decoders: export only)")
+    p.add_argument("--num_layers", type=int, default=0,
+                   help="block-description models (sdar_moe): how many "
+                        "layers to build (default 0: the model's own)")
     p.add_argument("--dataset", default=None,
                    help="default: the model's canonical dataset")
     p.add_argument("--data_dir", default=None,
@@ -437,6 +441,7 @@ def config_from_args(args: argparse.Namespace) -> TrainConfig:
         profile_steps = (int(a), int(b))
     return TrainConfig(
         model=args.model,
+        num_layers=args.num_layers,
         train_steps=args.train_steps,
         label_smoothing=args.label_smoothing,
         moe_experts=args.moe_experts,

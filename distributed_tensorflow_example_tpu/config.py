@@ -299,6 +299,9 @@ class TrainConfig:
                                      # inert — production paths pay zero
                                      # cost
     seed: int = 0
+    num_layers: int = 0              # depth of a model built from a block
+                                     # description (models/decoder.py);
+                                     # 0 = the model's own
     dtype: str = "float32"           # compute dtype: float32 | bfloat16
     param_dtype: str = "float32"
     bn_stats_dtype: str = "float32"  # BN batch-statistic reduction dtype

@@ -13,5 +13,6 @@ from . import pipe_mlp as pipe_mlp  # registers "pipe_mlp"
 from . import pipe_bert as pipe_bert  # registers "pipe_bert"(+_tiny)
 from . import pipe_moe as pipe_moe  # registers "pipe_moe_bert"(+_tiny)
 from . import gpt as gpt          # registers "gpt", "gpt_tiny"
+from . import decoder as decoder  # registers "sdar_moe"(+_tiny)
 
 __all__ = ["Model", "get_model", "list_models", "register_model"]

@@ -332,3 +332,72 @@ def moe_ffn_shard_map(params: Params, x: jax.Array, mesh, *,
     fn = jax.shard_map(body, mesh=mesh, in_specs=(pspec, xspec),
                        out_specs=(xspec, aux_spec), check_vma=False)
     return fn(params, x)
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer that holds a SHARE of the experts (serving decoders)
+# ---------------------------------------------------------------------------
+
+def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
+                 top_k: int, first_expert: int = 0,
+                 dtype=jnp.bfloat16, router_dtype=jnp.float32
+                 ) -> tuple[jax.Array, jax.Array]:
+    """A gated (SiLU) expert FFN that never drops a token, told which
+    experts it holds.
+
+    ``x``: [T, H]; ``router``: [H, E], always the PUBLISHED expert count;
+    ``experts``: ``{"gate": [Eh, H, F], "up": [Eh, H, F], "down":
+    [Eh, F, H]}``, the held experts, whose global ids are
+    ``first_expert .. first_expert + Eh - 1``. Routing is over all E:
+    float32 softmax of the float32 router product, the ``top_k``
+    largest, renormalised to sum to 1. The (row, expert) pairs whose
+    expert is held are sorted by expert and run through one grouped
+    matmul per projection (``lax.ragged_dot``: a Mosaic grouped matmul
+    on the TPU, ``ragged-dot-*`` in a capture); the rest contribute
+    nothing, here as on the chip that would hold them, so the shares of
+    a layer add up to the whole layer. Matmul operands are ``dtype``,
+    accumulation float32; the router's operands are ``router_dtype``
+    (float32: which 8 of 128 near-equal probabilities are largest is
+    decided by the last bits, and a coarser product picks other experts).
+
+    Returns ``(y [T, H] float32, rows [Eh] int32)``: the held experts'
+    part of the layer's output, and how many rows each held expert
+    received. (The encoder's capacity dispatch, :func:`moe_ffn`, is
+    another layer: it drops past a capacity and trains.)
+    """
+    t, _ = x.shape
+    e_held = experts["gate"].shape[0]
+    with jax.named_scope("moe_route"):
+        logits = jnp.dot(
+            x.astype(router_dtype).astype(jnp.float32),
+            router.astype(router_dtype).astype(jnp.float32),
+            precision=lax.Precision.HIGHEST)
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        local = (idx - first_expert).reshape(-1)            # [T * k]
+        held = (local >= 0) & (local < e_held)
+        # pairs of absent experts sort past the last group
+        key = jnp.where(held, local, e_held)
+        order = jnp.argsort(key, stable=True)
+        rows = jnp.bincount(key, length=e_held + 1)[:e_held].astype(
+            jnp.int32)
+        live = (jnp.arange(t * top_k) < jnp.sum(rows))[:, None]
+    with jax.named_scope("moe_experts"):
+        xs = x.astype(dtype)[order // top_k]                # [T * k, H]
+
+        def grouped(a, w_e):
+            out = lax.ragged_dot(a, w_e.astype(dtype), rows,
+                                 preferred_element_type=jnp.float32)
+            # rows past the last group belong to no held expert: what
+            # the grouped matmul leaves there is not a result
+            return jnp.where(live, out, 0.0)
+
+        act = jax.nn.silu(grouped(xs, experts["gate"])) * grouped(
+            xs, experts["up"])
+        out = grouped(act.astype(dtype), experts["down"])   # [T * k, H]
+    with jax.named_scope("moe_combine"):
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(t * top_k, dtype=order.dtype))
+        y = jnp.sum(out[inverse].reshape(t, top_k, -1)
+                    * w[..., None], axis=1)
+    return y, rows

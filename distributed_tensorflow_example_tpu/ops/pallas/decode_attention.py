@@ -430,6 +430,176 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            k_scale=k_scale, v_scale=v_scale)
 
 
+# ---------------------------------------------------------------------------
+# Block steps over grouped KV heads (block-diffusion decoders)
+#
+# A block step runs B lanes a slot, and a KV head is shared by a group of
+# query heads, so a slot brings R = group x B query rows to each KV head.
+# Every one of them sees the same window: the block is bidirectional
+# inside and causal across, so row (slot, lane) attends to the logical
+# slots <= last[slot], the block's last position. That makes the mask one
+# scalar a slot, and the kernel a single pass over the slot's live pool
+# blocks with all its KV heads' rows resident.
+# ---------------------------------------------------------------------------
+
+def block_tile_friendly(block_size: int, rows: int, head_dim: int) -> bool:
+    """The shapes the TPU compiler takes for :func:`paged_block_attention`'s
+    kernel: K/V blocks carved [block_size, KVH*D] from the pool's view
+    (D a multiple of 128 lanes a head), score rows [R, block_size], and R
+    query rows a KV head in whole sublane tiles."""
+    return block_size % 128 == 0 and head_dim % 128 == 0 and rows % 8 == 0
+
+
+def xla_paged_block_attention(q, k_pool, v_pool, *, block_tables, last):
+    """Reference path: gather each slot's block run and attend plainly."""
+    _, kvh, _, d = q.shape
+    bs = k_pool.shape[1]
+    bt = jnp.asarray(block_tables, jnp.int32)
+    b, nb = bt.shape
+    k = k_pool[bt].reshape(b, nb * bs, kvh, d)
+    v = v_pool[bt].reshape(b, nb * bs, kvh, d)
+    s = jnp.einsum("bhrd,bthd->bhrt", q, k,
+                   preferred_element_type=jnp.float32) / math.sqrt(d)
+    live = jnp.arange(nb * bs)[None, :] <= last[:, None]       # [B, T]
+    s = jnp.where(live[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("bhrt,bthd->bhrd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def _block_kernel(bt_ref, last_ref, q_ref, k_ref, v_ref, o_ref,
+                  m_ref, l_ref, acc_ref, *, block_size: int, kv_heads: int,
+                  rows: int, head_dim: int, sm_scale: float):
+    """Grid (B, NB): one [block_size, KVH*D] K/V block of the slot's run a
+    step, every KV head's R query rows resident; online softmax over the
+    NB dimension. A step past the slot's last live block names that block
+    again (no DMA) and computes nothing."""
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    last = last_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(j * block_size <= last)
+    def _compute():
+        kpos = j * block_size + lax.broadcasted_iota(
+            jnp.int32, (1, block_size), 1)
+        live = kpos <= last
+        for h in range(kv_heads):           # static: KVH heads a block
+            r0, c0 = h * rows, h * head_dim
+            q = q_ref[0, r0:r0 + rows, :]                     # [R, D]
+            k = k_ref[0, :, c0:c0 + head_dim]                 # [Bs, D]
+            v = v_ref[0, :, c0:c0 + head_dim]
+            s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32
+                                ) * sm_scale
+            s = jnp.where(live, s, NEG_INF)
+            m_prev = m_ref[r0:r0 + rows, :]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[r0:r0 + rows, :] = (l_ref[r0:r0 + rows, :] * alpha
+                                      + jnp.sum(p, axis=-1, keepdims=True))
+            acc_ref[r0:r0 + rows, :] = (
+                acc_ref[r0:r0 + rows, :] * alpha
+                + jnp.dot(p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32))
+            m_ref[r0:r0 + rows, :] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        # logical slot 0 is live for every slot, so l > 0
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+def _block_dispatch(q, k_pool, v_pool, block_tables, last):
+    bs = k_pool.shape[1]
+    b, kvh, r, d = q.shape
+    nb = block_tables.shape[1]
+
+    def kv_map(bb, jj, bt, last_s):
+        return (bt[bb, jnp.minimum(jj, last_s[bb] // bs)], 0, 0)
+
+    def q_map(bb, jj, bt, last_s):
+        return (bb, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,              # block_tables, last
+        grid=(b, nb),
+        in_specs=[pl.BlockSpec((1, kvh * r, d), q_map),
+                  pl.BlockSpec((1, bs, kvh * d), kv_map),
+                  pl.BlockSpec((1, bs, kvh * d), kv_map)],
+        out_specs=pl.BlockSpec((1, kvh * r, d), q_map),
+        scratch_shapes=[pltpu.VMEM((kvh * r, 1), jnp.float32),
+                        pltpu.VMEM((kvh * r, 1), jnp.float32),
+                        pltpu.VMEM((kvh * r, d), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_block_kernel, block_size=bs, kv_heads=kvh,
+                          rows=r, head_dim=d, sm_scale=1.0 / math.sqrt(d)),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh * r, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name="paged_block_attn",
+        interpret=_interpret(),
+    )(block_tables, last, q.reshape(b, kvh * r, d), k_pool, v_pool)
+    return out.reshape(b, kvh, r, d)
+
+
+def paged_block_attention(q: jax.Array, k_pool: jax.Array,
+                          v_pool: jax.Array, *, block_tables, last,
+                          impl: str = "auto") -> jax.Array:
+    """A block step's attention against the block-paged pool, grouped KV
+    heads.
+
+    ``q``: [B, KVH, R, D], the R = (query heads a KV head) x (lanes a
+    block) rows each KV head of slot b answers; ``k_pool``/``v_pool``:
+    [N, block_size, KVH * D] (the lanes' own K/V already written), a
+    token's heads side by side in the lane dimension: the layout the
+    kernel's [block_size, KVH * D] blocks are carved from as they lie
+    (a [.., KVH, D] pool is tiled by (KVH, D) in HBM, and handing it to
+    the kernel costs a copy of the whole pool a call);
+    ``block_tables``: [B, NB] int32; ``last``: [B] int32, the last
+    logical slot the block's rows see (``pos | (lanes - 1)``): all R rows
+    of a slot attend to logical slots ``0 .. last[b]``. Returns
+    [B, KVH, R, D] in ``q``'s dtype. MXU operands stay in the inputs'
+    dtype; scores, softmax and accumulation are float32.
+
+    ``impl`` as in :func:`paged_decode_attention`: the kernel
+    (``paged_block_attn`` in a capture) needs :func:`block_tile_friendly`
+    shapes; anything else gathers the run and attends in XLA.
+    """
+    b, kvh, r, d = q.shape
+    bs = k_pool.shape[1]
+    if k_pool.ndim != 3 or k_pool.shape[2] != kvh * d:
+        raise ValueError(f"pool shape {k_pool.shape} is not [N, Bs, "
+                         f"{kvh * d}] for q {q.shape}")
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"unknown decode attention impl {impl!r}")
+    bt = jnp.asarray(block_tables, jnp.int32)
+    if bt.ndim != 2 or bt.shape[0] != b:
+        raise ValueError(f"block_tables shape {bt.shape} != ({b}, NB)")
+    friendly = block_tile_friendly(bs, r, d)
+    if impl == "pallas" and not friendly:
+        raise ValueError(
+            f"paged_block_attention's kernel needs block_size % 128 == 0, "
+            f"a head dim that is a multiple of 128 and rows % 8 == 0, got "
+            f"block_size={bs} R={r} D={d} (impl='auto' falls back to XLA)")
+    last = jnp.clip(jnp.broadcast_to(
+        jnp.asarray(last, jnp.int32).reshape(-1), (b,)),
+        0, bt.shape[1] * bs - 1)
+    if friendly and (impl == "pallas" or (
+            impl == "auto" and jax.default_backend() == "tpu")):
+        return _block_dispatch(q, k_pool, v_pool, bt, last)
+    return xla_paged_block_attention(q, k_pool, v_pool, block_tables=bt,
+                                     last=last)
+
+
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                      pos, pad, impl: str = "auto") -> jax.Array:
     """One-query attention against the cache slab.

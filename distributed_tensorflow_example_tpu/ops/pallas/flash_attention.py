@@ -246,13 +246,17 @@ def _interpret() -> bool:
 
 
 def _block_mask(s, mask_row, causal: bool, q_start, k_start,
-                blk_q: int, blk_k: int):
-    """Apply key-validity row mask and/or causal mask to a score block."""
+                blk_q: int, blk_k: int, causal_block: int = 1):
+    """Apply key-validity row mask and/or causal mask to a score block.
+    ``causal_block`` B > 1 (a power of two): block-causal, a query sees
+    the keys up to the end of its own block of B positions."""
     if mask_row is not None:
         s = jnp.where(mask_row != 0, s, NEG_INF)
     if causal:
         qpos = lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0) + q_start
         kpos = lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1) + k_start
+        if causal_block > 1:
+            qpos = qpos | (causal_block - 1)
         s = jnp.where(qpos >= kpos, s, NEG_INF)
     return s
 
@@ -287,13 +291,14 @@ def _q_stream(causal: bool, blk_q: int, blk_k: int):
 
 
 def _scores(q_ref, k_ref, mask_ref, *, causal: bool, q_start, k_start,
-            sm_scale: float):
+            sm_scale: float, causal_block: int = 1):
     """The scaled, masked f32 score block [blk_q, blk_k]. MXU operands
     stay in the input's dtype; the accumulator is f32."""
     s = lax.dot_general(q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
                         preferred_element_type=jnp.float32) * sm_scale
     mrow = mask_ref[0] if mask_ref is not None else None  # [1, blk_k]
-    return _block_mask(s, mrow, causal, q_start, k_start, *s.shape)
+    return _block_mask(s, mrow, causal, q_start, k_start, *s.shape,
+                       causal_block=causal_block)
 
 
 def _maskless(kernel, n_inputs: int):
@@ -324,7 +329,7 @@ def _compiler_params(semantics: tuple, blk_q: int, blk_k: int, d: int,
 def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *,
                 blk_q: int, blk_k: int, nk: int, causal: bool,
-                sm_scale: float):
+                sm_scale: float, causal_block: int = 1):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
 
@@ -338,7 +343,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
     def _compute():
         s = _scores(q_ref, k_ref, mask_ref, causal=causal,
                     q_start=qi * blk_q, k_start=ki * blk_k,
-                    sm_scale=sm_scale)
+                    sm_scale=sm_scale, causal_block=causal_block)
         v = v_ref[0]
         m_prev, l_prev = m_scr[...], l_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
@@ -365,8 +370,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
 
 
 def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
-         causal: bool):
-    """q3,k3,v3: [BH, S, D]; mask2: [B, S] or None. Returns (o, L)."""
+         causal: bool, causal_block: int = 1):
+    """q3,k3,v3: [BH, S, D]; mask2: [B, S] or None. Returns (o, L).
+    ``causal_block`` > 1 divides both tiles, so which tiles are live is
+    what plain causal has: only the mask inside a tile differs."""
     bh, s, d = q3.shape
     sm_scale = 1.0 / math.sqrt(d)
     nq, nk = s // blk_q, s // blk_k
@@ -381,6 +388,8 @@ def _fwd(q3, k3, v3, mask2, *, heads: int, blk_q: int, blk_k: int,
     args = [q3, k3, v3]
     kw = dict(blk_q=blk_q, blk_k=blk_k, nk=nk, causal=causal,
               sm_scale=sm_scale)
+    if causal_block > 1:        # the default adds no keyword: same trace
+        kw["causal_block"] = causal_block
     if mask2 is not None:
         in_specs.append(pl.BlockSpec(
             (1, 1, blk_k), lambda b, i, j: (b // heads, 0, kblk(i, j))))
@@ -750,13 +759,57 @@ def _partitioned(amesh, q, k, v, mask, **kw):
         out_specs=qkv, check_vma=False)(q, k, v, *masks)
 
 
+def _block_causal(q, k, v, mask, sch: FlashSchedule, causal_block: int):
+    """The forward under the block-causal mask: ``flash_fwd`` where the
+    shape tiles (and the tiles hold whole blocks), plain XLA attention
+    under the same mask elsewhere. No gradient: a serving prefill."""
+    b, s, h, d = q.shape
+    if mask is not None and mask.ndim == 4:
+        mask = mask[:, 0, 0, :]
+    if (sch.tileable(s, d) and sch.blk_q % causal_block == 0
+            and sch.blk_k % causal_block == 0):
+        def fold(x):
+            return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
+
+        o3, _ = _fwd(fold(q), fold(k), fold(v),
+                     None if mask is None else mask.astype(jnp.int32),
+                     heads=h, blk_q=sch.blk_q, blk_k=sch.blk_k,
+                     causal=True, causal_block=causal_block)
+        return o3.reshape(b, h, s, d).transpose(0, 2, 1, 3)
+    return xla_block_causal_attention(q, k, v, causal_block, mask=mask)
+
+
+def xla_block_causal_attention(q, k, v, causal_block: int, *, mask=None):
+    """Plain attention under the block-causal mask ([B,S,H,D] in/out,
+    ``mask`` [B,S] key validity): the kernel path's fallback, and what a
+    portable export pins."""
+    s, d = q.shape[1], q.shape[3]
+    scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
+                        preferred_element_type=jnp.float32) / math.sqrt(d)
+    pos = jnp.arange(s)
+    allowed = ((pos[:, None] | (causal_block - 1)) >= pos[None, :])[None,
+                                                                    None]
+    if mask is not None:
+        allowed = allowed & (mask != 0)[:, None, None, :]
+    probs = jax.nn.softmax(jnp.where(allowed, scores, NEG_INF), axis=-1)
+    out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    return out.astype(v.dtype)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     mask: jax.Array | None = None, causal: bool = False,
                     block_q: int | None = None,
                     block_k: int | None = None,
                     bwd_block: int = 0,
-                    bwd_variant: str | None = None) -> jax.Array:
+                    bwd_variant: str | None = None,
+                    causal_block: int = 1) -> jax.Array:
     """Drop-in for ``multi_head_attention(impl="xla")``: [B,S,H,D] in/out.
+
+    ``causal_block`` B > 1 (a power of two, with ``causal``) is the
+    block-causal mask of a block-diffusion decoder's prefill: query i
+    sees key j iff ``j // B <= i // B``. Forward only (it serves); 1, the
+    default, is the causal mask and traces what it always has.
 
     ``mask``: [B,S] key-validity (1 = attend) or broadcastable [B,1,1,S].
     The levers override ``flash_schedule``'s choice for this shape and
@@ -777,10 +830,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     if bwd_variant is not None and bwd_variant not in BWD_VARIANTS:
         raise ValueError(f"bwd_variant must be one of {BWD_VARIANTS}, "
                          f"got {bwd_variant!r}")
+    if causal_block < 1 or causal_block & (causal_block - 1) or (
+            causal_block > 1 and not causal):
+        raise ValueError(f"causal_block must be a power of two and needs "
+                         f"causal=True, got {causal_block} (causal="
+                         f"{causal})")
     b, s, h, d = q.shape
     sch = resolve_schedule(s, d, q.dtype, block_q=block_q,
                            block_k=block_k, bwd_block=bwd_block,
                            bwd_variant=bwd_variant)
+    if causal_block > 1:
+        return _block_causal(q, k, v, mask, sch, causal_block)
     if not sch.tileable(s, d):
         from ..attention import multi_head_attention
         m4 = None

@@ -52,6 +52,20 @@ _VERIFY = "verify.stablehlo"
 # interleaves these with shared decode steps so a long prompt can
 # never stall live decoders for a whole monolithic prefill
 _PREFILL_CHUNK = "prefill_chunk.stablehlo"
+# the batched block step of a block-diffusion decoder (models/decoder.py):
+# B lanes a slot, denoising and commit rows in one dispatch. It stands
+# where decode.stablehlo stands for a one-token-a-step decoder
+_BLOCK_STEP = "block_step.stablehlo"
+# parameters kept as a checkpoint beside the programs (weights as
+# arguments): one .npy a leaf plus params.json
+_PARAMS_DIR = "params"
+_PARAMS_INDEX = "params.json"
+#: parameters above this many bytes cannot be StableHLO constants, once
+#: per program: the artifact stores them as a checkpoint in their storage
+#: dtype and every program takes the one loaded tree as an argument. A
+#: rule on bytes: no flag, no model name. (The GPT family's programs keep
+#: the baked form whatever their size: ROADMAP D1.)
+BAKE_LIMIT_BYTES = 1 << 30
 
 
 def serving_signature(batch: dict[str, Any]) -> dict[str, Any]:
@@ -325,7 +339,33 @@ def export_generator(model, params, out_dir: str, *,
     standing parity discipline); the int8-pool composition rides the
     token-agreement drift gate instead. ``prefill_chunk`` lands in
     the ``stepwise`` metadata so the engine can validate the
-    serve-time budget against the exported chunk width."""
+    serve-time budget against the exported chunk width.
+
+    A model with a ``block_step`` (a block-diffusion decoder,
+    ``models/decoder.py``) exports the paged pair ``prefill.stablehlo``
+    + ``block_step.stablehlo`` and no monolithic program: see
+    :func:`_export_block_generator`."""
+    if hasattr(model, "block_step"):
+        refused = {"spec_tokens": spec_tokens, "weight_quant": weight_quant,
+                   "prefill_chunk": prefill_chunk,
+                   "kv_cache_dtype": (kv_cache_dtype
+                                      if kv_cache_dtype == "int8" else None)}
+        if any(refused.values()):
+            raise ValueError(
+                "a block-diffusion artifact takes no speculative verify "
+                "program, int8 weights, int8 KV or chunked prefill (a "
+                "block step is none of their programs); got "
+                f"{ {k: v for k, v in refused.items() if v} }")
+        if not (stepwise and paged):
+            raise ValueError(
+                "a block-diffusion decoder is served by the paged engine "
+                "only: export with stepwise=True, paged=True")
+        return _export_block_generator(
+            model, params, out_dir, prompt_len=prompt_len,
+            max_new_tokens=max_new_tokens, slots=slots,
+            block_size=block_size, num_blocks=num_blocks,
+            pool_bytes=pool_bytes, eos_id=eos_id, pad_id=pad_id,
+            platforms=platforms)
     from .ckpt.checkpoint import _to_host
     params = jax.tree_util.tree_map(_to_host, params)
 
@@ -748,6 +788,205 @@ def _export_stepwise_paged(model, params, out_dir: str, *,
         **quant_meta)
 
 
+def _flat_leaves(tree, prefix: str = "") -> list[tuple[str, Any]]:
+    out = []
+    for k in sorted(tree):
+        path = f"{prefix}/{k}" if prefix else k
+        out += (_flat_leaves(tree[k], path) if isinstance(tree[k], dict)
+                else [(path, tree[k])])
+    return out
+
+
+def save_params(directory: str, params) -> None:
+    """Write a nested dict of arrays as a checkpoint: one ``.npy`` a leaf
+    in its own dtype (bfloat16 as its uint16 bits: npy has no bfloat16)
+    plus ``params.json``. A leaf at a time, so the host never holds the
+    tree."""
+    os.makedirs(directory, exist_ok=True)
+    index = []
+    for i, (path, leaf) in enumerate(_flat_leaves(params)):
+        arr = np.asarray(leaf)
+        dtype = str(arr.dtype)
+        if dtype == "bfloat16":
+            arr = arr.view(np.uint16)
+        name = f"{i:05d}.npy"
+        np.save(os.path.join(directory, name), arr)
+        index.append({"path": path, "file": name, "dtype": dtype,
+                      "shape": list(arr.shape)})
+    with open(os.path.join(directory, _PARAMS_INDEX), "w") as f:
+        json.dump(index, f)
+
+
+def load_params(directory: str):
+    """The checkpoint :func:`save_params` wrote, each leaf committed to
+    the first device as it is read: the tree exists once, there."""
+    with open(os.path.join(directory, _PARAMS_INDEX)) as f:
+        index = json.load(f)
+    dev = jax.devices()[0]
+    tree: dict = {}
+    for entry in index:
+        arr = np.load(os.path.join(directory, entry["file"]))
+        if entry["dtype"] == "bfloat16":
+            arr = arr.view(jnp.bfloat16)
+        node = tree
+        *parents, last = entry["path"].split("/")
+        for part in parents:
+            node = node.setdefault(part, {})
+        node[last] = jax.device_put(arr, dev)
+    return tree
+
+
+def _export_block_generator(model, params, out_dir: str, *,
+                            prompt_len: int, max_new_tokens: int,
+                            slots: int, block_size: int,
+                            num_blocks: int | None,
+                            pool_bytes: int | None, eos_id, pad_id: int,
+                            platforms: Sequence[str]) -> str:
+    """The artifact of a block-diffusion decoder: ``prefill.stablehlo``
+    (one prompt under the block-causal mask, K/V written in whole pool
+    blocks) and ``block_step.stablehlo`` (B lanes a slot; ids and
+    confidences out, never logits), over a pool [L, N, Bs, KVH * D] in the
+    model's compute dtype (a token's heads side by side: the layout the
+    attention kernel reads in place, ``ops/pallas/decode_attention``).
+
+    Weights: under ``BAKE_LIMIT_BYTES`` they are constants of both
+    programs, as in every other artifact. Above it they are saved once,
+    in their storage dtype, under ``params/``, and both programs take the
+    tree as their first argument; the caller may delete its own tree
+    once this returns. ``export.json`` says which (``weights``)."""
+    c = model.cfg
+    if slots < 1 or block_size < 1:
+        raise ValueError(f"slots and block_size must be >= 1, got "
+                         f"{slots}, {block_size}")
+    lanes = int(c.block_length)
+    if block_size % lanes:
+        raise ValueError(f"block_size {block_size} must hold whole "
+                         f"generation blocks of {lanes} positions")
+    total = prompt_len + max_new_tokens
+    if total > c.max_len:
+        raise ValueError(f"prompt_len {prompt_len} + max_new_tokens "
+                         f"{max_new_tokens} exceeds max_len {c.max_len}")
+    # a request's last generation block may end past its max_new, never
+    # past the capacity rounded up to whole generation blocks
+    blocks_per_slot = -(-(-(-total // lanes) * lanes) // block_size)
+    prompt_blocks = -(-prompt_len // block_size)
+    cache_dtype = np.dtype(jnp.dtype(model.dtype))
+    block_bytes = (2 * c.layers * block_size * c.kv_heads * c.head_dim
+                   * int(cache_dtype.itemsize))
+    if pool_bytes is not None and num_blocks is not None:
+        raise ValueError("pass pool_bytes OR num_blocks, not both")
+    if pool_bytes is not None:
+        num_blocks = 1 + pool_bytes // block_bytes
+    if num_blocks is None:
+        num_blocks = 1 + slots * blocks_per_slot
+    if num_blocks - 1 < blocks_per_slot:
+        raise ValueError(
+            f"num_blocks {num_blocks} leaves {num_blocks - 1} usable "
+            f"blocks but one full-depth request needs {blocks_per_slot}")
+    pool_shape = (c.layers, num_blocks, block_size,
+                  c.kv_heads * c.head_dim)
+    on_tpu = (tuple(platforms) == ("tpu",)
+              and jax.default_backend() == "tpu")
+    # kernels only in a TPU-only export traced on a TPU host (the rule
+    # export_generator's decode_attention follows)
+    prefill_attention = "flash" if on_tpu else "xla"
+    step_attention = "auto" if on_tpu else "xla"
+
+    def prefill_fn(p, feats):
+        ck, cv = model.paged_prefill(
+            p, feats["input_ids"], feats["prompt_mask"], feats["cache_k"],
+            feats["cache_v"], feats["table_row"],
+            attention=prefill_attention)
+        return {"cache_k": ck, "cache_v": cv}
+
+    def step_fn(p, feats):
+        return model.block_step(
+            p, feats["cache_k"], feats["cache_v"], feats["block_tables"],
+            feats["tok"], feats["pos"], feats["alive"], feats["commit"],
+            attention=step_attention)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    pool_specs = {"cache_k": spec(pool_shape, cache_dtype),
+                  "cache_v": spec(pool_shape, cache_dtype)}
+    prefill_specs = {"input_ids": spec((1, prompt_len), np.int32),
+                     "prompt_mask": spec((1, prompt_len), np.int32),
+                     "table_row": spec((prompt_blocks,), np.int32),
+                     **pool_specs}
+    step_specs = {"tok": spec((slots, lanes), np.int32),
+                  "pos": spec((slots,), np.int32),
+                  "alive": spec((slots,), np.int32),
+                  "commit": spec((slots,), np.int32),
+                  "block_tables": spec((slots, blocks_per_slot), np.int32),
+                  **pool_specs}
+    leaves = jax.tree_util.tree_leaves(params)
+    param_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in leaves)
+    as_args = param_bytes > BAKE_LIMIT_BYTES
+    chief = jax.process_index() == 0
+    if chief:
+        os.makedirs(out_dir, exist_ok=True)
+    p_specs = jax.tree_util.tree_map(lambda x: spec(x.shape, x.dtype),
+                                     params)
+    for name, fn, specs in ((_PREFILL, prefill_fn, prefill_specs),
+                            (_BLOCK_STEP, step_fn, step_specs)):
+        if as_args:
+            exp = jax_export.export(
+                jax.jit(fn), platforms=list(platforms))(p_specs, specs)
+        else:
+            exp = jax_export.export(
+                jax.jit(lambda feats, fn=fn: fn(params, feats)),
+                platforms=list(platforms))(specs)
+        if chief:
+            with open(os.path.join(out_dir, name), "wb") as f:
+                f.write(exp.serialize())
+    if as_args and chief:
+        save_params(os.path.join(out_dir, _PARAMS_DIR), params)
+    meta = {
+        "model": getattr(model, "name", type(model).__name__),
+        "kind": "generator", "batch_polymorphic": False,
+        "input_signature": {"input_ids": {"shape": [1, prompt_len],
+                                          "dtype": "int32"}},
+        "platforms": list(platforms),
+        "param_count": sum(int(np.prod(x.shape)) for x in leaves),
+        "param_bytes": param_bytes,
+        "weights": "checkpoint" if as_args else "baked",
+        "jax_version": jax.__version__,
+        "prompt_len": prompt_len, "max_new_tokens": max_new_tokens,
+        "temperature": 0.0, "top_k": 0, "top_p": 0.0,
+        "eos_id": eos_id, "pad_id": pad_id, "ragged": True,
+        "prng_impl": str(jax.random.key_impl(jax.random.key(0))),
+        "stepwise": {
+            "slots": slots, "prompt_len": prompt_len,
+            "max_new_tokens": max_new_tokens, "max_context": total,
+            "pool_shape": list(pool_shape),
+            "cache_dtype": str(cache_dtype),
+            "kv_cache_dtype": str(cache_dtype),
+            "vocab_size": c.vocab_size, "paged": True,
+            "block_size": block_size, "num_blocks": num_blocks,
+            "blocks_per_slot": blocks_per_slot,
+            "prompt_blocks": prompt_blocks, "layout": "left_aligned",
+            "block_bytes": block_bytes, "spec_tokens": 0,
+            "prefill_chunk": 0,
+            # generation by diffusion over blocks: what the engine's
+            # slot state and transfer rule need of the model
+            "block": {"length": lanes, "mask_id": int(c.mask_id),
+                      "denoising_steps": int(c.denoising_steps),
+                      "threshold": float(c.confidence_threshold),
+                      "layers": int(c.layers),
+                      "experts": int(c.experts),
+                      "experts_held": int(c.held),
+                      "experts_per_token": int(c.experts_per_token)},
+        },
+    }
+    artifact = os.path.join(out_dir, _BLOCK_STEP)
+    if chief:
+        with open(os.path.join(out_dir, _META), "w") as f:
+            json.dump(meta, f, indent=1)
+    return artifact
+
+
 def validate_quant_meta(meta: dict, *, where: str = "artifact") -> None:
     """Loud load-time validation of an artifact's quantization
     metadata — every mismatch names the ``export.json`` field instead
@@ -813,9 +1052,24 @@ class ServableModel:
         with open(os.path.join(directory, _META)) as f:
             self.meta = json.load(f)
         validate_quant_meta(self.meta, where=directory)
-        with open(os.path.join(directory, _ARTIFACT), "rb") as f:
+        path = os.path.join(directory, _ARTIFACT)
+        if not os.path.exists(path) and (
+                self.meta.get("stepwise") or {}).get("block"):
+            # a block-diffusion artifact has no monolithic program: the
+            # scheduler's pair is all of it
+            self._exported = None
+            self._call = self._scheduler_only
+            return
+        with open(path, "rb") as f:
             self._exported = jax_export.deserialize(f.read())
         self._call = jax.jit(self._exported.call)
+
+    @staticmethod
+    def _scheduler_only(features):
+        raise ValueError(
+            "this artifact generates by diffusion over blocks and holds "
+            "no monolithic program: serve it with the scheduler on "
+            "(the default for stepwise artifacts)")
 
     @property
     def input_signature(self) -> dict:
@@ -833,7 +1087,8 @@ def has_stepwise(directory: str) -> bool:
     """True when ``directory`` holds the stepwise (prefill + shared
     decode step) artifacts a continuous-batching scheduler can drive."""
     return (os.path.exists(os.path.join(directory, _PREFILL))
-            and os.path.exists(os.path.join(directory, _DECODE)))
+            and (os.path.exists(os.path.join(directory, _DECODE))
+                 or os.path.exists(os.path.join(directory, _BLOCK_STEP))))
 
 
 class StepwiseGenerator:
@@ -889,9 +1144,21 @@ class StepwiseGenerator:
                 "missing — the export is torn; re-export with "
                 "export_generator(..., prefill_chunk="
                 f"{self.prefill_chunk_tokens})")
+        #: generation by diffusion over blocks: the artifact's ``block``
+        #: metadata (length, mask id, schedule), else None. Such an
+        #: artifact's step program is block_step.stablehlo
+        self.block: dict | None = step_meta.get("block")
+        #: the one loaded parameter tree of a weights-as-arguments
+        #: artifact (``weights: "checkpoint"``), which every program
+        #: takes, never donated; None where the weights are baked
+        self.params = None
+        if self.meta.get("weights") == "checkpoint":
+            self.params = load_params(os.path.join(directory, _PARAMS_DIR))
         with open(os.path.join(directory, _PREFILL), "rb") as f:
             self._prefill_exp = jax_export.deserialize(f.read())
-        with open(os.path.join(directory, _DECODE), "rb") as f:
+        with open(os.path.join(
+                directory, _BLOCK_STEP if self.block else _DECODE),
+                "rb") as f:
             self._decode_exp = jax_export.deserialize(f.read())
         self._verify_exp = None
         if self.spec_tokens:
@@ -909,13 +1176,24 @@ class StepwiseGenerator:
         # jit_prefill_chunk in a profiler capture and in compile events
         # (the exported artifacts are untouched)
         def split(call, name):
+            if self.params is not None:
+                return split_with_params(call, name)
+
             def fn(pool, rest):
                 return call({**rest, **pool})
             fn.__name__ = fn.__qualname__ = name
             return jax.jit(fn, donate_argnums=(0,))
 
+        def split_with_params(call, name):
+            def fn(pool, params, rest):
+                return call(params, {**rest, **pool})
+            fn.__name__ = fn.__qualname__ = name
+            jitted = jax.jit(fn, donate_argnums=(0,))
+            return lambda pool, rest: jitted(pool, self.params, rest)
+
         self._prefill = split(self._prefill_exp.call, "prefill")
-        self._decode = split(self._decode_exp.call, "decode")
+        self._decode = split(self._decode_exp.call,
+                             "block_step" if self.block else "decode")
         self._verify = (split(self._verify_exp.call, "verify")
                         if self._verify_exp is not None else None)
         self._chunk = (split(self._chunk_exp.call, "prefill_chunk")
@@ -964,6 +1242,16 @@ class StepwiseGenerator:
     def decode(self, feats: dict) -> dict:
         pool, rest = self._split(feats)
         return self._decode(pool, rest)
+
+    def block_step(self, feats: dict) -> dict:
+        """One batched block step of a block-diffusion artifact (``tok``
+        [slots, B], ``pos``/``alive``/``commit`` [slots],
+        ``block_tables``): ``ids``/``conf`` [slots, B], the routing
+        scalars and the pool."""
+        if self.block is None:
+            raise ValueError("this artifact holds no block-step program "
+                             "(it decodes one token a step)")
+        return self.decode(feats)
 
     def verify(self, feats: dict) -> dict:
         """The K-token speculative-verify dispatch (``tok`` is

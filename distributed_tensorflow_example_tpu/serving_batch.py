@@ -943,6 +943,17 @@ def filter_logits_np(logits: np.ndarray, top_k: int,
 # the generated field-wise __eq__ would compare numpy prompts of
 # different lengths (a broadcast ValueError that escalated to the
 # engine-fatal handler — caught by the chaos soak's deadline storm)
+def _fetch_logits(out: dict) -> np.ndarray:
+    return np.asarray(out["logits"])
+
+
+def _fetch_block(out: dict) -> tuple:
+    """What a block step hands the host: ids and confidences [slots, B]
+    and the two routing scalars, never logits."""
+    return (np.asarray(out["ids"]), np.asarray(out["conf"]),
+            int(out["expert_rows"]), float(out["max_expert_load"]))
+
+
 @dataclasses.dataclass(eq=False)
 class GenRequest:
     """One queued ``:generate`` request (per-request sampling knobs —
@@ -1093,6 +1104,31 @@ class _Slot:
         #: meaningful while the slot sits in the engine's _prefilling
         #: set (a slot joins _live with the prompt fully resident)
         self.chunk_done = 0
+        # ---- generation by diffusion over blocks --------------------
+        #: the block being generated (``pos`` is its first logical
+        #: slot): its tokens (the mask id where masked), which lanes
+        #: are still masked, the denoising forwards it has had, and per
+        #: lane the forward that committed it (0 = a prompt token,
+        #: -1 = still masked). Empty for one-token-a-step artifacts.
+        self.blk_tok: list[int] = []
+        self.blk_masked: list[bool] = []
+        self.blk_step = 0
+        self.blk_unmask: list[int] = []
+        #: denoising + commit forwards this request has ridden, and per
+        #: emitted token the denoising step that committed it
+        self.forwards = 0
+        self.unmask_steps: list[int] = []
+
+    def open_block(self, pos: int, known: list[int], lanes: int,
+                   mask_id: int) -> None:
+        """Begin the block at logical slot ``pos``: ``known`` prompt
+        tokens (the prompt ended inside it), ``[MASK]`` elsewhere."""
+        self.pos = pos
+        k = len(known)
+        self.blk_tok = list(known) + [mask_id] * (lanes - k)
+        self.blk_masked = [False] * k + [True] * (lanes - k)
+        self.blk_unmask = [0] * k + [-1] * (lanes - k)
+        self.blk_step = 0
 
     def remaining_steps(self) -> int:
         """ROW-STEPS until this slot retires at its max_new bound (EOS
@@ -1156,6 +1192,30 @@ class GenerationEngine:
             "pad_id": int(meta.get("pad_id", 0)),
         }
         self.max_queue = max_queue
+        # ---- generation by diffusion over blocks --------------------
+        #: the artifact's ``block`` metadata (models/decoder.py), else
+        #: None: a step is then B lanes a slot and commits 0..B tokens a
+        #: row, positions advance by blocks, and the shared dispatch is
+        #: the block step (denoising and commit rows together)
+        self.block: dict | None = getattr(stepwise, "block", None)
+        self._lanes = int(self.block["length"]) if self.block else 0
+        if self.block:
+            if spec_tokens or prefill_chunk_tokens:
+                raise ValueError(
+                    "this artifact generates by diffusion over blocks: "
+                    "speculative decoding and chunked prefill have no "
+                    "program in it (spec_tokens="
+                    f"{spec_tokens}, prefill_chunk_tokens="
+                    f"{prefill_chunk_tokens}); run with both 0")
+            if prefix_cache:
+                # auto-off, as --spec_tokens is on an artifact without
+                # a verify program: a hit's uncached suffix is
+                # teacher-forced one token a shared step, which a
+                # block step is not. Without sharing nothing is ever
+                # copied on write either.
+                log.info("block-diffusion artifact: prefix cache off "
+                         "(no forced-suffix path through a block step)")
+                prefix_cache = False
         self._pool = stepwise.make_pool()
         self._queue: deque[GenRequest] = deque()
         self._cond = threading.Condition()
@@ -1325,6 +1385,34 @@ class GenerationEngine:
                 f"scheduler-thread seconds in phase {ph!r} of the "
                 "working iterations (the phases tile an iteration)")
             for ph in SCHED_PHASES}
+        # generation by diffusion over blocks (zeros for artifacts that
+        # decode one token a step)
+        self._c_block_steps = reg.counter(
+            "serving_block_steps_total",
+            "block-step dispatches (B lanes a slot; denoising and commit "
+            "rows ride the same one)")
+        self._c_denoise_forwards = reg.counter(
+            "serving_denoise_forwards_total",
+            "rows of block-step dispatches that were denoising forwards")
+        self._c_commit_forwards = reg.counter(
+            "serving_commit_forwards_total",
+            "rows of block-step dispatches that were commit forwards "
+            "(one a generated block)")
+        self._c_tokens_committed = reg.counter(
+            "serving_tokens_committed_total",
+            "positions unmasked by denoising forwards")
+        self._c_moe_rows = reg.counter(
+            "serving_moe_rows_total",
+            "(row, expert) pairs the live rows of block steps sent "
+            "through the expert layers")
+        self._g_moe_load = reg.gauge(
+            "serving_moe_max_expert_load_ratio",
+            "last block step: the fullest expert's rows over the mean "
+            "an expert gets")
+        # held experts that received a row in the LAST block step: the
+        # next step's span carries it (a step's routing is known only
+        # when it returns)
+        self._expert_rows_last = 0
         self._c_decode_kv_bytes = reg.counter(
             "serving_decode_kv_bytes_total",
             "bytes of K and V the live rows held, summed over shared "
@@ -1691,6 +1779,12 @@ class GenerationEngine:
         if req.temperature < 0.0:
             raise ValueError(f"temperature must be >= 0, got "
                              f"{req.temperature}")
+        if self.block and req.temperature > 0.0:
+            raise ValueError(
+                "this artifact generates by diffusion over blocks, "
+                "greedily: its block step returns each lane's argmax "
+                f"and confidence, no logits (temperature "
+                f"{req.temperature})")
         vocab = int(self.sw.step_meta.get("vocab_size", 0))
         if req.top_k < 0 or (vocab and req.top_k > vocab):
             raise ValueError(f"top_k must be in [0, vocab_size={vocab}],"
@@ -2524,7 +2618,13 @@ class GenerationEngine:
                 # _admit_slab): an async device fault must leave
                 # self._pool naming the donated inputs so the outer
                 # handler's _pool_alive() probe escalates correctly
-                logits0 = np.asarray(out["logits"])[0]
+                if self.block:
+                    # no first token: the prompt's whole blocks are in
+                    # the pool, its remainder opens the first block
+                    out["cache_k"].block_until_ready()
+                    logits0 = None
+                else:
+                    logits0 = np.asarray(out["logits"])[0]
                 self._pool = {k: v for k, v in out.items()
                               if k.startswith("cache_")}
         except Exception:
@@ -2547,6 +2647,12 @@ class GenerationEngine:
                      seq=self._admit_counter)
         slot.drafter = self._drafter_for(req)
         slot.t_prefill_done = time.perf_counter()
+        if self.block:
+            start = p - p % self._lanes
+            slot.open_block(start, [int(t) for t in tokens[start:]],
+                            self._lanes, int(self.block["mask_id"]))
+            self._live[index] = slot
+            return True
         tok = self._pick(slot, logits0)
         self._emit(slot, tok)
         return True
@@ -2994,6 +3100,13 @@ class GenerationEngine:
             "outcome": "ok",
             "slo_good": good,
         }
+        if self.block:
+            # the forwards (denoising + commit) the request rode, and
+            # per returned token the denoising step, inside its block,
+            # that committed it
+            req.timings["forwards"] = slot.forwards
+            req.timings["unmask_step"] = slot.unmask_steps[
+                :len(slot.tokens)]
         with span("retire", process=self.process, lane=lane,
                   request_id=req.request_id, **req.trace):
             if self.paged:
@@ -3075,8 +3188,8 @@ class GenerationEngine:
     @scheduler_thread
     def _dispatch_decode(self, feats: dict, *, call=None,
                          rebuild=None,
-                         span_name: str = "decode_step"
-                         ) -> np.ndarray | None:
+                         span_name: str = "decode_step",
+                         describe=None, fetch=None):
         """One shared dispatch (normal decode step, or — ``call``/
         ``rebuild`` overridden — the K-token verify program) under the
         bounded re-dispatch protocol: a first failure that left the
@@ -3091,11 +3204,19 @@ class GenerationEngine:
         engine-fatal handler. Both programs share ONE protocol and ONE
         ``engine.decode_step`` fault seam — a verify dispatch is
         quarantined exactly like a normal one (eviction releases the
-        victim's whole span; survivors' drafts ride the rebuild)."""
+        victim's whole span; survivors' drafts ride the rebuild).
+        ``describe(feats)`` gives the span's arguments (``kv_bytes``
+        among them) and ``fetch(out)`` the host's copy of what the
+        dispatch returns (default: the logits); the block step passes
+        both."""
         if call is None:
             call = self.sw.decode
         if rebuild is None:
             rebuild = self._build_step_feats
+        if describe is None:
+            describe = self._describe_decode
+        if fetch is None:
+            fetch = _fetch_logits
         reg = faults.active()
         idx = reg.next_index("engine.decode_step") \
             if reg is not None else None
@@ -3108,13 +3229,10 @@ class GenerationEngine:
                     # rules stay one-shot transients, p-rules resample
                     reg.raise_if_armed("engine.decode_step", index=idx,
                                        attempt=attempt)
-                # what the step's live rows hold in K and V (a dead
-                # row's pos is 0): the decode kernels' least traffic
-                kv_bytes = int(feats["pos"].sum()) * self._kv_token_bytes
+                args = describe(feats)
+                kv_bytes = args["kv_bytes"]
                 with span(span_name, process=self.process,
-                          lane="scheduler",
-                          slots=int(feats["alive"].sum()),
-                          kv_bytes=kv_bytes):
+                          lane="scheduler", **args):
                     with self._phase(span_name="sched_dispatch"):
                         out = call(feats)
                     # blocks on the result BEFORE adopting the returned
@@ -3125,7 +3243,7 @@ class GenerationEngine:
                     # the FAILED call's outputs alive and re-dispatch
                     # feats whose buffers were consumed
                     with self._phase(span_name="sched_wait_logits"):
-                        logits = np.asarray(out["logits"])
+                        logits = fetch(out)
                         self._pool = {k: v for k, v in out.items()
                                       if k.startswith("cache_")}
                 self._c_decode_kv_bytes.inc(kv_bytes)
@@ -3163,6 +3281,13 @@ class GenerationEngine:
                 feats = rebuild()
                 self._c_redispatches.inc()
 
+    def _describe_decode(self, feats: dict) -> dict:
+        """A decode (or verify) step's span arguments: its live rows,
+        and what they hold in K and V (a dead row's pos is 0): the
+        decode kernels' least traffic."""
+        return {"slots": int(feats["alive"].sum()),
+                "kv_bytes": int(feats["pos"].sum()) * self._kv_token_bytes}
+
     @scheduler_thread
     def _propose_drafts(self) -> None:
         """Ask each eligible live slot's drafter for up to
@@ -3199,7 +3324,12 @@ class GenerationEngine:
         Four phases tile it on the scheduler lane (``_loop`` holds the
         two before): sched_secure_blocks, sched_build_feats, the
         dispatch (decode_step / verify_step with sched_dispatch and
-        sched_wait_logits inside) and sched_sample_emit."""
+        sched_wait_logits inside) and sched_sample_emit. A
+        block-diffusion artifact's shared dispatch is the block step
+        (:meth:`_block_step`), under the same phases."""
+        if self.block:
+            self._block_step()
+            return
         if self.paged:
             if self._verify_width:
                 with self._phase(span_name="sched_build_feats"):
@@ -3251,7 +3381,9 @@ class GenerationEngine:
         for s in list(self._live.values()):
             try:
                 try:
-                    self._ensure_write_block(s, 1 + len(s.draft))
+                    # a block step writes all its lanes
+                    self._ensure_write_block(
+                        s, self._lanes or 1 + len(s.draft))
                 except BlocksExhaustedError:
                     if not s.draft:
                         raise
@@ -3273,6 +3405,131 @@ class GenerationEngine:
                     f"request {s.req.request_id}: cache write-"
                     f"block allocation failed "
                     f"({type(e).__name__}: {e})"))
+
+    # ---- generation by diffusion over blocks --------------------------
+    @scheduler_thread
+    def _build_block_feats(self) -> dict:
+        """The block step's operand dict for the CURRENT live set: per
+        slot the block's B tokens (the mask id where masked), its first
+        logical slot, and whether this forward is its commit (no mask
+        left: the row carries the block's final tokens)."""
+        lanes = self._lanes
+        tok = np.zeros((self.slots, lanes), np.int32)
+        pos = np.zeros((self.slots,), np.int32)
+        alive = np.zeros((self.slots,), np.int32)
+        commit = np.zeros((self.slots,), np.int32)
+        for i, s in self._live.items():
+            tok[i] = s.blk_tok
+            pos[i] = s.pos
+            alive[i] = 1
+            commit[i] = not any(s.blk_masked)
+        return {"tok": tok, "pos": pos, "alive": alive, "commit": commit,
+                "block_tables": self._tables, **self._pool}
+
+    def _describe_block(self, feats: dict) -> dict:
+        """A block step's span arguments. ``kv_bytes``: what the live
+        rows' windows hold in K and V, their own block included;
+        ``expert_rows``: held experts that received a row in the step
+        before this one (this step's routing is known when it returns)."""
+        alive = feats["alive"] != 0
+        rows = int(alive.sum())
+        commits = int(feats["commit"][alive].sum())
+        return {"slots": rows, "denoise_rows": rows - commits,
+                "commit_rows": commits,
+                "kv_bytes": int((feats["pos"][alive] + self._lanes).sum())
+                * self._kv_token_bytes,
+                "expert_rows": self._expert_rows_last}
+
+    @scheduler_thread
+    def _block_step(self) -> None:
+        """ONE batched block step for every live slot: B lanes a slot,
+        slots that denoise and slots that commit in the same dispatch,
+        under the phases of :meth:`_shared_step`."""
+        with self._phase(span_name="sched_secure_blocks"):
+            self._secure_write_blocks()
+        if not self._live:
+            self._last_dispatch_t = 0.0
+            return
+        with self._phase(span_name="sched_build_feats"):
+            if self._last_dispatch_t:
+                self._h_decode_stall.observe(
+                    time.perf_counter() - self._last_dispatch_t)
+            feats = self._build_block_feats()
+        t0 = time.perf_counter()
+        got = self._dispatch_decode(
+            feats, call=self.sw.block_step,
+            rebuild=self._build_block_feats, span_name="block_step",
+            describe=self._describe_block, fetch=_fetch_block)
+        if got is None:
+            self._last_dispatch_t = 0.0
+            return
+        with self._phase(span_name="sched_sample_emit"):
+            self._retry.observe(time.perf_counter() - t0)
+            self._block_emit(*got)
+
+    @scheduler_thread
+    def _block_emit(self, ids: np.ndarray, conf: np.ndarray,
+                    expert_rows: int, load: float) -> None:
+        """After a block step. A denoising row applies the transfer
+        rule (``low_confidence_dynamic``): masked lanes whose
+        confidence exceeds the threshold are committed, and if fewer
+        than ``n_s = B / denoising_steps`` did, the ``n_s`` most
+        confident are. A commit row's block is final and its K/V is in
+        the pool: its generated tokens go to the request through
+        :meth:`_emit` (which retires at ``max_new`` inside a block, the
+        surplus dropped), and the next block opens, all masked."""
+        lanes, blk = self._lanes, self.block
+        n_s = -(-lanes // int(blk["denoising_steps"]))
+        threshold = float(blk["threshold"])
+        live = list(self._live.items())
+        commits = sum(1 for _, s in live if not any(s.blk_masked))
+        committed = 0
+        self._expert_rows_last = expert_rows
+        for i, s in live:
+            s.forwards += 1
+            if any(s.blk_masked):
+                s.blk_step += 1
+                masked = [j for j in range(lanes) if s.blk_masked[j]]
+                chosen = [j for j in masked if conf[i, j] > threshold]
+                if len(chosen) < n_s:
+                    chosen = sorted(masked,
+                                    key=lambda j: (-conf[i, j], j))[:n_s]
+                for j in chosen:
+                    s.blk_tok[j] = int(ids[i, j])
+                    s.blk_masked[j] = False
+                    s.blk_unmask[j] = s.blk_step
+                committed += len(chosen)
+                continue
+            # the block's commit forward has run
+            retired = False
+            for j in range(lanes):
+                if not s.blk_unmask[j]:
+                    continue                # a prompt token
+                s.unmask_steps.append(s.blk_unmask[j])
+                del self._live[i]           # _emit re-adds if still live
+                self._emit(s, s.blk_tok[j])
+                retired = s.index not in self._live
+                if retired:
+                    break                   # max_new / EOS / stop
+            if not retired:
+                s.open_block(s.pos + lanes, [], lanes, int(blk["mask_id"]))
+        with self.registry.atomic():
+            self._c_block_steps.inc()
+            self._c_decode_steps.inc()
+            self._c_decode_slot_steps.inc(len(live))
+            self._c_denoise_forwards.inc(len(live) - commits)
+            self._c_commit_forwards.inc(commits)
+            self._c_tokens_committed.inc(committed)
+            self._c_moe_rows.inc(len(live) * lanes * int(blk["layers"])
+                                 * int(blk["experts_per_token"]))
+            self._g_moe_load.set(load)
+        if live:
+            self._retry.observe_advance(committed / len(live))
+        left = list(self._live.values())
+        self._steps_to_free_hint = (
+            self._retry.dispatches_for(
+                min(s.remaining_steps() for s in left)) if left else 1.0)
+        self._last_dispatch_t = time.perf_counter() if left else 0.0
 
     @scheduler_thread
     def _sample_emit(self, logits: np.ndarray, use_verify: bool) -> None:
@@ -3474,6 +3731,14 @@ class GenerationEngine:
                 ph: round(c(f"serving_sched_{ph}_seconds_total"), 6)
                 for ph in SCHED_PHASES},
             "decode_kv_bytes": c("serving_decode_kv_bytes_total"),
+            # generation by diffusion over blocks (zeros otherwise)
+            "block_steps": c("serving_block_steps_total"),
+            "denoise_forwards": c("serving_denoise_forwards_total"),
+            "commit_forwards": c("serving_commit_forwards_total"),
+            "tokens_committed": c("serving_tokens_committed_total"),
+            "moe_rows": c("serving_moe_rows_total"),
+            "moe_max_expert_load_ratio": c(
+                "serving_moe_max_expert_load_ratio"),
             "jit_compiles": c("jit_compiles_total"),
             "jit_compile_s": round(
                 c("jit_compile_seconds_total"), 3),

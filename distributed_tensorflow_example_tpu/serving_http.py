@@ -472,8 +472,14 @@ class PredictServer:
                     max_queue=max_queue,
                     registry=self.registry,
                     process=self.process_name).start()
-        self._httpd = ThreadingHTTPServer((host, port),
-                                          self._make_handler())
+        # socketserver listens with a backlog of 5: a wave of more
+        # clients than that connecting at once has its SYNs dropped and
+        # retried 1-63 s later, unseen by the server, and a request whose
+        # body was in flight meanwhile can die on the handler's read
+        # timeout. The listener holds what the admission queue may hold.
+        httpd_cls = type("PredictHTTPServer", (ThreadingHTTPServer,),
+                         {"request_queue_size": max(5, int(max_queue))})
+        self._httpd = httpd_cls((host, port), self._make_handler())
         self.port = self._httpd.server_address[1]
         self._thread: threading.Thread | None = None
 

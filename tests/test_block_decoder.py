@@ -1,0 +1,530 @@
+"""The block-diffusion decoder (``models/decoder.py``: SDAR-30B-A3B-Chat's
+block at test widths) against its plain reference
+(``benchmark/reference/sdar-30b-a3b-chat.py``), seeded random weights:
+the forwards, the expert layer that holds a share, the kernels, the
+artifact with weights as arguments, and the engine's block steps."""
+
+import importlib
+import json
+import os
+import sys
+import urllib.request
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import weights_by_leaf                      # noqa: E402
+from benchmark.manifest import load_module                 # noqa: E402
+from distributed_tensorflow_example_tpu import serving     # noqa: E402
+from distributed_tensorflow_example_tpu.config import TrainConfig  # noqa: E402
+from distributed_tensorflow_example_tpu.models import get_model   # noqa: E402
+from distributed_tensorflow_example_tpu.ops.moe import moe_dropless  # noqa: E402
+from distributed_tensorflow_example_tpu.serving_batch import (  # noqa: E402
+    GenerationEngine)
+
+# the modules, not the same-named functions ops.pallas re-exports
+decode_mod = importlib.import_module(
+    "distributed_tensorflow_example_tpu.ops.pallas.decode_attention")
+flash_mod = importlib.import_module(
+    "distributed_tensorflow_example_tpu.ops.pallas.flash_attention")
+ref = load_module(os.path.join(ROOT, "benchmark", "reference",
+                               "sdar-30b-a3b-chat.py"))
+
+CFG = json.load(open(os.path.join(
+    ROOT, "benchmark", "configs",
+    "sdar-30b-a3b-chat.json")))["rehearsal"]["sizes"]
+LANES, MASK = 4, 511
+
+
+def build(dtype: str, seed: int = 7):
+    model = get_model("sdar_moe_tiny", TrainConfig(
+        model="sdar_moe_tiny", dtype=dtype, param_dtype=dtype))
+    model.cfg.denoising_steps = 2
+    params = weights_by_leaf.make_params(ref.param_spec(CFG), seed, dtype)
+    return model, params
+
+
+@pytest.fixture(scope="module")
+def f32():
+    return build("float32")
+
+
+def test_registry_builds_the_block_from_a_description():
+    big = get_model("sdar_moe", TrainConfig(model="sdar_moe", num_layers=6))
+    c = big.cfg
+    assert (c.hidden, c.heads, c.kv_heads, c.head_dim, c.experts,
+            c.experts_per_token, c.expert_width, c.vocab_size, c.layers,
+            c.rope_theta) == (2048, 32, 4, 128, 128, 8, 768, 151936, 6, 1e6)
+    from distributed_tensorflow_example_tpu.models.decoder import (
+        BlockDecoder, DecoderBlockConfig)
+    for bad in (dict(block_length=3), dict(heads=6, kv_heads=4),
+                dict(first_expert=4, experts_held=8, experts=8)):
+        with pytest.raises(ValueError):
+            BlockDecoder(DecoderBlockConfig(**{**DecoderBlockConfig.tiny(
+            ).__dict__, **bad}))
+    with pytest.raises(NotImplementedError, match="served decoder"):
+        big.loss(None, None, None, None)
+    # the program's tree is the reference's, leaf for leaf
+    tiny, params = build("bfloat16")
+    shapes = jax.tree_util.tree_map(lambda x: (x.shape, str(x.dtype)),
+                                    jax.eval_shape(tiny.init,
+                                                   jax.random.key(0)))
+    assert shapes == jax.tree_util.tree_map(
+        lambda x: (x.shape, str(x.dtype)), params)
+
+
+# ---- (a) the forwards against the reference ---------------------------
+
+def _prefill_and_step(model, params, prompt, dtype, impl="xla"):
+    """Paged prefill of ``prompt`` then the first block step, through the
+    pool: (ids, conf) of the block's lanes and the block's tokens."""
+    p = len(prompt)
+    s0, bs, n = 32, 16, 9
+    layers, width = model.cfg.layers, model.cfg.kv_heads * model.cfg.head_dim
+    ids = np.zeros((1, s0), np.int32)
+    ids[0, :p] = prompt
+    pool = jnp.zeros((layers, n, bs, width), dtype)
+    kp, vp = model.paged_prefill(params, ids, np.ones((1, s0), np.int32),
+                                 pool, pool, jnp.array([3, 5], jnp.int32),
+                                 attention="xla")
+    start = p - p % LANES
+    blk = list(prompt[start:]) + [MASK] * (LANES - p % LANES)
+    bt = np.zeros((2, 3), np.int32)
+    bt[1] = [3, 5, 7]
+    tok = np.zeros((2, LANES), np.int32)
+    tok[1] = blk
+    out = model.block_step(params, kp, vp, bt, tok,
+                           np.array([0, start], np.int32),
+                           np.array([0, 1]), np.array([0, 0]),
+                           attention=impl)
+    return out, start, blk
+
+
+@pytest.mark.parametrize("p", [22, 20, 3, 32])
+def test_prefill_then_block_step_match_the_reference_f32(f32, p):
+    """float32 program against the float32 reference: the same function,
+    so ids agree and confidences agree to float32 rounding (1e-5
+    relative: a softmax over 512 logits after two layers)."""
+    model, params = f32
+    prompt = np.random.RandomState(p).randint(0, 500, p)
+    out, start, blk = _prefill_and_step(model, params, prompt, jnp.float32)
+    lg = ref.block_step(CFG, params, jnp.asarray(prompt[:start]),
+                        jnp.asarray(blk, jnp.int32))
+    conf = jax.nn.softmax(lg, -1).max(-1)
+    np.testing.assert_array_equal(out["ids"][1], np.argmax(lg, -1))
+    np.testing.assert_allclose(out["conf"][1], conf, rtol=1e-5)
+    assert not np.asarray(out["conf"][0]).any()     # the dead row
+    assert int(out["expert_rows"]) <= 2 * 8
+    # and the reference's padded form is its plain form
+    x = np.zeros((40,), np.int32)
+    x[:start] = prompt[:start]
+    x[start:start + LANES] = blk
+    x[start + LANES:] = 77
+    np.testing.assert_allclose(
+        ref.block_logits_at(CFG, params, jnp.asarray(x), start, LANES), lg,
+        rtol=1e-5, atol=1e-6)
+
+
+def test_block_step_bf16_stays_near_the_reference():
+    """bfloat16 storage and operands against the float32 reference on the
+    same (bfloat16) weights: logit-level agreement to bfloat16's 2^-8 per
+    operand through two layers: confidences within 2 % (relative), and
+    the committed id within 0.02 of the reference's best logit (logits
+    spread ~0.5 here). A seed whose routing has no near-tie: one expert
+    chosen otherwise moves these by ten times as much (the configuration
+    file's ``why_float32``)."""
+    model, params = build("bfloat16", seed=11)
+    prompt = np.random.RandomState(5).randint(0, 500, 22)
+    out, start, blk = _prefill_and_step(model, params, prompt, jnp.bfloat16)
+    lg = np.asarray(ref.block_step(CFG, params, jnp.asarray(prompt[:start]),
+                                   jnp.asarray(blk, jnp.int32)))
+    conf = np.asarray(jax.nn.softmax(lg, -1).max(-1))
+    np.testing.assert_allclose(out["conf"][1], conf, rtol=0.02)
+    got = np.asarray(out["ids"][1])
+    assert (lg.max(-1) - lg[np.arange(LANES), got]).max() < 0.02
+
+
+def simulate(params, prompt, max_new, steps=2, threshold=0.9):
+    """Generation by diffusion over blocks with the REFERENCE's block
+    step and no cache: tokens, per token its unmask step, forwards."""
+    fwd = jax.jit(lambda pre, blk: ref.block_step(CFG, params, pre, blk))
+    n_s = -(-LANES // steps)
+    seq = list(prompt)
+    start = len(seq) - len(seq) % LANES
+    out, when, forwards = [], [], 0
+    while len(out) < max_new:
+        known = seq[start:]
+        blk = known + [MASK] * (LANES - len(known))
+        um = [0] * len(known) + [-1] * (LANES - len(known))
+        step = 0
+        while -1 in um:
+            lg = np.asarray(fwd(jnp.asarray(seq[:start], jnp.int32),
+                                jnp.asarray(blk, jnp.int32)))
+            forwards += 1
+            step += 1
+            conf = np.asarray(jax.nn.softmax(lg, -1).max(-1))
+            masked = [j for j in range(LANES) if um[j] < 0]
+            chosen = [j for j in masked if conf[j] > threshold]
+            if len(chosen) < n_s:
+                chosen = sorted(masked, key=lambda j: (-conf[j], j))[:n_s]
+            for j in chosen:
+                blk[j], um[j] = int(lg[j].argmax()), step
+        forwards += 1                       # the commit forward
+        for j in range(LANES):
+            if um[j]:
+                out.append(blk[j])
+                when.append(um[j])
+        seq = seq[:start] + blk
+        start += LANES
+    return out[:max_new], when[:max_new], forwards
+
+
+@pytest.fixture(scope="module")
+def artifact(f32, tmp_path_factory):
+    model, params = f32
+    d = str(tmp_path_factory.mktemp("sdar"))
+    serving.export_generator(model, params, d, ragged=True, stepwise=True,
+                             paged=True, slots=3, block_size=16,
+                             prompt_len=32, max_new_tokens=16,
+                             platforms=("cpu",))
+    return d
+
+
+@pytest.fixture(scope="module")
+def engine(artifact):
+    eng = GenerationEngine(serving.load_stepwise(artifact),
+                           max_queue=32).start()
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("p,max_new", [(22, 7), (20, 16), (3, 5), (32, 9),
+                                       (1, 1), (31, 16)])
+def test_engine_generates_what_the_reference_generates(f32, engine, p,
+                                                       max_new):
+    """Prefill + block steps through the engine (float32, so exact):
+    prompts ending on and inside a block, ``max_new`` ending inside one
+    (the surplus dropped). Tokens, per-token unmask steps and the
+    forwards taken equal the reference's cacheless generation."""
+    _, params = f32
+    prompt = np.random.RandomState(100 + p).randint(0, 500, p).tolist()
+    h = engine.submit(prompt, max_new=max_new)
+    got = h.result(120)
+    want, when, forwards = simulate(params, prompt, max_new)
+    assert got == want
+    assert h.timings["unmask_step"] == when
+    assert h.timings["forwards"] == forwards
+    assert h.timings["tokens"] == max_new
+
+
+def test_engine_batches_denoising_and_commit_rows_in_one_dispatch(
+        f32, engine):
+    """More requests than slots, ragged lengths: every request still
+    equals the reference, slots ride one dispatch a step (fewer block
+    steps than forwards), counters count tokens and forwards."""
+    _, params = f32
+    rs = np.random.RandomState(3)
+    before = engine.stats()
+    hs = [engine.submit(rs.randint(0, 500, int(rs.randint(1, 33))).tolist(),
+                        max_new=int(rs.randint(1, 17))) for _ in range(8)]
+    for h in hs:
+        assert h.result(120) == simulate(params, h.req.prompt.tolist(),
+                                         h.req.max_new)[0]
+    after = engine.stats()
+    d = {k: after[k] - before[k] for k in (
+        "block_steps", "denoise_forwards", "commit_forwards",
+        "tokens_committed", "moe_rows", "decode_steps", "requests_done")}
+    forwards = sum(h.timings["forwards"] for h in hs)
+    assert d["denoise_forwards"] + d["commit_forwards"] == forwards
+    assert d["block_steps"] == d["decode_steps"] < forwards
+    assert d["tokens_committed"] >= sum(h.req.max_new for h in hs)
+    assert d["moe_rows"] == forwards * LANES * 2 * 2   # lanes x layers x k
+    assert after["moe_max_expert_load_ratio"] > 0
+    assert after["blocks_free"] == after["blocks_total"]
+    assert after["prefix_cache_entries"] == 0 and after["cow_copies"] == 0
+
+
+def test_what_is_refused_for_this_artifact(f32, artifact, tmp_path):
+    model, params = f32
+    kw = dict(ragged=True, stepwise=True, paged=True, slots=2,
+              block_size=16, prompt_len=32, max_new_tokens=16,
+              platforms=("cpu",))
+    for bad in (dict(spec_tokens=4), dict(weight_quant="int8"),
+                dict(kv_cache_dtype="int8"), dict(prefill_chunk=16),
+                dict(paged=False), dict(block_size=6)):
+        with pytest.raises(ValueError):
+            serving.export_generator(model, params, str(tmp_path / "x"),
+                                     **{**kw, **bad})
+    sw = serving.load_stepwise(artifact)
+    for bad in (dict(spec_tokens=4), dict(prefill_chunk_tokens=16)):
+        with pytest.raises(ValueError, match="diffusion over blocks"):
+            GenerationEngine(sw, **bad)
+    eng = GenerationEngine(sw)              # prefix cache: off, not refused
+    assert eng.prefix_cache is None and eng.block["length"] == LANES
+    with pytest.raises(ValueError, match="greedily"):
+        eng.submit([1, 2, 3], temperature=0.7)
+    eng.close()
+    with pytest.raises(ValueError, match="scheduler on"):
+        serving.load_servable(artifact)({"input_ids": np.zeros((1, 32))})
+
+
+# ---- (b) the expert layer that holds a share --------------------------
+
+def _moe_case(skew: float):
+    k = jax.random.split(jax.random.key(1), 5)
+    t, h, e, f = 40, 64, 8, 32
+    x = jax.random.normal(k[0], (t, h))
+    router = jax.random.normal(k[1], (h, e)) * 0.2
+    # skewed: a feature every row has sends expert 0 nearly all of them
+    x = x.at[:, 0].set(1.0)
+    router = router.at[0, 0].add(8.0 * skew)
+    experts = {n: jax.random.normal(kk, s) * 0.2 for n, kk, s in (
+        ("gate", k[2], (e, h, f)), ("up", k[3], (e, h, f)),
+        ("down", k[4], (e, f, h)))}
+    cfg = dict(CFG, num_experts=e, num_experts_per_tok=2)
+    return x, router, experts, cfg
+
+
+@pytest.mark.parametrize("skew", [0.0, 0.5])
+def test_dropless_layer_equals_the_dense_loop(skew):
+    """No sort and no capacity in the reference; the sorted grouped
+    matmul must give the same layer, also when the router sends most
+    rows to one expert (nothing is dropped). float32 both: 1e-5."""
+    x, router, experts, cfg = _moe_case(skew)
+    y, rows = moe_dropless(x, router, experts, top_k=2, dtype=jnp.float32)
+    want = ref.experts(ref._sizes(cfg), {"router": router, **experts}, x,
+                       "f32")
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    assert int(rows.sum()) == x.shape[0] * 2
+    if skew:
+        assert int(rows[0]) > 0.9 * x.shape[0]
+
+
+def test_shares_of_the_experts_add_up_to_the_whole_layer():
+    """The guide's share test: 8 experts held as 4 shares of 2. Each
+    share routes over all 8 and computes its own experts' part; the parts
+    add up to the uncut reference's layer, and a share's part is the
+    reference's for the same share."""
+    x, router, experts, cfg = _moe_case(0.3)
+    whole = ref.experts(ref._sizes(cfg), {"router": router, **experts}, x,
+                        "f32")
+    total = 0.0
+    for s in range(4):
+        held = {n: v[2 * s:2 * s + 2] for n, v in experts.items()}
+        part, rows = moe_dropless(x, router, held, top_k=2,
+                                  first_expert=2 * s, dtype=jnp.float32)
+        share_cfg = dict(cfg, experts_held=2, first_expert=2 * s)
+        np.testing.assert_allclose(
+            part, ref.experts(ref._sizes(share_cfg),
+                              {"router": router, **held}, x, "f32"),
+            rtol=1e-5, atol=1e-5)
+        assert rows.shape == (2,)
+        total = total + part
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+
+
+# ---- (c) the kernels ----------------------------------------------------
+
+def _plain_attention(q, k, v, allowed):
+    s = jnp.einsum("qhd,khd->hqk", q, k) / np.sqrt(q.shape[-1])
+    p = jax.nn.softmax(jnp.where(allowed[None], s, -1e30), -1)
+    return jnp.einsum("hqk,khd->qhd", p, v)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+def test_paged_block_attention_grouped_kv(impl):
+    """8 rows a KV head (2 query heads x 4 lanes) against a paged pool,
+    windows ending inside the first, a middle and the last block, against
+    plain attention over the gathered run. float32 inputs: 2e-6 (the
+    online softmax sums in another order)."""
+    k = jax.random.split(jax.random.key(0), 3)
+    b, kvh, rows, d, n, bs = 3, 2, 8, 128, 9, 128
+    q = jax.random.normal(k[0], (b, kvh, rows, d))
+    kp = jax.random.normal(k[1], (n, bs, kvh * d))
+    vp = jax.random.normal(k[2], (n, bs, kvh * d))
+    bt = jnp.array([[1, 2, 3], [4, 5, 0], [6, 0, 0]], jnp.int32)
+    last = jnp.array([383, 131, 3], jnp.int32)
+    got = decode_mod.paged_block_attention(q, kp, vp, block_tables=bt,
+                                           last=last, impl=impl)
+    for i in range(b):
+        run = kp[bt[i]].reshape(-1, kvh, d), vp[bt[i]].reshape(-1, kvh, d)
+        allowed = (jnp.arange(3 * bs) <= last[i])[None, :].repeat(rows, 0)
+        for h in range(kvh):
+            want = _plain_attention(
+                q[i, h][:, None], run[0][:, h:h + 1], run[1][:, h:h + 1],
+                allowed)[:, 0]
+            np.testing.assert_allclose(got[i, h], want, rtol=2e-5,
+                                       atol=2e-6)
+    with pytest.raises(ValueError):
+        decode_mod.paged_block_attention(q[..., :64], kp, vp,
+                                         block_tables=bt, last=last)
+
+
+@pytest.mark.parametrize("seq,kw", [(512, dict(block_q=256, block_k=256)),
+                                    (40, {})])
+def test_flash_causal_block_4(seq, kw):
+    """``flash_fwd`` under the block-causal mask (and the XLA fallback at
+    a shape no tile takes) against plain attention under
+    ``floor(j / 4) <= floor(i / 4)``; float32: 2e-6."""
+    k = jax.random.split(jax.random.key(0), 3)
+    q, kk, v = [jax.random.normal(x, (1, seq, 2, 128)) for x in k]
+    got = flash_mod.flash_attention(q, kk, v, causal=True, causal_block=4,
+                                    **kw)
+    pos = jnp.arange(seq)
+    want = _plain_attention(q[0], kk[0], v[0],
+                            (pos[None, :] // 4) <= (pos[:, None] // 4))
+    np.testing.assert_allclose(got[0], want, rtol=2e-5, atol=2e-6)
+    for bad in (dict(causal_block=3), dict(causal_block=4, causal=False)):
+        with pytest.raises(ValueError):
+            flash_mod.flash_attention(q, kk, v, **{"causal": True, **bad})
+
+
+def test_defaults_leave_the_kernels_others_call_byte_identical():
+    """``causal_block`` 1 is the causal mask: the same jaxpr, the same
+    lowered program and the same bytes as a call that does not name it.
+    ``paged_decode_attention`` (GPT-2's, one head count) was not edited:
+    it still agrees with its XLA reference to the bit on float32."""
+    k = jax.random.split(jax.random.key(0), 4)
+    q, kk, v = [jax.random.normal(x, (2, 256, 2, 64)).astype(jnp.bfloat16)
+                for x in k[:3]]
+
+    def old(q, k_, v_):
+        return flash_mod.flash_attention(q, k_, v_, causal=True)
+
+    def new(q, k_, v_):
+        return flash_mod.flash_attention(q, k_, v_, causal=True,
+                                         causal_block=1)
+
+    assert str(jax.make_jaxpr(old)(q, kk, v)) == str(
+        jax.make_jaxpr(new)(q, kk, v))
+    assert jax.jit(old).lower(q, kk, v).as_text() == jax.jit(new).lower(
+        q, kk, v).as_text().replace("jit_new", "jit_old")
+    assert (old(q, kk, v) == new(q, kk, v)).all()
+    g_old = jax.grad(lambda a: old(a, kk, v).astype(jnp.float32).sum())(q)
+    g_new = jax.grad(lambda a: new(a, kk, v).astype(jnp.float32).sum())(q)
+    assert (g_old == g_new).all()
+    qd = jax.random.normal(k[3], (2, 12, 64))
+    pool = jax.random.normal(k[0], (5, 128, 12, 64))
+    kw = dict(block_tables=jnp.array([[1, 2], [3, 0]], jnp.int32),
+              pos=jnp.array([200, 17]), pad=jnp.array([0, 0]))
+    a = decode_mod.paged_decode_attention(qd, pool, pool, impl="pallas",
+                                          **kw)
+    b = decode_mod.paged_decode_attention(qd, pool, pool, impl="xla", **kw)
+    np.testing.assert_allclose(a, b, rtol=2e-5, atol=2e-6)
+
+
+# ---- (d) weights as arguments -----------------------------------------
+
+def test_weights_as_arguments_round_trip(f32, artifact, tmp_path,
+                                         monkeypatch):
+    """Above the byte rule the artifact keeps a checkpoint in the storage
+    dtype and its programs take the tree as an argument: exported,
+    loaded, one copy on the device (the loader's), and it generates what
+    the baked artifact generates."""
+    bf, params = build("bfloat16")
+    monkeypatch.setattr(serving, "BAKE_LIMIT_BYTES", 1000)
+    d = str(tmp_path / "args")
+    serving.export_generator(bf, params, d, ragged=True, stepwise=True,
+                             paged=True, slots=3, block_size=16,
+                             prompt_len=32, max_new_tokens=16,
+                             platforms=("cpu",))
+    meta = json.load(open(os.path.join(d, "export.json")))
+    assert meta["weights"] == "checkpoint"
+    assert not os.path.exists(os.path.join(d, "model.stablehlo"))
+    index = json.load(open(os.path.join(d, "params", "params.json")))
+    assert {e["dtype"] for e in index} == {"bfloat16"}
+    on_disk = sum(os.path.getsize(os.path.join(d, "params", e["file"]))
+                  for e in index)
+    assert meta["param_bytes"] <= on_disk < meta["param_bytes"] + 200 * len(
+        index)
+    # the programs hold no weights: they are small beside the checkpoint
+    assert os.path.getsize(os.path.join(d, "block_step.stablehlo")) < 0.2 * (
+        meta["param_bytes"])
+    del params
+    sw = serving.load_stepwise(d)
+    loaded = jax.tree_util.tree_leaves(sw.params)
+    assert sum(x.nbytes for x in loaded) == meta["param_bytes"]
+    eng = GenerationEngine(sw).start()
+    try:
+        held = [v for v in vars(eng).values()
+                if isinstance(v, (dict, jax.Array)) and v is not eng._pool]
+        assert not any(isinstance(x, jax.Array) and x.nbytes > 4096
+                       for v in held for x in jax.tree_util.tree_leaves(v))
+        prompt = np.random.RandomState(0).randint(0, 500, 13).tolist()
+        got = eng.generate(prompt, max_new=9)
+    finally:
+        eng.close()
+    # the same weights baked in (under the rule): the same tokens
+    monkeypatch.undo()
+    bf2, params2 = build("bfloat16")
+    d2 = str(tmp_path / "baked")
+    serving.export_generator(bf2, params2, d2, ragged=True, stepwise=True,
+                             paged=True, slots=3, block_size=16,
+                             prompt_len=32, max_new_tokens=16,
+                             platforms=("cpu",))
+    assert json.load(open(os.path.join(d2, "export.json")))[
+        "weights"] == "baked"
+    sw2 = serving.load_stepwise(d2)
+    assert sw2.params is None
+    eng2 = GenerationEngine(sw2).start()
+    try:
+        assert eng2.generate(prompt, max_new=9) == got
+    finally:
+        eng2.close()
+
+
+def test_gpt_artifact_keeps_its_baked_form(tmp_path):
+    gpt = get_model("gpt_tiny", TrainConfig(model="gpt_tiny"))
+    params = gpt.init(jax.random.key(0))
+    d = str(tmp_path / "gpt")
+    serving.export_generator(gpt, params, d, ragged=True, stepwise=True,
+                             paged=True, slots=2, block_size=16,
+                             prompt_len=16, max_new_tokens=8,
+                             platforms=("cpu",))
+    meta = json.load(open(os.path.join(d, "export.json")))
+    assert "weights" not in meta and "block" not in meta["stepwise"]
+    assert sorted(f for f in os.listdir(d)) == [
+        "decode.stablehlo", "export.json", "model.stablehlo",
+        "prefill.stablehlo"]
+    sw = serving.load_stepwise(d)
+    assert sw.params is None and sw.block is None
+    with pytest.raises(ValueError, match="no block-step program"):
+        sw.block_step({})
+
+
+# ---- HTTP: the response's fields and /stats ---------------------------
+
+def test_generate_over_http_reports_forwards_and_unmask_steps(f32,
+                                                              artifact):
+    from distributed_tensorflow_example_tpu.serving_http import PredictServer
+    _, params = f32
+    srv = PredictServer(artifact, port=0, max_queue=8)
+    srv.start()
+    try:
+        prompt = np.random.RandomState(9).randint(0, 500, 10).tolist()
+        base = f"http://127.0.0.1:{srv.port}"
+        req = urllib.request.Request(
+            f"{base}/v1/models/{srv.name}:generate",
+            data=json.dumps({"inputs": {"input_ids": [prompt]},
+                             "max_new": 6}).encode(),
+            headers={"Content-Type": "application/json"})
+        ans = json.loads(urllib.request.urlopen(req, timeout=120).read())
+        want, when, forwards = simulate(params, prompt, 6)
+        assert ans["generations"][0] == want
+        assert ans["timings"][0]["forwards"] == forwards
+        assert ans["timings"][0]["unmask_step"] == when
+        stats = json.loads(urllib.request.urlopen(f"{base}/stats",
+                                                  timeout=30).read())
+        flat = json.dumps(stats)
+        for key in ("block_steps", "denoise_forwards", "commit_forwards",
+                    "tokens_committed", "moe_rows",
+                    "moe_max_expert_load_ratio"):
+            assert f'"{key}"' in flat
+    finally:
+        srv.stop(drain=False)

@@ -373,3 +373,24 @@ def test_unknown_inputs_are_400(servable_dir):
                   {"inputs": {"x": x.tolist(), "prompt_mask": [[1]]}})
         assert e.value.code == 400
         assert "unknown model inputs" in json.loads(e.value.read())["error"]
+
+
+def test_listener_holds_a_wave_of_max_queue_connections(servable_dir):
+    """socketserver's listen backlog of 5 drops the SYNs of a wave of
+    clients connecting at once (retried seconds later, unseen by the
+    server). The listener takes ``max_queue`` instead: that many
+    connections complete while nothing accepts yet."""
+    import socket
+    d, _, _ = servable_dir
+    srv = PredictServer(d, max_queue=48)     # bound and listening, not
+    conns = []                               # started: nothing accepts
+    try:
+        assert srv._httpd.request_queue_size == 48
+        for _ in range(48):
+            conns.append(socket.create_connection(
+                ("127.0.0.1", srv.port), timeout=0.5))
+    finally:
+        for c in conns:
+            c.close()
+        srv._httpd.server_close()
+    assert len(conns) == 48

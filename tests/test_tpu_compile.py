@@ -279,3 +279,44 @@ def test_predicates_agree_with_the_compiler(chip, kernel, shape, accepted):
                     block_tables=jnp.zeros((1, 1), jnp.int32),
                     pos=jnp.zeros((1,), jnp.int32),
                     pad=jnp.zeros((1,), jnp.int32), impl="pallas")
+
+
+# ---------------------------------------------------------------------------
+# the block-diffusion decoder's kernels at SDAR-30B-A3B's widths:
+# 32 query heads over 4 KV heads x 128, blocks of 4 lanes
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["block_step_attention", "prefill_flash",
+                                  "grouped_matmul"])
+def test_block_decoder_kernels_compile(chip, name):
+    """``paged_block_attn`` over a [layers x blocks, 128, 4 x 128] pool
+    view with 32 rows a KV head; ``flash_fwd`` under ``causal_block`` 4
+    at S=4096; the expert layer's ``ragged_dot`` (a Mosaic grouped
+    matmul on the TPU) at 2,048 (row, expert) pairs over 128 experts."""
+    dev = chip[0]
+    if name == "block_step_attention":
+        slots, kvh, rows, d, nb = 64, 4, 32, 128, 34
+        assert decode_mod.block_tile_friendly(128, rows, d)
+        pool = on(dev, (6 * 2177, 128, kvh * d))
+        text = compile_text(
+            decode_mod._block_dispatch, on(dev, (slots, kvh, rows, d)),
+            pool, pool, on(dev, (slots, nb), jnp.int32),
+            on(dev, (slots,), jnp.int32))
+        assert "paged_block_attn" in text
+    elif name == "prefill_flash":
+        q = on(dev, (1, 4096, 32, 128))
+        text = compile_text(
+            functools.partial(flash_mod.flash_attention, causal=True,
+                              causal_block=4), q, q, q)
+        assert "flash_fwd" in text
+    else:
+        from distributed_tensorflow_example_tpu.ops.moe import moe_dropless
+        experts = {"gate": on(dev, (128, 2048, 768)),
+                   "up": on(dev, (128, 2048, 768)),
+                   "down": on(dev, (128, 768, 2048))}
+        text = compile_text(
+            functools.partial(moe_dropless, top_k=8),
+            on(dev, (256, 2048), jnp.float32), on(dev, (2048, 128)),
+            experts)
+        assert "ragged-dot" in text
+    assert "tpu_custom_call" in text
