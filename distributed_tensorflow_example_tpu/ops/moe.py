@@ -39,12 +39,15 @@ per-step metrics stream.
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import math
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.xla_metadata import set_xla_metadata
 
 from . import nn
 
@@ -338,6 +341,81 @@ def moe_ffn_shard_map(params: Params, x: jax.Array, mesh, *,
 # Dropless expert layer that holds a SHARE of the experts (serving decoders)
 # ---------------------------------------------------------------------------
 
+#: rows of a grouped-matmul tile (see :func:`ragged_tiling`)
+_RAGGED_ROWS = 128
+#: what a tile may hold of the chip's VMEM, counted as both operand tiles
+#: twice (they are double-buffered) and the float32 result tile three
+#: times. Compiled for a described v5e, every tile up to 15.25 MiB by
+#: this count passed and every one from 16 MiB on was refused; the
+#: largest the sweep ran, 128 x 2048 x 768, counts 8.1 MiB.
+_RAGGED_VMEM_BUDGET = 14 * 2**20
+
+
+def ragged_tiling(pairs: int, k: int, n: int, dtype) -> str | None:
+    """The tile ``"m,k,n"`` of one ``lax.ragged_dot`` of ``pairs`` rows
+    over groups of ``[k, n]`` weights, or ``None`` for XLA's
+    own choice: a rule on the shapes the call observes, set from the
+    chip sweep on record (``experiments/flash_sweep.py ragged``;
+    ``benchmark/records/pr28/ragged_sweep.jsonl``; DESIGN §24).
+
+    XLA's grouped matmul visits every (row tile, group) pair in which
+    the group owns a row of the tile and multiplies the tile WHOLE; its
+    own tile is 512 rows, which at 16 rows a group is multiplied 32
+    times over. The sweep's answer is one tile for every row count it
+    read (8 to 256 rows a group, 128 groups, 2048 x 768 and 768 x 2048,
+    uniform and skewed groups): 128 rows by the whole ``k`` and the
+    whole ``n``. Fewer rows waste less at each group's edge; the whole
+    weight matrix in the tile is fetched once a group (consecutive
+    visits of a group name the same block) and the rows are read once,
+    not once a column tile; 64 rows gain nothing more. It was within
+    2.5 % of the best tile in all twelve shapes and 2.0 to 3.3 times
+    faster than XLA's own. The number of groups does not enter: the tile
+    that is best at 8 rows a group is best at 256. ``None`` wherever no
+    sweep was read or XLA refuses the tile: operands that are not
+    bfloat16, ``k`` or ``n`` not a multiple of 128 or over 4096,
+    ``pairs`` not a multiple of the tile's rows, a tile over
+    ``_RAGGED_VMEM_BUDGET``."""
+    tm = _RAGGED_ROWS
+    vmem = 2 * 2 * (tm * k + k * n) + 3 * 4 * tm * n
+    if (jnp.dtype(dtype) != jnp.bfloat16 or k % 128 or n % 128
+            or max(k, n) > 4096 or pairs % tm
+            or vmem > _RAGGED_VMEM_BUDGET):
+        return None
+    return f"{tm},{k},{n}"
+
+
+def ragged_dot_tiled(a: jax.Array, w: jax.Array, rows: jax.Array,
+                     tile: str | None) -> jax.Array:
+    """``lax.ragged_dot`` with float32 accumulation at the tile
+    ``"m,k,n"``, handed to XLA as the ``ragged_dot_tiling`` frontend
+    attribute (inert off the TPU); ``None`` = XLA's own tile."""
+    with (set_xla_metadata(ragged_dot_tiling=tile) if tile
+          else contextlib.nullcontext()):
+        return lax.ragged_dot(a, w, rows,
+                              preferred_element_type=jnp.float32)
+
+
+_TILE_LOG: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "moe_tile_log", default=None)
+
+
+@contextlib.contextmanager
+def tile_log():
+    """While a program is traced under this, collect the tile each of
+    :func:`moe_dropless`'s grouped matmuls was given: ``{"gate": "m,k,n"
+    | "xla", "up": ..., "down": ...}``. A compiled program carries a
+    tile always or never, so this is the evidence that the rule
+    engaged (``serving.export_generator`` keeps it in ``export.json``)."""
+    tiles: dict[str, str] = {}
+    # a ContextVar's set, not a metric's: the exporter's own dict, written
+    # while it traces and never under a compiled call
+    token = _TILE_LOG.set(tiles)  # graftlint: disable=JIT01
+    try:
+        yield tiles
+    finally:
+        _TILE_LOG.reset(token)
+
+
 def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
                  top_k: int, first_expert: int = 0,
                  dtype=jnp.bfloat16, router_dtype=jnp.float32
@@ -352,8 +430,9 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     float32 softmax of the float32 router product, the ``top_k``
     largest, renormalised to sum to 1. The (row, expert) pairs whose
     expert is held are sorted by expert and run through one grouped
-    matmul per projection (``lax.ragged_dot``: a Mosaic grouped matmul
-    on the TPU, ``ragged-dot-*`` in a capture); the rest contribute
+    matmul per projection (``lax.ragged_dot``, ``ragged-dot-*`` in a
+    capture, at the tile :func:`ragged_tiling` gives for its shape);
+    the rest contribute
     nothing, here as on the chip that would hold them, so the shares of
     a layer add up to the whole layer. Matmul operands are ``dtype``,
     accumulation float32; the router's operands are ``router_dtype``
@@ -385,16 +464,20 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     with jax.named_scope("moe_experts"):
         xs = x.astype(dtype)[order // top_k]                # [T * k, H]
 
-        def grouped(a, w_e):
-            out = lax.ragged_dot(a, w_e.astype(dtype), rows,
-                                 preferred_element_type=jnp.float32)
+        log = _TILE_LOG.get()
+
+        def grouped(a, name):
+            w_e = experts[name].astype(dtype)
+            tile = ragged_tiling(a.shape[0], *w_e.shape[1:], dtype)
+            if log is not None:
+                log[name] = tile or "xla"
             # rows past the last group belong to no held expert: what
             # the grouped matmul leaves there is not a result
-            return jnp.where(live, out, 0.0)
+            return jnp.where(live, ragged_dot_tiled(a, w_e, rows, tile),
+                             0.0)
 
-        act = jax.nn.silu(grouped(xs, experts["gate"])) * grouped(
-            xs, experts["up"])
-        out = grouped(act.astype(dtype), experts["down"])   # [T * k, H]
+        act = jax.nn.silu(grouped(xs, "gate")) * grouped(xs, "up")
+        out = grouped(act.astype(dtype), "down")            # [T * k, H]
     with jax.named_scope("moe_combine"):
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(t * top_k, dtype=order.dtype))

@@ -30,6 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import export as jax_export
 
+from .ops.moe import tile_log
+
 # label-side batch keys never consumed by `apply` (loss/eval only):
 # pruned from the serving signature so a servable takes features only
 _LABEL_KEYS = ("y", "masked_labels", "masked_weights", "__valid__")
@@ -929,15 +931,20 @@ def _export_block_generator(model, params, out_dir: str, *,
         os.makedirs(out_dir, exist_ok=True)
     p_specs = jax.tree_util.tree_map(lambda x: spec(x.shape, x.dtype),
                                      params)
+    moe_tiles = {}
     for name, fn, specs in ((_PREFILL, prefill_fn, prefill_specs),
                             (_BLOCK_STEP, step_fn, step_specs)):
-        if as_args:
-            exp = jax_export.export(
-                jax.jit(fn), platforms=list(platforms))(p_specs, specs)
-        else:
-            exp = jax_export.export(
-                jax.jit(lambda feats, fn=fn: fn(params, feats)),
-                platforms=list(platforms))(specs)
+        # the tile each of the expert layer's grouped matmuls was traced
+        # with (every layer has the same shapes): fixed once compiled
+        with tile_log() as tiles:
+            if as_args:
+                exp = jax_export.export(
+                    jax.jit(fn), platforms=list(platforms))(p_specs, specs)
+            else:
+                exp = jax_export.export(
+                    jax.jit(lambda feats, fn=fn: fn(params, feats)),
+                    platforms=list(platforms))(specs)
+        moe_tiles[name.removesuffix(".stablehlo")] = tiles
         if chief:
             with open(os.path.join(out_dir, name), "wb") as f:
                 f.write(exp.serialize())
@@ -977,7 +984,8 @@ def _export_block_generator(model, params, out_dir: str, *,
                       "layers": int(c.layers),
                       "experts": int(c.experts),
                       "experts_held": int(c.held),
-                      "experts_per_token": int(c.experts_per_token)},
+                      "experts_per_token": int(c.experts_per_token),
+                      "moe_tiles": moe_tiles},
         },
     }
     artifact = os.path.join(out_dir, _BLOCK_STEP)

@@ -1199,6 +1199,10 @@ class GenerationEngine:
         #: the block step (denoising and commit rows together)
         self.block: dict | None = getattr(stepwise, "block", None)
         self._lanes = int(self.block["length"]) if self.block else 0
+        #: per program, the tile each grouped matmul of the expert layer
+        #: was traced with ("xla" = XLA's own; ops/moe.ragged_tiling): a
+        #: compiled program carries one always or never
+        self.moe_tiles: dict = (self.block or {}).get("moe_tiles", {})
         if self.block:
             if spec_tokens or prefill_chunk_tokens:
                 raise ValueError(
@@ -1409,6 +1413,15 @@ class GenerationEngine:
             "serving_moe_max_expert_load_ratio",
             "last block step: the fullest expert's rows over the mean "
             "an expert gets")
+        # chosen when a program is traced, so counted once, at load
+        for tiles in self.moe_tiles.values():
+            for tile in tiles.values():
+                reg.counter(
+                    "serving_moe_ragged_dot_tiled_"
+                    f"{tile.replace(',', 'x')}_total",
+                    "grouped matmuls a layer, over the loaded programs, "
+                    "traced with this tile m x k x n (xla: XLA's own)"
+                ).inc()
         # held experts that received a row in the LAST block step: the
         # next step's span carries it (a step's routing is known only
         # when it returns)
@@ -3737,6 +3750,7 @@ class GenerationEngine:
             "commit_forwards": c("serving_commit_forwards_total"),
             "tokens_committed": c("serving_tokens_committed_total"),
             "moe_rows": c("serving_moe_rows_total"),
+            "moe_tiles": self.moe_tiles,
             "moe_max_expert_load_ratio": c(
                 "serving_moe_max_expert_load_ratio"),
             "jit_compiles": c("jit_compiles_total"),

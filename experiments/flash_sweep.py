@@ -49,6 +49,13 @@ another's device memory):
                      (the serving prefill, GPT-small at S=512, D=128,
                      S=2048 and 4096). Rows also go to OUT (JSON lines).
                      ``flash_schedule`` is what the rows chose from.
+  ragged [OUT]       the expert layer's grouped matmul alone (PR 28): one
+                     bfloat16 ``lax.ragged_dot`` over 128 groups at the
+                     widths ``sdar-serve-backlog`` runs, 1,024 to 32,768
+                     pairs, under XLA's own tile and each
+                     ``ragged_dot_tiling`` of the grid: device ms of
+                     ``ragged-dot*`` and ``max_abs_diff`` against XLA's
+                     own. ``ops/moe.ragged_tiling`` chose from these rows.
 
 The measured columns are TPU columns: off-TPU the kernels run in Pallas
 interpret mode (orders of magnitude slow, numbers meaningless), so
@@ -358,6 +365,32 @@ def kernel_rows() -> list[dict]:
     return rows
 
 
+def _capture(call, args, iters: int) -> dict:
+    """Warm ``call`` up, capture ``iters`` calls of it and reduce the
+    capture (``benchmark.trace_reduce``)."""
+    import shutil
+    import tempfile
+
+    import jax
+
+    from benchmark import trace_reduce
+
+    for _ in range(2):
+        jax.block_until_ready(call(*args))
+    tmp = tempfile.mkdtemp(prefix="flash_sweep_")
+    try:
+        jax.profiler.start_trace(tmp)
+        try:
+            for _ in range(iters):
+                out = call(*args)
+            jax.block_until_ready(out)
+        finally:
+            jax.profiler.stop_trace()
+        return trace_reduce.reduce(trace_reduce.find_xplane(tmp))
+    finally:
+        shutil.rmtree(tmp, ignore_errors=True)
+
+
 def measure_kernels(row: dict, *, iters: int = 5) -> dict:
     """One row: compile, warm up, capture ``iters`` calls, and read the
     device ms per call of each kernel by name (``flash_fwd``,
@@ -365,8 +398,6 @@ def measure_kernels(row: dict, *, iters: int = 5) -> dict:
     whole program."""
     import importlib
     import re
-    import shutil
-    import tempfile
 
     import jax
     import jax.numpy as jnp
@@ -418,20 +449,7 @@ def measure_kernels(row: dict, *, iters: int = 5) -> dict:
 
         call = jax.jit(jax.grad(loss, argnums=(0, 1, 2)) if row["grad"]
                        else attend)
-        for _ in range(2):
-            jax.block_until_ready(call(q, k, v))
-        tmp = tempfile.mkdtemp(prefix="flash_sweep_")
-        try:
-            jax.profiler.start_trace(tmp)
-            try:
-                for _ in range(iters):
-                    out = call(q, k, v)
-                jax.block_until_ready(out)
-            finally:
-                jax.profiler.stop_trace()
-            red = trace_reduce.reduce(trace_reduce.find_xplane(tmp))
-        finally:
-            shutil.rmtree(tmp, ignore_errors=True)
+        red = _capture(call, (q, k, v), iters)
     finally:
         for n, f in saved.items():
             setattr(fm, n, f)
@@ -459,7 +477,69 @@ def measure_kernels(row: dict, *, iters: int = 5) -> dict:
     return out
 
 
-def kernels(out_path: str | None) -> None:
+# ---------------------------------------------------------------------------
+# the expert layer's grouped matmul alone (PR 28): device ms by tile
+# ---------------------------------------------------------------------------
+
+def ragged_rows() -> list[dict]:
+    """What ``ragged`` measures, in order: ``m`` pairs over 128 groups of
+    ``[k, n]`` weights, ``skew`` (group sizes from a Dirichlet(0.3) draw
+    in place of a uniform one), ``tiling`` (None = XLA's own, first for
+    each shape: the others are compared with its result)."""
+    rows = []
+    for k, n, tns in ((2048, 768, (256, 768)),
+                      (768, 2048, (256, 512, 1024, 2048))):
+        for m in (1024, 2048, 4096, 8192, 16384, 32768):
+            tiles = [f"{tm},{k},{tn}" for tm in (128, 256, 512)
+                     for tn in tns]
+            if m in (2048, 32768):      # the cell's shapes: split K, 64 rows
+                tiles += [f"128,{k // 2},{n}", f"256,{k // 2},256",
+                          f"64,{k},{n}"]
+            rows += [dict(m=m, k=k, n=n, groups=128, skew=False, tiling=t)
+                     for t in [None] + tiles]
+            if m in (2048, 16384):
+                rows += [dict(m=m, k=k, n=n, groups=128, skew=True, tiling=t)
+                         for t in (None, f"128,{k},{n}", f"256,{k},{tns[0]}",
+                                   f"256,{k},{tns[-1]}")]
+    return rows
+
+
+def measure_ragged(row: dict, held: dict, *, iters: int = 5) -> dict:
+    """One row: device ms a call of the ``ragged-dot*`` operations, and
+    the widest difference from the result under XLA's own tile. ``held``
+    keeps the operands and that result for the shape in hand (made anew
+    by each row whose ``tiling`` is None)."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace_reduce
+    from distributed_tensorflow_example_tpu.ops.moe import ragged_dot_tiled
+
+    m, k, n, g = (row[x] for x in ("m", "k", "n", "groups"))
+    call = jax.jit(functools.partial(ragged_dot_tiled, tile=row["tiling"]))
+    if row["tiling"] is None:
+        rs = np.random.RandomState(m + k)
+        share = rs.dirichlet([0.3] * g) if row["skew"] else [1 / g] * g
+        ka, kw = jax.random.split(jax.random.key(m + k))
+        held["args"] = (
+            jax.random.normal(ka, (m, k), jnp.bfloat16) * 0.5,
+            jax.random.normal(kw, (g, k, n), jnp.bfloat16) * 0.02,
+            jnp.asarray(rs.multinomial(m, share), jnp.int32))
+        held["out"] = call(*held["args"])
+    red = _capture(call, held["args"], iters)
+    return dict(row, tiling=row["tiling"] or "xla",
+                ms=round(trace_reduce.op_seconds(red, pattern="ragged-dot")
+                         / iters * 1e3, 4),
+                program_ms=round(red["busy_s"] / iters * 1e3, 4),
+                max_group=int(held["args"][2].max()),
+                max_abs_diff=float(jnp.max(jnp.abs(
+                    call(*held["args"]) - held["out"]))))
+
+
+def kernels(out_path: str | None, mode: str = "kernels") -> None:
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -467,15 +547,24 @@ def kernels(out_path: str | None) -> None:
         raise SystemExit("kernel rows are TPU rows (interpret-mode Pallas "
                          "timings are meaningless); set FLASH_SWEEP_CPU=1 "
                          "for a CI smoke run")
-    rows = kernel_rows()
-    if not on_tpu:                       # smoke: the control flow only
-        rows = [dict(r, shape=(1, 256, 2, 64), blk_q=r["blk_q"] and 128,
-                     blk_k=r["blk_k"] and 128) for r in rows[:4]]
+    if mode == "ragged":
+        rows, held = ragged_rows(), {}
+        if not on_tpu:                   # smoke: the control flow only
+            rows = [dict(r, m=64, k=128, n=128, groups=4,
+                         tiling=r["tiling"] and "128,128,128")
+                    for r in rows[:3]]
+    else:
+        rows = kernel_rows()
+        if not on_tpu:                   # smoke: the control flow only
+            rows = [dict(r, shape=(1, 256, 2, 64),
+                         blk_q=r["blk_q"] and 128, blk_k=r["blk_k"] and 128)
+                    for r in rows[:4]]
     sink = open(out_path, "w") if out_path else None
     failed = 0
     for row in rows:
         try:
-            line = measure_kernels(row)
+            line = (measure_ragged(row, held) if mode == "ragged"
+                    else measure_kernels(row))
         except Exception as e:  # noqa: BLE001 — a refused tile is a row
             failed += 1
             line = {**{k: (list(v) if isinstance(v, tuple) else v)
@@ -529,11 +618,11 @@ def main() -> None:
                                   512))
         run_cells(os.path.abspath(__file__), cells)
         return
-    if sys.argv[1:2] == ["kernels"]:
+    if sys.argv[1:2] in (["kernels"], ["ragged"]):
         if len(sys.argv) > 2:
             os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])),
                         exist_ok=True)
-        kernels(sys.argv[2] if len(sys.argv) > 2 else None)
+        kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1])
         return
     if sys.argv[1:2] == ["--trace"]:
         outdir, mn = sys.argv[2], sys.argv[3]

@@ -24,7 +24,11 @@ from benchmark.manifest import load_module                 # noqa: E402
 from distributed_tensorflow_example_tpu import serving     # noqa: E402
 from distributed_tensorflow_example_tpu.config import TrainConfig  # noqa: E402
 from distributed_tensorflow_example_tpu.models import get_model   # noqa: E402
-from distributed_tensorflow_example_tpu.ops.moe import moe_dropless  # noqa: E402
+from distributed_tensorflow_example_tpu.models.decoder import (  # noqa: E402
+    BlockDecoder, DecoderBlockConfig)
+from distributed_tensorflow_example_tpu.ops import moe as moe_mod   # noqa: E402
+from distributed_tensorflow_example_tpu.ops.moe import (  # noqa: E402
+    moe_dropless, ragged_tiling)
 from distributed_tensorflow_example_tpu.serving_batch import (  # noqa: E402
     GenerationEngine)
 
@@ -329,6 +333,164 @@ def test_shares_of_the_experts_add_up_to_the_whole_layer():
     np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
 
 
+# ---- (b') the grouped matmuls' tile ---------------------------------------
+
+BF16 = jnp.bfloat16
+
+
+@pytest.mark.parametrize("pairs,k,n,dtype,want", [
+    # what sdar-serve-backlog runs: 64 slots x 4 lanes x 8 experts a block
+    # step, a 4,096-wide prefill x 8; gate/up then down
+    (2048, 2048, 768, BF16, "128,2048,768"),
+    (2048, 768, 2048, BF16, "128,768,2048"),
+    (32768, 2048, 768, BF16, "128,2048,768"),
+    (32768, 768, 2048, BF16, "128,768,2048"),
+    # the prefill widths ROADMAP S10(a) brings: 1,024 and 2,048 tokens
+    (8192, 2048, 768, BF16, "128,2048,768"),
+    (8192, 768, 2048, BF16, "128,768,2048"),
+    (16384, 2048, 768, BF16, "128,2048,768"),
+    (16384, 768, 2048, BF16, "128,768,2048"),
+    # the tile does not depend on the rows a group gets (8 to 256 read)
+    (1024, 2048, 768, BF16, "128,2048,768"),
+    (4096, 2048, 768, BF16, "128,2048,768"),
+    # outside what was swept: XLA's own, exactly the parent's program
+    (2048, 2000, 768, BF16, None),          # K not a multiple of 128
+    (2048, 2048, 800, BF16, None),          # N not a multiple of 128
+    (2048, 8192, 768, BF16, None),          # over 4,096
+    (2048, 768, 4224, BF16, None),
+    (2048, 2048, 768, jnp.float32, None),   # only bfloat16 was read
+    (64, 2048, 768, BF16, None),            # fewer pairs than a tile
+    (2000, 2048, 768, BF16, None),          # XLA wants m % tile rows == 0
+    (2048, 4096, 4096, BF16, None),         # 128 x 4096 x 4096: VMEM
+    (24, 64, 32, BF16, None),               # sdar_moe_tiny's
+])
+def test_ragged_tiling_is_a_rule_on_shapes(pairs, k, n, dtype, want):
+    assert ragged_tiling(pairs, k, n, dtype) == want
+
+
+def _ragged_dots(text):
+    """Per ``chlo.ragged_dot`` of a program lowered for the TPU, the tile
+    it carries (None = XLA's own)."""
+    import re
+    return [(re.search(r'ragged_dot_tiling = "([^"]*)"', line) or [None, None]
+             )[1] for line in text.splitlines() if "chlo.ragged_dot" in line]
+
+
+@pytest.mark.parametrize("program,name,want", [
+    ("block_step", "sdar_moe",
+     ["128,2048,768", "128,2048,768", "128,768,2048"]),
+    ("paged_prefill", "sdar_moe",
+     ["128,2048,768", "128,2048,768", "128,768,2048"]),
+    ("block_step", "sdar_moe_tiny", [None] * 3),
+    ("paged_prefill", "sdar_moe_tiny", [None] * 3),
+])
+def test_lowered_programs_carry_the_tile_on_each_grouped_matmul(
+        program, name, want):
+    """The two served programs at the cell's widths and sizes (bfloat16,
+    64 slots, 4,096-wide prefill), two layers, lowered for the TPU from
+    shapes alone: gate, up and down of each layer carry
+    ``ragged_dot_tiling`` (a prefill returns the pools, so its last
+    layer's experts are dead code); at widths outside the rule none
+    does (the parent's program)."""
+    model = get_model(name, TrainConfig(
+        model=name, num_layers=2, dtype="bfloat16", param_dtype="bfloat16"))
+    c = model.cfg
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    spec = jax.ShapeDtypeStruct
+    slots, width, nb = (64, 4096, 34) if name == "sdar_moe" else (3, 32, 3)
+    pool = spec((2, 1 + slots * nb, 128, c.kv_heads * c.head_dim),
+                model.dtype)
+    i32 = np.int32
+    if program == "block_step":
+        def fn(p, *a):
+            return model.block_step(p, *a, attention="xla")
+        args = (pool, pool, spec((slots, nb), i32),
+                spec((slots, c.block_length), i32), spec((slots,), i32),
+                spec((slots,), i32), spec((slots,), i32))
+    else:
+        def fn(p, *a):
+            return model.paged_prefill(p, *a, attention="xla")
+        args = (spec((1, width), i32), spec((1, width), i32), pool, pool,
+                spec((width // 128 or 1,), i32))
+    text = jax.jit(fn).trace(params, *args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert _ragged_dots(text) == want * (2 if program == "block_step" else 1)
+
+
+def test_the_tile_changes_no_byte_off_the_tpu(monkeypatch):
+    """A layer inside the rule (bfloat16, 128 wide, 128 and 512 pairs):
+    the attribute is on the traced matmuls, the log says which tile, and
+    the result is the one XLA's own tile gives, to the bit."""
+    k = jax.random.split(jax.random.key(3), 5)
+    router = jax.random.normal(k[1], (128, 4)) * 0.5
+    experts = {"gate": jax.random.normal(k[2], (4, 128, 128)) * 0.1,
+               "up": jax.random.normal(k[3], (4, 128, 128)) * 0.1,
+               "down": jax.random.normal(k[4], (4, 128, 128)) * 0.1}
+
+    def run(x):
+        """(the tiles logged, the lowered text, the layer's results), each
+        from a trace of its own"""
+        def layer(x):
+            return moe_dropless(x, router, experts, top_k=2)
+        with moe_mod.tile_log() as tiles:
+            text = jax.jit(layer).lower(x).as_text()
+        return tiles, text, jax.jit(layer)(x)
+
+    tile = "128,128,128"
+    for rows in (64, 256):
+        x = jax.random.normal(k[0], (rows, 128))
+        tiles, text, with_tile = run(x)
+        assert tiles == {"gate": tile, "up": tile, "down": tile}
+        assert f'ragged_dot_tiling = "{tile}"' in text
+        with monkeypatch.context() as mp:
+            mp.setattr(moe_mod, "ragged_tiling", lambda *a: None)
+            tiles, text, without = run(x)
+        assert set(tiles.values()) == {"xla"}
+        assert "ragged_dot_tiling" not in text
+        for a, b in zip(with_tile, without):
+            assert a.tobytes() == b.tobytes()
+
+
+def test_artifact_keeps_the_tiles_and_the_engine_shows_them(artifact,
+                                                            tmp_path):
+    """A block 128 wide (inside the rule), exported and read back: both
+    programs still carry the attribute, ``export.json`` names the tiles
+    per program, ``/stats`` and the registry show them, and it generates.
+    The tiny artifact (outside the rule) says ``xla`` and carries none."""
+    from jax import export as jax_export
+    cfg = DecoderBlockConfig(
+        vocab_size=512, hidden=128, layers=2, heads=4, kv_heads=2,
+        head_dim=32, experts=4, experts_per_token=2, expert_width=128,
+        mask_id=511, max_len=512, denoising_steps=2)
+    model = BlockDecoder(cfg)
+    d = str(tmp_path / "wide")
+    serving.export_generator(model, model.init(jax.random.key(1)), d,
+                             ragged=True, stepwise=True, paged=True,
+                             slots=16, block_size=16, prompt_len=256,
+                             max_new_tokens=8, platforms=("cpu",))
+    names = ("gate", "up", "down")
+    tiled = {p: dict.fromkeys(names, "128,128,128")
+             for p in ("prefill", "block_step")}
+    own = {p: dict.fromkeys(names, "xla") for p in tiled}
+    for d_, want in ((d, tiled), (artifact, own)):
+        meta = json.load(open(os.path.join(d_, "export.json")))
+        assert meta["stepwise"]["block"]["moe_tiles"] == want
+        for prog, tiles in want.items():
+            with open(os.path.join(d_, prog + ".stablehlo"), "rb") as f:
+                text = jax_export.deserialize(f.read()).mlir_module()
+            tile = tiles["gate"]
+            assert (f'ragged_dot_tiling = "{tile}"' in text) == (
+                "ragged_dot_tiling" in text) == (tile != "xla")
+    eng = GenerationEngine(serving.load_stepwise(d)).start()
+    try:
+        assert len(eng.generate(list(range(1, 40)), max_new=6)) == 6
+        assert eng.stats()["moe_tiles"] == tiled
+        assert eng.registry.snapshot()[
+            "serving_moe_ragged_dot_tiled_128x128x128_total"]["value"] == 6
+    finally:
+        eng.close()
+
+
 # ---- (c) the kernels ----------------------------------------------------
 
 def _plain_attention(q, k, v, allowed):
@@ -523,7 +685,7 @@ def test_generate_over_http_reports_forwards_and_unmask_steps(f32,
                                                   timeout=30).read())
         flat = json.dumps(stats)
         for key in ("block_steps", "denoise_forwards", "commit_forwards",
-                    "tokens_committed", "moe_rows",
+                    "tokens_committed", "moe_rows", "moe_tiles",
                     "moe_max_expert_load_ratio"):
             assert f'"{key}"' in flat
     finally:

@@ -287,12 +287,15 @@ def test_predicates_agree_with_the_compiler(chip, kernel, shape, accepted):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("name", ["block_step_attention", "prefill_flash",
-                                  "grouped_matmul"])
+                                  "grouped_matmul",
+                                  "grouped_matmul_prefill"])
 def test_block_decoder_kernels_compile(chip, name):
     """``paged_block_attn`` over a [layers x blocks, 128, 4 x 128] pool
     view with 32 rows a KV head; ``flash_fwd`` under ``causal_block`` 4
     at S=4096; the expert layer's ``ragged_dot`` (a Mosaic grouped
-    matmul on the TPU) at 2,048 (row, expert) pairs over 128 experts."""
+    matmul on the TPU) over 128 experts at the block step's 2,048 (row,
+    expert) pairs and the prefill's 32,768, at the tiles
+    ``ops/moe.ragged_tiling`` hands the compiler."""
     dev = chip[0]
     if name == "block_step_attention":
         slots, kvh, rows, d, nb = 64, 4, 32, 128, 34
@@ -314,9 +317,12 @@ def test_block_decoder_kernels_compile(chip, name):
         experts = {"gate": on(dev, (128, 2048, 768)),
                    "up": on(dev, (128, 2048, 768)),
                    "down": on(dev, (128, 768, 2048))}
+        rows = 256 if name == "grouped_matmul" else 4096
         text = compile_text(
             functools.partial(moe_dropless, top_k=8),
-            on(dev, (256, 2048), jnp.float32), on(dev, (2048, 128)),
+            on(dev, (rows, 2048), jnp.float32), on(dev, (2048, 128)),
             experts)
         assert "ragged-dot" in text
+        for tile in ("128,2048,768", "128,768,2048"):
+            assert f'ragged_dot_tiling="{tile}"' in text
     assert "tpu_custom_call" in text
