@@ -49,17 +49,17 @@ from .bert import REMAT_POLICIES
 
 def quantize_kv_rows(x):
     """Symmetric per-row int8 quantization of K/V entries: ``x``
-    [..., H, D] -> ``(q int8 [..., H, D], scale f32 [...])`` with
-    ``scale = max|row| / 127`` over each trailing [H, D] plane (eps
+    [..., H*D] (a token's heads side by side, as the paged pool holds
+    them) -> ``(q int8 [..., H*D], scale f32 [...])`` with
+    ``scale = max|row| / 127`` over each token row's H*D values (eps
     floor so an all-zero row dequantizes to exact zeros instead of
     NaN). Deterministic in the row values alone — the property the
     prefix cache's byte-identity contract rides: the same token prefix
     always produces the same int8 block bytes, whether written by
     prefill or by a teacher-forced decode step."""
     xf = x.astype(jnp.float32)
-    scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=(-2, -1)),
-                        1e-8) / 127.0
-    q = jnp.round(xf / scale[..., None, None]).astype(jnp.int8)
+    scale = jnp.maximum(jnp.max(jnp.abs(xf), axis=-1), 1e-8) / 127.0
+    q = jnp.round(xf / scale[..., None]).astype(jnp.int8)
     return q, scale
 
 
@@ -700,7 +700,14 @@ class GPT:
 
     # ------------------------------------------------------------------
     # block-paged serving path (round 10): the KV pool is shared
-    # [L, N, block_size, H, D] physical blocks + per-slot block tables
+    # physical blocks + per-slot block tables. ONE layout, stated here
+    # once: ``[L, N, Bs, H*D]``, a token's heads side by side; layer
+    # i's block b is row ``i * N + b`` of the flat ``[L*N, Bs, H*D]``
+    # view the decode kernel reads. Every writer scatters whole token
+    # rows and the kernel carves its [Bs, g*D] blocks from it as it
+    # lies, so the cache is written and read where it lies: no program
+    # re-lays a pool (tests/test_tpu_compile.py holds the compiled
+    # programs to that).
     # ------------------------------------------------------------------
     def paged_prefill(self, params, input_ids, prompt_mask, k_pool,
                       v_pool, table_row, *, k_scale=None, v_scale=None):
@@ -718,7 +725,7 @@ class GPT:
         shared-scalar trick here.
 
         ``input_ids``/``prompt_mask``: [1, S0] (mask 1 = real token,
-        left-aligned); ``k_pool``/``v_pool``: [L, N, Bs, H, D];
+        left-aligned); ``k_pool``/``v_pool``: [L, N, Bs, H*D];
         ``table_row``: [ceil(S0 / Bs)] int32 physical block ids (the
         engine points unused trailing entries at the reserved null
         block 0 — whole-block writes land there and are never read).
@@ -727,8 +734,8 @@ class GPT:
         overwritten.
 
         ``k_scale``/``v_scale`` ([L, N, Bs] f32 parallel pools) switch
-        on QUANTIZE-ON-WRITE for an int8 pool: each token row's [H, D]
-        K/V plane is stored symmetric int8 with its per-row scale
+        on QUANTIZE-ON-WRITE for an int8 pool: each token row's H*D
+        K/V values are stored symmetric int8 with their per-row scale
         (:func:`quantize_kv_rows` — deterministic in the bytes, so
         prefix-cache sharing mounts byte-identical blocks) and the
         return grows to ``(logits, k_pool', v_pool', k_scale',
@@ -750,7 +757,7 @@ class GPT:
         l = c.layers
 
         def scatter(pool, stacked):
-            blocks = stacked[:, 0].reshape(l, nb_p, bs, *stacked.shape[3:])
+            blocks = stacked[:, 0].reshape(l, nb_p, bs, c.hidden)
             return pool.at[:, table_row].set(blocks.astype(pool.dtype))
 
         logits = self.lm_logits(params, last_h[:, None])[:, 0]
@@ -761,8 +768,9 @@ class GPT:
         # the scale rows ride a parallel [L, N, Bs] pool through the
         # same table indices
         def scatter_q(pool, spool, stacked):
-            q, s = quantize_kv_rows(stacked[:, 0])      # [L,T,H,D]/[L,T]
-            qb = q.reshape(l, nb_p, bs, *q.shape[2:])
+            q, s = quantize_kv_rows(                  # [L,T,H*D] / [L,T]
+                stacked[:, 0].reshape(l, total, c.hidden))
+            qb = q.reshape(l, nb_p, bs, c.hidden)
             sb = s.reshape(l, nb_p, bs)
             return (pool.at[:, table_row].set(qb),
                     spool.at[:, table_row].set(sb))
@@ -785,6 +793,7 @@ class GPT:
 
         ``input_ids``/``chunk_mask``: [1, C] (mask 1 = real token,
         left-aligned — only the final chunk of a prompt is ragged);
+        ``k_pool``/``v_pool``: [L, N, Bs, H*D];
         ``start``: scalar int32, the chunk's first logical slot (the
         engine keeps it block-aligned); ``table_row``: [NB_p] int32,
         the slot's WHOLE prompt-capacity block run (the attention
@@ -839,20 +848,20 @@ class GPT:
                  & (slots[None, :] <= qpos[:, None]))[None, None]
         quant = k_scale is not None
 
-        def write(pool, fresh):
-            # [1, C, H, D] fresh K/V -> the chunk's whole blocks (same
-            # scatter shape as paged_prefill, through chunk_blocks)
-            blocks = fresh[0].reshape(nb_c, bs, *fresh.shape[2:])
-            return pool.at[chunk_blocks].set(blocks.astype(pool.dtype))
+        def write(pool, i, fresh):
+            # [1, C, H, D] fresh K/V -> layer i's whole blocks of flat
+            # token rows (same scatter shape as paged_prefill, through
+            # chunk_blocks), in place on the pool
+            blocks = fresh[0].reshape(nb_c, bs, c.hidden)
+            return pool.at[i, chunk_blocks].set(blocks.astype(pool.dtype))
 
-        def write_q(pool, spool, fresh):
-            q, s = quantize_kv_rows(fresh[0])          # [C,H,D] / [C]
-            return (pool.at[chunk_blocks].set(
-                        q.reshape(nb_c, bs, *q.shape[1:])),
-                    spool.at[chunk_blocks].set(s.reshape(nb_c, bs)))
+        def write_q(pool, spool, i, fresh):
+            q, s = quantize_kv_rows(                   # [C,H*D] / [C]
+                fresh[0].reshape(cw, c.hidden))
+            return (pool.at[i, chunk_blocks].set(
+                        q.reshape(nb_c, bs, c.hidden)),
+                    spool.at[i, chunk_blocks].set(s.reshape(nb_c, bs)))
 
-        new_k, new_v = [], []
-        new_ks, new_vs = [], []
         for i in range(c.layers):
             lp = params[f"layer_{i}"]
             q, k, v = self._qkv(lp["attn"], nn.layernorm(lp["ln1"], h))
@@ -860,20 +869,16 @@ class GPT:
             # below must already see lanes 0..j-1's keys), then gather
             # the whole context window back through the table
             if quant:
-                kp, ksp = write_q(k_pool[i], k_scale[i], k)
-                vp, vsp = write_q(v_pool[i], v_scale[i], v)
-                ctx_k = (kp[table_row].astype(jnp.float32)
-                         * ksp[table_row][..., None, None])
-                ctx_v = (vp[table_row].astype(jnp.float32)
-                         * vsp[table_row][..., None, None])
-                new_ks.append(ksp)
-                new_vs.append(vsp)
+                k_pool, k_scale = write_q(k_pool, k_scale, i, k)
+                v_pool, v_scale = write_q(v_pool, v_scale, i, v)
+                ctx_k = (k_pool[i, table_row].astype(jnp.float32)
+                         * k_scale[i, table_row][..., None])
+                ctx_v = (v_pool[i, table_row].astype(jnp.float32)
+                         * v_scale[i, table_row][..., None])
             else:
-                kp = write(k_pool[i], k)
-                vp = write(v_pool[i], v)
-                ctx_k, ctx_v = kp[table_row], vp[table_row]
-            new_k.append(kp)
-            new_v.append(vp)
+                k_pool = write(k_pool, i, k)
+                v_pool = write(v_pool, i, v)
+                ctx_k, ctx_v = k_pool[i, table_row], v_pool[i, table_row]
             ctx_k = ctx_k.reshape(1, total, c.heads, self.head_dim) \
                 .astype(self.dtype)
             ctx_v = ctx_v.reshape(1, total, c.heads, self.head_dim) \
@@ -891,9 +896,9 @@ class GPT:
             h, jnp.maximum(p_chunk - 1, 0)[None, None, None],
             axis=1)[:, 0]
         logits = self.lm_logits(params, last_h[:, None])[:, 0]
-        out = (logits, jnp.stack(new_k), jnp.stack(new_v))
+        out = (logits, k_pool, v_pool)
         if quant:
-            out += (jnp.stack(new_ks), jnp.stack(new_vs))
+            out += (k_scale, v_scale)
         return out
 
     def decode_step_batched_paged(self, params, stacked, pools,
@@ -904,8 +909,14 @@ class GPT:
         THROUGH per-slot block tables: row b's token writes physical
         block ``block_tables[b, pos_b // Bs]`` at offset ``pos_b % Bs``,
         and attention gathers K/V through the same table (both decode-
-        attention impls). ``pools``: ``{"k"/"v": [L, N, Bs, H, D]}``;
-        ``block_tables``: [B, NB] int32. Rows stay independent — the
+        attention impls). ``pools``: ``{"k"/"v": [L, N, Bs, H*D]}``;
+        ``block_tables``: [B, NB] int32. The pools ride the layer
+        scan's CARRY: layer i writes its B token rows at
+        ``[i, block, offset]`` in place and attention reads the flat
+        ``[L*N, Bs, H*D]`` view through ``block_tables + i * N``, so
+        no layer is sliced out of the pool or stacked back into it
+        (as scanned ``xs``/``ys`` the pools cost a second pool and a
+        copy of every layer's slice a step). Rows stay independent — the
         engine guarantees a written block is uniquely owned (copy-on-
         write happens host-side before the step), and a dead row's
         table points at the null block, where its gated write rewrites
@@ -922,7 +933,7 @@ class GPT:
         from ..ops.pallas.decode_attention import paged_decode_attention
         c = self.cfg
         b = tok.shape[0]
-        bs = pools["k"].shape[2]
+        n_layers, n, bs, _ = pools["k"].shape
         nb = block_tables.shape[1]
         impl = decode_attention or self.decode_attention_impl
         pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, nb * bs - 1)
@@ -943,47 +954,38 @@ class GPT:
         off = pos % bs
         quant = "k_scale" in pools
 
-        def body(h, xs):
-            if quant:
-                lp, ck, cv, cks, cvs = xs
-            else:
-                lp, ck, cv = xs
+        def body(carry, xs):
+            h, cache = carry
+            lp, i = xs
             with jax.named_scope("qkv"):
                 qkv = nn.dense(self._dequant(lp["qkv"]),
                                nn.layernorm(lp["ln1"], h),
                                dtype=self.dtype)
-                q, k, v = [x.reshape(b, c.heads, self.head_dim)
-                           for x in jnp.split(qkv, 3, axis=-1)]
-            if quant:
-                # quantize-on-write: the new row's int8 bytes + scale,
-                # gated like the float write (dead rows rewrite old)
-                with jax.named_scope("cache_write"):
-                    kq, ksc = quantize_kv_rows(k)
-                    vq, vsc = quantize_kv_rows(v)
-                    ck = ck.at[pbid, off].set(jnp.where(
-                        alive[:, None, None], kq, ck[pbid, off]))
-                    cv = cv.at[pbid, off].set(jnp.where(
-                        alive[:, None, None], vq, cv[pbid, off]))
-                    cks = cks.at[pbid, off].set(jnp.where(
-                        alive, ksc, cks[pbid, off]))
-                    cvs = cvs.at[pbid, off].set(jnp.where(
-                        alive, vsc, cvs[pbid, off]))
-                with jax.named_scope("attention"):
-                    ctx = paged_decode_attention(
-                        q, ck, cv, block_tables=bt, pos=pos, pad=pad,
-                        k_scale=cks, v_scale=cvs, impl=impl)
-            else:
-                with jax.named_scope("cache_write"):
-                    k_w = jnp.where(alive[:, None, None],
-                                    k.astype(ck.dtype), ck[pbid, off])
-                    v_w = jnp.where(alive[:, None, None],
-                                    v.astype(cv.dtype), cv[pbid, off])
-                    ck = ck.at[pbid, off].set(k_w)
-                    cv = cv.at[pbid, off].set(v_w)
-                with jax.named_scope("attention"):
-                    ctx = paged_decode_attention(
-                        q, ck, cv, block_tables=bt, pos=pos, pad=pad,
-                        impl=impl)
+                q, k, v = jnp.split(qkv, 3, axis=-1)       # [B, H*D]
+            with jax.named_scope("cache_write"):
+                fresh = {"k": k, "v": v}
+                if quant:
+                    # quantize-on-write: the new row's int8 bytes + scale
+                    fresh["k"], fresh["k_scale"] = quantize_kv_rows(k)
+                    fresh["v"], fresh["v_scale"] = quantize_kv_rows(v)
+                # the gated write, in place on the carried pools: a dead
+                # row rewrites the bytes its (null) target already holds
+                # (the gate is [B, 1] over K/V rows, [B] over scales)
+                cache = {
+                    name: pool.at[i, pbid, off].set(jnp.where(
+                        alive.reshape((b,) + (1,) * (pool.ndim - 3)),
+                        fresh[name].astype(pool.dtype), pool[i, pbid, off]))
+                    for name, pool in cache.items()}
+            with jax.named_scope("attention"):
+                # read where it lies: every layer's blocks in one flat
+                # view, layer i's block b at row i * N + b
+                view = {name: pool.reshape(n_layers * n, *pool.shape[2:])
+                        for name, pool in cache.items()}
+                ctx = paged_decode_attention(
+                    q.reshape(b, c.heads, self.head_dim), view["k"],
+                    view["v"], block_tables=bt + i * n, pos=pos, pad=pad,
+                    k_scale=view.get("k_scale"),
+                    v_scale=view.get("v_scale"), impl=impl)
             with jax.named_scope("projection"):
                 a = nn.dense(self._dequant(lp["o"]),
                              ctx.reshape(b, c.hidden), dtype=self.dtype)
@@ -995,18 +997,11 @@ class GPT:
                 f = nn.dense(self._dequant(lp["ffn_out"]), f,
                              dtype=self.dtype)
                 h = h + f.astype(h.dtype)
-            return h, (ck, cv, cks, cvs) if quant else (ck, cv)
+            return (h, cache), None
 
-        if quant:
-            h, (ks, vs, kss, vss) = lax.scan(
-                body, h, (stacked, pools["k"], pools["v"],
-                          pools["k_scale"], pools["v_scale"]))
-            out_pools = {"k": ks, "v": vs, "k_scale": kss,
-                         "v_scale": vss}
-        else:
-            h, (ks, vs) = lax.scan(body, h,
-                                   (stacked, pools["k"], pools["v"]))
-            out_pools = {"k": ks, "v": vs}
+        (h, out_pools), _ = lax.scan(
+            body, (h, dict(pools)),
+            (stacked, jnp.arange(n_layers, dtype=jnp.int32)))
         with jax.named_scope("head"):
             h = nn.layernorm(params["ln_f"], h)
             logits = self.lm_logits(params, h[:, None])[:, 0]

@@ -173,12 +173,14 @@ def xla_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 
 # ---------------------------------------------------------------------------
 # block-paged decode attention (round 10): K/V live in a shared block pool
-# [N, block_size, H, D] instead of per-slot slabs; each row's logical cache
-# is the run of physical blocks its block-table row names. Both impls gather
-# THROUGH the table: the XLA fallback with one advanced-indexing gather (then
-# the exact slab reference math), the kernel with scalar-prefetch index maps
-# (the block id is read from SMEM before each K/V block's DMA is issued — no
-# gathered [B, T, H, D] tensor ever exists).
+# [N, block_size, H*D] (a token's heads side by side in the lane dimension,
+# the layout the kernel's blocks are carved from as they lie) instead of
+# per-slot slabs; each row's logical cache is the run of physical blocks its
+# block-table row names. Both impls gather THROUGH the table: the XLA
+# fallback with one advanced-indexing gather (then the exact slab reference
+# math), the kernel with scalar-prefetch index maps (the block id is read
+# from SMEM before each K/V block's DMA is issued — no gathered [B, T, H, D]
+# tensor ever exists).
 #
 # K-query speculative verify (round 16): the verify program presents BOTH
 # impls with row-expanded queries — K lanes of one slot become K rows at
@@ -202,8 +204,9 @@ def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                                v_pool: jax.Array, *, block_tables,
                                pos, pad, k_scale=None,
                                v_scale=None) -> jax.Array:
-    """Reference path: gather each row's block run out of the pool (one
-    advanced-indexing gather -> the row's [T, H, D] logical cache, with
+    """Reference path: gather each row's block run out of the flat
+    [N, Bs, H*D] pool (one advanced-indexing gather, heads split after
+    it -> the row's [T, H, D] logical cache, with
     T = blocks_per_row * block_size) and run the exact slab reference.
     Bitwise equal to the slab path on equal logical contents — the
     paged byte-parity oracle.
@@ -212,15 +215,16 @@ def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     the gather additionally dequantizes each row — f32 multiply, cast
     to the query dtype — before the slab reference math (the kernel
     path's parity oracle for the quantized cache)."""
-    n, bs, h, d = k_pool.shape
+    _, h, d = q.shape
+    bs = k_pool.shape[1]
     bt = jnp.asarray(block_tables, jnp.int32)
     b, nb = bt.shape
 
     def gather(pool, scale):
-        g = pool[bt]                                # [B, NB, Bs, H, D]
+        g = pool[bt]                                # [B, NB, Bs, H*D]
         if scale is not None:
             g = (g.astype(jnp.float32)
-                 * scale[bt][..., None, None]).astype(q.dtype)
+                 * scale[bt][..., None]).astype(q.dtype)
         return g.reshape(b, nb * bs, h, d)
 
     return xla_decode_attention(q, gather(k_pool, k_scale),
@@ -301,11 +305,13 @@ def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
                     k_scale=None, v_scale=None):
     """Grid (B, H/g, NB); per program ONE [Bs, g·D] K/V block of the
     pool, selected by the block table via scalar-prefetch index maps.
-    Same [N, Bs, H·D]-view blocks as the slab kernel so every tile is
-    one the compiler accepts. int8 pools additionally stream the
-    matching [1, Bs] scale row per block ([N, 1, Bs] view so the
+    The pool is [N, Bs, H·D] as it lies (the slab kernel's view, here
+    the layout itself: no reshape, so no relayout of the pool a call),
+    every tile one the compiler accepts. int8 pools additionally stream
+    the matching [1, Bs] scale row per block ([N, 1, Bs] view so the
     singleton tile dim matches its array dim)."""
-    n, bs, h, d = k_pool.shape
+    n, bs, _ = k_pool.shape
+    _, h, d = q.shape
     b, nb = block_tables.shape
     quant = k_scale is not None
     g = _group(d)
@@ -325,8 +331,7 @@ def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
         pl.BlockSpec((1, bs, w), kv_map),
         pl.BlockSpec((1, bs, w), kv_map),
     ]
-    operands = [q.reshape(b, 1, h * d), k_pool.reshape(n, bs, h * d),
-                v_pool.reshape(n, bs, h * d)]
+    operands = [q.reshape(b, 1, h * d), k_pool, v_pool]
     if quant:
         in_specs += [pl.BlockSpec((1, 1, bs), scale_map)] * 2
         operands += [k_scale.reshape(n, 1, bs).astype(jnp.float32),
@@ -362,9 +367,13 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                            impl: str = "auto") -> jax.Array:
     """One-query attention against the block-paged cache pool.
 
-    ``q``: [B, H, D]; ``k_pool``/``v_pool``: [N, block_size, H, D]
-    shared physical blocks; ``block_tables``: [B, NB] int32 — row b's
-    logical slot j lives in ``pool[block_tables[b, j // Bs], j % Bs]``;
+    ``q``: [B, H, D] (heads and head size are read from it);
+    ``k_pool``/``v_pool``: [N, block_size, H*D] shared physical blocks,
+    a token's heads side by side (the caller may hand every layer's
+    blocks at once, ``[L*N, Bs, H*D]`` with ``block_tables + i * N``:
+    the kernel then reads layer i where it lies); ``block_tables``:
+    [B, NB] int32 — row b's logical slot j lives in
+    ``pool[block_tables[b, j // Bs], j % Bs]``;
     ``pos``/``pad``: [B] int32, the same live-window semantics as the
     slab path (row b attends to logical slots ``pad_b <= j <= pos_b``).
     Returns [B, H, D] context.
@@ -380,11 +389,12 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     :func:`paged_tile_friendly` shapes, anything else falls back to the
     gather + slab-reference XLA path.
     """
-    n, bs, h, d = k_pool.shape
-    b = q.shape[0]
-    if q.shape != (b, h, d):
-        raise ValueError(f"q shape {q.shape} != {(b, h, d)} from pool "
-                         f"{k_pool.shape}")
+    b, h, d = q.shape
+    if k_pool.ndim != 3 or k_pool.shape[2] != h * d \
+            or v_pool.shape != k_pool.shape:
+        raise ValueError(f"pool shapes {k_pool.shape}/{v_pool.shape} are "
+                         f"not [N, Bs, {h * d}] for q {q.shape}")
+    n, bs, _ = k_pool.shape
     if impl not in ("auto", "pallas", "xla"):
         raise ValueError(f"unknown decode attention impl {impl!r}")
     if (k_scale is None) != (v_scale is None):

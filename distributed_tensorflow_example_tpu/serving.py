@@ -275,7 +275,7 @@ def export_generator(model, params, out_dir: str, *,
 
     ``paged=True`` (requires ``stepwise``) exports BLOCK-PAGED stepwise
     programs instead of the slab pair: the pool is ``[L, num_blocks,
-    block_size, H, D]`` shared physical blocks plus a per-slot block
+    block_size, H*D]`` shared physical blocks plus a per-slot block
     table, prefill writes whole blocks through a table row
     (left-aligned layout — see ``GPT.paged_prefill``), and the decode
     step reads/writes through ``[slots, blocks_per_slot]`` tables.
@@ -659,7 +659,8 @@ def _export_stepwise_paged(model, params, out_dir: str, *,
             "tokens — raise num_blocks or block_size"
             + (f" (pool_bytes {pool_bytes} at {kv_block_bytes} K/V "
                "bytes per block)" if pool_bytes is not None else ""))
-    pool_shape = (c.layers, num_blocks, block_size, c.heads, head_dim)
+    # a token's heads side by side (GPT's paged layout, models/gpt.py)
+    pool_shape = (c.layers, num_blocks, block_size, c.heads * head_dim)
     scale_shape = (c.layers, num_blocks, block_size)
 
     pool_specs = {
@@ -1122,7 +1123,7 @@ class StepwiseGenerator:
                 "(or serve it with the scheduler off)")
         validate_quant_meta(self.meta, where=directory)
         self.step_meta = step_meta
-        #: block-paged artifacts ([L, N, Bs, H, D] pool + block tables)
+        #: block-paged artifacts ([L, N, Bs, H*D] pool + block tables)
         #: vs the slab pair ([L, slots, T, H, D]) — the engine branches
         #: its allocator/prefix-cache machinery on this
         self.paged: bool = bool(step_meta.get("paged", False))

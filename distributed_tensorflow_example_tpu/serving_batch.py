@@ -1668,13 +1668,12 @@ class GenerationEngine:
             # takes them as a per-step operand; 0 = the null block)
             self._tables = np.zeros((self.slots, self.blocks_per_slot),
                                     np.int32)
-            shape = m["pool_shape"]                # [L, N, Bs, H, D]
+            shape = m["pool_shape"]                # [L, N, Bs, ...]
             # per-block residency incl. int8 scale rows: recorded at
             # export since round 12; the fallback recomputes the K/V
             # payload for pre-quant artifacts
             self._block_bytes = int(m.get("block_bytes") or (
-                2 * int(np.prod([shape[0], shape[2], shape[3],
-                                 shape[4]])) * np.dtype(
+                2 * int(np.prod([shape[0], *shape[2:]])) * np.dtype(
                     m["cache_dtype"]).itemsize))
             self._copy_block = self._make_block_copy()
         else:
@@ -3767,6 +3766,7 @@ class GenerationEngine:
             out.update({
                 "paged": True,
                 "block_size": self.block_size,
+                "pool_shape": list(self.sw.step_meta["pool_shape"]),
                 "blocks_total": self.blocks.usable,
                 "blocks_free": c("serving_blocks_free"),
                 "bytes_resident": c("serving_bytes_resident"),

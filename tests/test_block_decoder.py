@@ -550,8 +550,9 @@ def test_flash_causal_block_4(seq, kw):
 def test_defaults_leave_the_kernels_others_call_byte_identical():
     """``causal_block`` 1 is the causal mask: the same jaxpr, the same
     lowered program and the same bytes as a call that does not name it.
-    ``paged_decode_attention`` (GPT-2's, one head count) was not edited:
-    it still agrees with its XLA reference to the bit on float32."""
+    ``paged_decode_attention`` (GPT-2's, one head count; its pool flat
+    like this decoder's since PR 31) agrees with its XLA reference on
+    float32."""
     k = jax.random.split(jax.random.key(0), 4)
     q, kk, v = [jax.random.normal(x, (2, 256, 2, 64)).astype(jnp.bfloat16)
                 for x in k[:3]]
@@ -572,7 +573,7 @@ def test_defaults_leave_the_kernels_others_call_byte_identical():
     g_new = jax.grad(lambda a: new(a, kk, v).astype(jnp.float32).sum())(q)
     assert (g_old == g_new).all()
     qd = jax.random.normal(k[3], (2, 12, 64))
-    pool = jax.random.normal(k[0], (5, 128, 12, 64))
+    pool = jax.random.normal(k[0], (5, 128, 12 * 64))
     kw = dict(block_tables=jnp.array([[1, 2], [3, 0]], jnp.int32),
               pos=jnp.array([200, 17]), pad=jnp.array([0, 0]))
     a = decode_mod.paged_decode_attention(qd, pool, pool, impl="pallas",

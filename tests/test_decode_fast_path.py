@@ -327,32 +327,36 @@ def test_export_generator_sampled_records_prng_impl(tmp_path):
 # sequential decode steps, bit for bit
 # ---------------------------------------------------------------------------
 
-def test_verify_step_matches_sequential_paged_decode_bitwise():
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+def test_verify_step_matches_sequential_paged_decode_bitwise(kv):
     """``decode_verify_batched_paged`` is the batched step over
     row-expanded lanes — its per-lane logits AND its pool writes must
     equal K sequential ``decode_step_batched_paged`` dispatches of the
     same tokens EXACTLY (the byte-parity foundation the engine's
     accept rule stands on), and write-gated lanes (>= n_tok, or a dead
-    row) must leave the pool untouched."""
+    row) must leave the pool untouched. Float and int8 pools
+    ([L, N, Bs, H*D], carried through the step's layer scan)."""
     m = _model()
     params = m.init(jax.random.key(0))
     c = m.cfg
     slots, bs, nblocks, kk = 2, 4, 12, 3
     hd = c.hidden // c.heads
-    shape = (c.layers, nblocks, bs, c.heads, hd)
-    pools = {"k": jnp.zeros(shape, jnp.float32),
-             "v": jnp.zeros(shape, jnp.float32)}
+    shape = (c.layers, nblocks, bs, c.heads * hd)
     prompt = np.array([[5, 6, 7, 8, 9]], np.int32)
-    _, ck, cv = m.paged_prefill(params, prompt, np.ones_like(prompt),
-                                pools["k"], pools["v"],
-                                np.array([1, 2], np.int32))
+    scales = ({} if kv == "float32" else
+              {"k_scale": jnp.zeros(shape[:3], jnp.float32),
+               "v_scale": jnp.zeros(shape[:3], jnp.float32)})
+    out = m.paged_prefill(params, prompt, np.ones_like(prompt),
+                          jnp.zeros(shape, kv), jnp.zeros(shape, kv),
+                          np.array([1, 2], np.int32), **scales)
+    start = dict(zip(("k", "v", "k_scale", "v_scale"), out[1:]))
     stacked = m.stack_decode_params(params)
     bt = np.zeros((slots, 4), np.int32)
     bt[0, :3] = [1, 2, 3]
     toks = [9, 17, 23]                    # anchor + two draft tokens
     pos0 = 5
 
-    seq_pools = {"k": ck, "v": cv}
+    seq_pools = start
     seq_logits = []
     for j, t in enumerate(toks):
         lg, seq_pools = m.decode_step_batched_paged(
@@ -366,7 +370,7 @@ def test_verify_step_matches_sequential_paged_decode_bitwise():
     tokv = np.zeros((slots, kk), np.int32)
     tokv[0] = toks
     ver_logits, ver_pools = m.decode_verify_batched_paged(
-        params, stacked, {"k": ck, "v": cv}, jnp.asarray(bt),
+        params, stacked, start, jnp.asarray(bt),
         jnp.asarray(tokv), jnp.array([pos0, 0], jnp.int32),
         jnp.zeros((slots,), jnp.int32), jnp.array([1, 0], jnp.int32),
         jnp.array([kk, 1], jnp.int32), decode_attention="xla")
@@ -374,25 +378,25 @@ def test_verify_step_matches_sequential_paged_decode_bitwise():
     assert ver_logits.shape == (slots, kk, c.vocab_size)
     for j in range(kk):
         np.testing.assert_array_equal(seq_logits[j], ver_logits[0, j])
-    for n in ("k", "v"):
+    for n in start:
         np.testing.assert_array_equal(np.asarray(seq_pools[n]),
                                       np.asarray(ver_pools[n]))
 
     # n_tok gating: width 1 (no drafts) must write EXACTLY what one
     # sequential step writes — the extra lanes rewrite old bytes
     one_logits, one_pools = m.decode_verify_batched_paged(
-        params, stacked, {"k": ck, "v": cv}, jnp.asarray(bt),
+        params, stacked, start, jnp.asarray(bt),
         jnp.asarray(tokv), jnp.array([pos0, 0], jnp.int32),
         jnp.zeros((slots,), jnp.int32), jnp.array([1, 0], jnp.int32),
         jnp.array([1, 1], jnp.int32), decode_attention="xla")
     lg1, p1 = m.decode_step_batched_paged(
-        params, stacked, {"k": ck, "v": cv}, jnp.asarray(bt),
+        params, stacked, start, jnp.asarray(bt),
         jnp.array([toks[0], 0], jnp.int32),
         jnp.array([pos0, 0], jnp.int32),
         jnp.zeros((slots,), jnp.int32),
         jnp.array([1, 0], jnp.int32), decode_attention="xla")
     np.testing.assert_array_equal(np.asarray(one_logits)[0, 0],
                                   np.asarray(lg1)[0])
-    for n in ("k", "v"):
+    for n in start:
         np.testing.assert_array_equal(np.asarray(one_pools[n]),
                                       np.asarray(p1[n]))

@@ -45,8 +45,10 @@ BLOCK = 4
 # ---------------------------------------------------------------------------
 
 def _rand_pool(rs, n, bs, h, d):
-    return (rs.randn(n, bs, h, d).astype(np.float32),
-            rs.randn(n, bs, h, d).astype(np.float32))
+    """K and V pools in the one paged layout: [N, Bs, H*D], a token's
+    heads side by side."""
+    return (rs.randn(n, bs, h * d).astype(np.float32),
+            rs.randn(n, bs, h * d).astype(np.float32))
 
 
 def test_paged_xla_gather_bitwise_matches_slab_reference():
@@ -137,7 +139,7 @@ def test_paged_kernel_matches_gather_on_verify_expanded_rows():
 
 def test_paged_kernel_rejects_unfriendly_shapes():
     q = jnp.zeros((1, 2, 32))
-    kp = jnp.zeros((2, 4, 2, 32))
+    kp = jnp.zeros((2, 4, 2 * 32))
     with pytest.raises(ValueError, match="block_size"):
         paged_decode_attention(q, kp, kp, block_tables=np.zeros(
             (1, 1), np.int32), pos=np.zeros(1, np.int32),
@@ -150,25 +152,32 @@ def tiny_model():
     return m, m.init(jax.random.key(0))
 
 
-def test_paged_decode_step_bitwise_matches_slab(tiny_model):
-    """decode_step_batched_paged == decode_step_batched bit for bit on
-    equal logical contents — logits AND the written cache bytes."""
+@pytest.mark.parametrize("dead_on_null", [False, True])
+def test_paged_decode_step_bitwise_matches_slab(tiny_model, dead_on_null):
+    """decode_step_batched_paged (the pools flat, carried through the
+    layer scan, layer i read at row i * N of the flat view) ==
+    decode_step_batched on the slab, bit for bit on equal logical
+    contents: logits AND every byte of the pools, the dead row's
+    blocks, the null block and the blocks no table names included.
+    ``dead_on_null``: the dead row's table names the null block 0, as
+    the engine leaves a retired slot's."""
     m, params = tiny_model
     c = m.cfg
     rs = np.random.RandomState(2)
     b, bs, nb = 3, 4, 3
     t = nb * bs
     l, h, d = c.layers, c.heads, m.head_dim
-    n = 1 + b * nb
+    n = 2 + b * nb                          # null + the runs + one spare
     slab = {x: rs.randn(l, b, t, h, d).astype(np.float32)
             for x in ("k", "v")}
     bt = (1 + np.arange(b * nb).reshape(b, nb)).astype(np.int32)
     pools = {}
     for x in ("k", "v"):
-        pool = np.zeros((l, n, bs, h, d), np.float32)
+        pool = rs.randn(l, n, bs, h * d).astype(np.float32)
         for bb in range(b):
             for j in range(nb):
-                pool[:, bt[bb, j]] = slab[x][:, bb, j * bs:(j + 1) * bs]
+                pool[:, bt[bb, j]] = slab[x][
+                    :, bb, j * bs:(j + 1) * bs].reshape(l, bs, h * d)
         pools[x] = jnp.asarray(pool)
     slabj = {x: jnp.asarray(v) for x, v in slab.items()}
     stacked = m.stack_decode_params(params)
@@ -179,13 +188,27 @@ def test_paged_decode_step_bitwise_matches_slab(tiny_model):
     lg_s, new_s = m.decode_step_batched(params, stacked, slabj, tok,
                                         pos, pad, alive,
                                         decode_attention="xla")
+    bt_p = bt.copy()
+    if dead_on_null:
+        bt_p[2] = 0
     lg_p, new_p = m.decode_step_batched_paged(params, stacked, pools,
-                                              bt, tok, pos, pad, alive,
+                                              bt_p, tok, pos, pad, alive,
                                               decode_attention="xla")
-    np.testing.assert_array_equal(np.asarray(lg_s), np.asarray(lg_p))
+    live = slice(0, 2) if dead_on_null else slice(None)
+    np.testing.assert_array_equal(np.asarray(lg_s)[live],
+                                  np.asarray(lg_p)[live])
     for x in ("k", "v"):
-        gathered = np.asarray(new_p[x])[:, bt].reshape(l, b, t, h, d)
+        got = np.asarray(new_p[x])
+        assert got.shape == (l, n, bs, h * d)
+        gathered = got[:, bt].reshape(l, b, t, h, d)
         np.testing.assert_array_equal(gathered, np.asarray(new_s[x]))
+        # outside the two live rows' written token rows not a byte
+        # moved: null block, spare block, the dead row's run
+        want = np.asarray(pools[x]).copy()
+        for bb in (0, 1):
+            pb, off = bt[bb, int(pos[bb]) // bs], int(pos[bb]) % bs
+            want[:, pb, off] = got[:, pb, off]
+        np.testing.assert_array_equal(got, want)
 
 
 def test_paged_prefill_matches_oracle_and_writes_blocks(tiny_model):
@@ -203,8 +226,8 @@ def test_paged_prefill_matches_oracle_and_writes_blocks(tiny_model):
     ids[0, :p] = prompt
     mask[0, :p] = 1
     tr = np.array([2, 4], np.int32)
-    kp = jnp.zeros((l, 6, BLOCK, h, d), jnp.float32)
-    vp = jnp.zeros((l, 6, BLOCK, h, d), jnp.float32)
+    kp = jnp.zeros((l, 6, BLOCK, h * d), jnp.float32)
+    vp = jnp.zeros((l, 6, BLOCK, h * d), jnp.float32)
     logits, kp2, vp2 = m.paged_prefill(params, jnp.asarray(ids),
                                        jnp.asarray(mask), kp, vp,
                                        jnp.asarray(tr))
@@ -219,7 +242,7 @@ def test_paged_prefill_matches_oracle_and_writes_blocks(tiny_model):
         pos_ids=jnp.arange(PROMPT_LEN, dtype=jnp.int32)[None])
     kv = m._stack_caches(caches)
     for x, pool in (("k", kp2), ("v", vp2)):
-        want_blocks = np.asarray(kv[x])[:, 0].reshape(l, 2, BLOCK, h, d)
+        want_blocks = np.asarray(kv[x])[:, 0].reshape(l, 2, BLOCK, h * d)
         np.testing.assert_array_equal(np.asarray(pool)[:, tr],
                                       want_blocks)
 
@@ -508,6 +531,42 @@ def test_cow_on_divergence_protects_cached_blocks(paged_dir,
     eng.close()
 
 
+@pytest.mark.parametrize("chunk", [0, BLOCK])
+def test_served_tokens_survive_hit_cow_and_chunked_prefill(tmp_path,
+                                                           tiny_model,
+                                                           chunk):
+    """One engine over the flat pool, every path that writes or copies
+    it: a cold prefill (monolithic, or in whole-block chunks read back
+    through the table), an exact prefix hit whose first token
+    copies-on-write the shared tail block, and a divergent suffix
+    teacher-forced onto mounted prefix blocks. Each request returns the
+    single-request oracle's tokens."""
+    m, params = tiny_model
+    d = str(tmp_path / "art")
+    export_generator(m, params, d, prompt_len=PROMPT_LEN,
+                     max_new_tokens=MAX_NEW, batch_size=1, ragged=True,
+                     stepwise=True, slots=SLOTS, paged=True,
+                     block_size=BLOCK, num_blocks=48, prefill_chunk=chunk,
+                     platforms=("cpu",))
+    rs = np.random.RandomState(31)
+    base = rs.randint(0, 1000, (BLOCK + 2,)).astype(np.int32)
+    other = np.concatenate([base[:BLOCK],
+                            rs.randint(0, 1000, (3,)).astype(np.int32)])
+    eng = GenerationEngine(load_stepwise(d),
+                           prefill_chunk_tokens=chunk).start()
+    try:
+        for prompt in (base, base, other):
+            assert eng.submit(prompt).result(timeout=120) == _oracle(
+                m, params, prompt)
+        s = eng.stats()
+    finally:
+        eng.close()
+    assert s["prefills"] + s["prefill_chunks"] > 0
+    assert (s["prefill_chunks"] > 0) == bool(chunk)
+    assert s["prefix_cache_hits"] >= 2 and s["cow_copies"] >= 1
+    assert len(s["pool_shape"]) == 4
+
+
 def test_block_exhaustion_fails_one_request_loudly(tmp_path,
                                                    tiny_model):
     """Mid-decode block exhaustion: the request that cannot get a
@@ -604,6 +663,10 @@ def test_paged_stats_block_observability(paged_dir):
                 "prefill_tokens_saved", "cow_copies", "block_size"):
         assert key in s, key
     assert s["paged"] is True
+    # the one pool layout: [L, N, Bs, H*D], as export.json records it
+    with open(f"{paged_dir}/export.json") as f:
+        assert s["pool_shape"] == json.load(f)["stepwise"]["pool_shape"]
+    assert s["pool_shape"] == [2, 48, BLOCK, 128]
     assert s["blocks_total"] == 47
     assert 0 <= s["blocks_free"] <= s["blocks_total"]
     resident = s["blocks_total"] - s["blocks_free"]

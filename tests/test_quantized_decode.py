@@ -66,15 +66,20 @@ def test_quantize_kv_rows_error_bound_and_zero_rows():
     and the bytes are a pure function of the row values — the
     property prefix-cache block sharing rides."""
     rs = np.random.RandomState(0)
-    x = rs.randn(3, 7, 4, 16).astype(np.float32)
+    x = rs.randn(3, 7, 4 * 16).astype(np.float32)   # [.., H*D] token rows
     x[1, 2] = 0.0                              # an all-zero row
     q, s = quantize_kv_rows(jnp.asarray(x))
     assert q.dtype == jnp.int8 and s.dtype == jnp.float32
     assert q.shape == x.shape and s.shape == (3, 7)
-    deq = np.asarray(q, np.float32) * np.asarray(s)[..., None, None]
+    deq = np.asarray(q, np.float32) * np.asarray(s)[..., None]
     err = np.abs(deq - x)
-    assert (err <= np.asarray(s)[..., None, None] / 2 + 1e-7).all()
-    np.testing.assert_array_equal(deq[1, 2], np.zeros((4, 16)))
+    assert (err <= np.asarray(s)[..., None] / 2 + 1e-7).all()
+    np.testing.assert_array_equal(deq[1, 2], np.zeros(4 * 16))
+    # one scale a token row over the same H*D values as the [H, D]
+    # plane the 5-D pool quantized: the bytes did not change
+    plane = np.abs(x.reshape(3, 7, 4, 16)).max(axis=(-2, -1))
+    np.testing.assert_array_equal(
+        np.asarray(s), np.maximum(plane, 1e-8) / np.float32(127.0))
     q2, s2 = quantize_kv_rows(jnp.asarray(x))
     np.testing.assert_array_equal(np.asarray(q), np.asarray(q2))
     np.testing.assert_array_equal(np.asarray(s), np.asarray(s2))
@@ -85,7 +90,7 @@ def test_quantize_kv_rows_error_bound_and_zero_rows():
 # ---------------------------------------------------------------------------
 
 def _quantized_pool(rs, n, bs, h, d):
-    kf = rs.randn(n, bs, h, d).astype(np.float32)
+    kf = rs.randn(n, bs, h * d).astype(np.float32)
     q, s = quantize_kv_rows(jnp.asarray(kf))
     return np.asarray(q), np.asarray(s)
 
@@ -107,8 +112,8 @@ def test_int8_paged_xla_matches_manual_dequant():
         jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
         block_tables=bt, pos=pos, pad=pad, k_scale=jnp.asarray(ks),
         v_scale=jnp.asarray(vs), impl="xla")
-    kf = (kq.astype(np.float32) * ks[..., None, None]).astype(np.float32)
-    vf = (vq.astype(np.float32) * vs[..., None, None]).astype(np.float32)
+    kf = (kq.astype(np.float32) * ks[..., None]).astype(np.float32)
+    vf = (vq.astype(np.float32) * vs[..., None]).astype(np.float32)
     want = paged_decode_attention(jnp.asarray(q), jnp.asarray(kf),
                                   jnp.asarray(vf), block_tables=bt,
                                   pos=pos, pad=pad, impl="xla")
@@ -196,8 +201,8 @@ def test_paged_prefill_int8_writes_quantized_blocks(one_layer_model):
     ids[0, :p] = rs.randint(0, c.vocab_size, (p,))
     mask[0, :p] = 1
     tr = np.array([2, 4], np.int32)
-    zf = jnp.zeros((l, 6, BLOCK, h, d), jnp.float32)
-    zq = jnp.zeros((l, 6, BLOCK, h, d), jnp.int8)
+    zf = jnp.zeros((l, 6, BLOCK, h * d), jnp.float32)
+    zq = jnp.zeros((l, 6, BLOCK, h * d), jnp.int8)
     zs = jnp.zeros((l, 6, BLOCK), jnp.float32)
     lg_f, kf, vf = m.paged_prefill(params, jnp.asarray(ids),
                                    jnp.asarray(mask), zf, zf,
@@ -235,12 +240,12 @@ def test_paged_decode_step_int8_write_and_dead_row_gating(
     stacked = m.stack_decode_params(params)
     bt = (1 + np.arange(b * nb).reshape(b, nb)).astype(np.int32)
     # seed the pools with an already-quantized history
-    hist = rs.randn(l, n, bs, h, d).astype(np.float32)
+    hist = rs.randn(l, n, bs, h * d).astype(np.float32)
     q, s = quantize_kv_rows(jnp.asarray(hist))
     pools_f = {"k": jnp.asarray(np.asarray(q, np.float32)
-                                * np.asarray(s)[..., None, None]),
+                                * np.asarray(s)[..., None]),
                "v": jnp.asarray(np.asarray(q, np.float32)
-                                * np.asarray(s)[..., None, None])}
+                                * np.asarray(s)[..., None])}
     pools_q = {"k": q, "v": q, "k_scale": s, "v_scale": s}
     tok = jnp.asarray(rs.randint(0, c.vocab_size, (b,)), jnp.int32)
     pos = jnp.asarray([2, 5], jnp.int32)
@@ -259,7 +264,7 @@ def test_paged_decode_step_int8_write_and_dead_row_gating(
     pb, off = bt[0, int(pos[0]) // bs], int(pos[0]) % bs
     for x, sx in (("k", "k_scale"), ("v", "v_scale")):
         row_f = np.asarray(new_f[x])[:, 0, int(pos[0])]     # [L, H, D]
-        wq, ws = quantize_kv_rows(jnp.asarray(row_f))
+        wq, ws = quantize_kv_rows(jnp.asarray(row_f.reshape(l, h * d)))
         np.testing.assert_array_equal(
             np.asarray(new_q[x])[:, pb, off], np.asarray(wq))
         np.testing.assert_array_equal(
@@ -269,6 +274,121 @@ def test_paged_decode_step_int8_write_and_dead_row_gating(
         np.testing.assert_array_equal(
             np.asarray(new_q[x])[:, bt[1]],
             np.asarray(pools_q[x])[:, bt[1]])
+
+
+def _step_on_5d_pools(m, params, stacked, pools, bt, tok, pos, pad, alive):
+    """The paged decode step as it stood over ``[L, N, Bs, H, D]`` pools
+    (before PR 31): the pools scanned as ``xs`` beside the stacked
+    weights and stacked back as ``ys``, the new [H, D] plane written
+    into the layer's slice (int8: one scale over the plane), the slot's
+    run gathered 5-D, dequantized and attended by the slab reference.
+    The oracle the flat, carried step must match to the byte."""
+    from jax import lax
+
+    from distributed_tensorflow_example_tpu.ops import nn
+    from distributed_tensorflow_example_tpu.ops.pallas.decode_attention \
+        import xla_decode_attention
+    c = m.cfg
+    b, nb = bt.shape
+    bs = pools["k"].shape[2]
+    names = sorted(pools)
+    live = jnp.asarray(alive) != 0
+    h, _ = m._embed(params, tok[:, None], (pos - pad)[:, None], rng=None,
+                    train=False)
+    pbid, off = bt[jnp.arange(b), pos // bs], pos % bs
+
+    def body(h, xs):
+        lp, cache = xs[0], dict(zip(names, xs[1:]))
+        qkv = nn.dense(m._dequant(lp["qkv"]), nn.layernorm(lp["ln1"], h),
+                       dtype=m.dtype)
+        q, k, v = [x.reshape(b, c.heads, m.head_dim)
+                   for x in jnp.split(qkv, 3, axis=-1)]
+        ctx_kv = []
+        for name, fresh in (("k", k), ("v", v)):
+            pool, spool = cache[name], cache.get(name + "_scale")
+            if spool is not None:
+                xf = fresh.astype(jnp.float32)
+                sc = jnp.maximum(jnp.max(jnp.abs(xf), axis=(-2, -1)),
+                                 1e-8) / 127.0
+                fresh = jnp.round(xf / sc[:, None, None]).astype(jnp.int8)
+                spool = cache[name + "_scale"] = spool.at[pbid, off].set(
+                    jnp.where(live, sc, spool[pbid, off]))
+            pool = cache[name] = pool.at[pbid, off].set(jnp.where(
+                live[:, None, None], fresh.astype(pool.dtype),
+                pool[pbid, off]))
+            g = pool[bt]                            # [B, NB, Bs, H, D]
+            if spool is not None:
+                g = (g.astype(jnp.float32)
+                     * spool[bt][..., None, None]).astype(q.dtype)
+            ctx_kv.append(g.reshape(b, nb * bs, c.heads, m.head_dim))
+        ctx = xla_decode_attention(q, *ctx_kv, pos=pos, pad=pad)
+        a = nn.dense(m._dequant(lp["o"]), ctx.reshape(b, c.hidden),
+                     dtype=m.dtype)
+        h = h + a.astype(h.dtype)
+        f = nn.dense(m._dequant(lp["ffn_in"]), nn.layernorm(lp["ln2"], h),
+                     dtype=m.dtype)
+        f = jax.nn.gelu(f.astype(jnp.float32)).astype(m.dtype)
+        h = h + nn.dense(m._dequant(lp["ffn_out"]), f,
+                         dtype=m.dtype).astype(h.dtype)
+        return h, tuple(cache[name] for name in names)
+
+    h, ys = lax.scan(body, h[:, 0],
+                     (stacked, *(pools[name] for name in names)))
+    h = nn.layernorm(params["ln_f"], h)
+    return m.lm_logits(params, h[:, None])[:, 0], dict(zip(names, ys))
+
+
+@pytest.mark.parametrize("kv", ["float32", "int8"])
+@pytest.mark.parametrize("weights", [None, "int8"])
+def test_flat_carried_step_matches_the_5d_oracle(kv, weights):
+    """Same mathematics, same bytes: ``decode_step_batched_paged`` over
+    ``[L, N, Bs, H*D]`` pools carried through the layer scan against the
+    old arithmetic over ``[L, N, Bs, H, D]`` pools, two steps in a row:
+    identical logits and identical pools, float and int8, a live row, a
+    live row at a block's last offset, and a dead row whose table names
+    the null block (which keeps every byte it held)."""
+    m = get_model("gpt_tiny", TrainConfig(model="gpt_tiny"))
+    params = m.init(jax.random.key(3))
+    c = m.cfg
+    l, hh, d = c.layers, c.heads, m.head_dim
+    rs = np.random.RandomState(6)
+    b, bs, nb = 3, 4, 3
+    n = 2 + b * nb
+    stacked = m.stack_decode_params(params, weight_quant=weights)
+    bt = (1 + np.arange(b * nb).reshape(b, nb)).astype(np.int32)
+    bt[2] = 0                               # the dead row: null block
+    flat = {x: rs.randn(l, n, bs, hh * d).astype(np.float32)
+            for x in ("k", "v")}
+    if kv == "int8":
+        for x in ("k", "v"):
+            flat[x], flat[x + "_scale"] = quantize_kv_rows(
+                jnp.asarray(flat[x]))
+    flat = {x: jnp.asarray(v) for x, v in flat.items()}
+    five = {x: v.reshape(l, n, bs, hh, d) if v.ndim == 4 else v
+            for x, v in flat.items()}
+    before = {x: np.asarray(v) for x, v in flat.items()}
+    pad = jnp.asarray([0, 1, 0], jnp.int32)
+    alive = jnp.asarray([1, 1, 0], jnp.int32)
+    for step in range(2):
+        tok = jnp.asarray(rs.randint(0, c.vocab_size, (b,)), jnp.int32)
+        pos = jnp.asarray([5 + step, 2 + step, 9], jnp.int32)
+        want_lg, five = _step_on_5d_pools(m, params, stacked, five,
+                                          jnp.asarray(bt), tok, pos, pad,
+                                          alive)
+        got_lg, flat = m.decode_step_batched_paged(
+            params, stacked, flat, bt, tok, pos, pad, alive,
+            decode_attention="xla")
+        np.testing.assert_array_equal(np.asarray(want_lg),
+                                      np.asarray(got_lg))
+        assert set(flat) == set(five)
+        for x in flat:
+            np.testing.assert_array_equal(
+                np.asarray(five[x]).reshape(flat[x].shape),
+                np.asarray(flat[x]), err_msg=f"{x} after step {step}")
+    for x, was in before.items():
+        now = np.asarray(flat[x])
+        assert (now[:, bt[0, 1], 1] != was[:, bt[0, 1], 1]).any()  # pos 5
+        np.testing.assert_array_equal(now[:, 0], was[:, 0])   # null block
 
 
 # ---------------------------------------------------------------------------
@@ -420,7 +540,7 @@ def _int8_meta():
         "quant_schema": 1, "weight_quant": "int8",
         "stepwise": {"paged": True, "kv_cache_dtype": "int8",
                      "cache_dtype": "int8",
-                     "pool_shape": [2, 9, 4, 4, 32],
+                     "pool_shape": [2, 9, 4, 4 * 32],
                      "kv_scale_shape": [2, 9, 4],
                      "kv_scale_dtype": "float32"}}
 

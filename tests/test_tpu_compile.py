@@ -13,6 +13,7 @@ that passes is not a chip run.
 import functools
 import importlib
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # compiler logs off /tmp
 
@@ -196,7 +197,7 @@ def _slab_specs(dev, heads=H, head_dim=D):
 
 
 def _paged_specs(dev, heads=H, head_dim=D, dtype=BF16):
-    pool = on(dev, (POOL_BLOCKS, BS, heads, head_dim), dtype)
+    pool = on(dev, (POOL_BLOCKS, BS, heads * head_dim), dtype)
     row = on(dev, (SLOTS,), jnp.int32)
     specs = (on(dev, (SLOTS, heads, head_dim)), pool, pool,
              on(dev, (SLOTS, T // BS), jnp.int32), row, row)
@@ -217,6 +218,131 @@ def test_decode_kernels_compile(chip, name):
         text = compile_text(decode_mod._paged_dispatch,
                             *_paged_specs(chip[0], dtype=dtype))
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# the served GPT-2 programs at the gpt2s-serve cells' shape: the paged cache
+# is written and read where it lies
+# ---------------------------------------------------------------------------
+
+CELL = dict(layers=12, blocks=385, slots=64, blocks_per_slot=6,
+            prompt_len=512)
+_ITEMSIZE = {"bf16": 2, "s8": 1, "f32": 4, "s32": 4, "pred": 1}
+
+
+def _pool_specs(dev, quant):
+    """The engine's pool as the served programs take it: [L, N, Bs, H*D]
+    K and V (int8 with their [L, N, Bs] scale pools when ``quant``)."""
+    shape = (CELL["layers"], CELL["blocks"], BS, H * D)
+    pool = {"cache_k": on(dev, shape, jnp.int8 if quant else BF16),
+            "cache_v": on(dev, shape, jnp.int8 if quant else BF16)}
+    if quant:
+        pool.update({k + "_scale": on(dev, shape[:3], jnp.float32)
+                     for k in tuple(pool)})
+    return pool
+
+
+@pytest.fixture(scope="module")
+def gpt2_small(chip):
+    """gpt2-small as the serving cells build it, its parameter tree and
+    stacked decode weights as shapes on the described chip."""
+    from distributed_tensorflow_example_tpu.models.gpt import GPT, GPTConfig
+    dev = chip[0]
+    cfg = GPTConfig.small()
+    cfg.vocab_size, cfg.dropout = 50257, 0.0
+    model = GPT(cfg, dtype=BF16, attention_impl="flash",
+                decode_attention_impl="pallas")
+    params = jax.eval_shape(model.init, jax.random.key(0))
+    stacked = jax.eval_shape(model.stack_decode_params, params)
+    place = functools.partial(jax.tree_util.tree_map,
+                              lambda x: on(dev, x.shape, x.dtype))
+    return model, place(params), place(stacked)
+
+
+def _served_program(model, name):
+    """``serving._export_stepwise_paged``'s decode / verify / prefill
+    function over (pool, weights, inputs): the pool first, to be donated
+    as ``StepwiseGenerator`` donates it."""
+    def unpack(pool):
+        return {k[len("cache_"):]: v for k, v in pool.items()}
+
+    def pack(logits, new):
+        return {"logits": logits,
+                **{"cache_" + k: v for k, v in new.items()}}
+
+    if name in ("prefill", "prefill_chunk"):
+        # (params, ids, mask[, start], k_pool, v_pool, table_row[, blocks])
+        lead = 2 if name == "prefill" else 3
+
+        def fn(pool, params, *inputs):
+            p = unpack(pool)
+            out = getattr(model, "paged_" + name)(
+                params, *inputs[:lead], p.pop("k"), p.pop("v"),
+                *inputs[lead:], **p)
+            return pack(out[0], dict(zip(
+                ("k", "v", "k_scale", "v_scale"), out[1:])))
+    elif name == "verify":
+        def fn(pool, params, stacked, bt, tok, pos, pad, alive, n_tok):
+            return pack(*model.decode_verify_batched_paged(
+                params, stacked, unpack(pool), bt, tok, pos, pad, alive,
+                n_tok))
+    else:
+        def fn(pool, params, stacked, bt, tok, pos, pad, alive):
+            return pack(*model.decode_step_batched_paged(
+                params, stacked, unpack(pool), bt, tok, pos, pad, alive))
+    return fn
+
+
+@pytest.mark.parametrize("name,quant", [
+    ("decode", False), ("decode", True), ("verify", False),
+    ("prefill", False), ("prefill", True), ("prefill_chunk", False)])
+def test_served_programs_leave_the_pool_where_it_lies(chip, gpt2_small,
+                                                      name, quant):
+    """``jit_decode`` (float and int8 pools), the verify expansion at
+    K = 4, ``jit_prefill`` and one 128-token chunk of a chunked prefill,
+    of gpt2-small at the serving cells' shape (12 layers, 385 blocks of
+    128 tokens, 64 slots of 6 blocks, 512-token prompts), pools donated:
+    (a) no ``copy`` as large as one layer's slice of a pool (with
+    [.., H, D] pools scanned as xs/ys there were ten: eight a layer in
+    the scan and two whole pools after it), (b) every ``cache_*`` input
+    is aliased to an output, (c) under 256 MB of temporaries (were
+    3.10 GB)."""
+    dev = chip[0]
+    model, params, stacked = gpt2_small
+    pool = _pool_specs(dev, quant)
+    row = on(dev, (CELL["slots"],), jnp.int32)
+    table_row = on(dev, (CELL["prompt_len"] // BS,), jnp.int32)
+    if name == "prefill":
+        ids = on(dev, (1, CELL["prompt_len"]), jnp.int32)
+        args = (params, ids, ids, table_row)
+    elif name == "prefill_chunk":
+        ids = on(dev, (1, BS), jnp.int32)
+        args = (params, ids, ids, on(dev, (), jnp.int32), table_row,
+                on(dev, (1,), jnp.int32))
+    else:
+        tok = (on(dev, (CELL["slots"], 4), jnp.int32) if name == "verify"
+               else row)
+        args = (params, stacked,
+                on(dev, (CELL["slots"], CELL["blocks_per_slot"]), jnp.int32),
+                tok, row, row, row) + ((row,) if name == "verify" else ())
+    compiled = jax.jit(_served_program(model, name),
+                       donate_argnums=0).lower(pool, *args).compile()
+    text = compiled.as_text()
+    if name != "prefill_chunk":             # (its attention is XLA's)
+        assert "tpu_custom_call" in text    # the Pallas kernels
+    layer_slice = CELL["blocks"] * BS * H * D * (1 if quant else 2)
+    big = [m.group(0) for m in re.finditer(
+               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+               [int(x) for x in m.group(2).split(",")]) >= layer_slice]
+    assert not big, big
+    # the donated pool's leaves are parameters 0..n-1; each one must be
+    # the buffer of an output: "{out}: (param, {}, may-alias)"
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    assert aliased == set(range(len(pool))), aliased
+    assert compiled.memory_analysis().temp_size_in_bytes < 256e6
 
 
 # ---------------------------------------------------------------------------
@@ -275,7 +401,7 @@ def test_predicates_agree_with_the_compiler(chip, kernel, shape, accepted):
                     impl="pallas")
             else:
                 decode_mod.paged_decode_attention(
-                    q, jnp.zeros((2, BS, h, d)), jnp.zeros((2, BS, h, d)),
+                    q, jnp.zeros((2, BS, h * d)), jnp.zeros((2, BS, h * d)),
                     block_tables=jnp.zeros((1, 1), jnp.int32),
                     pos=jnp.zeros((1,), jnp.int32),
                     pad=jnp.zeros((1,), jnp.int32), impl="pallas")
