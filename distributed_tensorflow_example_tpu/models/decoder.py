@@ -31,6 +31,23 @@ positions, grouped-query attention with per-head q/k RMSNorm, 128 routed
 experts top-8, no bias, untied head) and ``sdar_moe_tiny`` (the same
 block at test widths). Nothing here trains: ``loss`` says so.
 
+``block_length = 1`` is plain causal generation, one token a step, and
+the description then names a KIND a layer (``linear_attn``): the mixer
+is Kimi Delta Attention (``ops/kda.py``: a float32 recurrent state a
+request, no cache that grows) except on ``full_attn_layers``, where it
+is multi-head latent attention without positions (``ops/mla.py``: one
+latent row a token in a paged pool); the FFN of the first
+``dense_layers`` is a dense gated SiLU, of the others sigmoid-routed
+experts (a selection bias, renormalised top-k times ``routed_scale``)
+plus ``shared_experts`` every token passes through; the embedding and
+the head may hold a slice of the vocabulary (``vocab_held`` ids from
+``first_vocab``). Its two forwards are :meth:`BlockDecoder.prefill_chunk`
+(a fixed width of prompt tokens from the state carried in) and
+:meth:`BlockDecoder.decode_step` (one token a slot), both returning
+greedy ids, never logits. Registered as ``kimi_linear``
+(Kimi-Linear-48B-A3B-Instruct's layers) and ``kimi_linear_tiny``; the
+equations are in ``benchmark/reference/kimi-linear-48b-a3b.py``.
+
 Equations (per layer, pre-norm, no bias anywhere)::
 
     h = x + W_o . Attn(rope(rms_d(W_q n)), rope(rms_d(W_k n)), W_v n),
@@ -53,6 +70,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..config import TrainConfig
+from ..ops import kda as kda_ops
+from ..ops import mla as mla_ops
 from ..ops.moe import moe_dropless
 from .base import DefaultRulesMixin, register_model, resolve_dtype
 
@@ -89,10 +108,52 @@ class DecoderBlockConfig:
     denoising_steps: int = 4
     confidence_threshold: float = 0.9
     max_len: int = 32768
+    # ---- a kind a layer (block_length = 1; see the module docstring) ----
+    #: KDA mixers, MLA on ``full_attn_layers`` (numbered from 1, as
+    #: published; entries past ``layers`` name layers held elsewhere)
+    linear_attn: bool = False
+    full_attn_layers: tuple = ()
+    conv_kernel: int = 4            # KDA's short convolution over time
+    gate_rank: int = 128            # rank of KDA's decay and gate pairs
+    kv_lora_rank: int = 512         # MLA: the latent's normed values
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64           # (never rotated: mla_use_nope)
+    v_head_dim: int = 128
+    dense_layers: int = 0           # leading layers with a dense FFN
+    dense_width: int = 0
+    shared_experts: int = 0
+    router_scores: str = "softmax"  # or "sigmoid" (with a selection bias)
+    routed_scale: float = 1.0
+    #: the slice of the vocabulary this chip embeds and scores:
+    #: ``vocab_held`` ids from ``first_vocab`` on (0 = all of it)
+    vocab_held: int = 0
+    first_vocab: int = 0
 
     @classmethod
     def sdar_30b_a3b(cls) -> "DecoderBlockConfig":
         return cls()
+
+    @classmethod
+    def kimi_linear_48b_a3b(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=163840, hidden=2304, layers=27, heads=32,
+                   kv_heads=32, head_dim=128, norm_eps=1e-5, qk_norm=False,
+                   experts=256, experts_per_token=8, expert_width=1024,
+                   block_length=1, mask_id=0, max_len=1048576,
+                   linear_attn=True,
+                   full_attn_layers=(4, 8, 12, 16, 20, 24, 27),
+                   dense_layers=1, dense_width=9216, shared_experts=1,
+                   router_scores="sigmoid", routed_scale=2.446)
+
+    @classmethod
+    def kimi_linear_tiny(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=512, hidden=64, layers=5, heads=4, kv_heads=4,
+                   head_dim=16, norm_eps=1e-5, qk_norm=False, experts=8,
+                   experts_per_token=2, expert_width=32, block_length=1,
+                   mask_id=0, max_len=4096, linear_attn=True,
+                   full_attn_layers=(4, 8), gate_rank=8, kv_lora_rank=32,
+                   qk_nope_dim=16, qk_rope_dim=8, v_head_dim=16,
+                   dense_layers=1, dense_width=128, shared_experts=1,
+                   router_scores="sigmoid", routed_scale=2.446)
 
     @classmethod
     def tiny(cls) -> "DecoderBlockConfig":
@@ -103,6 +164,42 @@ class DecoderBlockConfig:
     @property
     def held(self) -> int:
         return self.experts_held or self.experts
+
+    @property
+    def vocab(self) -> int:
+        """Rows of the embedding and columns of the head held here."""
+        return self.vocab_held or self.vocab_size
+
+    def mixer(self, i: int) -> str:
+        """Layer ``i``'s mixer (``i`` from 0): ``gqa``, ``kda`` or ``mla``."""
+        if not self.linear_attn:
+            return "gqa"
+        return "mla" if i + 1 in self.full_attn_layers else "kda"
+
+    def layers_of(self, kind: str) -> list[int]:
+        return [i for i in range(self.layers) if self.mixer(i) == kind]
+
+    def state_rows(self) -> tuple[dict, dict]:
+        """Layer -> its row in the recurrent arrays (KDA layers) and in
+        the latent pool (MLA layers)."""
+        return ({i: j for j, i in enumerate(self.layers_of("kda"))},
+                {i: j for j, i in enumerate(self.layers_of("mla"))})
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def latent_row(self) -> int:
+        """A token's row of the latent pool: ``latent_dim`` values and
+        zeros up to whole 128-lane tiles. (The chip lays a [.., Bs, 576]
+        array out tokens-minor so as not to pad it, and every program
+        that reads it rows-minor then copies the whole pool.)"""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def conv_channels(self) -> int:
+        return 3 * self.heads * self.head_dim
 
 
 def _rms(x, scale, eps: float):
@@ -139,6 +236,19 @@ class BlockDecoder(DefaultRulesMixin):
             raise ValueError(
                 f"experts {cfg.first_expert}..{cfg.first_expert + cfg.held}"
                 f" are not among the {cfg.experts} the router knows")
+        if cfg.linear_attn != (b == 1):
+            raise ValueError(
+                "a kind a layer (linear_attn) and one token a step "
+                "(block_length = 1) come together: the block-diffusion "
+                "forwards run grouped-query attention only, the one-token "
+                f"forwards KDA and MLA only; got linear_attn="
+                f"{cfg.linear_attn}, block_length={b}")
+        if cfg.router_scores not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router_scores {cfg.router_scores!r}")
+        if cfg.first_vocab + cfg.vocab > cfg.vocab_size:
+            raise ValueError(
+                f"ids {cfg.first_vocab}..{cfg.first_vocab + cfg.vocab} are "
+                f"not among the {cfg.vocab_size} of the vocabulary")
         self.cfg = cfg
         self.dtype = dtype
         self.param_dtype = param_dtype
@@ -150,7 +260,8 @@ class BlockDecoder(DefaultRulesMixin):
         """Seeded parameters in ``param_dtype`` (tests; a served
         checkpoint or the benchmark's seeded leaves replace them)."""
         c = self.cfg
-        keys = iter(jax.random.split(rng, 2 + 8 * c.layers))
+        keys = iter(jax.random.split(
+            rng, 2 + (24 if c.linear_attn else 8) * c.layers))
         qd, kd = c.heads * c.head_dim, c.kv_heads * c.head_dim
 
         def glorot(*shape):
@@ -161,30 +272,74 @@ class BlockDecoder(DefaultRulesMixin):
         def ones(n):
             return jnp.ones((n,), self.param_dtype)
 
+        def uniform(lo, hi, *shape):
+            return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
         params = {
-            "embed": (jax.random.normal(next(keys),
-                                        (c.vocab_size, c.hidden),
+            "embed": (jax.random.normal(next(keys), (c.vocab, c.hidden),
                                         jnp.float32) * 0.02
                       ).astype(self.param_dtype),
             "layers": {},
             "norm_f": ones(c.hidden),
-            "head": glorot(c.hidden, c.vocab_size),
+            "head": glorot(c.hidden, c.vocab),
         }
+
+        def gated(width, *lead):
+            return {"gate": glorot(*lead, c.hidden, width),
+                    "up": glorot(*lead, c.hidden, width),
+                    "down": glorot(*lead, width, c.hidden)}
+
+        def mixer(kind):
+            if kind == "gqa":
+                return {"wq": glorot(c.hidden, qd),
+                        "wk": glorot(c.hidden, kd),
+                        "wv": glorot(c.hidden, kd),
+                        "wo": glorot(qd, c.hidden),
+                        "q_norm": ones(c.head_dim),
+                        "k_norm": ones(c.head_dim)}
+            if kind == "mla":
+                return {"wq": glorot(c.hidden, c.heads * (
+                            c.qk_nope_dim + c.qk_rope_dim)),
+                        "wkva": glorot(c.hidden, c.latent_dim),
+                        "kv_norm": ones(c.kv_lora_rank),
+                        "wkvb": glorot(c.kv_lora_rank, c.heads * (
+                            c.qk_nope_dim + c.v_head_dim)),
+                        "wo": glorot(c.heads * c.v_head_dim, c.hidden)}
+            # kda: the family's initial ranges for the decay: exp(a_log)
+            # in [1, 16], softplus(dt_bias) log-uniform in [0.001, 0.1]
+            dt = jnp.exp(uniform(math.log(1e-3), math.log(0.1), qd))
+            return {"wqkv": glorot(c.hidden, c.conv_channels),
+                    "conv": glorot(c.conv_kernel, c.conv_channels),
+                    "f_a": glorot(c.hidden, c.gate_rank),
+                    "f_b": glorot(c.gate_rank, qd),
+                    # float32 whatever the storage: a few thousand
+                    # values that enter two exponentials
+                    "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                    "a_log": jnp.log(uniform(1.0, 16.0, c.heads)),
+                    "wb": glorot(c.hidden, c.heads),
+                    "g_a": glorot(c.hidden, c.gate_rank),
+                    "g_b": glorot(c.gate_rank, qd),
+                    "o_norm": ones(c.head_dim),
+                    "wo": glorot(qd, c.hidden)}
+
         for i in range(c.layers):
-            params["layers"][str(i)] = {
-                "attn_norm": ones(c.hidden),
-                "attn": {"wq": glorot(c.hidden, qd),
-                         "wk": glorot(c.hidden, kd),
-                         "wv": glorot(c.hidden, kd),
-                         "wo": glorot(qd, c.hidden),
-                         "q_norm": ones(c.head_dim),
-                         "k_norm": ones(c.head_dim)},
-                "ffn_norm": ones(c.hidden),
-                "moe": {"router": glorot(c.hidden, c.experts),
-                        "gate": glorot(c.held, c.hidden, c.expert_width),
-                        "up": glorot(c.held, c.hidden, c.expert_width),
-                        "down": glorot(c.held, c.expert_width, c.hidden)},
-            }
+            kind = c.mixer(i)
+            lp = {"attn_norm": ones(c.hidden),
+                  {"gqa": "attn"}.get(kind, kind): mixer(kind),
+                  "ffn_norm": ones(c.hidden)}
+            if i < c.dense_layers:
+                lp["mlp"] = gated(c.dense_width)
+            else:
+                lp["moe"] = {"router": glorot(c.hidden, c.experts),
+                             **gated(c.expert_width, c.held)}
+                if c.router_scores == "sigmoid":
+                    lp["moe"]["router_bias"] = (jax.random.normal(
+                        next(keys), (c.experts,), jnp.float32) * 0.02
+                    ).astype(self.param_dtype)
+                if c.shared_experts:
+                    lp["moe"]["shared"] = gated(
+                        c.shared_experts * c.expert_width)
+            params["layers"][str(i)] = lp
         return params
 
     # ---- the trainer's protocol: this model is served, not trained ----
@@ -360,6 +515,297 @@ class BlockDecoder(DefaultRulesMixin):
                 "expert_rows": expert_rows,
                 "max_expert_load": fullest.astype(jnp.float32) / mean_load}
 
+    # ------------------------------------------------------------------
+    # one token a step: a kind a layer (linear_attn)
+    # ------------------------------------------------------------------
+    def state_specs(self, *, slots: int, num_blocks: int,
+                    block_size: int) -> dict:
+        """What a server keeps for this model between dispatches, by
+        layer kind: ``{name: {"shape", "dtype", "layers", "per"}}``.
+        ``per = "block"``: rows behind the block tables, allocated and
+        released with a request's blocks, never zeroed (a row is written
+        before it is read). ``per = "slot"``: one row a slot, zeroed when
+        a request takes the slot, carried from chunk to chunk to the
+        decode steps, and left as it lies at release."""
+        c = self.cfg
+        kda, mla = c.layers_of("kda"), c.layers_of("mla")
+        return {
+            "cache_latent": {
+                "shape": [len(mla), num_blocks, block_size, c.latent_row],
+                "dtype": str(jnp.dtype(self.dtype)), "layers": mla,
+                "per": "block"},
+            "cache_state": {
+                "shape": [len(kda), slots, c.heads, c.head_dim, c.head_dim],
+                "dtype": "float32", "layers": kda, "per": "slot"},
+            # the K - 1 inputs before a slot's next token, oldest first,
+            # side by side in one row (3 rows of their own in the tiled
+            # dimensions would be padded to 8, or laid out slots-minor)
+            "cache_conv": {
+                "shape": [len(kda), slots,
+                          (c.conv_kernel - 1) * c.conv_channels],
+                "dtype": "float32", "layers": kda, "per": "slot"},
+        }
+
+    def _mm32(self, x, w):
+        """float32 operands at ``highest`` precision: KDA's decay, rate
+        and gate (small products whose results enter an exponential)."""
+        return jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST)
+
+    def _embed(self, params, ids):
+        """Rows of the held slice; an id held elsewhere embeds as zeros
+        here (its row arrives from the chip that holds it)."""
+        c = self.cfg
+        local = ids - c.first_vocab
+        mine = (local >= 0) & (local < c.vocab)
+        rows = params["embed"][jnp.clip(local, 0, c.vocab - 1)]
+        return jnp.where(mine[:, None], rows.astype(jnp.float32), 0.0)
+
+    def _greedy(self, params, h, with_logits: bool):
+        """The held slice's best id (as an id of the whole vocabulary)."""
+        c = self.cfg
+        with jax.named_scope("sample"):
+            logits = self._mm(_rms(h, params["norm_f"], c.norm_eps),
+                              params["head"])               # [T, V] f32
+            ids = jnp.argmax(logits, axis=-1).astype(jnp.int32) \
+                + c.first_vocab
+        return ids, (logits if with_logits else None)
+
+    def _kda_inputs(self, kp, n, conv_in):
+        """What KDA's recurrence takes, from the normed rows ``n`` [T, h]
+        and the convolution of their projections ``conv_in`` [T, 3 H d]
+        (already through the short convolution): ``q, k, v`` [T, H, d],
+        ``log_a`` [T, H, d] (<= 0) and ``b`` [T, H], float32."""
+        c = self.cfg
+        t = n.shape[0]
+        hd = (t, c.heads, c.head_dim)
+        q, k, v = jnp.split(jax.nn.silu(conv_in), 3, axis=-1)
+        q = kda_ops.l2norm(q.reshape(hd)) * c.head_dim ** -0.5
+        k = kda_ops.l2norm(k.reshape(hd))
+        f = self._mm32(self._mm32(n, kp["f_a"]), kp["f_b"]) \
+            + kp["dt_bias"].astype(jnp.float32)
+        log_a = -jnp.exp(kp["a_log"].astype(jnp.float32))[None, :, None] \
+            * jax.nn.softplus(f).reshape(hd)
+        b = jax.nn.sigmoid(self._mm32(n, kp["wb"]))
+        return q, k, v.reshape(hd), log_a, b
+
+    def _kda_output(self, kp, n, o):
+        """``W_o [ RMSNorm_d(o) * sigmoid(W_g2 W_g1 n) ]``."""
+        c = self.cfg
+        t = n.shape[0]
+        gate = jax.nn.sigmoid(self._mm32(self._mm32(n, kp["g_a"]),
+                                         kp["g_b"]))
+        o = _rms(o, kp["o_norm"], c.norm_eps).reshape(t, -1) * gate
+        return self._mm(o, kp["wo"])
+
+    def _mla_latent(self, mp, n):
+        """The row a token keeps: ``[RMSNorm(c[:rank]) ; c[rank:]]``."""
+        c = self.cfg
+        ckv = self._mm(n, mp["wkva"])
+        return jnp.concatenate(
+            [_rms(ckv[:, :c.kv_lora_rank], mp["kv_norm"], c.norm_eps),
+             ckv[:, c.kv_lora_rank:],
+             jnp.zeros((n.shape[0], c.latent_row - c.latent_dim))],
+            axis=-1).astype(self.dtype)
+
+    def _ffn_of(self, i, lp, h):
+        """Layer ``i``'s FFN on the residual stream; the held experts
+        that received a row, per expert (None for a dense layer)."""
+        c = self.cfg
+        m = _rms(h, lp["ffn_norm"], c.norm_eps)
+
+        def gated(p, x):
+            with jax.named_scope("dense_ffn"):
+                act = jax.nn.silu(self._mm(x, p["gate"])) * self._mm(
+                    x, p["up"])
+                return self._mm(act, p["down"])
+
+        if "mlp" in lp:
+            return h + gated(lp["mlp"], m), None
+        mp = lp["moe"]
+        y, rows = moe_dropless(
+            m, mp["router"], mp, top_k=c.experts_per_token,
+            first_expert=c.first_expert, dtype=self.dtype,
+            router_dtype=self.router_dtype, scores=c.router_scores,
+            select_bias=mp.get("router_bias"), scale=c.routed_scale)
+        if "shared" in mp:
+            y = y + gated(mp["shared"], m)
+        return h + y, rows
+
+    def prefill_chunk(self, params, state, input_ids, n_valid, start, slot,
+                      table_row, chunk_blocks, *, with_logits: bool = False,
+                      kda_chunk: int = kda_ops.SUB_CHUNK):
+        """One chunk of one prompt: ``C`` tokens from what the chunks
+        before it left (the slot's KDA state and conv tails, the latent
+        rows in its blocks) to what the next chunk, or the first decode
+        step, starts from.
+
+        ``state``: the three arrays of :meth:`state_specs`; ``input_ids``
+        [1, C], of which the first ``n_valid`` are tokens (positions
+        ``start .. start + n_valid - 1``; ``start`` a multiple of C);
+        ``slot`` the request's slot; ``table_row`` [NBp] its blocks;
+        ``chunk_blocks`` [C / Bs] the blocks this chunk's rows go to (the
+        null block 0 past the prompt's run). Padding leaves the recurrent
+        rows as they were; its latent rows land where a decode step
+        overwrites them before any read. Returns the state and ``ids``
+        [1]: the greedy token after the chunk's last token."""
+        c = self.cfg
+        cw = input_ids.shape[1]
+        latent, s_all, conv_all = (state["cache_latent"],
+                                   state["cache_state"],
+                                   state["cache_conv"])
+        n_mla, nb, bs, r = latent.shape
+        flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
+        table_row = jnp.asarray(table_row, jnp.int32)
+        chunk_blocks = jnp.asarray(chunk_blocks, jnp.int32)
+        valid = jnp.arange(cw) < n_valid
+        h = self._embed(params, input_ids[0])
+        expert_rows = jnp.zeros((), jnp.int32)
+        kda_at, mla_at = c.state_rows()
+        for i in range(c.layers):
+            lp = params["layers"][str(i)]
+            n = _rms(h, lp["attn_norm"], c.norm_eps)
+            if i in kda_at:
+                j, kp = kda_at[i], lp["kda"]
+                with jax.named_scope("kda"):
+                    y, tail = kda_ops.causal_conv(
+                        self._mm(n, kp["wqkv"]),
+                        conv_all[j, slot].reshape(c.conv_kernel - 1, -1),
+                        kp["conv"], n_valid)
+                    q, k, v, log_a, b = self._kda_inputs(kp, n, y)
+                    log_a = jnp.where(valid[:, None, None], log_a, 0.0)
+                    b = jnp.where(valid[:, None], b, 0.0)
+                    o, s_new = kda_ops.kda_chunk_scan(
+                        s_all[j, slot], q, k, v, log_a, b,
+                        chunk=min(kda_chunk, cw))
+                    s_all = s_all.at[j, slot].set(s_new)
+                    conv_all = conv_all.at[j, slot].set(tail.reshape(-1))
+                    h = h + self._kda_output(kp, n, o)
+            else:
+                j, mp = mla_at[i], lp["mla"]
+                with jax.named_scope("mla"):
+                    lat = self._mla_latent(mp, n)
+                    latent = latent.at[j, chunk_blocks].set(
+                        lat.reshape(cw // bs, bs, r))
+                    q = self._mm(n, mp["wq"]).reshape(
+                        cw, c.heads, c.qk_nope_dim + c.qk_rope_dim)
+                    ctx = mla_ops.mla_prefill_attention(
+                        q, latent.reshape(flat), table_row + j * nb, start,
+                        mp["wkvb"].reshape(c.kv_lora_rank, c.heads, -1),
+                        rank=c.kv_lora_rank, nope=c.qk_nope_dim,
+                        pe=c.qk_rope_dim, v_dim=c.v_head_dim,
+                        scale=(c.qk_nope_dim + c.qk_rope_dim) ** -0.5)
+                    h = h + self._mm(ctx.reshape(cw, -1), mp["wo"])
+            h, rows = self._ffn_of(i, lp, h)
+            if rows is not None:
+                expert_rows += jnp.sum(rows > 0).astype(jnp.int32)
+        # the head over the last token's row only (tests ask for all)
+        last = jnp.maximum(n_valid - 1, 0)
+        ids, logits = self._greedy(
+            params, h if with_logits else lax.dynamic_slice_in_dim(
+                h, last, 1), with_logits)
+        if with_logits:
+            ids = lax.dynamic_slice_in_dim(ids, last, 1)
+        out = {"ids": ids, "cache_latent": latent,
+               "cache_state": s_all, "cache_conv": conv_all,
+               "expert_rows": expert_rows}
+        if with_logits:
+            out["logits"] = logits
+        return out
+
+    def decode_step(self, params, state, block_tables, tok, pos, alive, *,
+                    attention: str = "auto", with_logits: bool = False):
+        """One token of every slot. ``tok`` [slots] the token each live
+        slot feeds, ``pos`` [slots] its position (= the tokens before
+        it), ``alive`` [slots], ``block_tables`` [slots, NB]. A live
+        row's latent row is written at ``pos`` through its table before
+        the attention reads it and its recurrent rows move one token; a
+        row that is not alive (a free slot, or one whose prompt is still
+        being chunked in) writes the null block and keeps its recurrent
+        rows to the bit. ``attention``: ``"auto"`` (the Pallas kernel
+        ``paged_latent_attn`` on a TPU where the shapes allow),
+        ``"pallas"`` or ``"xla"``. Returns ``ids``
+        [slots] (the greedy next token), the state and the routing
+        numbers of :meth:`block_step`."""
+        c = self.cfg
+        s = tok.shape[0]
+        latent, s_all, conv_all = (state["cache_latent"],
+                                   state["cache_state"],
+                                   state["cache_conv"])
+        n_mla, nb, bs, r = latent.shape
+        flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
+        bt = jnp.asarray(block_tables, jnp.int32)
+        live = jnp.asarray(alive) != 0
+        pos = jnp.clip(jnp.asarray(pos, jnp.int32), 0, bt.shape[1] * bs - 1)
+        pbid = jnp.where(live, bt[jnp.arange(s), pos // bs], 0)
+        off = pos % bs
+        h = self._embed(params, tok)
+        expert_rows = jnp.zeros((), jnp.int32)
+        fullest = jnp.zeros((), jnp.int32)
+        kda_at, mla_at = c.state_rows()
+        scale = (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
+        for i in range(c.layers):
+            lp = params["layers"][str(i)]
+            n = _rms(h, lp["attn_norm"], c.norm_eps)
+            if i in kda_at:
+                j, kp = kda_at[i], lp["kda"]
+                with jax.named_scope("kda"):
+                    xx = jnp.concatenate(
+                        [conv_all[j], self._mm(n, kp["wqkv"])],
+                        axis=1)                     # [S, K * 3 H d]
+                    y = jnp.sum(
+                        xx.reshape(s, c.conv_kernel, -1)
+                        * kp["conv"].astype(jnp.float32)[None], axis=1)
+                    conv_all = conv_all.at[j].set(jnp.where(
+                        live[:, None], xx[:, c.conv_channels:],
+                        conv_all[j]))
+                    q, k, v, log_a, b = self._kda_inputs(kp, n, y)
+                    a = jnp.where(live[:, None, None], jnp.exp(log_a), 1.0)
+                    b = jnp.where(live[:, None], b, 0.0)
+                    o, s_new = kda_ops.kda_step(s_all[j], q, k, v, a, b)
+                    s_all = s_all.at[j].set(s_new)
+                    h = h + self._kda_output(kp, n, o)
+            else:
+                j, mp = mla_at[i], lp["mla"]
+                with jax.named_scope("mla"):
+                    latent = latent.at[j, pbid, off].set(
+                        self._mla_latent(mp, n))
+                    q = self._mm(n, mp["wq"]).reshape(
+                        s, c.heads, c.qk_nope_dim + c.qk_rope_dim) * scale
+                    wkvb = mp["wkvb"].reshape(c.kv_lora_rank, c.heads, -1)
+                    # W_kvb absorbed: the query meets the latent itself
+                    q_lat = jnp.einsum(
+                        "shd,chd->shc",
+                        q[..., :c.qk_nope_dim].astype(self.dtype),
+                        wkvb[..., :c.qk_nope_dim].astype(self.dtype),
+                        preferred_element_type=jnp.float32)
+                    q_abs = jnp.concatenate(
+                        [q_lat, q[..., c.qk_nope_dim:],
+                         jnp.zeros((s, c.heads,
+                                    c.latent_row - c.latent_dim))], axis=-1)
+                    ctx = mla_ops.mla_decode_attention(
+                        q_abs.astype(self.dtype), latent.reshape(flat),
+                        block_tables=bt + j * nb, last=pos,
+                        rank=c.kv_lora_rank, impl=attention)
+                    ctx = jnp.einsum(
+                        "shc,chd->shd", ctx.astype(self.dtype),
+                        wkvb[..., c.qk_nope_dim:].astype(self.dtype),
+                        preferred_element_type=jnp.float32)
+                    h = h + self._mm(ctx.reshape(s, -1), mp["wo"])
+            h, rows = self._ffn_of(i, lp, h)
+            if rows is not None:
+                expert_rows += jnp.sum(rows > 0).astype(jnp.int32)
+                fullest = jnp.maximum(fullest, jnp.max(rows))
+        ids, logits = self._greedy(params, h, with_logits)
+        mean_load = s * c.experts_per_token / c.experts
+        out = {"ids": ids, "cache_latent": latent,
+               "cache_state": s_all, "cache_conv": conv_all,
+               "expert_rows": expert_rows,
+               "max_expert_load": fullest.astype(jnp.float32) / mean_load}
+        if with_logits:
+            out["logits"] = logits
+        return out
+
 
 def _make(config: TrainConfig, cfg: DecoderBlockConfig) -> BlockDecoder:
     if config.num_layers:
@@ -376,3 +822,17 @@ def _make_sdar_moe(config: TrainConfig) -> BlockDecoder:
 @register_model("sdar_moe_tiny")
 def _make_sdar_moe_tiny(config: TrainConfig) -> BlockDecoder:
     return _make(config, DecoderBlockConfig.tiny())
+
+
+@register_model("kimi_linear")
+def _make_kimi_linear(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.kimi_linear_48b_a3b())
+    model.name = "kimi_linear"
+    return model
+
+
+@register_model("kimi_linear_tiny")
+def _make_kimi_linear_tiny(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.kimi_linear_tiny())
+    model.name = "kimi_linear"
+    return model

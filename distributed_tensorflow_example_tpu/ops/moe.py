@@ -416,10 +416,22 @@ def tile_log():
         _TILE_LOG.reset(token)
 
 
+def sigmoid_top_k(logits, select_bias, top_k: int, scale: float):
+    """The sigmoid router's choice: per row the ``top_k`` largest of
+    ``sigmoid(logits) + select_bias`` and their weights, the picked
+    scores (the bias left out) renormalised to sum to ``scale``."""
+    s = jax.nn.sigmoid(logits.astype(jnp.float32))
+    pick = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    _, idx = lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    return w * (scale / jnp.sum(w, axis=-1, keepdims=True)), idx
+
+
 def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
                  top_k: int, first_expert: int = 0,
-                 dtype=jnp.bfloat16, router_dtype=jnp.float32
-                 ) -> tuple[jax.Array, jax.Array]:
+                 dtype=jnp.bfloat16, router_dtype=jnp.float32,
+                 scores: str = "softmax", select_bias=None,
+                 scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
     """A gated (SiLU) expert FFN that never drops a token, told which
     experts it holds.
 
@@ -428,7 +440,11 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     [Eh, F, H]}``, the held experts, whose global ids are
     ``first_expert .. first_expert + Eh - 1``. Routing is over all E:
     float32 softmax of the float32 router product, the ``top_k``
-    largest, renormalised to sum to 1. The (row, expert) pairs whose
+    largest, renormalised to sum to 1. ``scores="sigmoid"`` is the other
+    published router: float32 sigmoid scores, the ``top_k`` largest of
+    ``score + select_bias`` (a bias [E] that picks and does not weigh),
+    the picked scores renormalised to sum to ``scale``
+    (``routed_scaling_factor``). The (row, expert) pairs whose
     expert is held are sorted by expert and run through one grouped
     matmul per projection (``lax.ragged_dot``, ``ragged-dot-*`` in a
     capture, at the tile :func:`ragged_tiling` gives for its shape);
@@ -451,8 +467,11 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
             x.astype(router_dtype).astype(jnp.float32),
             router.astype(router_dtype).astype(jnp.float32),
             precision=lax.Precision.HIGHEST)
-        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-        w = w / jnp.sum(w, axis=-1, keepdims=True)
+        if scores == "softmax":
+            w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        else:
+            w, idx = sigmoid_top_k(logits, select_bias, top_k, scale)
         local = (idx - first_expert).reshape(-1)            # [T * k]
         held = (local >= 0) & (local < e_held)
         # pairs of absent experts sort past the last group

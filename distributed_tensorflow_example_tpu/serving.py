@@ -346,7 +346,34 @@ def export_generator(model, params, out_dir: str, *,
     A model with a ``block_step`` (a block-diffusion decoder,
     ``models/decoder.py``) exports the paged pair ``prefill.stablehlo``
     + ``block_step.stablehlo`` and no monolithic program: see
-    :func:`_export_block_generator`."""
+    :func:`_export_block_generator`. A model with a kind a layer and
+    per-request recurrent state (``cfg.linear_attn``) exports
+    ``prefill_chunk.stablehlo`` + ``decode.stablehlo`` over the state
+    its ``state_specs`` name: see :func:`_export_state_generator`."""
+    if hasattr(model, "decode_step") and model.cfg.linear_attn:
+        refused = {"spec_tokens": spec_tokens, "weight_quant": weight_quant,
+                   "temperature": temperature, "top_k": top_k,
+                   "top_p": top_p,
+                   "kv_cache_dtype": (kv_cache_dtype
+                                      if kv_cache_dtype == "int8" else None)}
+        if any(refused.values()):
+            raise ValueError(
+                "an artifact with per-request recurrent state takes no "
+                "speculative verify program (a recurrent state cannot be "
+                "rewound by position arithmetic), no int8 weights or "
+                "cache, and no sampling (its programs return greedy ids, "
+                f"not logits); got { {k: v for k, v in refused.items() if v} }")
+        if not (stepwise and paged and prefill_chunk):
+            raise ValueError(
+                "a decoder with per-request recurrent state is served by "
+                "the paged engine through a chunk program: export with "
+                "stepwise=True, paged=True, prefill_chunk=C")
+        return _export_state_generator(
+            model, params, out_dir, prompt_len=prompt_len,
+            max_new_tokens=max_new_tokens, slots=slots,
+            block_size=block_size, num_blocks=num_blocks,
+            pool_bytes=pool_bytes, prefill_chunk=prefill_chunk,
+            eos_id=eos_id, pad_id=pad_id, platforms=platforms)
     if hasattr(model, "block_step"):
         refused = {"spec_tokens": spec_tokens, "weight_quant": weight_quant,
                    "prefill_chunk": prefill_chunk,
@@ -923,43 +950,17 @@ def _export_block_generator(model, params, out_dir: str, *,
                   "commit": spec((slots,), np.int32),
                   "block_tables": spec((slots, blocks_per_slot), np.int32),
                   **pool_specs}
-    leaves = jax.tree_util.tree_leaves(params)
-    param_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
-                      for x in leaves)
-    as_args = param_bytes > BAKE_LIMIT_BYTES
-    chief = jax.process_index() == 0
-    if chief:
-        os.makedirs(out_dir, exist_ok=True)
-    p_specs = jax.tree_util.tree_map(lambda x: spec(x.shape, x.dtype),
-                                     params)
-    moe_tiles = {}
-    for name, fn, specs in ((_PREFILL, prefill_fn, prefill_specs),
-                            (_BLOCK_STEP, step_fn, step_specs)):
-        # the tile each of the expert layer's grouped matmuls was traced
-        # with (every layer has the same shapes): fixed once compiled
-        with tile_log() as tiles:
-            if as_args:
-                exp = jax_export.export(
-                    jax.jit(fn), platforms=list(platforms))(p_specs, specs)
-            else:
-                exp = jax_export.export(
-                    jax.jit(lambda feats, fn=fn: fn(params, feats)),
-                    platforms=list(platforms))(specs)
-        moe_tiles[name.removesuffix(".stablehlo")] = tiles
-        if chief:
-            with open(os.path.join(out_dir, name), "wb") as f:
-                f.write(exp.serialize())
-    if as_args and chief:
-        save_params(os.path.join(out_dir, _PARAMS_DIR), params)
+    weights, param_count, param_bytes, moe_tiles = _trace_with_params(
+        ((_PREFILL, prefill_fn, prefill_specs),
+         (_BLOCK_STEP, step_fn, step_specs)), params, platforms, out_dir)
     meta = {
         "model": getattr(model, "name", type(model).__name__),
         "kind": "generator", "batch_polymorphic": False,
         "input_signature": {"input_ids": {"shape": [1, prompt_len],
                                           "dtype": "int32"}},
         "platforms": list(platforms),
-        "param_count": sum(int(np.prod(x.shape)) for x in leaves),
-        "param_bytes": param_bytes,
-        "weights": "checkpoint" if as_args else "baked",
+        "param_count": param_count, "param_bytes": param_bytes,
+        "weights": weights,
         "jax_version": jax.__version__,
         "prompt_len": prompt_len, "max_new_tokens": max_new_tokens,
         "temperature": 0.0, "top_k": 0, "top_p": 0.0,
@@ -990,7 +991,184 @@ def _export_block_generator(model, params, out_dir: str, *,
         },
     }
     artifact = os.path.join(out_dir, _BLOCK_STEP)
+    if jax.process_index() == 0:
+        with open(os.path.join(out_dir, _META), "w") as f:
+            json.dump(meta, f, indent=1)
+    return artifact
+
+
+def _trace_with_params(fns, params, platforms, out_dir: str):
+    """Export each ``(file name, fn(params, feats), feature specs)``:
+    weights baked under ``BAKE_LIMIT_BYTES``, else saved once under
+    ``params/`` and taken as every program's first argument. Returns
+    ``(weights, param_count, param_bytes, moe_tiles)``."""
+    leaves = jax.tree_util.tree_leaves(params)
+    param_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                      for x in leaves)
+    as_args = param_bytes > BAKE_LIMIT_BYTES
+    chief = jax.process_index() == 0
     if chief:
+        os.makedirs(out_dir, exist_ok=True)
+    p_specs = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
+    moe_tiles = {}
+    for name, fn, specs in fns:
+        # the tile each of the expert layer's grouped matmuls was traced
+        # with (every layer has the same shapes): fixed once compiled
+        with tile_log() as tiles:
+            if as_args:
+                exp = jax_export.export(
+                    jax.jit(fn), platforms=list(platforms))(p_specs, specs)
+            else:
+                exp = jax_export.export(
+                    jax.jit(lambda feats, fn=fn: fn(params, feats)),
+                    platforms=list(platforms))(specs)
+        moe_tiles[name.removesuffix(".stablehlo")] = tiles
+        if chief:
+            with open(os.path.join(out_dir, name), "wb") as f:
+                f.write(exp.serialize())
+    if as_args and chief:
+        save_params(os.path.join(out_dir, _PARAMS_DIR), params)
+    return ("checkpoint" if as_args else "baked",
+            sum(int(np.prod(x.shape)) for x in leaves), param_bytes,
+            moe_tiles)
+
+
+def _export_state_generator(model, params, out_dir: str, *,
+                            prompt_len: int, max_new_tokens: int,
+                            slots: int, block_size: int,
+                            num_blocks: int | None,
+                            pool_bytes: int | None, prefill_chunk: int,
+                            eos_id, pad_id: int,
+                            platforms: Sequence[str]) -> str:
+    """The artifact of a decoder with a kind a layer (``models/decoder.py``,
+    ``linear_attn``): ``prefill_chunk.stablehlo`` (``prefill_chunk``
+    tokens of one prompt from the state the chunks before left) and
+    ``decode.stablehlo`` (one token of every slot), greedy ids out of
+    both, never logits. No monolithic program and no whole-prompt
+    prefill: one compiled width serves every prompt length.
+
+    What the server keeps between dispatches is what the model's
+    ``state_specs`` names, by layer kind, recorded under
+    ``stepwise.state``: ``per: "block"`` arrays lie behind the block
+    tables (the latent pool ``[L_mla, N, Bs, R]``), ``per: "slot"`` arrays
+    hold one row a slot (the recurrent state ``[L_kda, slots, H, d, d]``
+    float32 and the convolutions' tails). All of them are the donated
+    ``cache_*`` operands of both programs, updated in place. Weights as in
+    :func:`_export_block_generator`."""
+    c = model.cfg
+    if slots < 1 or block_size < 1:
+        raise ValueError(f"slots and block_size must be >= 1, got "
+                         f"{slots}, {block_size}")
+    if prefill_chunk < 1 or prefill_chunk % block_size:
+        raise ValueError(
+            f"prefill_chunk must be a positive multiple of block_size="
+            f"{block_size} (a chunk's latent rows fill whole blocks), got "
+            f"{prefill_chunk}")
+    total = prompt_len + max_new_tokens
+    if total > c.max_len:
+        raise ValueError(f"prompt_len {prompt_len} + max_new_tokens "
+                         f"{max_new_tokens} exceeds max_len {c.max_len}")
+    blocks_per_slot = -(-total // block_size)
+    # a prompt's last chunk names whole chunks of its table row
+    prompt_blocks = -(-prompt_len // prefill_chunk) * (
+        prefill_chunk // block_size)
+    cache_dtype = np.dtype(jnp.dtype(model.dtype))
+    n_latent = len(c.layers_of("mla"))
+    block_bytes = (n_latent * block_size * c.latent_row
+                   * int(cache_dtype.itemsize))
+    if pool_bytes is not None and num_blocks is not None:
+        raise ValueError("pass pool_bytes OR num_blocks, not both")
+    if pool_bytes is not None:
+        num_blocks = 1 + pool_bytes // max(1, block_bytes)
+    if num_blocks is None:
+        num_blocks = 1 + slots * blocks_per_slot
+    if num_blocks - 1 < blocks_per_slot:
+        raise ValueError(
+            f"num_blocks {num_blocks} leaves {num_blocks - 1} usable "
+            f"blocks but one full-depth request needs {blocks_per_slot}")
+    specs = model.state_specs(slots=slots, num_blocks=num_blocks,
+                              block_size=block_size)
+    on_tpu = (tuple(platforms) == ("tpu",)
+              and jax.default_backend() == "tpu")
+    step_attention = "auto" if on_tpu else "xla"
+
+    def state_of(feats):
+        return {k: feats[k] for k in specs}
+
+    def chunk_fn(p, feats):
+        return model.prefill_chunk(
+            p, state_of(feats), feats["input_ids"], feats["n_valid"],
+            feats["start"], feats["slot"], feats["table_row"],
+            feats["chunk_blocks"])
+
+    def decode_fn(p, feats):
+        return model.decode_step(
+            p, state_of(feats), feats["block_tables"], feats["tok"],
+            feats["pos"], feats["alive"], attention=step_attention)
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), np.dtype(dtype))
+
+    state_specs = {k: spec(v["shape"], v["dtype"]) for k, v in specs.items()}
+    chunk_specs = {"input_ids": spec((1, prefill_chunk), np.int32),
+                   "n_valid": spec((), np.int32),
+                   "start": spec((), np.int32),
+                   "slot": spec((), np.int32),
+                   "table_row": spec((prompt_blocks,), np.int32),
+                   "chunk_blocks": spec((prefill_chunk // block_size,),
+                                        np.int32),
+                   **state_specs}
+    decode_specs = {"tok": spec((slots,), np.int32),
+                    "pos": spec((slots,), np.int32),
+                    "alive": spec((slots,), np.int32),
+                    "block_tables": spec((slots, blocks_per_slot), np.int32),
+                    **state_specs}
+    weights, param_count, param_bytes, moe_tiles = _trace_with_params(
+        ((_PREFILL_CHUNK, chunk_fn, chunk_specs),
+         (_DECODE, decode_fn, decode_specs)), params, platforms, out_dir)
+    pool_shape = specs["cache_latent"]["shape"]
+    meta = {
+        "model": getattr(model, "name", type(model).__name__),
+        "kind": "generator", "batch_polymorphic": False,
+        "input_signature": {"input_ids": {"shape": [1, prompt_len],
+                                          "dtype": "int32"}},
+        "platforms": list(platforms),
+        "param_count": param_count, "param_bytes": param_bytes,
+        "weights": weights, "jax_version": jax.__version__,
+        "prompt_len": prompt_len, "max_new_tokens": max_new_tokens,
+        "temperature": 0.0, "top_k": 0, "top_p": 0.0,
+        "eos_id": eos_id, "pad_id": pad_id, "ragged": True,
+        "prng_impl": str(jax.random.key_impl(jax.random.key(0))),
+        "stepwise": {
+            "slots": slots, "prompt_len": prompt_len,
+            "max_new_tokens": max_new_tokens, "max_context": total,
+            "pool_shape": list(pool_shape),
+            "cache_dtype": str(cache_dtype),
+            "kv_cache_dtype": str(cache_dtype),
+            "vocab_size": c.vocab_size, "paged": True,
+            "block_size": block_size, "num_blocks": num_blocks,
+            "blocks_per_slot": blocks_per_slot,
+            "prompt_blocks": prompt_blocks, "layout": "left_aligned",
+            "block_bytes": block_bytes, "spec_tokens": 0,
+            "prefill_chunk": prefill_chunk,
+            # a kind a layer: what the engine allocates, zeroes, carries
+            # and releases, and what it refuses (see GenerationEngine)
+            "state": {"specs": specs,
+                      "mixers": [c.mixer(i) for i in range(c.layers)],
+                      "ffns": ["dense" if i < c.dense_layers else "moe"
+                               for i in range(c.layers)],
+                      "layers": int(c.layers),
+                      "experts": int(c.experts),
+                      "experts_held": int(c.held),
+                      "experts_per_token": int(c.experts_per_token),
+                      "vocab_held": int(c.vocab),
+                      "first_vocab": int(c.first_vocab),
+                      "moe_tiles": moe_tiles},
+        },
+    }
+    artifact = os.path.join(out_dir, _DECODE)
+    if jax.process_index() == 0:
         with open(os.path.join(out_dir, _META), "w") as f:
             json.dump(meta, f, indent=1)
     return artifact
@@ -1062,10 +1240,11 @@ class ServableModel:
             self.meta = json.load(f)
         validate_quant_meta(self.meta, where=directory)
         path = os.path.join(directory, _ARTIFACT)
-        if not os.path.exists(path) and (
-                self.meta.get("stepwise") or {}).get("block"):
-            # a block-diffusion artifact has no monolithic program: the
-            # scheduler's pair is all of it
+        sm = self.meta.get("stepwise") or {}
+        if not os.path.exists(path) and (sm.get("block")
+                                         or sm.get("state")):
+            # a block-diffusion or per-request-state artifact has no
+            # monolithic program: the scheduler's pair is all of it
             self._exported = None
             self._call = self._scheduler_only
             return
@@ -1076,9 +1255,9 @@ class ServableModel:
     @staticmethod
     def _scheduler_only(features):
         raise ValueError(
-            "this artifact generates by diffusion over blocks and holds "
-            "no monolithic program: serve it with the scheduler on "
-            "(the default for stepwise artifacts)")
+            "this artifact holds no monolithic program (it generates by "
+            "diffusion over blocks, or keeps per-request state): serve it "
+            "with the scheduler on (the default for stepwise artifacts)")
 
     @property
     def input_signature(self) -> dict:
@@ -1095,9 +1274,11 @@ def load_servable(directory: str) -> ServableModel:
 def has_stepwise(directory: str) -> bool:
     """True when ``directory`` holds the stepwise (prefill + shared
     decode step) artifacts a continuous-batching scheduler can drive."""
-    return (os.path.exists(os.path.join(directory, _PREFILL))
-            and (os.path.exists(os.path.join(directory, _DECODE))
-                 or os.path.exists(os.path.join(directory, _BLOCK_STEP))))
+    def has(name):
+        return os.path.exists(os.path.join(directory, name))
+
+    return ((has(_PREFILL) or has(_PREFILL_CHUNK))
+            and (has(_DECODE) or has(_BLOCK_STEP)))
 
 
 class StepwiseGenerator:
@@ -1157,14 +1338,21 @@ class StepwiseGenerator:
         #: metadata (length, mask id, schedule), else None. Such an
         #: artifact's step program is block_step.stablehlo
         self.block: dict | None = step_meta.get("block")
+        #: a kind a layer: the artifact's ``state`` metadata (the state
+        #: specs by layer kind, :func:`_export_state_generator`), else
+        #: None. Such an artifact has no whole-prompt prefill program:
+        #: prompts go through ``prefill_chunk``
+        self.state: dict | None = step_meta.get("state")
         #: the one loaded parameter tree of a weights-as-arguments
         #: artifact (``weights: "checkpoint"``), which every program
         #: takes, never donated; None where the weights are baked
         self.params = None
         if self.meta.get("weights") == "checkpoint":
             self.params = load_params(os.path.join(directory, _PARAMS_DIR))
-        with open(os.path.join(directory, _PREFILL), "rb") as f:
-            self._prefill_exp = jax_export.deserialize(f.read())
+        self._prefill_exp = None
+        if not self.state:
+            with open(os.path.join(directory, _PREFILL), "rb") as f:
+                self._prefill_exp = jax_export.deserialize(f.read())
         with open(os.path.join(
                 directory, _BLOCK_STEP if self.block else _DECODE),
                 "rb") as f:
@@ -1200,7 +1388,17 @@ class StepwiseGenerator:
             jitted = jax.jit(fn, donate_argnums=(0,))
             return lambda pool, rest: jitted(pool, self.params, rest)
 
-        self._prefill = split(self._prefill_exp.call, "prefill")
+        self._prefill = (split(self._prefill_exp.call, "prefill")
+                         if self._prefill_exp is not None else None)
+        self._zero = None
+        if self.state:
+            per_slot = [k for k, v in self.state["specs"].items()
+                        if v["per"] == "slot"]
+
+            def zero_slot(pool, slot):
+                return {k: (v.at[:, slot].set(0) if k in per_slot else v)
+                        for k, v in pool.items()}
+            self._zero = jax.jit(zero_slot, donate_argnums=(0,))
         self._decode = split(self._decode_exp.call,
                              "block_step" if self.block else "decode")
         self._verify = (split(self._verify_exp.call, "verify")
@@ -1213,6 +1411,12 @@ class StepwiseGenerator:
         one-time allocation) — int8 artifacts include the parallel
         per-token-row scale pools."""
         m = self.step_meta
+        if self.state:
+            dev = jax.devices()[0]
+            return {k: jax.device_put(
+                        jnp.zeros(tuple(v["shape"]), np.dtype(v["dtype"])),
+                        dev)
+                    for k, v in self.state["specs"].items()}
         shape = tuple(m["pool_shape"])
         dtype = np.dtype(m["cache_dtype"])
         # COMMITTED to its device, like every pool the programs hand
@@ -1245,8 +1449,20 @@ class StepwiseGenerator:
         return pool, rest
 
     def prefill(self, feats: dict) -> dict:
+        if self._prefill is None:
+            raise ValueError("this artifact holds no whole-prompt prefill "
+                             "program: its prompts go through "
+                             "prefill_chunk")
         pool, rest = self._split(feats)
         return self._prefill(pool, rest)
+
+    def zero_slot(self, pool: dict, slot: int) -> dict:
+        """The pool with slot ``slot``'s rows of every ``per: "slot"``
+        array zeroed (in place: the pool is donated): what a request
+        that takes the slot starts from."""
+        if self._zero is None:
+            raise ValueError("this artifact keeps no per-slot state")
+        return self._zero(pool, np.int32(slot))
 
     def decode(self, feats: dict) -> dict:
         pool, rest = self._split(feats)

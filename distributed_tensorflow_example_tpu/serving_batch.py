@@ -1202,7 +1202,41 @@ class GenerationEngine:
         #: per program, the tile each grouped matmul of the expert layer
         #: was traced with ("xla" = XLA's own; ops/moe.ragged_tiling): a
         #: compiled program carries one always or never
-        self.moe_tiles: dict = (self.block or {}).get("moe_tiles", {})
+        #: a kind a layer (``export.json`` ``stepwise.state``), else None:
+        #: the artifact's state specs say what this engine allocates
+        #: (every array, once, at load), zeroes (the ``per: "slot"``
+        #: rows of a slot, when a request takes it), carries (the same
+        #: rows, chunk to chunk to the decode steps, and the ``per:
+        #: "block"`` rows behind the block tables) and releases (the
+        #: blocks; a slot's rows are left as they lie). Prompts go
+        #: through the chunk program only, and both programs return
+        #: greedy ids.
+        self.state: dict | None = getattr(stepwise, "state", None)
+        self.moe_tiles: dict = (self.block or self.state or {}).get(
+            "moe_tiles", {})
+        if self.state:
+            if spec_tokens:
+                raise ValueError(
+                    "this artifact keeps per-request recurrent state, "
+                    "which a rejected draft cannot rewind by position "
+                    f"arithmetic: run with spec_tokens=0 (got "
+                    f"{spec_tokens})")
+            chunk = int(getattr(stepwise, "prefill_chunk_tokens", 0))
+            if prefill_chunk_tokens not in (0, chunk):
+                raise ValueError(
+                    f"this artifact's prompts go through its chunk "
+                    f"program at the exported width {chunk} only (a "
+                    f"chunk starts where the last one's state ends); "
+                    f"got prefill_chunk_tokens={prefill_chunk_tokens}")
+            prefill_chunk_tokens = chunk
+            if prefix_cache:
+                log.info("per-request-state artifact: prefix cache off "
+                         "(a prefix is reused by a snapshot of the "
+                         "recurrent rows, not by block hash)")
+                prefix_cache = False
+            log.info("per-request-state artifact: speculation, prefix "
+                     "reuse and temperature > 0 are refused; prompts "
+                     "prefill in chunks of %d", chunk)
         if self.block:
             if spec_tokens or prefill_chunk_tokens:
                 raise ValueError(
@@ -1334,6 +1368,9 @@ class GenerationEngine:
         self._c_prefill_chunks = reg.counter(
             "serving_prefill_chunks_total",
             "chunked-prefill dispatches (prefill_chunk_tokens > 0)")
+        self._c_prefill_chunk_tokens = reg.counter(
+            "serving_prefill_chunk_tokens_total",
+            "prompt tokens the chunked-prefill dispatches carried")
         self._c_shed = reg.counter(
             "serving_shed_total",
             "requests shed with 429 + measured Retry-After by the "
@@ -1422,6 +1459,11 @@ class GenerationEngine:
                     "grouped matmuls a layer, over the loaded programs, "
                     "traced with this tile m x k x n (xla: XLA's own)"
                 ).inc()
+        #: (row, expert) pairs one row sends through a per-request-state
+        #: artifact's expert layers
+        self._moe_pairs_a_row = (
+            self.state["ffns"].count("moe")
+            * int(self.state["experts_per_token"]) if self.state else 0)
         # held experts that received a row in the LAST block step: the
         # next step's span carries it (a step's routing is known only
         # when it returns)
@@ -1683,9 +1725,27 @@ class GenerationEngine:
         # engine holds (every cache_* array is [L, rows, tokens, ...]):
         # the /metrics-visible dtype signal next to the string in
         # /stats, and what one live token costs a decode step to read
+        per_slot = {k for k, v in (self.state or {}).get(
+            "specs", {}).items() if v["per"] == "slot"}
         self._kv_token_bytes = sum(
             int(v.nbytes) // (int(v.shape[1]) * int(v.shape[2]))
-            for v in self._pool.values())
+            for k, v in self._pool.items() if k not in per_slot)
+        #: bytes of recurrent rows one slot holds (0 without any): what
+        #: a decode step reads and writes of them a live row
+        self._state_slot_bytes = sum(
+            int(self._pool[k].nbytes) // self.slots for k in per_slot)
+        self._g_state_bytes = reg.gauge(
+            "serving_state_bytes",
+            "bytes of per-slot recurrent state the engine holds (every "
+            "slot's rows, live or not; 0 for artifacts without any)")
+        self._g_state_bytes.set(self._state_slot_bytes * self.slots)
+        self._g_latent_pool_bytes = reg.gauge(
+            "serving_latent_pool_bytes",
+            "bytes of the paged latent pool of a per-request-state "
+            "artifact (0 otherwise)")
+        self._g_latent_pool_bytes.set(sum(
+            int(v.nbytes) for k, v in self._pool.items()
+            if k not in per_slot) if self.state else 0)
         self._g_kv_bytes_per_token = reg.gauge(
             "serving_kv_cache_bytes_per_token",
             "bytes one cached token occupies at the artifact's "
@@ -1791,6 +1851,11 @@ class GenerationEngine:
         if req.temperature < 0.0:
             raise ValueError(f"temperature must be >= 0, got "
                              f"{req.temperature}")
+        if self.state and req.temperature > 0.0:
+            raise ValueError(
+                "this artifact's programs return greedy ids from the "
+                "device, no logits: temperature must be 0 (got "
+                f"{req.temperature})")
         if self.block and req.temperature > 0.0:
             raise ValueError(
                 "this artifact generates by diffusion over blocks, "
@@ -2600,6 +2665,10 @@ class GenerationEngine:
             # interleaved with the shared decode step, and the final
             # chunk's logits become the first sample point
             self._tables[index, :needed] = run
+            if self.state:
+                # the slot's recurrent rows still hold the request that
+                # left it: this one starts from zeros
+                self._zero_slot_state(index)
             with self.registry.atomic():
                 self._c_admissions.inc()
                 if self.prefix_cache is not None:
@@ -2670,6 +2739,12 @@ class GenerationEngine:
         return True
 
     @scheduler_thread
+    def _zero_slot_state(self, index: int) -> None:
+        """Zero slot ``index``'s rows of every ``per: "slot"`` array
+        (in place; the pool is donated to the call)."""
+        self._pool = self.sw.zero_slot(self._pool, index)
+
+    @scheduler_thread
     def _prefill_chunk_step(self) -> None:
         """Dispatch ONE chunked-prefill chunk for the oldest parked
         slot (admission order) — at most ``prefill_chunk_tokens``
@@ -2714,20 +2789,30 @@ class GenerationEngine:
             with span("prefill_chunk", process=self.process,
                       lane=f"slot{slot.index}",
                       request_id=req.request_id, start=start,
-                      chunk_tokens=n, prompt_tokens=p, **req.trace):
+                      chunk_tokens=n, tokens=n, prompt_tokens=p,
+                      **req.trace):
                 faults.inject("engine.prefill",
                               detail=f"{req.request_id}@{start}")
-                out = self.sw.prefill_chunk({
-                    "input_ids": ids, "chunk_mask": mask,
-                    "start": np.int32(start),
-                    "table_row": np.ascontiguousarray(
-                        row[:self.prompt_blocks]),
-                    "chunk_blocks": cb, **self._pool})
+                feats = {"input_ids": ids, "start": np.int32(start),
+                         "table_row": np.ascontiguousarray(
+                             row[:self.prompt_blocks]),
+                         "chunk_blocks": cb, **self._pool}
+                if self.state:
+                    # this model's chunk program: the slot's recurrent
+                    # rows are what the chunk starts from and leaves
+                    feats.update(n_valid=np.int32(n),
+                                 slot=np.int32(slot.index))
+                else:
+                    feats["chunk_mask"] = mask
+                out = self.sw.prefill_chunk(feats)
                 # materialize BEFORE adopting the returned pool (the
                 # _admit_slab convention): an async device fault must
                 # leave self._pool naming the donated inputs so
                 # _pool_alive() escalates correctly
-                logits0 = np.asarray(out["logits"])[0]
+                if self.state:
+                    tok0 = int(np.asarray(out["ids"])[0])
+                else:
+                    logits0 = np.asarray(out["logits"])[0]
                 self._pool = {k: v for k, v in out.items()
                               if k.startswith("cache_")}
         except Exception as e:
@@ -2744,7 +2829,11 @@ class GenerationEngine:
         # the SPLIT estimator: chunk wall time feeds the prefill EMA,
         # never the decode-step EMA Retry-After reads
         self._retry.observe_prefill(time.perf_counter() - t0)
-        self._c_prefill_chunks.inc()
+        with self.registry.atomic():
+            self._c_prefill_chunks.inc()
+            self._c_prefill_chunk_tokens.inc(n)
+            if self.state:
+                self._c_moe_rows.inc(n * self._moe_pairs_a_row)
         slot.chunk_done = start + n
         if slot.chunk_done < p:
             return
@@ -2756,7 +2845,7 @@ class GenerationEngine:
         if self.prefix_cache is not None:
             self.prefix_cache.insert(
                 tokens, [int(b) for b in row[:needed]])
-        tok = self._pick(slot, logits0)
+        tok = tok0 if self.state else self._pick(slot, logits0)
         self._emit(slot, tok)
         with self._cond:
             self._g_live_slots.set(len(self._live))
@@ -3166,6 +3255,8 @@ class GenerationEngine:
             alive[i] = 1
         feats = {"tok": tok, "pos": pos, "pad": pad, "alive": alive,
                  **self._pool}
+        if self.state:
+            del feats["pad"]            # prompts are never padded here
         if self.paged:
             feats["block_tables"] = self._tables
         return feats
@@ -3300,6 +3391,27 @@ class GenerationEngine:
         return {"slots": int(feats["alive"].sum()),
                 "kv_bytes": int(feats["pos"].sum()) * self._kv_token_bytes}
 
+    def _describe_state_decode(self, feats: dict) -> dict:
+        """A decode step's span arguments for a per-request-state
+        artifact: ``kv_bytes`` the latent rows the live contexts hold,
+        ``state_bytes`` the recurrent rows the live slots read and write,
+        ``expert_rows`` the held experts that received a row in the step
+        before this one (this step's routing is known when it returns)."""
+        rows = int(feats["alive"].sum())
+        return {"slots": rows,
+                "kv_bytes": int((feats["pos"] + feats["alive"]).sum())
+                * self._kv_token_bytes,
+                "state_bytes": 2 * rows * self._state_slot_bytes,
+                "expert_rows": self._expert_rows_last}
+
+    def _fetch_state_step(self, out: dict) -> np.ndarray:
+        """What such a decode step hands the host: the greedy ids
+        [slots] (never logits) and the two routing scalars."""
+        ids = np.asarray(out["ids"])
+        self._expert_rows_last = int(out["expert_rows"])
+        self._g_moe_load.set(float(out["max_expert_load"]))
+        return ids
+
     @scheduler_thread
     def _propose_drafts(self) -> None:
         """Ask each eligible live slot's drafter for up to
@@ -3373,6 +3485,10 @@ class GenerationEngine:
                 feats, call=self.sw.verify,
                 rebuild=self._build_verify_feats,
                 span_name="verify_step")
+        elif self.state:
+            logits = self._dispatch_decode(
+                feats, describe=self._describe_state_decode,
+                fetch=self._fetch_state_step)
         else:
             logits = self._dispatch_decode(feats)
         if logits is None:
@@ -3554,6 +3670,8 @@ class GenerationEngine:
             else:
                 self._c_decode_steps.inc()
                 self._c_decode_slot_steps.inc(len(self._live))
+                self._c_moe_rows.inc(len(self._live)
+                                     * self._moe_pairs_a_row)
         advance = rows = 0
         for i, s in list(self._live.items()):
             rows += 1
@@ -3625,8 +3743,11 @@ class GenerationEngine:
                 continue
             s.pos += 1
             advance += 1
-            nxt = self._pick(s, row_logits[0] if use_verify
-                             else row_logits)
+            # a per-request-state artifact's step returns the greedy
+            # id itself
+            nxt = (int(row_logits) if self.state
+                   else self._pick(s, row_logits[0] if use_verify
+                                   else row_logits))
             del self._live[i]           # _emit re-adds if still live
             self._emit(s, nxt)
         if rows:
@@ -3750,6 +3871,16 @@ class GenerationEngine:
             "tokens_committed": c("serving_tokens_committed_total"),
             "moe_rows": c("serving_moe_rows_total"),
             "moe_tiles": self.moe_tiles,
+            # a kind a layer (None otherwise): which layers mix and feed
+            # forward how, and the arrays the engine keeps for them
+            "state": ({"mixers": self.state["mixers"],
+                       "ffns": self.state["ffns"],
+                       "specs": self.state["specs"]}
+                      if self.state else None),
+            "state_bytes": c("serving_state_bytes"),
+            "latent_pool_bytes": c("serving_latent_pool_bytes"),
+            "prefill_chunk_tokens_total": c(
+                "serving_prefill_chunk_tokens_total"),
             "moe_max_expert_load_ratio": c(
                 "serving_moe_max_expert_load_ratio"),
             "jit_compiles": c("jit_compiles_total"),

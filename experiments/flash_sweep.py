@@ -56,6 +56,11 @@ another's device memory):
                      ``ragged_dot_tiling`` of the grid: device ms of
                      ``ragged-dot*`` and ``max_abs_diff`` against XLA's
                      own. ``ops/moe.ragged_tiling`` chose from these rows.
+  ragged OUT wide    the same at the widths ``kimi-serve-backlog`` runs
+                     (PR 32): 128 groups of [2304, 1024] and [1024, 2304],
+                     4 and 32 rows a group in 1,024 and 8,192 rows (half
+                     of a step's pairs name experts held elsewhere and
+                     sort past the last group, as in the served step).
 
 The measured columns are TPU columns: off-TPU the kernels run in Pallas
 interpret mode (orders of magnitude slow, numbers meaningless), so
@@ -481,12 +486,27 @@ def measure_kernels(row: dict, *, iters: int = 5) -> dict:
 # the expert layer's grouped matmul alone (PR 28): device ms by tile
 # ---------------------------------------------------------------------------
 
-def ragged_rows() -> list[dict]:
+def ragged_rows(wide: bool = False) -> list[dict]:
     """What ``ragged`` measures, in order: ``m`` pairs over 128 groups of
     ``[k, n]`` weights, ``skew`` (group sizes from a Dirichlet(0.3) draw
     in place of a uniform one), ``tiling`` (None = XLA's own, first for
-    each shape: the others are compared with its result)."""
+    each shape: the others are compared with its result). ``wide``: the
+    other configuration's widths, ``m`` rows of which ``grouped`` lie in
+    a group."""
     rows = []
+    if wide:
+        for k, n in ((2304, 1024), (1024, 2304)):
+            for m, grouped in ((1024, 512), (8192, 4096)):
+                tiles = [f"{tm},{k},{tn}" for tm in (128, 256)
+                         for tn in (n, 512, 256)] + [f"64,{k},{n}",
+                                                     f"128,{k // 2},{n}"]
+                rows += [dict(m=m, k=k, n=n, groups=128, skew=False,
+                              grouped=grouped, tiling=t)
+                         for t in [None] + tiles]
+                rows += [dict(m=m, k=k, n=n, groups=128, skew=True,
+                              grouped=grouped, tiling=t)
+                         for t in (None, f"128,{k},{n}")]
+        return rows
     for k, n, tns in ((2048, 768, (256, 768)),
                       (768, 2048, (256, 512, 1024, 2048))):
         for m in (1024, 2048, 4096, 8192, 16384, 32768):
@@ -523,11 +543,12 @@ def measure_ragged(row: dict, held: dict, *, iters: int = 5) -> dict:
     if row["tiling"] is None:
         rs = np.random.RandomState(m + k)
         share = rs.dirichlet([0.3] * g) if row["skew"] else [1 / g] * g
+        grouped = row.get("grouped", m)     # the rest lie past the groups
         ka, kw = jax.random.split(jax.random.key(m + k))
         held["args"] = (
             jax.random.normal(ka, (m, k), jnp.bfloat16) * 0.5,
             jax.random.normal(kw, (g, k, n), jnp.bfloat16) * 0.02,
-            jnp.asarray(rs.multinomial(m, share), jnp.int32))
+            jnp.asarray(rs.multinomial(grouped, share), jnp.int32))
         held["out"] = call(*held["args"])
     red = _capture(call, held["args"], iters)
     return dict(row, tiling=row["tiling"] or "xla",
@@ -536,10 +557,12 @@ def measure_ragged(row: dict, held: dict, *, iters: int = 5) -> dict:
                 program_ms=round(red["busy_s"] / iters * 1e3, 4),
                 max_group=int(held["args"][2].max()),
                 max_abs_diff=float(jnp.max(jnp.abs(
-                    call(*held["args"]) - held["out"]))))
+                    (call(*held["args"]) - held["out"])[
+                        :row.get("grouped", m)]))))
 
 
-def kernels(out_path: str | None, mode: str = "kernels") -> None:
+def kernels(out_path: str | None, mode: str = "kernels",
+            wide: bool = False) -> None:
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -548,9 +571,9 @@ def kernels(out_path: str | None, mode: str = "kernels") -> None:
                          "timings are meaningless); set FLASH_SWEEP_CPU=1 "
                          "for a CI smoke run")
     if mode == "ragged":
-        rows, held = ragged_rows(), {}
+        rows, held = ragged_rows(wide), {}
         if not on_tpu:                   # smoke: the control flow only
-            rows = [dict(r, m=64, k=128, n=128, groups=4,
+            rows = [dict(r, m=64, k=128, n=128, groups=4, grouped=64,
                          tiling=r["tiling"] and "128,128,128")
                     for r in rows[:3]]
     else:
@@ -622,7 +645,8 @@ def main() -> None:
         if len(sys.argv) > 2:
             os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])),
                         exist_ok=True)
-        kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1])
+        kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1],
+                wide=sys.argv[3:4] == ["wide"])
         return
     if sys.argv[1:2] == ["--trace"]:
         outdir, mn = sys.argv[2], sys.argv[3]

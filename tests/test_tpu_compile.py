@@ -452,3 +452,72 @@ def test_block_decoder_kernels_compile(chip, name):
         for tile in ("128,2048,768", "128,768,2048"):
             assert f'ragged_dot_tiling="{tile}"' in text
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# a kind a layer: the one-token decode step and the prefill chunk
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["decode", "prefill_chunk"])
+def test_state_programs_update_their_state_where_it_lies(chip, name):
+    """``kimi_linear``'s two served programs at the ``kimi-serve-backlog``
+    cell's shape (5 layers, 128 held experts, half the vocabulary; 128
+    slots, 17,409 latent blocks of 128 tokens, 1,024-token chunks), the
+    state donated: (a) no ``copy`` as large as one KDA layer's recurrent
+    rows (268 MB) or a tenth of the latent pool (with rows of 576 values
+    the chip lays the pool out tokens-minor and the decode step copied
+    it four times, 2.57 GB each), (b) every ``cache_*`` input is aliased
+    to an output, (c) under 512 MB of temporaries, (d) the latent
+    attention is the Pallas kernel in the decode step."""
+    from distributed_tensorflow_example_tpu.config import TrainConfig
+    from distributed_tensorflow_example_tpu.models import get_model
+    mla_mod = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.mla")
+    mp = pytest.MonkeyPatch()
+    mp.setattr(mla_mod, "_interpret", lambda: False)
+    try:
+        dev = chip[0]
+        model = get_model("kimi_linear", TrainConfig(
+            model="kimi_linear", dtype="bfloat16", param_dtype="bfloat16",
+            num_layers=5))
+        model.cfg.experts_held, model.cfg.vocab_held = 128, 81920
+        slots, bs, chunk, prompt, new = 128, 128, 1024, 16384, 1024
+        nb = (prompt + new) // bs
+        params = jax.tree_util.tree_map(
+            lambda x: on(dev, x.shape, x.dtype),
+            jax.eval_shape(model.init, jax.random.key(0)))
+        specs = model.state_specs(slots=slots, num_blocks=1 + slots * nb,
+                                  block_size=bs)
+        state = {k: on(dev, tuple(v["shape"]), jnp.dtype(v["dtype"]))
+                 for k, v in specs.items()}
+        i32 = functools.partial(on, dev, dtype=jnp.int32)
+        if name == "decode":
+            fn = lambda st, p, bt, tok, pos, alive: model.decode_step(  # noqa: E731
+                p, st, bt, tok, pos, alive, attention="pallas")
+            args = (i32((slots, nb)), i32((slots,)), i32((slots,)),
+                    i32((slots,)))
+        else:
+            fn = lambda st, p, ids, n, start, slot, row, cb: (  # noqa: E731
+                model.prefill_chunk(p, st, ids, n, start, slot, row, cb))
+            args = (i32((1, chunk)), i32(()), i32(()), i32(()),
+                    i32((prompt // bs,)), i32((chunk // bs,)))
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            state, params, *args).compile()
+    finally:
+        mp.undo()
+    text = compiled.as_text()
+    if name == "decode":
+        assert "paged_latent_attn" in text
+    layer_rows = int(np.prod(specs["cache_state"]["shape"][1:])) * 4
+    pool = int(np.prod(specs["cache_latent"]["shape"])) * 2
+    big = [m.group(0) for m in re.finditer(
+               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+               [int(x) for x in m.group(2).split(",")])
+           >= min(layer_rows, pool // 10)]
+    assert not big, big
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    assert aliased == set(range(len(state))), aliased
+    assert compiled.memory_analysis().temp_size_in_bytes < 512e6
