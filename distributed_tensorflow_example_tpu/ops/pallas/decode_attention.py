@@ -18,23 +18,29 @@ exist), probabilities cast to the value dtype before the context matmul
 with f32 accumulation.
 
 Scope: the kernel path needs tiles the TPU compiler accepts
-(:func:`tile_friendly`). Every K/V block is carved from the
-[B, T, H·D] view of the cache, so its lane width must be a multiple of
-128: a head dim that is one (D % 128 == 0) is one block per head; at
-D == 64 — GPT-2's — one block holds a PAIR of adjacent heads (the head
-count must be even) and the kernel keeps the two apart with a lane mask
-on the query rows. The score row's lane dim is the cache length
-(T % 128 == 0). Anything else takes the XLA path (which the ``"loop"``
-decode impl uses anyway). Off-TPU the kernel runs in Pallas interpret
-mode so CPU tests exercise the same code path (same recipe as
-flash_attention); tests/test_tpu_compile.py compiles it for a described
-v5e at GPT-2 widths.
+(:func:`tile_friendly`). Every K/V block of the SLAB kernel is carved
+from the [B, T, H·D] view of the cache, so its lane width must be a
+multiple of 128: a head dim that is one (D % 128 == 0) is one block per
+head; at D == 64 — GPT-2's — one block holds a PAIR of adjacent heads
+(the head count must be even) and the kernel keeps the two apart with a
+lane mask on the query rows. The score row's lane dim is the cache
+length (T % 128 == 0). Anything else takes the XLA path (which the
+``"loop"`` decode impl uses anyway). The PAGED kernel (the served one,
+further down) takes whole [Bs, H·D] blocks of the pool, every head at
+once, and walks only a row's live blocks
+(:func:`paged_tile_friendly`, :func:`paged_schedule`). Off-TPU the
+kernels run in Pallas interpret mode so CPU tests exercise the same code
+path (same recipe as flash_attention); tests/test_tpu_compile.py
+compiles them for a described v5e at GPT-2 widths.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 import math
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -66,10 +72,11 @@ def tile_friendly(total: int, heads: int, head_dim: int) -> bool:
     return total % 128 == 0 and _head_dim_ok(heads, head_dim)
 
 
-def _own_lanes(group: int, head_dim: int):
-    """[g, g·D] bool: row r owns the lanes of the r-th head in the
-    block. Comparisons only — no vector integer division."""
-    shape = (group, group * head_dim)
+def _own_lanes(group: int, head_dim: int, width: int | None = None):
+    """[g, g·D] bool ([g, width] where given): row r owns the lanes of
+    the r-th head in the block. Comparisons only — no vector integer
+    division."""
+    shape = (group, group * head_dim if width is None else width)
     lo = lax.broadcasted_iota(jnp.int32, shape, 0) * head_dim
     lane = lax.broadcasted_iota(jnp.int32, shape, 1)
     return (lane >= lo) & (lane < lo + head_dim)
@@ -174,13 +181,22 @@ def xla_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # ---------------------------------------------------------------------------
 # block-paged decode attention (round 10): K/V live in a shared block pool
 # [N, block_size, H*D] (a token's heads side by side in the lane dimension,
-# the layout the kernel's blocks are carved from as they lie) instead of
+# the layout the kernel's blocks are taken from as they lie) instead of
 # per-slot slabs; each row's logical cache is the run of physical blocks its
 # block-table row names. Both impls gather THROUGH the table: the XLA
 # fallback with one advanced-indexing gather (then the exact slab reference
 # math), the kernel with scalar-prefetch index maps (the block id is read
 # from SMEM before each K/V block's DMA is issued — no gathered [B, T, H, D]
 # tensor ever exists).
+#
+# The kernel's grid (PR 34) is (rows, NB / entries): a grid step holds
+# `entries` consecutive table entries of one row, each a WHOLE [Bs, H*D]
+# block, every head at once under one masked [H, H*D] query tile; entries
+# past `pos` neither compute (pl.when) nor fetch (the fetch table names the
+# block the operand already holds); `paged_schedule` chooses `entries` from
+# the static shapes. A grid step has a price whatever it does (~0.4 us on a
+# v5e), and the (B, H/g, NB) grid this replaced paid it 2,304 times a call
+# for GPT-2's 64 rows.
 #
 # K-query speculative verify (round 16): the verify program presents BOTH
 # impls with row-expanded queries — K lanes of one slot become K rows at
@@ -190,15 +206,6 @@ def xla_decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
 # rule rides; kernel-vs-gather parity on the expanded shape is pinned in
 # tests/test_paged_serving.py.
 # ---------------------------------------------------------------------------
-
-def paged_tile_friendly(block_size: int, heads: int,
-                        head_dim: int) -> bool:
-    """Exactly the pool shapes the TPU compiler accepts for the paged
-    kernel: each score row is [g, block_size] (block_size in the lane
-    dim — 128-multiples) over the same 128-lane K/V blocks as the slab
-    kernel (:func:`tile_friendly`)."""
-    return block_size % 128 == 0 and _head_dim_ok(heads, head_dim)
-
 
 def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                                v_pool: jax.Array, *, block_tables,
@@ -232,31 +239,166 @@ def xla_paged_decode_attention(q: jax.Array, k_pool: jax.Array,
                                 pad=pad)
 
 
-def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
-                  block_size: int, head_dim: int, sm_scale: float,
-                  quant: bool):
-    """Grid (B, H/g, NB): one [block_size, g·D] K/V block per step,
-    gathered through the block table by the index maps (scalar
-    prefetch). The softmax runs online over the NB dimension (per-head
-    m/l/acc scratch persists across the revisited output block);
-    masked slots are zeroed explicitly so never-written pool blocks
-    (incl. the engine's null block) contribute exact 0 regardless of
-    their bytes.
+#: what :func:`paged_schedule` lets the kernel's VMEM estimate reach: the
+#: compiler's default scoped limit (16 MiB on a v5e) less room for what
+#: the estimate does not count
+_PAGED_VMEM_BUDGET = 12 * 2**20
+#: the most table entries a grid step takes (the body is unrolled once an
+#: entry; GPT-2's tables hold 6, the widest the sweep read)
+_PAGED_ENTRIES_MAX = 8
 
-    ``quant=True`` (int8 pools): two extra [1, 1, Bs] scale-row inputs
-    follow v. The dequant is fused ALGEBRAICALLY — K's per-row scale
-    multiplies the score COLUMNS (q·(k·s)ᵀ = (q·kᵀ)·s, broadcast along
-    the [g, Bs] score rows) and V's scale folds into the probabilities
-    before the context matmul (p·(v·s) = (p·s)·v) — so no dequantized
-    [Bs, g·D] tile is ever materialized and no transpose of the scale
-    row is needed."""
+
+def _tile_rows(heads: int) -> int:
+    """Rows of the query tile and its accumulator: the heads, padded to
+    whole 16-row sublane tiles (bfloat16 packs 16 rows a tile)."""
+    return -(-heads // 16) * 16
+
+
+class PagedSchedule(NamedTuple):
+    """How one ``paged_decode_attn`` call walks the block tables:
+    ``entries`` consecutive table entries a grid step (a divisor of the
+    table's width) on a grid ``(rows, width // entries)``, and the VMEM
+    the estimate gives it."""
+    entries: int
+    vmem_bytes: int
+
+    def describe(self, rows: int, width: int) -> dict:
+        """What ``export.json`` and ``/stats`` say of a traced program."""
+        return {"kernel": "paged_decode_attn",
+                "entries_per_step": self.entries,
+                "grid": [rows, width // self.entries],
+                "vmem_bytes": self.vmem_bytes}
+
+
+def _paged_vmem_bytes(entries: int, heads: int, head_dim: int,
+                      block_size: int, pool_dtype) -> int:
+    """Estimate of the kernel's VMEM at ``entries`` table entries a grid
+    step: each entry's K and V block [Bs, H*D] double-buffered in the
+    pool's dtype (an int8 pool's [1, Bs] scale rows pad to a sublane
+    tile), one entry's float32 temporaries (the [H, Bs] scores,
+    probabilities and select; an int8 block's float32 V copy), the
+    [H, H*D] query tile, accumulator and its update, the statistics and
+    the q / output rows."""
+    hp, w = _tile_rows(heads), heads * head_dim
+    item = jnp.dtype(pool_dtype).itemsize
+    blocks = entries * 2 * 2 * block_size * w * item
+    if item == 1:
+        blocks += entries * 2 * 2 * 8 * block_size * 4
+    tmps = 4 * (4 * hp * block_size + 3 * hp * w
+                + (block_size * w if item == 1 else 0))
+    return blocks + tmps + 4 * 2 * hp * 128 + 4 * 4 * w
+
+
+def paged_tile_friendly(block_size: int, heads: int, head_dim: int,
+                        pool_dtype=jnp.bfloat16) -> bool:
+    """The pool shapes the paged kernel takes: whole [block_size, H*D]
+    blocks of the pool as it lies, in whole tiles (block_size a multiple
+    of 128: it is the score rows' lane dim; H*D a multiple of 128 lanes,
+    whatever the head size: the query tile keeps the heads apart, not the
+    blocks), one of which, double-buffered, fits the kernel's VMEM
+    budget (:func:`paged_schedule`). The TPU compiler also takes blocks
+    that are not whole tiles (it pads them); they were never read on a
+    chip and take the XLA path."""
+    return (block_size % 128 == 0 and (heads * head_dim) % 128 == 0
+            and _paged_vmem_bytes(1, heads, head_dim, block_size,
+                                  pool_dtype) <= _PAGED_VMEM_BUDGET)
+
+
+def paged_schedule(rows: int, heads: int, head_dim: int, block_size: int,
+                   table_width: int, pool_dtype=jnp.bfloat16
+                   ) -> PagedSchedule:
+    """The schedule of one :func:`paged_decode_attention` kernel call: a
+    rule on what the call observes, set from the chip sweep on record
+    (``experiments/flash_sweep.py paged``;
+    ``benchmark/records/pr34/paged_sweep.jsonl``; DESIGN section 13.1).
+
+    A grid step has its price whatever it computes (PR 25's finding in
+    the flash kernels, read again here), so the kernel takes as few as
+    the table allows: the most consecutive table entries a step, up to
+    ``_PAGED_ENTRIES_MAX``, that divide the table's width and whose
+    blocks fit ``_PAGED_VMEM_BUDGET``. ``rows`` does not enter (the
+    grid's leading dimension takes any count: decode rows, or a verify
+    program's K-fold expansion), nor the head size beyond the block's
+    width. A shape the sweep did not read gets the same rule and at
+    worst one entry a step, the least the kernel holds. A block so wide
+    that even that passes the budget is a ValueError:
+    :func:`paged_tile_friendly` says so first and ``impl="auto"`` takes
+    the XLA path."""
+    del rows
+    for entries in range(min(table_width, _PAGED_ENTRIES_MAX), 0, -1):
+        need = _paged_vmem_bytes(entries, heads, head_dim, block_size,
+                                 pool_dtype)
+        if table_width % entries == 0 and need <= _PAGED_VMEM_BUDGET:
+            return PagedSchedule(entries, need)
+    raise ValueError(
+        f"paged decode_attention kernel: a [{block_size}, "
+        f"{heads * head_dim}] block of each pool, double-buffered, "
+        f"passes {_PAGED_VMEM_BUDGET} bytes of VMEM")
+
+
+_SCHEDULE_LOG: contextvars.ContextVar[list | None] = contextvars.ContextVar(
+    "paged_schedule_log", default=None)
+
+
+@contextlib.contextmanager
+def schedule_log():
+    """While a program is traced under this, collect what each
+    :func:`paged_decode_attention` call in it was traced with:
+    ``PagedSchedule.describe`` for the kernel, ``{"kernel": "xla"}`` for
+    the gather. A compiled program carries one schedule for good, so this
+    is the evidence that the rule engaged (``serving.export_generator``
+    keeps it in ``export.json``, as it keeps ``ops.moe.tile_log``'s)."""
+    seen: list[dict] = []
+    # a ContextVar's set, not a metric's: the exporter's own list, written
+    # while it traces and never under a compiled call
+    token = _SCHEDULE_LOG.set(seen)  # graftlint: disable=JIT01
+    try:
+        yield seen
+    finally:
+        _SCHEDULE_LOG.reset(token)
+
+
+def _log_schedule(what: dict) -> None:
+    seen = _SCHEDULE_LOG.get()
+    if seen is not None and what not in seen:
+        seen.append(what)
+
+
+def _paged_kernel(ft_ref, pos_ref, pad_ref, q_ref, *rest, entries: int,
+                  block_size: int, heads: int, head_dim: int,
+                  sm_scale: float, quant: bool):
+    """Grid (B, NB / entries): a step holds ``entries`` consecutive table
+    entries of row b, each a whole [block_size, H*D] K and V block of the
+    pool as it lies, every head at once. The heads are kept apart by the
+    query tile: row h of the [H, H*D] tile holds head h's query in head
+    h's lanes and zeros elsewhere, so ONE matmul against the block gives
+    each head's own scores (the zeros contribute exact 0), and row h of
+    the [H, H*D] context accumulator is meaningful in head h's lanes,
+    which the last step keeps. An entry past ``pos`` computes nothing
+    (and fetched nothing: see :func:`_fetch_table`). The softmax runs
+    online over the live entries (m / l / acc scratch persists across
+    the revisited output block); masked slots are zeroed explicitly so
+    stale slots of a live block contribute exact 0 whatever their bytes.
+
+    MXU operands stay in the dtype the query and the pool share
+    (bfloat16 x bfloat16 products are exact in the float32 accumulator);
+    scores, softmax, scales and accumulation are float32.
+
+    ``quant=True`` (int8 pools): ``entries`` [1, 1, Bs] scale rows for K
+    and for V follow the blocks. The dequant is fused ALGEBRAICALLY: K's
+    per-row scale multiplies the score COLUMNS (q.(k.s)^T = (q.k^T).s,
+    the int8 values exact in the query's dtype) and V's scale folds into
+    the probabilities before the float32 context matmul
+    (p.(v.s) = (p.s).v), so no dequantized [Bs, H*D] tile is ever
+    materialized and no transpose of the scale row is needed."""
+    n = entries
+    k_refs, v_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
     if quant:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        ks_refs, vs_refs, rest = rest[:n], rest[n:2 * n], rest[2 * n:]
+    o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    g = _group(head_dim)
+    j = pl.program_id(1)
+    pos, pad = pos_ref[b], pad_ref[b]
 
     @pl.when(j == 0)
     def _init():
@@ -264,100 +406,142 @@ def _paged_kernel(bt_ref, pos_ref, pad_ref, q_ref, k_ref, v_ref, *rest,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = _split_heads(q_ref[0].astype(jnp.float32), g, head_dim)  # [g, W]
-    k = k_ref[0].astype(jnp.float32)                    # [Bs, W]
-    s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                        preferred_element_type=jnp.float32) * sm_scale
-    if quant:
-        s = s * ks_ref[0]                               # [1, Bs] scales
-    kpos = j * block_size + lax.broadcasted_iota(
-        jnp.int32, (1, block_size), 1)
-    live = (kpos <= pos_ref[b]) & (kpos >= pad_ref[b])
-    s = jnp.where(live, s, NEG_INF)
-    m_prev = m_ref[...]                                 # [g, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    # explicit zeroing (not exp underflow): with the finite NEG_INF fill
-    # an all-masked block would otherwise see exp(NEG_INF - NEG_INF) = 1
-    p = jnp.where(live, jnp.exp(s - m_new), 0.0)        # [g, Bs]
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    if quant:
-        pv = p * vs_ref[0]                              # fold V scales
-        vblk = v_ref[0].astype(jnp.float32)
-    else:
-        pv = p.astype(v_ref.dtype)
-        vblk = v_ref[0]
-    acc_ref[...] = (acc_ref[...] * alpha
-                    + lax.dot_general(
-                        pv, vblk,
-                        (((1,), (0,)), ((), ())),
-                        preferred_element_type=jnp.float32))
-    m_ref[...] = m_new
+    own = _own_lanes(_tile_rows(heads), head_dim,
+                     heads * head_dim)                  # [Hp, W]
+    operand = (q_ref.dtype if quant
+               else jnp.promote_types(q_ref.dtype, k_refs[0].dtype))
+    # (the select in float32: a mask is laid out 8 rows a tile, a
+    # bfloat16 operand 16, and Mosaic refuses to re-lay the mask)
+    q = jnp.where(own, q_ref[0].astype(jnp.float32),
+                  0.0).astype(operand)                  # [Hp, W]
 
-    @pl.when(j == pl.num_programs(2) - 1)
+    for e in range(n):              # static: the step's table entries
+        first = (j * n + e) * block_size
+
+        @pl.when(first <= pos)
+        def _compute(e=e, first=first):
+            s = lax.dot_general(
+                q, k_refs[e][0].astype(operand), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale  # [Hp, Bs]
+            if quant:
+                s = s * ks_refs[e][0]                   # [1, Bs] scales
+            kpos = first + lax.broadcasted_iota(
+                jnp.int32, (1, block_size), 1)
+            live = (kpos <= pos) & (kpos >= pad)
+            s = jnp.where(live, s, NEG_INF)
+            m_prev = m_ref[...]                         # [Hp, 1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # explicit zeroing (not exp underflow): with the finite
+            # NEG_INF fill an all-masked block would otherwise see
+            # exp(NEG_INF - NEG_INF) = 1
+            p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+            alpha = jnp.exp(m_prev - m_new)
+            l_ref[...] = (l_ref[...] * alpha
+                          + jnp.sum(p, axis=-1, keepdims=True))
+            if quant:
+                pv = p * vs_refs[e][0]                  # fold V scales
+                vblk = v_refs[e][0].astype(jnp.float32)
+            else:
+                vblk = v_refs[e][0]
+                pv = p.astype(vblk.dtype)
+            acc_ref[...] = (acc_ref[...] * alpha
+                            + lax.dot_general(
+                                pv, vblk, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32))
+            m_ref[...] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        # slot `pos` is always live, so l >= exp(0) > 0
-        o_ref[0] = _merge_heads(acc_ref[...] / l_ref[...], g,
-                                head_dim).astype(o_ref.dtype)
+        # slot `pos` is always live, so l >= exp(0) > 0; row h of the
+        # accumulator keeps head h's lanes (the padding rows own none)
+        ctx = jnp.where(own, acc_ref[...] / l_ref[...], 0.0)
+        o_ref[0] = jnp.sum(ctx, axis=0, keepdims=True).astype(o_ref.dtype)
+
+
+def _fetch_table(block_tables, pos, entries: int, block_size: int):
+    """The block each grid step's K/V operands name, [B, NB]: a live
+    table entry (``entry * Bs <= pos``) names its own block; a dead one
+    names whatever the SAME operand held a grid step earlier (operand e
+    of step (b, j) holds entry ``j * entries + e``), so the pipeline
+    sees an unchanged index and fetches nothing. Entries past ``pos``
+    cost no traffic at all, whatever their table holds, and a row that
+    is not alive (pos 0, every entry the null block) fetches one block.
+    An operand no row has used yet names row 0's entry."""
+    b, nb = block_tables.shape
+    steps = b * (nb // entries)
+    live = (jnp.arange(nb, dtype=jnp.int32) * block_size)[None] \
+        <= pos[:, None]
+    step = jnp.arange(steps, dtype=jnp.int32)[:, None]
+    src = lax.cummax(jnp.where(live.reshape(steps, entries), step, 0),
+                     axis=0)
+    # table[src[s, e], e] as one select-and-sum fusion: a gather of so few
+    # integers is several operations a layer on the chip (1.3 x this one's
+    # time at 64 rows, 2.3 x at a verify program's 256: PR 34's sweep)
+    pick = src[:, None, :] == step[None]
+    return jnp.sum(jnp.where(pick, block_tables.reshape(steps, entries)[None],
+                             0), axis=1).reshape(b, nb)
 
 
 def _paged_dispatch(q, k_pool, v_pool, block_tables, pos, pad,
-                    k_scale=None, v_scale=None):
-    """Grid (B, H/g, NB); per program ONE [Bs, g·D] K/V block of the
-    pool, selected by the block table via scalar-prefetch index maps.
-    The pool is [N, Bs, H·D] as it lies (the slab kernel's view, here
-    the layout itself: no reshape, so no relayout of the pool a call),
-    every tile one the compiler accepts. int8 pools additionally stream
-    the matching [1, Bs] scale row per block ([N, 1, Bs] view so the
+                    k_scale=None, v_scale=None, schedule=None):
+    """One ``paged_decode_attn`` call under ``schedule`` (the rule's own,
+    :func:`paged_schedule`, unless the sweep hands another): grid
+    (B, NB / entries); per step ``entries`` whole [Bs, H*D] K and V
+    blocks of the pool, [N, Bs, H*D] as it lies (no reshape, so no
+    relayout of the pool a call), each named by the fetch table through
+    its own scalar-prefetch index map. int8 pools additionally stream the
+    matching [1, Bs] scale row per block ([N, 1, Bs] view so the
     singleton tile dim matches its array dim)."""
-    n, bs, _ = k_pool.shape
+    n, bs, w = k_pool.shape
     _, h, d = q.shape
     b, nb = block_tables.shape
     quant = k_scale is not None
-    g = _group(d)
-    w = g * d
+    sch = schedule or paged_schedule(b, h, d, bs, nb, k_pool.dtype)
+    hp = _tile_rows(h)
 
-    def kv_map(bb, hh, jj, bt, pos_s, pad_s):
-        return (bt[bb, jj], 0, hh)
+    def table_map(e):
+        return lambda bb, jj, ft, pos_s, pad_s: (
+            ft[bb, jj * sch.entries + e], 0, 0)
 
-    def scale_map(bb, hh, jj, bt, pos_s, pad_s):
-        return (bt[bb, jj], 0, 0)
+    def q_map(bb, jj, ft, pos_s, pad_s):
+        return (bb, 0, 0)
 
-    def q_map(bb, hh, jj, bt, pos_s, pad_s):
-        return (bb, 0, hh)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, w), q_map),
-        pl.BlockSpec((1, bs, w), kv_map),
-        pl.BlockSpec((1, bs, w), kv_map),
-    ]
-    operands = [q.reshape(b, 1, h * d), k_pool, v_pool]
+    blocks = [pl.BlockSpec((1, bs, w), table_map(e))
+              for e in range(sch.entries)]
+    in_specs = [pl.BlockSpec((1, 1, w), q_map)] + blocks + blocks
+    operands = ([q.reshape(b, 1, w)] + [k_pool] * sch.entries
+                + [v_pool] * sch.entries)
     if quant:
-        in_specs += [pl.BlockSpec((1, 1, bs), scale_map)] * 2
-        operands += [k_scale.reshape(n, 1, bs).astype(jnp.float32),
-                     v_scale.reshape(n, 1, bs).astype(jnp.float32)]
+        rows = [pl.BlockSpec((1, 1, bs), table_map(e))
+                for e in range(sch.entries)]
+        in_specs += rows + rows
+        operands += (
+            [k_scale.reshape(n, 1, bs).astype(jnp.float32)] * sch.entries
+            + [v_scale.reshape(n, 1, bs).astype(jnp.float32)] * sch.entries)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,          # block_tables, pos, pad
-        grid=(b, h // g, nb),
+        num_scalar_prefetch=3,          # fetch table, pos, pad
+        grid=(b, nb // sch.entries),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, w), q_map),
         scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),            # running max
-            pltpu.VMEM((g, 1), jnp.float32),            # running sum
-            pltpu.VMEM((g, w), jnp.float32),            # context acc
+            pltpu.VMEM((hp, 1), jnp.float32),           # running max
+            pltpu.VMEM((hp, 1), jnp.float32),           # running sum
+            pltpu.VMEM((hp, w), jnp.float32),           # context acc
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, block_size=bs, head_dim=d,
+        functools.partial(_paged_kernel, entries=sch.entries,
+                          block_size=bs, heads=h, head_dim=d,
                           sm_scale=1.0 / math.sqrt(d), quant=quant),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (b, 1, h * d), q.dtype if quant else v_pool.dtype),
+            (b, 1, w), q.dtype if quant else v_pool.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         name="paged_decode_attn",
         interpret=_interpret(),
-    )(block_tables, pos, pad, *operands)
+    )(_fetch_table(block_tables, pos, sch.entries, bs), pos, pad,
+      *operands)
     return out.reshape(b, h, d)
 
 
@@ -419,25 +603,28 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     bt = jnp.asarray(block_tables, jnp.int32)
     if bt.ndim != 2 or bt.shape[0] != b:
         raise ValueError(f"block_tables shape {bt.shape} != ({b}, NB)")
-    friendly = paged_tile_friendly(bs, h, d)
+    friendly = paged_tile_friendly(bs, h, d, k_pool.dtype)
     if impl == "pallas" and not friendly:
         raise ValueError(
             f"paged decode_attention kernel needs block_size % 128 == 0 "
-            f"and a head dim of 64 (even head count) or a multiple of "
-            f"128, got block_size={bs} H={h} D={d} (use impl='auto' for "
-            "the XLA fallback)")
+            f"and heads x head dim a multiple of 128 whose [block_size, "
+            f"H*D] block fits VMEM, got block_size={bs} H={h} D={d} (use "
+            "impl='auto' for the XLA fallback)")
     use_kernel = friendly and (
         impl == "pallas"
         or (impl == "auto" and jax.default_backend() == "tpu"))
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
     padb = jnp.broadcast_to(jnp.asarray(pad, jnp.int32).reshape(-1), (b,))
     if not use_kernel:
+        _log_schedule({"kernel": "xla"})
         return xla_paged_decode_attention(q, k_pool, v_pool,
                                           block_tables=bt, pos=posb,
                                           pad=padb, k_scale=k_scale,
                                           v_scale=v_scale)
+    sch = paged_schedule(b, h, d, bs, bt.shape[1], k_pool.dtype)
+    _log_schedule(sch.describe(b, bt.shape[1]))
     return _paged_dispatch(q, k_pool, v_pool, bt, posb, padb,
-                           k_scale=k_scale, v_scale=v_scale)
+                           k_scale=k_scale, v_scale=v_scale, schedule=sch)
 
 
 # ---------------------------------------------------------------------------

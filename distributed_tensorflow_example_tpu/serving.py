@@ -31,6 +31,7 @@ import numpy as np
 from jax import export as jax_export
 
 from .ops.moe import tile_log
+from .ops.pallas.decode_attention import schedule_log
 
 # label-side batch keys never consumed by `apply` (loss/eval only):
 # pruned from the serving signature so a servable takes features only
@@ -531,14 +532,23 @@ def _trace_and_write_stepwise(out_dir: str, prefill_fn, decode_fn,
         programs.append((_VERIFY, verify_fn, verify_specs))
     if chunk_fn is not None:
         programs.append((_PREFILL_CHUNK, chunk_fn, chunk_specs))
-    exported = [(name, jax_export.export(
-        jax.jit(fn), platforms=list(platforms))(specs))
-        for name, fn, specs in programs]
+    exported, attn_schedule = [], {}
+    for name, fn, specs in programs:
+        # what each program's paged decode attention was traced with
+        # (the kernel's schedule, or the XLA gather): fixed once compiled
+        with schedule_log() as seen:
+            exported.append((name, jax_export.export(
+                jax.jit(fn), platforms=list(platforms))(specs)))
+        if seen:
+            attn_schedule[name.removesuffix(".stablehlo")] = (
+                seen[0] if len(seen) == 1 else seen)
     if jax.process_index() == 0:
         os.makedirs(out_dir, exist_ok=True)
         for name, exp in exported:
             with open(os.path.join(out_dir, name), "wb") as f:
                 f.write(exp.serialize())
+    if attn_schedule:
+        extra_meta["decode"] = {"attn_schedule": attn_schedule}
     return {**base_meta, **extra_meta}
 
 
@@ -1343,6 +1353,10 @@ class StepwiseGenerator:
         #: None. Such an artifact has no whole-prompt prefill program:
         #: prompts go through ``prefill_chunk``
         self.state: dict | None = step_meta.get("state")
+        #: what each program's paged decode attention was traced with
+        #: (``ops.pallas.decode_attention.schedule_log``), by program
+        self.attn_schedule: dict = (step_meta.get("decode") or {}).get(
+            "attn_schedule", {})
         #: the one loaded parameter tree of a weights-as-arguments
         #: artifact (``weights: "checkpoint"``), which every program
         #: takes, never donated; None where the weights are baked

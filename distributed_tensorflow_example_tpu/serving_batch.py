@@ -3388,8 +3388,18 @@ class GenerationEngine:
         """A decode (or verify) step's span arguments: its live rows,
         and what they hold in K and V (a dead row's pos is 0): the
         decode kernels' least traffic."""
-        return {"slots": int(feats["alive"].sum()),
+        args = {"slots": int(feats["alive"].sum()),
                 "kv_bytes": int(feats["pos"].sum()) * self._kv_token_bytes}
+        if self.paged:
+            # the table entries the paged kernel works on: every row of a
+            # live slot (a verify step's lanes sit at consecutive pos)
+            # reads the blocks up to its own pos; of slots x lanes x
+            # blocks_per_slot entries a call
+            pos = feats["pos"][feats["alive"] != 0]
+            lanes = np.arange(feats["tok"][0].size)
+            args["kv_blocks"] = int(
+                ((pos[:, None] + lanes) // self.block_size + 1).sum())
+        return args
 
     def _describe_state_decode(self, feats: dict) -> dict:
         """A decode step's span arguments for a per-request-state
@@ -3871,6 +3881,8 @@ class GenerationEngine:
             "tokens_committed": c("serving_tokens_committed_total"),
             "moe_rows": c("serving_moe_rows_total"),
             "moe_tiles": self.moe_tiles,
+            # what each program's paged decode attention was traced with
+            "attn_schedule": self.sw.attn_schedule,
             # a kind a layer (None otherwise): which layers mix and feed
             # forward how, and the arrays the engine keeps for them
             "state": ({"mixers": self.state["mixers"],

@@ -61,6 +61,22 @@ another's device memory):
                      4 and 32 rows a group in 1,024 and 8,192 rows (half
                      of a step's pairs name experts held elsewhere and
                      sort past the last group, as in the served step).
+  paged [OUT [PARENT_PY]]
+                     the one-token paged attention kernels alone (PR 34):
+                     ``paged_decode_attn`` by NAME at 16 to 256 rows of
+                     6-entry tables over a [12 * 385, 128, H*D] pool, live
+                     context 128 / 256 / 768, the backlog cell's lognormal
+                     mix and the chat cell's (a fifth of the rows alive),
+                     12 x 64 and 6 x 128 heads, bf16 and int8 pools, under
+                     ``paged_schedule``'s choice and each other number of
+                     table entries a grid step; the live rows' bytes over
+                     819 GB/s beside each; ``max_abs_diff`` against the
+                     XLA gather. PARENT_PY: a copy of an older
+                     ``decode_attention.py`` to time at the same inputs
+                     (``impl: parent``). Also ``paged_block_attn`` and
+                     ``paged_latent_attn`` at their cells' shapes, for the
+                     issues that take them up (this sweep changed neither).
+                     ``paged_schedule`` is what the rows chose from.
 
 The measured columns are TPU columns: off-TPU the kernels run in Pallas
 interpret mode (orders of magnitude slow, numbers meaningless), so
@@ -561,8 +577,177 @@ def measure_ragged(row: dict, held: dict, *, iters: int = 5) -> dict:
                         :row.get("grouped", m)]))))
 
 
+# ---------------------------------------------------------------------------
+# the one-token paged attention kernels alone (PR 34): device ms by schedule
+# ---------------------------------------------------------------------------
+
+PAGED_CELL = dict(layers=12, blocks=385, width=6, block=128)
+
+
+def paged_rows() -> list[dict]:
+    """What ``paged`` measures, in order. A row: ``kernel``, ``rows``,
+    ``heads`` x ``head_dim``, ``ctx`` (every row's live context, or
+    ``backlog`` / ``chat``: lognormal contexts of mean ~212, in ``chat``
+    under a fifth of the rows alive and the others at pos 0 on the null
+    block), ``dtype`` of the pool, ``entries`` a grid step (None = the
+    schedule's), ``impl`` (``parent``: the older module's kernel)."""
+    base = dict(kernel="paged_decode_attn", rows=64, heads=12, head_dim=64,
+                ctx="backlog", dtype="bfloat16", entries=None,
+                impl="change")
+    rows = []
+    for ctx in ("backlog", "chat", 256, 768, 128):
+        rows += [dict(base, ctx=ctx, entries=e) for e in (None, 1, 2, 3)]
+        rows.append(dict(base, ctx=ctx, impl="parent"))
+    for n in (16, 128):
+        rows += [dict(base, rows=n, ctx=ctx) for ctx in ("backlog", 768)]
+    rows.append(dict(base, rows=256, ctx="backlog"))    # a verify program's
+    for ctx in ("backlog", 768):
+        rows += [dict(base, heads=6, head_dim=128, ctx=ctx, entries=e)
+                 for e in (None, 1)]
+        rows += [dict(base, dtype="int8", ctx=ctx, entries=e)
+                 for e in (None, 1)]
+        rows.append(dict(base, dtype="int8", ctx=ctx, impl="parent"))
+    # the other two kernels at their cells' shapes, as they are
+    rows += [dict(kernel="paged_block_attn", rows=64, heads=4, head_dim=128,
+                  lanes=32, width=34, ctx=ctx, dtype="bfloat16",
+                  entries=None, impl="change") for ctx in (1280, 4352)]
+    rows += [dict(kernel="paged_latent_attn", rows=128, heads=32,
+                  head_dim=640, width=136, ctx=ctx, dtype="bfloat16",
+                  entries=None, impl="change") for ctx in (3200, 17408)]
+    return rows
+
+
+def _paged_inputs(row: dict, blocks: int, width: int, block: int):
+    """Block tables, pos and what the live rows hold: every live entry
+    its own pool block (scattered, as the engine's allocator leaves
+    them), dead entries and dead rows on the null block 0."""
+    import numpy as np
+
+    rs = np.random.RandomState(34)
+    n, ctx = row["rows"], row["ctx"]
+    if isinstance(ctx, int):
+        pos = np.full(n, ctx - 1)
+    else:
+        pos = np.clip(rs.lognormal(np.log(170.0), 0.7, n), 16,
+                      width * block - 1).astype(np.int64)
+        if ctx == "chat":
+            pos[rs.permutation(n)[max(1, n // 5):]] = 0
+    live = pos // block + 1
+    ids = rs.permutation(np.arange(1, blocks))[:int(live.sum())]
+    bt = np.zeros((n, width), np.int32)
+    at = 0
+    for r in range(n):
+        if pos[r]:
+            bt[r, :live[r]] = ids[at:at + live[r]]
+            at += live[r]
+    return bt, pos.astype(np.int32), int(live.sum())
+
+
+def measure_paged(row: dict, parent, *, iters: int = 5) -> dict:
+    """One row: device ms a call of the kernel by NAME, of the rest of the
+    program (the fetch table's operations), and the live rows' K and V
+    bytes over the chip's bandwidth."""
+    import functools
+    import importlib
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark import trace_reduce
+    from distributed_tensorflow_example_tpu.ops import mla
+    dm = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.pallas.decode_attention")
+
+    h, d, bs = row["heads"], row["head_dim"], PAGED_CELL["block"]
+    width = row.get("width", PAGED_CELL["width"])
+    n = row["rows"]
+    # GPT-2's pool as the cells hold it; the other kernels' hold a block
+    # for every table entry
+    blocks = (PAGED_CELL["layers"] * PAGED_CELL["blocks"]
+              if row["kernel"] == "paged_decode_attn" else n * width + 1)
+    if os.environ.get("FLASH_SWEEP_CPU"):
+        blocks = min(blocks, 64)
+    bt, pos, live = _paged_inputs(row, blocks, width, bs)
+    key = jax.random.key(n + h)
+    dtype = jnp.dtype(row["dtype"])
+    out = dict(row)
+    if row["kernel"] == "paged_latent_attn":
+        pool = jax.random.normal(key, (blocks, bs, d), dtype)
+        q = jax.random.normal(key, (n, h, d), dtype) * 0.05
+        call = jax.jit(functools.partial(mla._latent_dispatch, rank=512))
+        args = (q, pool, jnp.asarray(bt), jnp.asarray(pos))
+        want = mla.xla_latent_attention(q, pool, block_tables=args[2],
+                                        last=args[3], rank=512)
+        token_bytes = d * 2
+    elif row["kernel"] == "paged_block_attn":
+        pool = jax.random.normal(key, (blocks, bs, h * d), dtype)
+        q = jax.random.normal(key, (n, h, row["lanes"], d), dtype)
+        call = jax.jit(dm._block_dispatch)
+        args = (q, pool, pool, jnp.asarray(bt), jnp.asarray(pos))
+        want = dm.xla_paged_block_attention(
+            q, pool, pool, block_tables=args[3], last=args[4])
+        token_bytes = 2 * h * d * 2
+    else:
+        kf = jax.random.normal(key, (blocks, bs, h * d), jnp.float32)
+        q = jax.random.normal(key, (n, h, d), jnp.bfloat16)
+        scales = ()
+        if dtype == jnp.int8:
+            sc = jnp.max(jnp.abs(kf), axis=-1) / 127.0
+            pool = jnp.round(kf / sc[..., None]).astype(jnp.int8)
+            scales = (sc, sc)
+        else:
+            pool = kf.astype(dtype)
+        del kf
+        mod = parent if row["impl"] == "parent" else dm
+        kw = {}
+        if row["entries"]:
+            kw["schedule"] = dm.PagedSchedule(row["entries"], 0)
+        call = jax.jit(functools.partial(mod._paged_dispatch, **kw))
+        args = (q, pool, pool, jnp.asarray(bt), jnp.asarray(pos),
+                jnp.zeros((n,), jnp.int32)) + scales
+        want = dm.xla_paged_decode_attention(
+            q, pool, pool, block_tables=args[3], pos=args[4], pad=args[5],
+            **(dict(k_scale=sc, v_scale=sc) if scales else {}))
+        token_bytes = 2 * h * d * dtype.itemsize
+        if row["impl"] == "change":
+            sch = dm.paged_schedule(n, h, d, bs, width, dtype)
+            out.update(chosen=row["entries"] is None,
+                       entries=row["entries"] or sch.entries)
+            out["grid_steps"] = n * width // out["entries"]
+        else:
+            out["grid_steps"] = n * (h // max(1, 128 // d)) * width
+    red = _capture(call, args, iters)
+    got = call(*args)
+    kv_bytes = int(np.sum(pos.astype(np.int64) + 1)) * token_bytes
+    ms = trace_reduce.op_seconds(red, pattern=row["kernel"]) / iters * 1e3
+    out.update(
+        live_blocks=live, ms=round(ms, 4),
+        program_ms=round(red["busy_s"] / iters * 1e3, 4),
+        other_ms=round(red["busy_s"] / iters * 1e3 - ms, 4),
+        kv_mb=round(kv_bytes / 1e6, 2),
+        roofline_pct=round(kv_bytes / HBM_BPS / (ms / 1e3) * 100, 2)
+        if ms else None,
+        max_abs_diff=float(jnp.max(jnp.abs(
+            got.astype(jnp.float32) - want.astype(jnp.float32)))))
+    return out
+
+
+def _load_parent(path: str):
+    """An older ``decode_attention.py`` as a sibling of the package's own
+    (its relative imports resolve there)."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "distributed_tensorflow_example_tpu.ops.pallas."
+        "decode_attention_parent", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def kernels(out_path: str | None, mode: str = "kernels",
-            wide: bool = False) -> None:
+            wide: bool = False, parent_py: str | None = None) -> None:
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -576,6 +761,13 @@ def kernels(out_path: str | None, mode: str = "kernels",
             rows = [dict(r, m=64, k=128, n=128, groups=4, grouped=64,
                          tiling=r["tiling"] and "128,128,128")
                     for r in rows[:3]]
+    elif mode == "paged":
+        parent = _load_parent(parent_py) if parent_py else None
+        rows = [r for r in paged_rows()
+                if parent is not None or r["impl"] != "parent"]
+        if not on_tpu:                   # smoke: the control flow only
+            rows = [dict(r, rows=4, heads=2, ctx=r["ctx"] if isinstance(
+                r["ctx"], str) else 256) for r in rows[:7] + rows[-4:]]
     else:
         rows = kernel_rows()
         if not on_tpu:                   # smoke: the control flow only
@@ -587,6 +779,7 @@ def kernels(out_path: str | None, mode: str = "kernels",
     for row in rows:
         try:
             line = (measure_ragged(row, held) if mode == "ragged"
+                    else measure_paged(row, parent) if mode == "paged"
                     else measure_kernels(row))
         except Exception as e:  # noqa: BLE001 — a refused tile is a row
             failed += 1
@@ -641,12 +834,14 @@ def main() -> None:
                                   512))
         run_cells(os.path.abspath(__file__), cells)
         return
-    if sys.argv[1:2] in (["kernels"], ["ragged"]):
+    if sys.argv[1:2] in (["kernels"], ["ragged"], ["paged"]):
         if len(sys.argv) > 2:
             os.makedirs(os.path.dirname(os.path.abspath(sys.argv[2])),
                         exist_ok=True)
+        third = sys.argv[3] if len(sys.argv) > 3 else None
         kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1],
-                wide=sys.argv[3:4] == ["wide"])
+                wide=third == "wide",
+                parent_py=third if sys.argv[1] == "paged" else None)
         return
     if sys.argv[1:2] == ["--trace"]:
         outdir, mn = sys.argv[2], sys.argv[3]

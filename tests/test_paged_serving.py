@@ -14,6 +14,7 @@
   at equal pool bytes, and block-level /stats.
 """
 
+import importlib
 import json
 import threading
 import urllib.request
@@ -33,6 +34,10 @@ from distributed_tensorflow_example_tpu.serving_batch import (
     BlockPool, BlocksExhaustedError, GenerationEngine, PrefixCache,
     RetryAfterEstimator)
 from distributed_tensorflow_example_tpu.serving_http import PredictServer
+
+# the module, not the same-named function ops.pallas re-exports
+decode_mod = importlib.import_module(
+    "distributed_tensorflow_example_tpu.ops.pallas.decode_attention")
 
 PROMPT_LEN = 8
 MAX_NEW = 5
@@ -135,6 +140,187 @@ def test_paged_kernel_matches_gather_on_verify_expanded_rows():
                 pos=pos[r:r + 1], pad=pad[r:r + 1], impl="xla")
             np.testing.assert_array_equal(np.asarray(want[r]),
                                           np.asarray(one[0]))
+
+
+def _kernel_case(name):
+    """Inputs of one case of ``test_paged_kernel_cases``: (q, k_pool,
+    v_pool, block_tables, pos, pad) and, where the kernel's pool differs
+    from the reference's (NaN bytes nothing may read), the reference's
+    pools."""
+    rs = np.random.RandomState(sum(map(ord, name)))
+    h, d = (4, 128) if "4x128" in name else (4, 96) if "4x96" in name \
+        else (12, 64)
+    bs, nb = 128, 6 if "six" in name else 3
+    b = 3
+    n = 1 + b * nb
+    kp, vp = _rand_pool(rs, n, bs, h, d)
+    bt = rs.permutation(np.arange(1, n)).astype(np.int32).reshape(b, nb)
+    pos = np.array([130, nb * bs - 1, 5], np.int32)
+    pad = np.zeros(b, np.int32)
+    ref = None
+    if name.startswith("pos"):          # the block edges, in every row
+        pos[:] = {"pos0": 0, "pos127": 127, "pos128": 128,
+                  "poslast": nb * bs - 1}[name.split("_")[0]]
+    elif name.startswith("pad"):
+        pad[:] = [3, 140, 5]            # a whole masked block; pad == pos
+    elif name.startswith("nulltable"):  # rows that are not alive
+        bt[0] = bt[2] = 0
+        pos[[0, 2]] = 0
+    elif name.startswith("nan"):
+        # bytes nothing may read: the blocks of entries past pos (row 0:
+        # entry 2; row 2: entries 1, 2) and the null block
+        ref = (kp.copy(), vp.copy())
+        for blk in (0, bt[0, 2], bt[2, 1], bt[2, 2]):
+            kp[blk] = vp[blk] = np.nan
+    q = rs.randn(b, h, d).astype(np.float32)
+    if name.startswith("kfold"):        # a verify program's expanded rows
+        kk = 4
+        q = rs.randn(b * kk, h, d).astype(np.float32)
+        bt = np.repeat(bt, kk, axis=0)
+        pos = (np.array([100, 250, 126], np.int32)[:, None]
+               + np.arange(kk, dtype=np.int32)[None]).reshape(-1)
+        pad = np.repeat(np.array([3, 0, 0], np.int32), kk)
+    return (q, kp, vp, bt, pos, pad), ref
+
+
+@pytest.mark.parametrize("name", [
+    "mixed_12x64", "mixed_4x128", "mixed_4x96", "mixed_six_12x64",
+    "pos0_12x64", "pos127_12x64", "pos128_12x64", "poslast_12x64",
+    "pos0_4x128", "poslast_six_4x128", "pad_12x64", "pad_4x128",
+    "nulltable_12x64", "nulltable_six_4x128", "nan_12x64", "nan_4x128",
+    "kfold_12x64", "kfold_six_4x128"])
+def test_paged_kernel_cases(name):
+    """The kernel (interpret mode off-TPU) against the gather reference:
+    12 heads x 64 and 4 x 128 (and 4 x 96: the query tile keeps any head
+    size apart), pos on the block edges, a pad window, rows that are not
+    alive (every entry the null block, pos 0), blocks past pos and a null
+    block full of NaN (entries past pos are neither fetched nor
+    computed: exact 0), a verify program's K-fold rows at consecutive
+    pos over one table row."""
+    (q, kp, vp, bt, pos, pad), ref = _kernel_case(name)
+    assert paged_tile_friendly(kp.shape[1], *q.shape[1:])
+    rk, rv = ref or (kp, vp)
+    want = paged_decode_attention(jnp.asarray(q), jnp.asarray(rk),
+                                  jnp.asarray(rv), block_tables=bt,
+                                  pos=pos, pad=pad, impl="xla")
+    got = paged_decode_attention(jnp.asarray(q), jnp.asarray(kp),
+                                 jnp.asarray(vp), block_tables=bt,
+                                 pos=pos, pad=pad, impl="pallas")
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(want), np.asarray(got),
+                               rtol=3e-6, atol=3e-6)
+
+
+@pytest.mark.parametrize("entries", [1, 2, 3, 6])
+def test_paged_kernel_same_result_at_every_schedule(entries):
+    """1, 2, 3 or 6 table entries a grid step: the same online softmax in
+    the same order, so the same bytes as the schedule's own choice."""
+    (q, kp, vp, bt, pos, pad), _ = _kernel_case("mixed_six_12x64")
+    args = tuple(map(jnp.asarray, (q, kp, vp, bt, pos, pad)))
+    want = decode_mod._paged_dispatch(*args)
+    got = decode_mod._paged_dispatch(
+        *args, schedule=decode_mod.PagedSchedule(entries, 0))
+    np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_paged_kernel_reads_every_layer_where_it_lies(dtype):
+    """The flat [L*N, Bs, H*D] view with ``block_tables + i * N``: layer
+    i's call equals the call on layer i's own pool, bf16 pools under a
+    bf16 query (MXU operands stay bf16) included."""
+    rs = np.random.RandomState(7)
+    layers, b, h, d, bs, nb = 3, 2, 12, 64, 128, 3
+    n = 1 + b * nb
+    kp = jnp.asarray(rs.randn(layers, n, bs, h * d), dtype)
+    vp = jnp.asarray(rs.randn(layers, n, bs, h * d), dtype)
+    q = jnp.asarray(rs.randn(b, h, d), dtype)
+    bt = np.arange(1, 1 + b * nb, dtype=np.int32).reshape(b, nb)
+    pos = np.array([200, 383], np.int32)
+    pad = np.zeros(b, np.int32)
+    tol = 2e-2 if dtype == "bfloat16" else 3e-6
+    for i in range(layers):
+        got = paged_decode_attention(
+            q, kp.reshape(layers * n, bs, h * d),
+            vp.reshape(layers * n, bs, h * d), block_tables=bt + i * n,
+            pos=pos, pad=pad, impl="pallas")
+        assert got.dtype == jnp.dtype(dtype)
+        own = paged_decode_attention(q, kp[i], vp[i], block_tables=bt,
+                                     pos=pos, pad=pad, impl="pallas")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(own))
+        want = paged_decode_attention(q, kp[i], vp[i], block_tables=bt,
+                                      pos=pos, pad=pad, impl="xla")
+        np.testing.assert_allclose(
+            np.asarray(want, np.float32), np.asarray(got, np.float32),
+            rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("entries", [1, 2, 3, 6])
+def test_fetch_table_names_what_the_operand_already_holds(entries):
+    """A live entry names its own block; a dead one the block the same
+    operand (entry index modulo ``entries``) held a grid step earlier,
+    in grid order, so a dead entry is never fetched."""
+    rs = np.random.RandomState(entries)
+    b, nb, bs = 9, 6, 128
+    bt = rs.randint(1, 500, (b, nb)).astype(np.int32)
+    pos = rs.randint(0, nb * bs, b).astype(np.int32)
+    pos[[2, 3]] = 0
+    got = np.asarray(decode_mod._fetch_table(
+        jnp.asarray(bt), jnp.asarray(pos), entries, bs))
+    held = {e: bt[0, e] for e in range(entries)}     # before any fetch
+    for r in range(b):
+        for j in range(nb):
+            e = j % entries
+            if j * bs <= pos[r]:
+                held[e] = bt[r, j]
+            assert got[r, j] == held[e], (r, j)
+
+
+#: (rows, heads, head_dim, block_size, table width, pool dtype): what the
+#: GPT-2 cells serve (decode, a verify program's 4-fold rows, int8 pools)
+#: and every shape ``experiments/flash_sweep.py paged`` times
+_READ_SHAPES = [(rows, h, d, 128, 6, dt)
+                for rows in (16, 64, 128, 256)
+                for h, d in ((12, 64), (6, 128))
+                for dt in ("bfloat16", "int8")]
+
+
+def test_paged_schedule_fits_vmem_and_falls_back_conservatively():
+    for rows, h, d, bs, nb, dt in _READ_SHAPES:
+        sch = decode_mod.paged_schedule(rows, h, d, bs, nb, dt)
+        assert sch.entries == nb                     # one grid step a row
+        assert sch.vmem_bytes == decode_mod._paged_vmem_bytes(
+            sch.entries, h, d, bs, dt) <= decode_mod._PAGED_VMEM_BUDGET
+        assert sch.describe(rows, nb) == {
+            "kernel": "paged_decode_attn", "entries_per_step": nb,
+            "grid": [rows, 1], "vmem_bytes": sch.vmem_bytes}
+    # shapes no sweep read: the same rule, down to one entry a step
+    assert decode_mod.paged_schedule(64, 12, 64, 128, 7).entries == 7
+    assert decode_mod.paged_schedule(64, 12, 64, 128, 34).entries == 2
+    assert decode_mod.paged_schedule(64, 12, 64, 128, 13).entries == 1
+    wide = decode_mod.paged_schedule(8, 32, 128, 128, 8)      # 4096 lanes
+    assert 1 <= wide.entries < 8
+    assert wide.vmem_bytes <= decode_mod._PAGED_VMEM_BUDGET
+    # a block that cannot fit: the predicate says so, the rule refuses
+    assert not paged_tile_friendly(128, 96, 128)
+    with pytest.raises(ValueError, match="VMEM"):
+        decode_mod.paged_schedule(8, 96, 128, 128, 8)
+
+
+def test_schedule_log_names_what_a_program_was_traced_with():
+    (q, kp, vp, bt, pos, pad), _ = _kernel_case("mixed_six_12x64")
+    args = tuple(map(jnp.asarray, (q, kp, vp)))
+    kw = dict(block_tables=bt, pos=pos, pad=pad)
+    with decode_mod.schedule_log() as seen:
+        jax.eval_shape(lambda *a: paged_decode_attention(
+            *a, impl="pallas", **kw), *args)
+        jax.eval_shape(lambda *a: paged_decode_attention(
+            *a, impl="pallas", **kw), *args)          # a layer scan: once
+    sch = decode_mod.paged_schedule(3, 12, 64, 128, 6, jnp.float32)
+    assert seen == [sch.describe(3, 6)]
+    with decode_mod.schedule_log() as seen:
+        paged_decode_attention(*args, impl="xla", **kw)
+    assert seen == [{"kernel": "xla"}]
+    paged_decode_attention(*args, impl="xla", **kw)    # no log: no-op
 
 
 def test_paged_kernel_rejects_unfriendly_shapes():
@@ -360,6 +546,37 @@ def paged_dir(tmp_path_factory, tiny_model):
                      block_size=BLOCK, num_blocks=48,
                      platforms=("cpu",))
     return d
+
+
+def test_export_and_stats_name_the_attention_schedule(
+        tmp_path, paged_dir, tiny_model, monkeypatch):
+    """``export.json`` ``stepwise.decode.attn_schedule`` and ``/stats``
+    ``attn_schedule``: what each program's paged decode attention was
+    traced with. An export off the TPU holds the gather; one for the TPU
+    alone, made where the backend says it is one (held so here: the
+    programs are lowered for the chip and never run), names the kernel's
+    schedule, the verify program at its K-fold rows."""
+    import os
+    with open(os.path.join(paged_dir, "export.json")) as f:
+        meta = json.load(f)
+    assert meta["stepwise"]["decode"]["attn_schedule"] == {
+        "decode": {"kernel": "xla"}}
+    m, params = tiny_model                      # 4 heads x 32 = 128 lanes
+    d = str(tmp_path)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    export_generator(m, params, d, prompt_len=PROMPT_LEN,
+                     max_new_tokens=MAX_NEW, batch_size=1, ragged=True,
+                     stepwise=True, slots=SLOTS, paged=True, block_size=128,
+                     spec_tokens=2, platforms=("tpu",))
+    with open(os.path.join(d, "export.json")) as f:
+        got = json.load(f)["stepwise"]["decode"]["attn_schedule"]
+    sch = decode_mod.paged_schedule(SLOTS, 4, 32, 128, 1, jnp.float32)
+    assert sch.entries == 1
+    assert got == {"decode": sch.describe(SLOTS, 1),
+                   "verify": sch.describe(SLOTS * 2, 1)}
+    monkeypatch.undo()
+    eng = GenerationEngine(load_stepwise(d), prefix_cache=False)
+    assert eng.stats()["attn_schedule"] == got
 
 
 def _oracle(m, params, prompt, max_new=MAX_NEW, **kw):

@@ -145,6 +145,51 @@ def test_int8_paged_kernel_matches_gather_reference():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("qdtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", ["mixed", "pos0", "pos127", "pos128",
+                                  "poslast", "pad", "nulltable", "flat"])
+@pytest.mark.parametrize("heads,head_dim", [(12, 64), (4, 128)])
+def test_int8_paged_kernel_cases(heads, head_dim, case, qdtype):
+    """int8 pools with their scale rows, kernel against gather: every
+    head of a block at once at 12 x 64 and 4 x 128, pos on the block
+    edges, a pad window, rows that are not alive, and layer 1 of a flat
+    [L*N, Bs, H*D] view; under a float32 query and a bfloat16 one (the
+    int8 values are exact in either: same scores)."""
+    rs = np.random.RandomState(heads + len(case))
+    h, d, b, bs, nb = heads, head_dim, 3, 128, 3
+    n = 1 + b * nb
+    layers = 2 if case == "flat" else 1
+    kq, ks = _quantized_pool(rs, layers * n, bs, h, d)
+    vq, vs = _quantized_pool(rs, layers * n, bs, h, d)
+    q = jnp.asarray(rs.randn(b, h, d), qdtype)
+    bt = rs.permutation(np.arange(1, n)).astype(np.int32).reshape(b, nb)
+    pos = np.array([130, nb * bs - 1, 5], np.int32)
+    pad = np.zeros(b, np.int32)
+    if case.startswith("pos"):
+        pos[:] = {"pos0": 0, "pos127": 127, "pos128": 128,
+                  "poslast": nb * bs - 1}[case]
+    elif case == "pad":
+        pad[:] = [3, 140, 5]
+    elif case == "nulltable":
+        bt[0] = bt[2] = 0
+        pos[[0, 2]] = 0
+    elif case == "flat":
+        bt = bt + n
+    kw = dict(block_tables=bt, pos=pos, pad=pad,
+              k_scale=jnp.asarray(ks), v_scale=jnp.asarray(vs))
+    want = paged_decode_attention(q, jnp.asarray(kq), jnp.asarray(vq),
+                                  impl="xla", **kw)
+    got = paged_decode_attention(q, jnp.asarray(kq), jnp.asarray(vq),
+                                 impl="pallas", **kw)
+    assert got.dtype == q.dtype
+    # (the gather rounds each dequantized row to the query's dtype, the
+    # kernel keeps the scales in float32: bfloat16 agrees to its rounding)
+    tol = 2e-5 if qdtype == "float32" else 3e-2
+    np.testing.assert_allclose(np.asarray(want, np.float32),
+                               np.asarray(got, np.float32),
+                               rtol=tol, atol=tol)
+
+
 def test_int8_paged_scale_validation():
     """Scales and int8 pools travel together — one without the other
     (or mis-shaped) is a loud error, never a silent garbage read."""

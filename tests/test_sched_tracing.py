@@ -216,6 +216,24 @@ def test_kv_bytes_is_the_live_slots_positions(ring, paged_dir):
     assert eng.stats()["decode_kv_bytes"] == sum(want)
 
 
+def test_kv_blocks_is_the_live_rows_table_entries(ring, paged_dir):
+    """``decode_step`` spans carry ``kv_blocks``: over the live slots,
+    the table entries up to each one's pos (``pos // block + 1``), what
+    the paged kernel works on of its slots x blocks_per_slot a call."""
+    eng = GenerationEngine(load_stepwise(paged_dir), prefix_cache=False)
+    for p in _prompts(3, seed=11):
+        eng.submit(p, max_new=MAX_NEW)
+    eng._admit()
+    want = []
+    while eng._live:
+        want.append(sum(s.pos // BLOCK + 1 for s in eng._live.values()))
+        eng._shared_step()
+    steps = [s[5] for s in ring.drain() if s[2] == "decode_step"]
+    assert [s["kv_blocks"] for s in steps] == want
+    assert all(s["slots"] <= s["kv_blocks"]
+               <= s["slots"] * eng.blocks_per_slot for s in steps)
+
+
 def test_phase_counters_add_up_to_the_spans(ring, paged_dir):
     stats = _run_engine(paged_dir, _prompts(SLOTS, seed=3))
     phases = stats["sched_phase_seconds"]

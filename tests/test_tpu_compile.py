@@ -196,27 +196,45 @@ def _slab_specs(dev, heads=H, head_dim=D):
     return on(dev, (SLOTS, heads, head_dim)), kv, kv, row, row
 
 
-def _paged_specs(dev, heads=H, head_dim=D, dtype=BF16):
-    pool = on(dev, (POOL_BLOCKS, BS, heads * head_dim), dtype)
-    row = on(dev, (SLOTS,), jnp.int32)
-    specs = (on(dev, (SLOTS, heads, head_dim)), pool, pool,
-             on(dev, (SLOTS, T // BS), jnp.int32), row, row)
+def _paged_specs(dev, heads=H, head_dim=D, dtype=BF16, *, rows=SLOTS,
+                 width=T // BS, blocks=POOL_BLOCKS):
+    pool = on(dev, (blocks, BS, heads * head_dim), dtype)
+    row = on(dev, (rows,), jnp.int32)
+    specs = (on(dev, (rows, heads, head_dim)), pool, pool,
+             on(dev, (rows, width), jnp.int32), row, row)
     if dtype == jnp.int8:
-        scale = on(dev, (POOL_BLOCKS, BS), jnp.float32)
+        scale = on(dev, (blocks, BS), jnp.float32)
         specs += (scale, scale)
     return specs
 
 
-@pytest.mark.parametrize("name", ["slab", "paged", "paged_int8"])
+@pytest.mark.parametrize("name", ["slab", "paged", "paged_int8",
+                                  "paged_cell", "paged_cell_int8",
+                                  "paged_cell_verify", "paged_d128"])
 def test_decode_kernels_compile(chip, name):
+    """``paged_cell*``: the schedule ``paged_schedule`` chooses at
+    GPT-2's served shape (64 rows, or a verify program's 4 x 64, of
+    6-entry tables over every layer's blocks, [12 * 385, 128, 768]),
+    bfloat16 and the int8 pair; ``paged_d128``: 6 heads x 128."""
     if name == "slab":
         assert decode_mod.tile_friendly(T, H, D)
         text = compile_text(decode_mod._dispatch, *_slab_specs(chip[0]))
     else:
-        assert decode_mod.paged_tile_friendly(BS, H, D)
-        dtype = jnp.int8 if name == "paged_int8" else BF16
-        text = compile_text(decode_mod._paged_dispatch,
-                            *_paged_specs(chip[0], dtype=dtype))
+        dtype = jnp.int8 if name.endswith("int8") else BF16
+        heads, head_dim = (6, 128) if name == "paged_d128" else (H, D)
+        assert decode_mod.paged_tile_friendly(BS, heads, head_dim, dtype)
+        shape = {}
+        if "cell" in name:
+            shape = dict(
+                rows=CELL["slots"] * (4 if name.endswith("verify") else 1),
+                width=CELL["blocks_per_slot"],
+                blocks=CELL["layers"] * CELL["blocks"])
+            sch = decode_mod.paged_schedule(
+                shape["rows"], H, D, BS, shape["width"], dtype)
+            assert sch.entries == CELL["blocks_per_slot"]   # one step a row
+        text = compile_text(
+            decode_mod._paged_dispatch,
+            *_paged_specs(chip[0], heads, head_dim, dtype, **shape))
     assert "tpu_custom_call" in text
 
 
@@ -336,6 +354,10 @@ def test_served_programs_leave_the_pool_where_it_lies(chip, gpt2_small,
            if _ITEMSIZE.get(m.group(1), 4) * np.prod(
                [int(x) for x in m.group(2).split(",")]) >= layer_slice]
     assert not big, big
+    if name in ("decode", "verify"):
+        # the layer scan and nothing else: the benchmark's readers pair a
+        # decode step's span with the ONE `while` of its program
+        assert len(re.findall(r" while\(", text)) == 1
     # the donated pool's leaves are parameters 0..n-1; each one must be
     # the buffer of an output: "{out}: (param, {}, may-alias)"
     aliased = {int(p) for p in re.findall(
@@ -369,7 +391,8 @@ def _raw_flash_fwd(chip, block_k):
     ("slab", dict(heads=12, head_dim=64), True),
     ("slab", dict(heads=12, head_dim=96), False),
     ("paged", dict(heads=12, head_dim=64), True),
-    ("paged", dict(heads=12, head_dim=96), False),
+    ("paged", dict(heads=12, head_dim=96), True),
+    ("paged", dict(heads=96, head_dim=128), False),
     ("flash", dict(block_k=128), True),
     ("flash", dict(block_k=64), False),
 ])
@@ -388,9 +411,15 @@ def test_predicates_agree_with_the_compiler(chip, kernel, shape, accepted):
         assert _compiles(decode_mod._dispatch,
                          *_slab_specs(chip[0], h, d)) is accepted
     else:
+        # the paged kernel holds whole [BS, H*D] blocks, every head at
+        # once: any head size whose blocks are whole tiles, until a block
+        # passes VMEM (the raw kernel at its least schedule, one table
+        # entry a grid step, is what the compiler is asked)
         assert decode_mod.paged_tile_friendly(BS, h, d) is accepted
-        assert _compiles(decode_mod._paged_dispatch,
-                         *_paged_specs(chip[0], h, d)) is accepted
+        assert _compiles(
+            functools.partial(decode_mod._paged_dispatch,
+                              schedule=decode_mod.PagedSchedule(1, 0)),
+            *_paged_specs(chip[0], h, d)) is accepted
     if not accepted:
         q = jnp.zeros((1, h, d))
         with pytest.raises(ValueError, match="head dim"):
