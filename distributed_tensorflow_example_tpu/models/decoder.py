@@ -48,6 +48,20 @@ greedy ids, never logits. Registered as ``kimi_linear``
 (Kimi-Linear-48B-A3B-Instruct's layers) and ``kimi_linear_tiny``; the
 equations are in ``benchmark/reference/kimi-linear-48b-a3b.py``.
 
+``layer_types`` names the kind a layer outright (one token a step too):
+``full_attention`` layers mix by MLA over a SELECTED set of the earlier
+rows (``mla_sparse``: a query low-rank pair, rotary positions on ``q_pe``
+and on the shared ``k_pe`` before the row is cached, an indexer whose
+keys lie in a second paged pool behind the same block tables,
+``ops/dsa.py``), ``sliding_attention`` layers by MLA of another geometry
+(``swa_*``: its own head count, ranks, head sizes and rotary base) over
+the last ``window`` rows, kept in a ring a slot (``mla_window``); both
+gate each head's output by ``sigmoid(W_g n)`` and rescale the normed
+latents by ``sqrt(hidden / rank)``. The same two forwards, the same FFNs.
+Registered as ``dots3_note`` (dots3-note-prev's layers) and
+``dots3_note_tiny``; the equations are in
+``benchmark/reference/dots3-note-prev.py``.
+
 Equations (per layer, pre-norm, no bias anywhere)::
 
     h = x + W_o . Attn(rope(rms_d(W_q n)), rope(rms_d(W_k n)), W_v n),
@@ -70,6 +84,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..config import TrainConfig
+from ..ops import dsa as dsa_ops
 from ..ops import kda as kda_ops
 from ..ops import mla as mla_ops
 from ..ops.moe import moe_dropless
@@ -117,7 +132,8 @@ class DecoderBlockConfig:
     gate_rank: int = 128            # rank of KDA's decay and gate pairs
     kv_lora_rank: int = 512         # MLA: the latent's normed values
     qk_nope_dim: int = 128
-    qk_rope_dim: int = 64           # (never rotated: mla_use_nope)
+    qk_rope_dim: int = 64           # (Kimi's are never rotated:
+    #                                 mla_use_nope; see mla_rope)
     v_head_dim: int = 128
     dense_layers: int = 0           # leading layers with a dense FFN
     dense_width: int = 0
@@ -128,6 +144,65 @@ class DecoderBlockConfig:
     #: ``vocab_held`` ids from ``first_vocab`` on (0 = all of it)
     vocab_held: int = 0
     first_vocab: int = 0
+    # ---- a kind a layer by name (block_length = 1) ----
+    #: ``full_attention`` / ``sliding_attention`` a layer, as published
+    #: (entries past ``layers`` name layers held elsewhere); () = none
+    layer_types: tuple = ()
+    mla_rope: bool = False          # rotary on q_pe and on the cached k_pe
+    q_lora_rank: int = 0            # 0: one query matrix
+    lora_rescale: bool = False      # normed latents x sqrt(hidden / rank)
+    head_gate: bool = False         # sigmoid(W_g n), one value a head
+    index_heads: int = 0            # the indexer (full_attention layers)
+    index_head_dim: int = 0
+    index_topk: int = 0
+    window: int = 0                 # rows a sliding layer sees, its own one
+    swa_heads: int = 0              # the sliding layers' own geometry
+    swa_q_lora_rank: int = 0
+    swa_kv_lora_rank: int = 0
+    swa_qk_nope_dim: int = 0
+    swa_qk_rope_dim: int = 0
+    swa_v_head_dim: int = 0
+    swa_rope_theta: float = 0.0
+
+    @classmethod
+    def dots3_note_prev(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=152064, hidden=5120, layers=46, heads=128,
+                   kv_heads=128, head_dim=128, norm_eps=1e-5, qk_norm=False,
+                   rope_theta=8e7, experts=256, experts_per_token=8,
+                   expert_width=1536, block_length=1, mask_id=0,
+                   max_len=524288,
+                   layer_types=("full_attention", "full_attention")
+                   + ("sliding_attention",) * 3
+                   + ("full_attention",
+                      *("sliding_attention",) * 3) * 10 + ("full_attention",),
+                   mla_rope=True, q_lora_rank=1024, lora_rescale=True,
+                   head_gate=True, kv_lora_rank=512, qk_nope_dim=128,
+                   qk_rope_dim=64, v_head_dim=128, index_heads=64,
+                   index_head_dim=128, index_topk=2048, window=513,
+                   swa_heads=64, swa_q_lora_rank=1024, swa_kv_lora_rank=1024,
+                   swa_qk_nope_dim=192, swa_qk_rope_dim=64,
+                   swa_v_head_dim=128, swa_rope_theta=5e4,
+                   dense_layers=1, dense_width=13824, shared_experts=1,
+                   router_scores="sigmoid", routed_scale=1.0)
+
+    @classmethod
+    def dots3_note_tiny(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=512, hidden=64, layers=5, heads=4, kv_heads=4,
+                   head_dim=16, norm_eps=1e-5, qk_norm=False, rope_theta=8e7,
+                   experts=8, experts_per_token=2, expert_width=32,
+                   block_length=1, mask_id=0, max_len=4096,
+                   layer_types=("full_attention", "full_attention",
+                                "sliding_attention", "sliding_attention",
+                                "sliding_attention", "full_attention"),
+                   mla_rope=True, q_lora_rank=24, lora_rescale=True,
+                   head_gate=True, kv_lora_rank=32, qk_nope_dim=16,
+                   qk_rope_dim=8, v_head_dim=16, index_heads=2,
+                   index_head_dim=16, index_topk=16, window=9, swa_heads=2,
+                   swa_q_lora_rank=24, swa_kv_lora_rank=48,
+                   swa_qk_nope_dim=24, swa_qk_rope_dim=8, swa_v_head_dim=16,
+                   swa_rope_theta=5e4, dense_layers=1, dense_width=128,
+                   shared_experts=1, router_scores="sigmoid",
+                   routed_scale=1.0)
 
     @classmethod
     def sdar_30b_a3b(cls) -> "DecoderBlockConfig":
@@ -170,20 +245,49 @@ class DecoderBlockConfig:
         """Rows of the embedding and columns of the head held here."""
         return self.vocab_held or self.vocab_size
 
+    @property
+    def stateful(self) -> bool:
+        """A kind a layer: served by a chunk program and a one-token
+        step over the arrays :meth:`BlockDecoder.state_specs` names."""
+        return self.linear_attn or bool(self.layer_types)
+
     def mixer(self, i: int) -> str:
-        """Layer ``i``'s mixer (``i`` from 0): ``gqa``, ``kda`` or ``mla``."""
+        """Layer ``i``'s mixer (``i`` from 0): ``gqa``, ``kda``, ``mla``,
+        ``mla_sparse`` or ``mla_window``."""
+        if self.layer_types:
+            return {"full_attention": "mla_sparse",
+                    "sliding_attention": "mla_window"}[self.layer_types[i]]
         if not self.linear_attn:
             return "gqa"
         return "mla" if i + 1 in self.full_attn_layers else "kda"
 
+    def geometry(self, kind: str) -> "MlaGeometry":
+        """The sizes of an MLA layer of ``kind``."""
+        if kind == "mla_window":
+            return MlaGeometry(self.swa_heads, self.swa_q_lora_rank,
+                               self.swa_kv_lora_rank, self.swa_qk_nope_dim,
+                               self.swa_qk_rope_dim, self.swa_v_head_dim,
+                               self.swa_rope_theta)
+        return MlaGeometry(self.heads, self.q_lora_rank, self.kv_lora_rank,
+                           self.qk_nope_dim, self.qk_rope_dim,
+                           self.v_head_dim, self.rope_theta)
+
     def layers_of(self, kind: str) -> list[int]:
         return [i for i in range(self.layers) if self.mixer(i) == kind]
+
+    def rows_of(self, kind: str) -> dict[int, int]:
+        """Layer -> its row in the state arrays of ``kind``'s layers."""
+        return {i: j for j, i in enumerate(self.layers_of(kind))}
 
     def state_rows(self) -> tuple[dict, dict]:
         """Layer -> its row in the recurrent arrays (KDA layers) and in
         the latent pool (MLA layers)."""
-        return ({i: j for j, i in enumerate(self.layers_of("kda"))},
-                {i: j for j, i in enumerate(self.layers_of("mla"))})
+        return self.rows_of("kda"), self.rows_of("mla")
+
+    @property
+    def ring(self) -> int:
+        """Rows of a sliding layer's ring a slot."""
+        return mla_ops.ring_rows(self.window)
 
     @property
     def latent_dim(self) -> int:
@@ -200,6 +304,30 @@ class DecoderBlockConfig:
     @property
     def conv_channels(self) -> int:
         return 3 * self.heads * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaGeometry:
+    """One MLA layer kind's sizes (a model may hold two)."""
+    heads: int
+    q_rank: int                     # 0: one query matrix
+    rank: int
+    nope: int
+    pe: int
+    v: int
+    theta: float
+
+    @property
+    def row(self) -> int:
+        return mla_ops.latent_row(self.rank, self.pe)
+
+    @property
+    def scale(self) -> float:
+        return (self.nope + self.pe) ** -0.5
+
+
+#: the parameter group a layer's mixer lies under, where not its kind
+_GROUP = {"gqa": "attn", "mla_sparse": "mla", "mla_window": "mla"}
 
 
 def _rms(x, scale, eps: float):
@@ -236,13 +364,22 @@ class BlockDecoder(DefaultRulesMixin):
             raise ValueError(
                 f"experts {cfg.first_expert}..{cfg.first_expert + cfg.held}"
                 f" are not among the {cfg.experts} the router knows")
-        if cfg.linear_attn != (b == 1):
+        if cfg.stateful != (b == 1):
             raise ValueError(
-                "a kind a layer (linear_attn) and one token a step "
-                "(block_length = 1) come together: the block-diffusion "
-                "forwards run grouped-query attention only, the one-token "
-                f"forwards KDA and MLA only; got linear_attn="
-                f"{cfg.linear_attn}, block_length={b}")
+                "a kind a layer (linear_attn or layer_types) and one token "
+                "a step (block_length = 1) come together: the "
+                "block-diffusion forwards run grouped-query attention "
+                "only, the one-token forwards KDA and MLA only; got "
+                f"linear_attn={cfg.linear_attn}, layer_types of "
+                f"{len(cfg.layer_types)}, block_length={b}")
+        if cfg.layer_types:
+            if cfg.linear_attn or len(cfg.layer_types) < cfg.layers:
+                raise ValueError(
+                    f"layer_types names {len(cfg.layer_types)} layers of "
+                    f"{cfg.layers} (and excludes linear_attn)")
+            if not (cfg.window and cfg.index_topk and cfg.index_heads):
+                raise ValueError("layer_types needs window, index_topk and "
+                                 "index_heads")
         if cfg.router_scores not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_scores {cfg.router_scores!r}")
         if cfg.first_vocab + cfg.vocab > cfg.vocab_size:
@@ -261,7 +398,7 @@ class BlockDecoder(DefaultRulesMixin):
         checkpoint or the benchmark's seeded leaves replace them)."""
         c = self.cfg
         keys = iter(jax.random.split(
-            rng, 2 + (24 if c.linear_attn else 8) * c.layers))
+            rng, 2 + (24 if c.stateful else 8) * c.layers))
         qd, kd = c.heads * c.head_dim, c.kv_heads * c.head_dim
 
         def glorot(*shape):
@@ -297,6 +434,26 @@ class BlockDecoder(DefaultRulesMixin):
                         "wo": glorot(qd, c.hidden),
                         "q_norm": ones(c.head_dim),
                         "k_norm": ones(c.head_dim)}
+            if kind in ("mla_sparse", "mla_window"):
+                g = c.geometry(kind)
+                mp = {"wqa": glorot(c.hidden, g.q_rank),
+                      "q_norm": ones(g.q_rank),
+                      "wqb": glorot(g.q_rank, g.heads * (g.nope + g.pe)),
+                      "wkva": glorot(c.hidden, g.rank + g.pe),
+                      "kv_norm": ones(g.rank),
+                      "wkvb": glorot(g.rank, g.heads * (g.nope + g.v)),
+                      "wg": glorot(c.hidden, g.heads),
+                      "wo": glorot(g.heads * g.v, c.hidden)}
+                if kind == "mla_sparse":
+                    mp["index"] = {
+                        "wq": glorot(g.q_rank,
+                                     c.index_heads * c.index_head_dim),
+                        "wk": glorot(c.hidden, c.index_head_dim),
+                        "k_scale": ones(c.index_head_dim),
+                        "k_bias": jnp.zeros((c.index_head_dim,),
+                                            self.param_dtype),
+                        "ww": glorot(c.hidden, c.index_heads)}
+                return mp
             if kind == "mla":
                 return {"wq": glorot(c.hidden, c.heads * (
                             c.qk_nope_dim + c.qk_rope_dim)),
@@ -325,7 +482,7 @@ class BlockDecoder(DefaultRulesMixin):
         for i in range(c.layers):
             kind = c.mixer(i)
             lp = {"attn_norm": ones(c.hidden),
-                  {"gqa": "attn"}.get(kind, kind): mixer(kind),
+                  _GROUP.get(kind, kind): mixer(kind),
                   "ffn_norm": ones(c.hidden)}
             if i < c.dense_layers:
                 lp["mlp"] = gated(c.dense_width)
@@ -528,6 +685,26 @@ class BlockDecoder(DefaultRulesMixin):
         a request takes the slot, carried from chunk to chunk to the
         decode steps, and left as it lies at release."""
         c = self.cfg
+        if c.layer_types:
+            full, win = c.layers_of("mla_sparse"), c.layers_of("mla_window")
+            dt = str(jnp.dtype(self.dtype))
+            return {
+                "cache_latent": {
+                    "shape": [len(full), num_blocks, block_size,
+                              c.geometry("mla_sparse").row],
+                    "dtype": dt, "layers": full, "per": "block"},
+                # a token's index key, behind the same block tables
+                "cache_index": {
+                    "shape": [len(full), num_blocks, block_size,
+                              c.index_head_dim],
+                    "dtype": dt, "layers": full, "per": "block"},
+                # the last `window` latent rows of a slot, row p % ring
+                # holding position p: bounded, whatever the context
+                "cache_window": {
+                    "shape": [len(win), slots, c.ring,
+                              c.geometry("mla_window").row],
+                    "dtype": dt, "layers": win, "per": "slot"},
+            }
         kda, mla = c.layers_of("kda"), c.layers_of("mla")
         return {
             "cache_latent": {
@@ -608,6 +785,114 @@ class BlockDecoder(DefaultRulesMixin):
              jnp.zeros((n.shape[0], c.latent_row - c.latent_dim))],
             axis=-1).astype(self.dtype)
 
+    def _typed_q(self, mp, n, pos, g: MlaGeometry):
+        """A typed MLA layer's queries from the normed rows ``n`` [T, h]:
+        the query latent ``c_q`` [T, q_rank] float32 (normed, rescaled:
+        what the indexer reads too) and ``q`` [T, H, nope + pe] float32
+        with rotary positions on the last ``pe`` values."""
+        c = self.cfg
+        t = n.shape[0]
+        cq = _rms(self._mm(n, mp["wqa"]), mp["q_norm"], c.norm_eps)
+        if c.lora_rescale:
+            cq = cq * math.sqrt(c.hidden / g.q_rank)
+        q = self._mm(cq, mp["wqb"]).reshape(t, g.heads, g.nope + g.pe)
+        if c.mla_rope:
+            q = jnp.concatenate(
+                [q[..., :g.nope], _rope(q[..., g.nope:], pos, g.theta)],
+                axis=-1)
+        return cq, q
+
+    def _typed_latent(self, mp, n, pos, g: MlaGeometry):
+        """The row a token keeps in a typed MLA layer: ``[RMSNorm(c[:rank])
+        (rescaled) ; rope(c[rank:]) ; zeros to whole lane tiles]``."""
+        c = self.cfg
+        ckv = self._mm(n, mp["wkva"])
+        lat = _rms(ckv[:, :g.rank], mp["kv_norm"], c.norm_eps)
+        if c.lora_rescale:
+            lat = lat * math.sqrt(c.hidden / g.rank)
+        k_pe = ckv[:, g.rank:]
+        if c.mla_rope:
+            k_pe = self._rope_k(k_pe, pos, g.theta)
+        return jnp.concatenate(
+            [lat, k_pe, jnp.zeros((n.shape[0], g.row - g.rank - g.pe))],
+            axis=-1).astype(self.dtype)
+
+    def _rope_k(self, k_pe, pos, theta):
+        """Rotary positions on the one ``k_pe`` a token's heads share
+        (a method so that a planted fault can leave it off)."""
+        return _rope(k_pe[:, None, :], pos, theta)[:, 0]
+
+    def _index_inputs(self, ip, n, cq, pos):
+        """The indexer's three inputs for rows ``n`` [T, h]: queries
+        [T, J, D] and this token's key [T, D] in ``dtype`` (the leading
+        ``qk_rope_dim`` values of each rotated), and the heads' weights
+        [T, J] float32 (``J^-1/2 D^-1/2`` folded in)."""
+        c = self.cfg
+        t = n.shape[0]
+        j, d, pe = c.index_heads, c.index_head_dim, c.qk_rope_dim
+
+        def rot(x):                         # [T, heads, D]
+            return jnp.concatenate(
+                [_rope(x[..., :pe], pos, c.rope_theta), x[..., pe:]],
+                axis=-1)
+
+        q = rot(self._mm(cq, ip["wq"]).reshape(t, j, d))
+        k = self._mm(n, ip["wk"])
+        mu = jnp.mean(k, axis=-1, keepdims=True)
+        k = (k - mu) * lax.rsqrt(
+            jnp.mean(jnp.square(k - mu), axis=-1, keepdims=True)
+            + c.norm_eps) * ip["k_scale"].astype(jnp.float32) \
+            + ip["k_bias"].astype(jnp.float32)
+        k = rot(k[:, None, :])[:, 0]
+        w = self._mm(n, ip["ww"]) * (j ** -0.5 * d ** -0.5)
+        return q.astype(self.dtype), k.astype(self.dtype), w
+
+    def _absorb(self, mp, q, g: MlaGeometry):
+        """``W_kvb`` folded into one-token queries: ``q`` [S, H, nope +
+        pe] float32 -> the absorbed query [S, H, row] in ``dtype``
+        (scaled) and ``W_vb`` [rank, H, v] for the way out."""
+        s = q.shape[0]
+        q = q * g.scale
+        wkvb = mp["wkvb"].reshape(g.rank, g.heads, -1)
+        q_lat = jnp.einsum(
+            "shd,chd->shc", q[..., :g.nope].astype(self.dtype),
+            wkvb[..., :g.nope].astype(self.dtype),
+            preferred_element_type=jnp.float32)
+        q_abs = jnp.concatenate(
+            [q_lat, q[..., g.nope:],
+             jnp.zeros((s, g.heads, g.row - g.rank - g.pe))], axis=-1)
+        return q_abs.astype(self.dtype), wkvb[..., g.nope:]
+
+    def _unabsorb(self, ctx, w_vb):
+        """The heads' outputs from the weighted latent rows: ``ctx``
+        [S, H, rank] float32, ``W_vb`` [rank, H, v] -> [S, H, v]."""
+        return jnp.einsum("shc,chd->shd", ctx.astype(self.dtype),
+                          w_vb.astype(self.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def _typed_out(self, mp, n, ctx):
+        """``W_o concat_h(g_h . ctx_h)``: ``ctx`` [T, H, v] float32."""
+        t = n.shape[0]
+        if self.cfg.head_gate:
+            ctx = ctx * jax.nn.sigmoid(self._mm(n, mp["wg"]))[..., None]
+        return self._mm(ctx.reshape(t, -1), mp["wo"])
+
+    def _chunk_index(self, index, j, blocks, keys):
+        """The index pool with a chunk's keys in its blocks (a method so
+        that a planted fault can leave what the blocks held)."""
+        return index.at[j, blocks].set(keys)
+
+    def _select_rows(self, scores, k: int):
+        """The selected positions of one row a slot (a method, as
+        :meth:`_select`)."""
+        return dsa_ops.top_k_rows(scores, k)
+
+    def _select(self, scores, k: int, live):
+        """The selection's mask for a chunk's rows, no candidate from
+        column ``live`` on (a method so that a planted fault can drop
+        it)."""
+        return dsa_ops.top_k_mask(scores, k, live=live)
+
     def _ffn_of(self, i, lp, h):
         """Layer ``i``'s FFN on the residual stream; the held experts
         that received a row, per expert (None for a dense layer)."""
@@ -634,7 +919,8 @@ class BlockDecoder(DefaultRulesMixin):
 
     def prefill_chunk(self, params, state, input_ids, n_valid, start, slot,
                       table_row, chunk_blocks, *, with_logits: bool = False,
-                      kda_chunk: int = kda_ops.SUB_CHUNK):
+                      kda_chunk: int = kda_ops.SUB_CHUNK,
+                      attention: str = "auto"):
         """One chunk of one prompt: ``C`` tokens from what the chunks
         before it left (the slot's KDA state and conv tails, the latent
         rows in its blocks) to what the next chunk, or the first decode
@@ -647,13 +933,17 @@ class BlockDecoder(DefaultRulesMixin):
         ``chunk_blocks`` [C / Bs] the blocks this chunk's rows go to (the
         null block 0 past the prompt's run). Padding leaves the recurrent
         rows as they were; its latent rows land where a decode step
-        overwrites them before any read. Returns the state and ``ids``
-        [1]: the greedy token after the chunk's last token."""
+        overwrites them before any read. ``attention``: the selected
+        attention of a ``full_attention`` layer, ``"auto"`` (the Pallas
+        kernel on a TPU where the shapes allow), ``"pallas"`` or ``"xla"``
+        (``ops/mla.mla_masked_prefill_attention``). Returns the state and
+        ``ids`` [1]: the greedy token after the chunk's last token."""
         c = self.cfg
         cw = input_ids.shape[1]
-        latent, s_all, conv_all = (state["cache_latent"],
-                                   state["cache_state"],
-                                   state["cache_conv"])
+        latent = state["cache_latent"]
+        # the arrays of the other kinds this model has (state_specs)
+        s_all, conv_all = state.get("cache_state"), state.get("cache_conv")
+        index, rings = state.get("cache_index"), state.get("cache_window")
         n_mla, nb, bs, r = latent.shape
         flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
         table_row = jnp.asarray(table_row, jnp.int32)
@@ -662,10 +952,49 @@ class BlockDecoder(DefaultRulesMixin):
         h = self._embed(params, input_ids[0])
         expert_rows = jnp.zeros((), jnp.int32)
         kda_at, mla_at = c.state_rows()
+        sparse_at, window_at = (c.rows_of("mla_sparse"),
+                                c.rows_of("mla_window"))
+        pos = start + jnp.arange(cw, dtype=jnp.int32)
         for i in range(c.layers):
             lp = params["layers"][str(i)]
             n = _rms(h, lp["attn_norm"], c.norm_eps)
-            if i in kda_at:
+            if i in sparse_at:
+                j, mp = sparse_at[i], lp["mla"]
+                g = c.geometry("mla_sparse")
+                with jax.named_scope("mla_sparse"):
+                    cq, q = self._typed_q(mp, n, pos, g)
+                    latent = latent.at[j, chunk_blocks].set(
+                        self._typed_latent(mp, n, pos, g).reshape(
+                            cw // bs, bs, r))
+                    qi, ki, wi = self._index_inputs(mp["index"], n, cq, pos)
+                    index = self._chunk_index(
+                        index, j, chunk_blocks, ki.reshape(cw // bs, bs, -1))
+                    scores = dsa_ops.chunk_scores(
+                        qi, wi, index.reshape(n_mla * nb, bs, -1),
+                        table_row + j * nb, start,
+                        width=table_row.shape[0] * bs)
+                    ctx = mla_ops.mla_masked_prefill_attention(
+                        q, latent.reshape(flat), table_row + j * nb, start,
+                        mp["wkvb"].reshape(g.rank, g.heads, -1),
+                        self._select(scores, c.index_topk, start + cw),
+                        rank=g.rank, nope=g.nope, pe=g.pe, v_dim=g.v,
+                        scale=g.scale, impl=attention)
+                    h = h + self._typed_out(mp, n, ctx)
+            elif i in window_at:
+                j, mp = window_at[i], lp["mla"]
+                g = c.geometry("mla_window")
+                with jax.named_scope("mla_window"):
+                    _, q = self._typed_q(mp, n, pos, g)
+                    lat = self._typed_latent(mp, n, pos, g)
+                    ctx = mla_ops.mla_window_prefill_attention(
+                        q, lat, rings[j, slot], start,
+                        mp["wkvb"].reshape(g.rank, g.heads, -1),
+                        window=self._window(), rank=g.rank, nope=g.nope,
+                        pe=g.pe, v_dim=g.v, scale=g.scale)
+                    rings = rings.at[j, slot].set(mla_ops.ring_after_chunk(
+                        rings[j, slot], lat, start, n_valid))
+                    h = h + self._typed_out(mp, n, ctx)
+            elif i in kda_at:
                 j, kp = kda_at[i], lp["kda"]
                 with jax.named_scope("kda"):
                     y, tail = kda_ops.causal_conv(
@@ -706,12 +1035,23 @@ class BlockDecoder(DefaultRulesMixin):
                 h, last, 1), with_logits)
         if with_logits:
             ids = lax.dynamic_slice_in_dim(ids, last, 1)
-        out = {"ids": ids, "cache_latent": latent,
-               "cache_state": s_all, "cache_conv": conv_all,
-               "expert_rows": expert_rows}
+        out = {"ids": ids, "expert_rows": expert_rows,
+               **self._state_out(latent, s_all, conv_all, index, rings)}
         if with_logits:
             out["logits"] = logits
         return out
+
+    @staticmethod
+    def _state_out(latent, s_all, conv_all, index, rings) -> dict:
+        arrays = {"cache_latent": latent, "cache_state": s_all,
+                  "cache_conv": conv_all, "cache_index": index,
+                  "cache_window": rings}
+        return {k: v for k, v in arrays.items() if v is not None}
+
+    def _window(self) -> int:
+        """Rows a sliding layer sees (a method so that a planted fault
+        can widen it)."""
+        return self.cfg.window
 
     def decode_step(self, params, state, block_tables, tok, pos, alive, *,
                     attention: str = "auto", with_logits: bool = False):
@@ -729,9 +1069,9 @@ class BlockDecoder(DefaultRulesMixin):
         numbers of :meth:`block_step`."""
         c = self.cfg
         s = tok.shape[0]
-        latent, s_all, conv_all = (state["cache_latent"],
-                                   state["cache_state"],
-                                   state["cache_conv"])
+        latent = state["cache_latent"]
+        s_all, conv_all = state.get("cache_state"), state.get("cache_conv")
+        index, rings = state.get("cache_index"), state.get("cache_window")
         n_mla, nb, bs, r = latent.shape
         flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
         bt = jnp.asarray(block_tables, jnp.int32)
@@ -743,11 +1083,47 @@ class BlockDecoder(DefaultRulesMixin):
         expert_rows = jnp.zeros((), jnp.int32)
         fullest = jnp.zeros((), jnp.int32)
         kda_at, mla_at = c.state_rows()
+        sparse_at, window_at = (c.rows_of("mla_sparse"),
+                                c.rows_of("mla_window"))
         scale = (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
         for i in range(c.layers):
             lp = params["layers"][str(i)]
             n = _rms(h, lp["attn_norm"], c.norm_eps)
-            if i in kda_at:
+            if i in sparse_at:
+                j, mp = sparse_at[i], lp["mla"]
+                g = c.geometry("mla_sparse")
+                with jax.named_scope("mla_sparse"):
+                    cq, q = self._typed_q(mp, n, pos, g)
+                    latent = latent.at[j, pbid, off].set(
+                        self._typed_latent(mp, n, pos, g))
+                    qi, ki, wi = self._index_inputs(mp["index"], n, cq, pos)
+                    index = index.at[j, pbid, off].set(ki)
+                    at, chosen = self._select_rows(dsa_ops.step_scores(
+                        qi, wi, index.reshape(n_mla * nb, bs, -1),
+                        bt + j * nb, pos), c.index_topk)
+                    q_abs, w_vb = self._absorb(mp, q, g)
+                    ctx = mla_ops.mla_gathered_attention(
+                        q_abs, latent.reshape(flat),
+                        block_tables=bt + j * nb, positions=at,
+                        chosen=chosen, rank=g.rank)
+                    h = h + self._typed_out(mp, n, self._unabsorb(ctx, w_vb))
+            elif i in window_at:
+                j, mp = window_at[i], lp["mla"]
+                g = c.geometry("mla_window")
+                with jax.named_scope("mla_window"):
+                    _, q = self._typed_q(mp, n, pos, g)
+                    at = pos % c.ring
+                    mine = jnp.arange(s)
+                    # a row that is not alive keeps its ring to the bit
+                    rings = rings.at[j, mine, at].set(jnp.where(
+                        live[:, None], self._typed_latent(mp, n, pos, g),
+                        rings[j, mine, at]))
+                    q_abs, w_vb = self._absorb(mp, q, g)
+                    ctx = mla_ops.mla_window_decode_attention(
+                        q_abs, rings[j], pos, window=self._window(),
+                        rank=g.rank)
+                    h = h + self._typed_out(mp, n, self._unabsorb(ctx, w_vb))
+            elif i in kda_at:
                 j, kp = kda_at[i], lp["kda"]
                 with jax.named_scope("kda"):
                     xx = jnp.concatenate(
@@ -798,10 +1174,9 @@ class BlockDecoder(DefaultRulesMixin):
                 fullest = jnp.maximum(fullest, jnp.max(rows))
         ids, logits = self._greedy(params, h, with_logits)
         mean_load = s * c.experts_per_token / c.experts
-        out = {"ids": ids, "cache_latent": latent,
-               "cache_state": s_all, "cache_conv": conv_all,
-               "expert_rows": expert_rows,
-               "max_expert_load": fullest.astype(jnp.float32) / mean_load}
+        out = {"ids": ids, "expert_rows": expert_rows,
+               "max_expert_load": fullest.astype(jnp.float32) / mean_load,
+               **self._state_out(latent, s_all, conv_all, index, rings)}
         if with_logits:
             out["logits"] = logits
         return out
@@ -835,4 +1210,18 @@ def _make_kimi_linear(config: TrainConfig) -> BlockDecoder:
 def _make_kimi_linear_tiny(config: TrainConfig) -> BlockDecoder:
     model = _make(config, DecoderBlockConfig.kimi_linear_tiny())
     model.name = "kimi_linear"
+    return model
+
+
+@register_model("dots3_note")
+def _make_dots3_note(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.dots3_note_prev())
+    model.name = "dots3_note"
+    return model
+
+
+@register_model("dots3_note_tiny")
+def _make_dots3_note_tiny(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.dots3_note_tiny())
+    model.name = "dots3_note"
     return model

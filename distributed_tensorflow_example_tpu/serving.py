@@ -348,10 +348,10 @@ def export_generator(model, params, out_dir: str, *,
     ``models/decoder.py``) exports the paged pair ``prefill.stablehlo``
     + ``block_step.stablehlo`` and no monolithic program: see
     :func:`_export_block_generator`. A model with a kind a layer and
-    per-request recurrent state (``cfg.linear_attn``) exports
+    per-request state (``cfg.stateful``) exports
     ``prefill_chunk.stablehlo`` + ``decode.stablehlo`` over the state
     its ``state_specs`` name: see :func:`_export_state_generator`."""
-    if hasattr(model, "decode_step") and model.cfg.linear_attn:
+    if hasattr(model, "decode_step") and model.cfg.stateful:
         refused = {"spec_tokens": spec_tokens, "weight_quant": weight_quant,
                    "temperature": temperature, "top_k": top_k,
                    "top_p": top_p,
@@ -1052,7 +1052,7 @@ def _export_state_generator(model, params, out_dir: str, *,
                             eos_id, pad_id: int,
                             platforms: Sequence[str]) -> str:
     """The artifact of a decoder with a kind a layer (``models/decoder.py``,
-    ``linear_attn``): ``prefill_chunk.stablehlo`` (``prefill_chunk``
+    ``linear_attn`` or ``layer_types``): ``prefill_chunk.stablehlo`` (``prefill_chunk``
     tokens of one prompt from the state the chunks before left) and
     ``decode.stablehlo`` (one token of every slot), greedy ids out of
     both, never logits. No monolithic program and no whole-prompt
@@ -1061,9 +1061,11 @@ def _export_state_generator(model, params, out_dir: str, *,
     What the server keeps between dispatches is what the model's
     ``state_specs`` names, by layer kind, recorded under
     ``stepwise.state``: ``per: "block"`` arrays lie behind the block
-    tables (the latent pool ``[L_mla, N, Bs, R]``), ``per: "slot"`` arrays
+    tables (the latent pool ``[L_mla, N, Bs, R]``; the index-key pool
+    ``[L_full, N, Bs, D]`` of a model that selects), ``per: "slot"`` arrays
     hold one row a slot (the recurrent state ``[L_kda, slots, H, d, d]``
-    float32 and the convolutions' tails). All of them are the donated
+    float32 and the convolutions' tails; a window layer's ring
+    ``[L_win, slots, ring, R]``). All of them are the donated
     ``cache_*`` operands of both programs, updated in place. Weights as in
     :func:`_export_block_generator`."""
     c = model.cfg
@@ -1084,9 +1086,13 @@ def _export_state_generator(model, params, out_dir: str, *,
     prompt_blocks = -(-prompt_len // prefill_chunk) * (
         prefill_chunk // block_size)
     cache_dtype = np.dtype(jnp.dtype(model.dtype))
-    n_latent = len(c.layers_of("mla"))
-    block_bytes = (n_latent * block_size * c.latent_row
-                   * int(cache_dtype.itemsize))
+    # what one block of a request costs: its rows of every paged array
+    block_bytes = sum(
+        int(np.prod([v["shape"][0], *v["shape"][2:]]))
+        * np.dtype(v["dtype"]).itemsize
+        for v in model.state_specs(slots=slots, num_blocks=1,
+                                   block_size=block_size).values()
+        if v["per"] == "block")
     if pool_bytes is not None and num_blocks is not None:
         raise ValueError("pass pool_bytes OR num_blocks, not both")
     if pool_bytes is not None:
@@ -1174,6 +1180,10 @@ def _export_state_generator(model, params, out_dir: str, *,
                       "experts_per_token": int(c.experts_per_token),
                       "vocab_held": int(c.vocab),
                       "first_vocab": int(c.first_vocab),
+                      # rows a selecting layer attends to, rows a window
+                      # layer sees (0: the model has no such layer)
+                      "index_topk": int(c.index_topk),
+                      "window": int(c.window),
                       "moe_tiles": moe_tiles},
         },
     }

@@ -1743,9 +1743,45 @@ class GenerationEngine:
             "serving_latent_pool_bytes",
             "bytes of the paged latent pool of a per-request-state "
             "artifact (0 otherwise)")
-        self._g_latent_pool_bytes.set(sum(
-            int(v.nbytes) for k, v in self._pool.items()
-            if k not in per_slot) if self.state else 0)
+        self._g_latent_pool_bytes.set(
+            int(self._pool["cache_latent"].nbytes) if self.state else 0)
+        self._g_index_pool_bytes = reg.gauge(
+            "serving_index_pool_bytes",
+            "bytes of the paged index-key pool of an artifact whose "
+            "full-attention layers select their rows (0 otherwise)")
+        self._g_window_cache_bytes = reg.gauge(
+            "serving_window_cache_bytes",
+            "bytes of the window layers' rings, every slot's (0 for "
+            "artifacts without window layers)")
+        for gauge, name in ((self._g_index_pool_bytes, "cache_index"),
+                            (self._g_window_cache_bytes, "cache_window")):
+            gauge.set(int(self._pool[name].nbytes)
+                      if name in self._pool else 0)
+        #: bytes of each array the engine holds for the artifact
+        self._pool_bytes = {k: int(v.nbytes) for k, v in self._pool.items()}
+        #: a selecting artifact (``state.index_topk`` > 0): per layer
+        #: kind, the layers and the bytes of one stored row, from which
+        #: the chunk and decode spans say what the live contexts held,
+        #: what was selected of it and what the window layers read
+        self._dsa: dict | None = None
+        if self.state and self.state.get("index_topk"):
+            def of(name):
+                a = self._pool[name]
+                return (int(a.shape[0]),
+                        int(a.shape[-1]) * a.dtype.itemsize)
+            self._dsa = {"top_k": int(self.state["index_topk"]),
+                         "window": int(self.state["window"]),
+                         "latent": of("cache_latent"),
+                         "index": of("cache_index"),
+                         "ring": of("cache_window")}
+        self._c_dsa_selected = reg.counter(
+            "serving_dsa_selected_rows_total",
+            "rows the full-attention layers attended to after selection, "
+            "summed over rows dispatched and layers: min(context, top-k)")
+        self._c_dsa_context = reg.counter(
+            "serving_dsa_context_rows_total",
+            "rows the same dispatches' contexts held (the same sum "
+            "without the min): what attention without a selection reads")
         self._g_kv_bytes_per_token = reg.gauge(
             "serving_kv_cache_bytes_per_token",
             "bytes one cached token occupies at the artifact's "
@@ -2790,6 +2826,8 @@ class GenerationEngine:
                       lane=f"slot{slot.index}",
                       request_id=req.request_id, start=start,
                       chunk_tokens=n, tokens=n, prompt_tokens=p,
+                      **self._describe_selection(
+                          start + 1 + np.arange(n), start + n),
                       **req.trace):
                 faults.inject("engine.prefill",
                               detail=f"{req.request_id}@{start}")
@@ -3401,6 +3439,32 @@ class GenerationEngine:
                 ((pos[:, None] + lanes) // self.block_size + 1).sum())
         return args
 
+    def _describe_selection(self, contexts: np.ndarray, keys: int) -> dict:
+        """Span arguments of a dispatch of a selecting artifact ({} for
+        any other), and its two counters: ``contexts`` the rows each
+        dispatched row's context holds (its own among them), ``keys``
+        the index keys read (a chunk's rows share one context).
+        ``index_bytes``: index keys the full layers read;
+        ``context_rows`` / ``selected_rows``: rows the contexts hold and
+        rows attended to after selection, summed over rows and full
+        layers; ``kv_bytes``: the selected latent rows as stored;
+        ``window_bytes``: ring rows the window layers read."""
+        d = self._dsa
+        if d is None:
+            return {}
+        (n_full, lat_b), (_, idx_b), (n_win, ring_b) = (
+            d["latent"], d["index"], d["ring"])
+        context = int(contexts.sum()) * n_full
+        chosen = int(np.minimum(contexts, d["top_k"]).sum()) * n_full
+        with self.registry.atomic():
+            self._c_dsa_selected.inc(chosen)
+            self._c_dsa_context.inc(context)
+        return {"index_bytes": int(keys) * n_full * idx_b,
+                "selected_rows": chosen, "context_rows": context,
+                "kv_bytes": chosen * lat_b,
+                "window_bytes": int(np.minimum(
+                    contexts, d["window"]).sum()) * n_win * ring_b}
+
     def _describe_state_decode(self, feats: dict) -> dict:
         """A decode step's span arguments for a per-request-state
         artifact: ``kv_bytes`` the latent rows the live contexts hold,
@@ -3408,11 +3472,16 @@ class GenerationEngine:
         ``expert_rows`` the held experts that received a row in the step
         before this one (this step's routing is known when it returns)."""
         rows = int(feats["alive"].sum())
-        return {"slots": rows,
+        args = {"slots": rows,
                 "kv_bytes": int((feats["pos"] + feats["alive"]).sum())
                 * self._kv_token_bytes,
                 "state_bytes": 2 * rows * self._state_slot_bytes,
                 "expert_rows": self._expert_rows_last}
+        if self._dsa is not None:
+            # what the SELECTED rows hold replaces what the contexts hold
+            contexts = feats["pos"][feats["alive"] != 0] + 1
+            args.update(self._describe_selection(contexts, contexts.sum()))
+        return args
 
     def _fetch_state_step(self, out: dict) -> np.ndarray:
         """What such a decode step hands the host: the greedy ids
@@ -3887,10 +3956,15 @@ class GenerationEngine:
             # forward how, and the arrays the engine keeps for them
             "state": ({"mixers": self.state["mixers"],
                        "ffns": self.state["ffns"],
-                       "specs": self.state["specs"]}
+                       "specs": self.state["specs"],
+                       "bytes": self._pool_bytes}
                       if self.state else None),
             "state_bytes": c("serving_state_bytes"),
             "latent_pool_bytes": c("serving_latent_pool_bytes"),
+            "index_pool_bytes": c("serving_index_pool_bytes"),
+            "window_cache_bytes": c("serving_window_cache_bytes"),
+            "dsa_selected_rows": c("serving_dsa_selected_rows_total"),
+            "dsa_context_rows": c("serving_dsa_context_rows_total"),
             "prefill_chunk_tokens_total": c(
                 "serving_prefill_chunk_tokens_total"),
             "moe_max_expert_load_ratio": c(

@@ -310,27 +310,83 @@ def test_dropless_layer_equals_the_dense_loop(skew):
         assert int(rows[0]) > 0.9 * x.shape[0]
 
 
-def test_shares_of_the_experts_add_up_to_the_whole_layer():
-    """The guide's share test: 8 experts held as 4 shares of 2. Each
-    share routes over all 8 and computes its own experts' part; the parts
-    add up to the uncut reference's layer, and a share's part is the
-    reference's for the same share."""
+def _sigmoid_shares():
+    """dots3-note-prev's expert layer at test widths, whole and as 8
+    shares of 2 of its 16 experts: sigmoid scores, a selection bias, the
+    picked scores renormalised, ONE shared expert every share computes
+    alike (counted once). Its own plain reference."""
+    dots3 = load_module(os.path.join(ROOT, "benchmark", "reference",
+                                     "dots3-note-prev.py"))
+    sizes = json.load(open(os.path.join(
+        ROOT, "benchmark", "configs", "dots3-note-prev.json")))[
+            "rehearsal"]["sizes"]
+    k = jax.random.split(jax.random.key(2), 9)
+    t, h, e, f = 40, sizes["hidden_size"], 16, sizes["moe_intermediate_size"]
+    x = jax.random.normal(k[0], (t, h))
+    mp = {"router": jax.random.normal(k[1], (h, e)) * 0.2,
+          "router_bias": jax.random.normal(k[2], (e,)) * 0.02,
+          "gate": jax.random.normal(k[3], (e, h, f)) * 0.2,
+          "up": jax.random.normal(k[4], (e, h, f)) * 0.2,
+          "down": jax.random.normal(k[5], (e, f, h)) * 0.2,
+          "shared": {"gate": jax.random.normal(k[6], (h, f)) * 0.2,
+                     "up": jax.random.normal(k[7], (h, f)) * 0.2,
+                     "down": jax.random.normal(k[8], (f, h)) * 0.2}}
+
+    def z(held, first):
+        return dots3._sizes(dict(
+            sizes, n_routed_experts=held,
+            published=dict(sizes["published"], n_routed_experts=e),
+            share=dict(sizes["share"], first_expert=first)))
+
+    shared = dots3.gated(mp["shared"], x, "f32")
+    whole = dots3.routed(z(e, 0), mp, x, "f32") + shared
+    kw = dict(top_k=sizes["num_experts_per_tok"], scores="sigmoid",
+              select_bias=mp["router_bias"],
+              scale=sizes["routed_scaling_factor"])
+
+    def held(first):
+        return {n: (v[first:first + 2] if n in ("gate", "up", "down") else v)
+                for n, v in mp.items()}
+
+    return x, whole, shared, 8, held, kw, (
+        lambda first: dots3.routed(z(2, first), held(first), x, "f32"))
+
+
+def _softmax_shares():
+    """SDAR's: 8 experts held as 4 shares of 2, softmax scores."""
     x, router, experts, cfg = _moe_case(0.3)
     whole = ref.experts(ref._sizes(cfg), {"router": router, **experts}, x,
                         "f32")
-    total = 0.0
-    for s in range(4):
-        held = {n: v[2 * s:2 * s + 2] for n, v in experts.items()}
-        part, rows = moe_dropless(x, router, held, top_k=2,
-                                  first_expert=2 * s, dtype=jnp.float32)
-        share_cfg = dict(cfg, experts_held=2, first_expert=2 * s)
-        np.testing.assert_allclose(
-            part, ref.experts(ref._sizes(share_cfg),
-                              {"router": router, **held}, x, "f32"),
-            rtol=1e-5, atol=1e-5)
+
+    def held(first):
+        return {"router": router,
+                **{n: v[first:first + 2] for n, v in experts.items()}}
+
+    return x, whole, 0.0, 4, held, dict(top_k=2), (
+        lambda first: ref.experts(
+            ref._sizes(dict(cfg, experts_held=2, first_expert=first)),
+            held(first), x, "f32"))
+
+
+@pytest.mark.parametrize("case", [_softmax_shares, _sigmoid_shares],
+                         ids=["softmax_4_shares", "sigmoid_8_shares"])
+def test_shares_of_the_experts_add_up_to_the_whole_layer(case):
+    """The guide's share test. Each share routes over all the experts and
+    computes its own experts' part; the parts, with what every share
+    computes alike (a shared expert) counted once, add up to the uncut
+    reference's layer, and a share's part is the reference's for the same
+    share."""
+    x, whole, alike, shares, held, kw, ref_part = case()
+    total = alike
+    for s in range(shares):
+        mp = held(2 * s)
+        part, rows = moe_dropless(x, mp["router"], mp, first_expert=2 * s,
+                                  dtype=jnp.float32, **kw)
+        np.testing.assert_allclose(part, ref_part(2 * s), rtol=1e-5,
+                                   atol=1e-5)
         assert rows.shape == (2,)
         total = total + part
-    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(total, whole, rtol=1e-5, atol=2e-5)
 
 
 # ---- (b') the grouped matmuls' tile ---------------------------------------

@@ -550,3 +550,94 @@ def test_state_programs_update_their_state_where_it_lies(chip, name):
         re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
     assert aliased == set(range(len(state))), aliased
     assert compiled.memory_analysis().temp_size_in_bytes < 512e6
+
+
+# ---------------------------------------------------------------------------
+# a learned sparse selection: the chunk program and the one-token step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["decode", "prefill_chunk"])
+def test_selecting_programs_keep_their_three_caches_where_they_lie(chip,
+                                                                   name):
+    """``dots3_note``'s two served programs at the ``dots3-serve-longctx``
+    cell's shape (5 layers, 32 held experts, an eighth of the vocabulary;
+    24 slots, 6,145 blocks of 128 tokens, 1,024-token chunks over up to
+    32,768 rows), the state donated: (a) the latent pool, the index-key
+    pool and the window rings are aliased to outputs and no ``copy`` is a
+    tenth of the latent pool; (b) no ``[rows, 64 heads, context]`` score
+    tensor and no sort of the chunk's ``[1024, 32768]`` score matrix (the
+    chunk finds its k-th largest by bisection; the step sorts one row a
+    slot); (c) temporaries under 1 GB beside 8.17 GB of weights; (d) the
+    chunk's selected attention is the Pallas kernel, and no ``[128 heads,
+    1024, 512]`` score tile is written to memory."""
+    from distributed_tensorflow_example_tpu.config import TrainConfig
+    from distributed_tensorflow_example_tpu.models import get_model
+    mla_mod = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.mla")
+    dev = chip[0]
+    model = get_model("dots3_note", TrainConfig(
+        model="dots3_note", dtype="bfloat16", param_dtype="bfloat16",
+        num_layers=5))
+    model.cfg.experts_held, model.cfg.vocab_held = 32, 19008
+    slots, bs, chunk, prompt, new = 24, 128, 1024, 32256, 512
+    nb = (prompt + new) // bs
+    params = jax.tree_util.tree_map(
+        lambda x: on(dev, x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 1e9 - 8.17) < 0.05
+    specs = model.state_specs(slots=slots, num_blocks=1 + slots * nb,
+                              block_size=bs)
+    state = {k: on(dev, tuple(v["shape"]), jnp.dtype(v["dtype"]))
+             for k, v in specs.items()}
+    i32 = functools.partial(on, dev, dtype=jnp.int32)
+    if name == "decode":
+        fn = lambda st, p, bt, tok, pos, alive: model.decode_step(  # noqa: E731
+            p, st, bt, tok, pos, alive)
+        args = (i32((slots, nb)), i32((slots,)), i32((slots,)),
+                i32((slots,)))
+    else:
+        fn = lambda st, p, ids, n, start, slot, row, cb: (  # noqa: E731
+            model.prefill_chunk(p, st, ids, n, start, slot, row, cb,
+                                attention="pallas"))
+        args = (i32((1, chunk)), i32(()), i32(()), i32(()),
+                i32((-(-prompt // chunk) * chunk // bs,)),
+                i32((chunk // bs,)))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(mla_mod, "_interpret", lambda: False)
+    try:
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            state, params, *args).compile()
+    finally:
+        mp.undo()
+    text = compiled.as_text()
+    if name == "prefill_chunk":
+        assert "dsa_selected_attn" in text and "tpu_custom_call" in text
+        assert not re.search(r"f32\[128,1024,512\]", text)
+    pool = int(np.prod(specs["cache_latent"]["shape"])) * 2
+    big = [m.group(0) for m in re.finditer(
+               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+               [int(x) for x in m.group(2).split(",")]) >= pool // 10]
+    assert not big, big
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    assert aliased == set(range(len(state))), aliased
+    width = prompt + new
+    # what is written to memory: the instructions outside the fusions'
+    # own computations (inside one, a value never leaves the core)
+    kept, fused = [], False
+    for ln in text.splitlines():
+        fused = (fused or ln.startswith("%fused_computation")) and ln != "}"
+        if not fused:
+            kept.append(ln)
+    assert not re.search(rf"\[(?:1024|{slots}),64,{width}\]",
+                         "\n".join(kept))
+    sorts = [re.search(r"\[([\d,]+)\]", ln.split(" = ", 1)[1]).group(1)
+             for ln in text.splitlines() if " sort(" in ln and " = " in ln]
+    assert all(s != f"1024,{width}" for s in sorts), sorts
+    if name == "decode":
+        assert f"{slots},{width}" in sorts      # one row a slot
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
