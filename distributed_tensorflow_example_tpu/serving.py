@@ -511,6 +511,14 @@ def export_generator(model, params, out_dir: str, *,
                            **extra_meta)
 
 
+def _greedy_ids(logits):
+    """Each row's greedy id (``[rows, V]`` -> int32 ``[rows]``, first
+    index on ties, as ``np.argmax``): what a one-token program hands
+    the host beside its logits, which then stay on the device unless a
+    live row samples (``serving_batch.GenerationEngine``)."""
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
 def _trace_and_write_stepwise(out_dir: str, prefill_fn, decode_fn,
                               prefill_specs: dict, decode_specs: dict,
                               platforms: Sequence[str],
@@ -532,23 +540,31 @@ def _trace_and_write_stepwise(out_dir: str, prefill_fn, decode_fn,
         programs.append((_VERIFY, verify_fn, verify_specs))
     if chunk_fn is not None:
         programs.append((_PREFILL_CHUNK, chunk_fn, chunk_specs))
-    exported, attn_schedule = [], {}
+    exported, attn_schedule, returns = [], {}, {}
     for name, fn, specs in programs:
         # what each program's paged decode attention was traced with
         # (the kernel's schedule, or the XLA gather): fixed once compiled
         with schedule_log() as seen:
-            exported.append((name, jax_export.export(
-                jax.jit(fn), platforms=list(platforms))(specs)))
+            exp = jax_export.export(
+                jax.jit(fn), platforms=list(platforms))(specs)
+        exported.append((name, exp))
+        program = name.removesuffix(".stablehlo")
         if seen:
-            attn_schedule[name.removesuffix(".stablehlo")] = (
-                seen[0] if len(seen) == 1 else seen)
+            attn_schedule[program] = seen[0] if len(seen) == 1 else seen
+        # what the program hands the host beside the pool it carries:
+        # the engine fetches ``ids`` where a program names them
+        returns[program] = sorted(
+            k for k in jax.tree_util.tree_unflatten(
+                exp.out_tree, exp.out_avals)
+            if not k.startswith("cache_"))
     if jax.process_index() == 0:
         os.makedirs(out_dir, exist_ok=True)
         for name, exp in exported:
             with open(os.path.join(out_dir, name), "wb") as f:
                 f.write(exp.serialize())
+    extra_meta["decode"] = {"returns": returns}
     if attn_schedule:
-        extra_meta["decode"] = {"attn_schedule": attn_schedule}
+        extra_meta["decode"]["attn_schedule"] = attn_schedule
     return {**base_meta, **extra_meta}
 
 
@@ -613,7 +629,8 @@ def _export_stepwise(model, params, out_dir: str, *, prompt_len: int,
         cv = jax.lax.dynamic_update_slice(
             feats["cache_v"], kv["v"].astype(feats["cache_v"].dtype),
             (0, slot, 0, 0, 0))
-        return {"logits": model.lm_logits(params, last_h[:, None])[:, 0],
+        logits = model.lm_logits(params, last_h[:, None])[:, 0]
+        return {"logits": logits, "ids": _greedy_ids(logits),
                 "pad": pad, "cache_k": ck, "cache_v": cv}
 
     stacked = model.stack_decode_params(params, weight_quant=weight_quant)
@@ -624,8 +641,8 @@ def _export_stepwise(model, params, out_dir: str, *, prompt_len: int,
             {"k": feats["cache_k"], "v": feats["cache_v"]},
             feats["tok"], feats["pos"], feats["pad"], feats["alive"],
             decode_attention=decode_attention)
-        return {"logits": logits, "cache_k": new["k"],
-                "cache_v": new["v"]}
+        return {"logits": logits, "ids": _greedy_ids(logits),
+                "cache_k": new["k"], "cache_v": new["v"]}
 
     pool_specs = {
         "cache_k": jax.ShapeDtypeStruct(pool_shape, cache_dtype),
@@ -717,12 +734,14 @@ def _export_stepwise_paged(model, params, out_dir: str, *,
                 feats["cache_k"], feats["cache_v"], feats["table_row"],
                 k_scale=feats["cache_k_scale"],
                 v_scale=feats["cache_v_scale"])
-            return {"logits": logits, "cache_k": ck, "cache_v": cv,
+            return {"logits": logits, "ids": _greedy_ids(logits),
+                    "cache_k": ck, "cache_v": cv,
                     "cache_k_scale": cks, "cache_v_scale": cvs}
         logits, ck, cv = model.paged_prefill(
             params, feats["input_ids"], feats["prompt_mask"],
             feats["cache_k"], feats["cache_v"], feats["table_row"])
-        return {"logits": logits, "cache_k": ck, "cache_v": cv}
+        return {"logits": logits, "ids": _greedy_ids(logits),
+                "cache_k": ck, "cache_v": cv}
 
     stacked = model.stack_decode_params(params, weight_quant=weight_quant)
 
@@ -736,8 +755,8 @@ def _export_stepwise_paged(model, params, out_dir: str, *,
             feats["block_tables"], feats["tok"], feats["pos"],
             feats["pad"], feats["alive"],
             decode_attention=decode_attention)
-        out = {"logits": logits, "cache_k": new["k"],
-               "cache_v": new["v"]}
+        out = {"logits": logits, "ids": _greedy_ids(logits),
+               "cache_k": new["k"], "cache_v": new["v"]}
         if kv_quant:
             out.update({"cache_k_scale": new["k_scale"],
                         "cache_v_scale": new["v_scale"]})
@@ -794,8 +813,8 @@ def _export_stepwise_paged(model, params, out_dir: str, *,
                 params, feats["input_ids"], feats["chunk_mask"],
                 feats["start"], feats["cache_k"], feats["cache_v"],
                 feats["table_row"], feats["chunk_blocks"], **scales)
-            res = {"logits": out[0], "cache_k": out[1],
-                   "cache_v": out[2]}
+            res = {"logits": out[0], "ids": _greedy_ids(out[0]),
+                   "cache_k": out[1], "cache_v": out[2]}
             if kv_quant:
                 res.update({"cache_k_scale": out[3],
                             "cache_v_scale": out[4]})
@@ -1367,6 +1386,11 @@ class StepwiseGenerator:
         #: (``ops.pallas.decode_attention.schedule_log``), by program
         self.attn_schedule: dict = (step_meta.get("decode") or {}).get(
             "attn_schedule", {})
+        #: by program, the names of what it hands the host beside the
+        #: pool ({} for an artifact exported before this was recorded:
+        #: its steps hand logits, and are served so)
+        self.returns: dict = (step_meta.get("decode") or {}).get(
+            "returns", {})
         #: the one loaded parameter tree of a weights-as-arguments
         #: artifact (``weights: "checkpoint"``), which every program
         #: takes, never donated; None where the weights are baked

@@ -947,6 +947,12 @@ def _fetch_logits(out: dict) -> np.ndarray:
     return np.asarray(out["logits"])
 
 
+def _fetch_ids(out: dict) -> np.ndarray:
+    """Each slot's greedy id (256 bytes at 64 slots): the logits beside
+    it never leave the device."""
+    return np.asarray(out["ids"])
+
+
 def _fetch_block(out: dict) -> tuple:
     """What a block step hands the host: ids and confidences [slots, B]
     and the two routing scalars, never logits."""
@@ -1473,6 +1479,20 @@ class GenerationEngine:
             "bytes of K and V the live rows held, summed over shared "
             "decode/verify dispatches (sum of pos x bytes per token): "
             "the least the decode-attention kernels had to read")
+        self._c_decode_ids_steps = reg.counter(
+            "serving_decode_ids_steps_total",
+            "shared decode steps whose host copy was the greedy ids "
+            "alone (no live row sampled; a block step's ids and "
+            "confidences count here)")
+        self._c_decode_logits_steps = reg.counter(
+            "serving_decode_logits_steps_total",
+            "shared decode steps whose logits were fetched (a live row "
+            "samples at temperature > 0, or the artifact's step "
+            "returns no ids)")
+        self._c_decode_host_bytes = reg.counter(
+            "serving_decode_host_bytes_total",
+            "bytes the shared decode/verify/block dispatches' results "
+            "brought to the host (ids or logits; the pool stays)")
         # the compilations this engine's scheduler thread asks for
         # (counted from _loop's first line on; here so that /metrics
         # shows them at zero before that)
@@ -1730,6 +1750,9 @@ class GenerationEngine:
         self._kv_token_bytes = sum(
             int(v.nbytes) // (int(v.shape[1]) * int(v.shape[2]))
             for k, v in self._pool.items() if k not in per_slot)
+        #: values in one row of a step's float32 logits (what a logits
+        #: step brings to the host a lane, x 4 bytes)
+        self._vocab = int(m.get("vocab_size", 0))
         #: bytes of recurrent rows one slot holds (0 without any): what
         #: a decode step reads and writes of them a live row
         self._state_slot_bytes = sum(
@@ -1898,7 +1921,7 @@ class GenerationEngine:
                 "greedily: its block step returns each lane's argmax "
                 f"and confidence, no logits (temperature "
                 f"{req.temperature})")
-        vocab = int(self.sw.step_meta.get("vocab_size", 0))
+        vocab = self._vocab
         if req.top_k < 0 or (vocab and req.top_k > vocab):
             raise ValueError(f"top_k must be in [0, vocab_size={vocab}],"
                              f" got {req.top_k}")
@@ -2606,7 +2629,7 @@ class GenerationEngine:
             # and self._pool must still name the donated (now deleted)
             # inputs so _pool_alive() escalates to the engine-fatal
             # rebuild instead of quarantining over a poisoned pool
-            logits0 = np.asarray(out["logits"])[0]
+            tok0, logits0 = self._fetch_first(req, out, "prefill")
             pad0 = int(np.asarray(out["pad"])[0])
             self._pool = {k: v for k, v in out.items()
                           if k.startswith("cache_")}
@@ -2618,8 +2641,8 @@ class GenerationEngine:
                      pos=self.prompt_len, rng=req.sampler(),
                      seq=self._admit_counter)
         slot.t_prefill_done = time.perf_counter()
-        tok = self._pick(slot, logits0)
-        self._emit(slot, tok)
+        self._emit(slot, tok0 if logits0 is None
+                   else self._pick(slot, logits0))
 
     @scheduler_thread
     def _admit_paged(self, req: GenRequest, index: int) -> bool:
@@ -2739,9 +2762,8 @@ class GenerationEngine:
                     # no first token: the prompt's whole blocks are in
                     # the pool, its remainder opens the first block
                     out["cache_k"].block_until_ready()
-                    logits0 = None
                 else:
-                    logits0 = np.asarray(out["logits"])[0]
+                    tok0, logits0 = self._fetch_first(req, out, "prefill")
                 self._pool = {k: v for k, v in out.items()
                               if k.startswith("cache_")}
         except Exception:
@@ -2770,8 +2792,8 @@ class GenerationEngine:
                             self._lanes, int(self.block["mask_id"]))
             self._live[index] = slot
             return True
-        tok = self._pick(slot, logits0)
-        self._emit(slot, tok)
+        self._emit(slot, tok0 if logits0 is None
+                   else self._pick(slot, logits0))
         return True
 
     @scheduler_thread
@@ -2847,10 +2869,8 @@ class GenerationEngine:
                 # _admit_slab convention): an async device fault must
                 # leave self._pool naming the donated inputs so
                 # _pool_alive() escalates correctly
-                if self.state:
-                    tok0 = int(np.asarray(out["ids"])[0])
-                else:
-                    logits0 = np.asarray(out["logits"])[0]
+                tok0, logits0 = self._fetch_first(
+                    req, out, "prefill_chunk")
                 self._pool = {k: v for k, v in out.items()
                               if k.startswith("cache_")}
         except Exception as e:
@@ -2883,8 +2903,8 @@ class GenerationEngine:
         if self.prefix_cache is not None:
             self.prefix_cache.insert(
                 tokens, [int(b) for b in row[:needed]])
-        tok = tok0 if self.state else self._pick(slot, logits0)
-        self._emit(slot, tok)
+        self._emit(slot, tok0 if logits0 is None
+                   else self._pick(slot, logits0))
         with self._cond:
             self._g_live_slots.set(len(self._live))
 
@@ -3106,6 +3126,36 @@ class GenerationEngine:
             if pb:
                 self.blocks.release([pb])
                 row[bi] = 0
+
+    def _hands_ids(self, program: str) -> bool:
+        """Whether ``program``'s outputs hold each row's greedy id: a
+        per-request-state artifact's hold nothing else, a GPT-2
+        artifact's where ``export.json`` says so
+        (``stepwise.decode.returns``; one exported before they did is
+        served from its logits, as it always was)."""
+        return bool(self.state) \
+            or "ids" in self.sw.returns.get(program, ())
+
+    def _fetch_first(self, req: GenRequest, out: dict,
+                     program: str) -> tuple:
+        """The host's copy of what a prompt's last prefill dispatch
+        hands over for the request's first token, ``(id, None)`` or
+        ``(None, logits [V])``: the decode step's rule
+        (:meth:`_needs_logits`) for the one row of ``program``."""
+        if req.temperature <= 0.0 and self._hands_ids(program):
+            return int(np.asarray(out["ids"])[0]), None
+        return None, np.asarray(out["logits"])[0]
+
+    @scheduler_thread
+    def _needs_logits(self) -> bool:
+        """Whether the next one-token decode step's logits must reach
+        the host: its program returns no ids, or a live row samples from
+        this step (``temperature > 0`` and not teacher-forced: the host
+        sampler and its per-request seed keep their bytes). Read from
+        the live requests each step."""
+        return not self._hands_ids("decode") or any(
+            s.req.temperature > 0.0 and not s.forced
+            for s in self._live.values())
 
     def _pick(self, slot: _Slot, logits: np.ndarray) -> int:
         """Per-request sampling on the host side of the step boundary
@@ -3340,16 +3390,16 @@ class GenerationEngine:
         re-dispatches the survivors, whose rows are computationally
         independent — their greedy bytes match an undisturbed run.
         Bounded: at most one retry plus one eviction per remaining
-        live slot. Returns the logits, or None when eviction emptied
-        the batch. A pool-consuming failure re-raises into the
+        live slot. Returns what ``fetch`` did, or None when eviction
+        emptied the batch. A pool-consuming failure re-raises into the
         engine-fatal handler. Both programs share ONE protocol and ONE
         ``engine.decode_step`` fault seam — a verify dispatch is
         quarantined exactly like a normal one (eviction releases the
         victim's whole span; survivors' drafts ride the rebuild).
         ``describe(feats)`` gives the span's arguments (``kv_bytes``
-        among them) and ``fetch(out)`` the host's copy of what the
-        dispatch returns (default: the logits); the block step passes
-        both."""
+        and ``host_bytes`` among them) and ``fetch(out)`` the host's
+        copy of what the dispatch returns (default: the logits); the
+        one-token step and the block step pass both."""
         if call is None:
             call = self.sw.decode
         if rebuild is None:
@@ -3371,7 +3421,6 @@ class GenerationEngine:
                     reg.raise_if_armed("engine.decode_step", index=idx,
                                        attempt=attempt)
                 args = describe(feats)
-                kv_bytes = args["kv_bytes"]
                 with span(span_name, process=self.process,
                           lane="scheduler", **args):
                     with self._phase(span_name="sched_dispatch"):
@@ -3384,11 +3433,12 @@ class GenerationEngine:
                     # the FAILED call's outputs alive and re-dispatch
                     # feats whose buffers were consumed
                     with self._phase(span_name="sched_wait_logits"):
-                        logits = fetch(out)
+                        got = fetch(out)
                         self._pool = {k: v for k, v in out.items()
                                       if k.startswith("cache_")}
-                self._c_decode_kv_bytes.inc(kv_bytes)
-                return logits
+                self._c_decode_kv_bytes.inc(args["kv_bytes"])
+                self._c_decode_host_bytes.inc(args["host_bytes"])
+                return got
             except Exception as e:
                 if not self._pool_alive():
                     raise          # donated pool consumed: engine-fatal
@@ -3422,21 +3472,25 @@ class GenerationEngine:
                 feats = rebuild()
                 self._c_redispatches.inc()
 
-    def _describe_decode(self, feats: dict) -> dict:
+    def _describe_decode(self, feats: dict, logits: bool = True) -> dict:
         """A decode (or verify) step's span arguments: its live rows,
-        and what they hold in K and V (a dead row's pos is 0): the
-        decode kernels' least traffic."""
+        what they hold in K and V (a dead row's pos is 0): the decode
+        kernels' least traffic, and what its result brings to the host:
+        every lane's float32 ``logits``, else an int32 id a slot."""
+        lanes = feats["tok"][0].size
         args = {"slots": int(feats["alive"].sum()),
-                "kv_bytes": int(feats["pos"].sum()) * self._kv_token_bytes}
+                "kv_bytes": int(feats["pos"].sum()) * self._kv_token_bytes,
+                "host_bytes": 4 * self.slots * (
+                    lanes * self._vocab if logits else 1)}
         if self.paged:
             # the table entries the paged kernel works on: every row of a
             # live slot (a verify step's lanes sit at consecutive pos)
             # reads the blocks up to its own pos; of slots x lanes x
             # blocks_per_slot entries a call
             pos = feats["pos"][feats["alive"] != 0]
-            lanes = np.arange(feats["tok"][0].size)
             args["kv_blocks"] = int(
-                ((pos[:, None] + lanes) // self.block_size + 1).sum())
+                ((pos[:, None] + np.arange(lanes)) // self.block_size
+                 + 1).sum())
         return args
 
     def _describe_selection(self, contexts: np.ndarray, keys: int) -> dict:
@@ -3476,7 +3530,9 @@ class GenerationEngine:
                 "kv_bytes": int((feats["pos"] + feats["alive"]).sum())
                 * self._kv_token_bytes,
                 "state_bytes": 2 * rows * self._state_slot_bytes,
-                "expert_rows": self._expert_rows_last}
+                "expert_rows": self._expert_rows_last,
+                # an id a slot and the two routing scalars
+                "host_bytes": 4 * self.slots + 8}
         if self._dsa is not None:
             # what the SELECTED rows hold replaces what the contexts hold
             contexts = feats["pos"][feats["alive"] != 0] + 1
@@ -3560,22 +3616,28 @@ class GenerationEngine:
                 feats = self._build_step_feats()
         t0 = time.perf_counter()
         if use_verify:
-            logits = self._dispatch_decode(
+            got = self._dispatch_decode(
                 feats, call=self.sw.verify,
                 rebuild=self._build_verify_feats,
                 span_name="verify_step")
         elif self.state:
-            logits = self._dispatch_decode(
+            got = self._dispatch_decode(
                 feats, describe=self._describe_state_decode,
                 fetch=self._fetch_state_step)
         else:
-            logits = self._dispatch_decode(feats)
-        if logits is None:
+            # decided once a step: should a repeat failure evict the
+            # one sampled row, the survivors' logits are fetched unread
+            logits = self._needs_logits()
+            got = self._dispatch_decode(
+                feats, describe=functools.partial(
+                    self._describe_decode, logits=logits),
+                fetch=_fetch_logits if logits else _fetch_ids)
+        if got is None:
             self._last_dispatch_t = 0.0
             return
         with self._phase(span_name="sched_sample_emit"):
             self._retry.observe(time.perf_counter() - t0)
-            self._sample_emit(logits, use_verify)
+            self._sample_emit(got, use_verify)
 
     @scheduler_thread
     def _secure_write_blocks(self) -> None:
@@ -3645,7 +3707,9 @@ class GenerationEngine:
                 "commit_rows": commits,
                 "kv_bytes": int((feats["pos"][alive] + self._lanes).sum())
                 * self._kv_token_bytes,
-                "expert_rows": self._expert_rows_last}
+                "expert_rows": self._expert_rows_last,
+                # an id and a confidence a lane, the two routing scalars
+                "host_bytes": 8 * self.slots * self._lanes + 8}
 
     @scheduler_thread
     def _block_step(self) -> None:
@@ -3723,6 +3787,7 @@ class GenerationEngine:
         with self.registry.atomic():
             self._c_block_steps.inc()
             self._c_decode_steps.inc()
+            self._c_decode_ids_steps.inc()
             self._c_decode_slot_steps.inc(len(live))
             self._c_denoise_forwards.inc(len(live) - commits)
             self._c_commit_forwards.inc(commits)
@@ -3739,15 +3804,19 @@ class GenerationEngine:
         self._last_dispatch_t = time.perf_counter() if left else 0.0
 
     @scheduler_thread
-    def _sample_emit(self, logits: np.ndarray, use_verify: bool) -> None:
+    def _sample_emit(self, got: np.ndarray, use_verify: bool) -> None:
         """After a shared dispatch: the step counters, each live row's
-        sample (or its accepted draft run) through :meth:`_emit`, and
-        the hint and stall stamps the next iteration reads."""
+        token through :meth:`_emit` (the id the step handed, or the
+        row's sample or accepted draft run from the logits it handed),
+        and the hint and stall stamps the next iteration reads."""
+        handed_ids = got.ndim == 1      # [slots], not [slots, (K,) V]
         with self.registry.atomic():
             if use_verify:
                 self._c_verify_steps.inc()
             else:
                 self._c_decode_steps.inc()
+                (self._c_decode_ids_steps if handed_ids
+                 else self._c_decode_logits_steps).inc()
                 self._c_decode_slot_steps.inc(len(self._live))
                 self._c_moe_rows.inc(len(self._live)
                                      * self._moe_pairs_a_row)
@@ -3776,7 +3845,7 @@ class GenerationEngine:
                 self.prefix_cache.insert(
                     tokens, [int(b) for b in self._tables[s.index, :nb]])
                 s.pending_insert = None
-            row_logits = logits[i]          # [V], or [K, V] on verify
+            row = got[i]            # an id, [V], or [K, V] on verify
             if s.draft:
                 # exact greedy rejection: accept the longest draft
                 # prefix matching the argmax chain, then ONE more token
@@ -3788,15 +3857,14 @@ class GenerationEngine:
                 drafts, s.draft = s.draft, []
                 emitted, acc = [], 0
                 for j, d in enumerate(drafts):
-                    a = int(np.argmax(row_logits[j]))
+                    a = int(np.argmax(row[j]))
                     if a != d:
                         emitted.append(a)
                         break
                     emitted.append(d)
                     acc += 1
                 else:
-                    emitted.append(int(np.argmax(row_logits[
-                        len(drafts)])))
+                    emitted.append(int(np.argmax(row[len(drafts)])))
                 span_end = s.pos + len(drafts)
                 s.pos += acc + 1            # the rejection rewind
                 advance += acc + 1
@@ -3822,11 +3890,8 @@ class GenerationEngine:
                 continue
             s.pos += 1
             advance += 1
-            # a per-request-state artifact's step returns the greedy
-            # id itself
-            nxt = (int(row_logits) if self.state
-                   else self._pick(s, row_logits[0] if use_verify
-                                   else row_logits))
+            nxt = (int(row) if handed_ids
+                   else self._pick(s, row[0] if use_verify else row))
             del self._live[i]           # _emit re-adds if still live
             self._emit(s, nxt)
         if rows:
@@ -3943,6 +4008,10 @@ class GenerationEngine:
                 ph: round(c(f"serving_sched_{ph}_seconds_total"), 6)
                 for ph in SCHED_PHASES},
             "decode_kv_bytes": c("serving_decode_kv_bytes_total"),
+            # shared steps by what the host fetched, and its bytes
+            "decode_ids_steps": c("serving_decode_ids_steps_total"),
+            "decode_logits_steps": c("serving_decode_logits_steps_total"),
+            "decode_host_bytes": c("serving_decode_host_bytes_total"),
             # generation by diffusion over blocks (zeros otherwise)
             "block_steps": c("serving_block_steps_total"),
             "denoise_forwards": c("serving_denoise_forwards_total"),

@@ -555,12 +555,14 @@ def _program_text(path):
 
 
 #: sha256 (16 hex digits) of each program exported at the parent commit
-#: (7da433e), weights baked: the same bytes for the same inputs
+#: (7da433e), weights baked: the same bytes for the same inputs. GPT's
+#: three one-token programs are PR 36's: they return the greedy ids
+#: beside the logits (its monolithic and verify programs are 7da433e's)
 PARENT_PROGRAMS = {
-    "gpt/decode.stablehlo": "3da4f3760480c0d0",
+    "gpt/decode.stablehlo": "c11f5b26cc8d1ff5",
     "gpt/model.stablehlo": "346d44b26d88bee3",
-    "gpt/prefill.stablehlo": "34a9f977f9af1c72",
-    "gpt/prefill_chunk.stablehlo": "6974e66d94450ad7",
+    "gpt/prefill.stablehlo": "73d6e69db488be7b",
+    "gpt/prefill_chunk.stablehlo": "95ce124047bddaa8",
     "gpt/verify.stablehlo": "f232c29410b90020",
     "sdar/block_step.stablehlo": "49e36af09163f155",
     "sdar/prefill.stablehlo": "0deeb66418599bf8",

@@ -737,7 +737,10 @@ def test_async_decode_fault_escalates_to_pool_rebuild(chaos_dir):
         out = orig(feats)          # REAL dispatch: pool donated
         if armed["on"]:
             armed["on"] = False
-            return {**out, "logits": _FailsOnRead()}
+            # whichever result the host reads (ids, or logits while a
+            # row samples) is where the fault surfaces
+            return {**out, "logits": _FailsOnRead(),
+                    "ids": _FailsOnRead()}
         return out
 
     eng.sw.decode = decode

@@ -24,6 +24,7 @@ import pytest
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from distributed_tensorflow_example_tpu import serving
 from distributed_tensorflow_example_tpu.parallel.mesh import AxisNames
 
 # the modules, not the same-named functions ops.pallas re-exports
@@ -285,8 +286,11 @@ def _served_program(model, name):
         return {k[len("cache_"):]: v for k, v in pool.items()}
 
     def pack(logits, new):
-        return {"logits": logits,
-                **{"cache_" + k: v for k, v in new.items()}}
+        out = {"logits": logits,
+               **{"cache_" + k: v for k, v in new.items()}}
+        if name != "verify":                # the host reads every lane
+            out["ids"] = serving._greedy_ids(logits)
+        return out
 
     if name in ("prefill", "prefill_chunk"):
         # (params, ids, mask[, start], k_pool, v_pool, table_row[, blocks])
@@ -311,11 +315,52 @@ def _served_program(model, name):
     return fn
 
 
+@pytest.fixture(scope="module")
+def served(chip, gpt2_small):
+    """``served(name, quant)``: that program of gpt2-small compiled for
+    the described chip at the serving cells' shape (pool donated), once
+    for the tests of this file that read it: ``(compiled, pool specs)``."""
+    dev = chip[0]
+    model, params, stacked = gpt2_small
+    row = on(dev, (CELL["slots"],), jnp.int32)
+    table_row = on(dev, (CELL["prompt_len"] // BS,), jnp.int32)
+
+    @functools.cache
+    def compile_served(name, quant):
+        pool = _pool_specs(dev, quant)
+        if name == "prefill":
+            ids = on(dev, (1, CELL["prompt_len"]), jnp.int32)
+            args = (params, ids, ids, table_row)
+        elif name == "prefill_chunk":
+            ids = on(dev, (1, BS), jnp.int32)
+            args = (params, ids, ids, on(dev, (), jnp.int32), table_row,
+                    on(dev, (1,), jnp.int32))
+        else:
+            tok = (on(dev, (CELL["slots"], 4), jnp.int32)
+                   if name == "verify" else row)
+            args = (params, stacked,
+                    on(dev, (CELL["slots"], CELL["blocks_per_slot"]),
+                       jnp.int32),
+                    tok, row, row, row) + (
+                        (row,) if name == "verify" else ())
+        return (jax.jit(_served_program(model, name), donate_argnums=0)
+                .lower(pool, *args).compile(), pool)
+    return compile_served
+
+
+def _copies_of(text, nbytes):
+    """The ``copy`` operations of a compiled program's text that move at
+    least ``nbytes``."""
+    return [m.group(0) for m in re.finditer(
+                r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+            if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+                [int(x) for x in m.group(2).split(",")]) >= nbytes]
+
+
 @pytest.mark.parametrize("name,quant", [
     ("decode", False), ("decode", True), ("verify", False),
     ("prefill", False), ("prefill", True), ("prefill_chunk", False)])
-def test_served_programs_leave_the_pool_where_it_lies(chip, gpt2_small,
-                                                      name, quant):
+def test_served_programs_leave_the_pool_where_it_lies(served, name, quant):
     """``jit_decode`` (float and int8 pools), the verify expansion at
     K = 4, ``jit_prefill`` and one 128-token chunk of a chunked prefill,
     of gpt2-small at the serving cells' shape (12 layers, 385 blocks of
@@ -325,34 +370,12 @@ def test_served_programs_leave_the_pool_where_it_lies(chip, gpt2_small,
     the scan and two whole pools after it), (b) every ``cache_*`` input
     is aliased to an output, (c) under 256 MB of temporaries (were
     3.10 GB)."""
-    dev = chip[0]
-    model, params, stacked = gpt2_small
-    pool = _pool_specs(dev, quant)
-    row = on(dev, (CELL["slots"],), jnp.int32)
-    table_row = on(dev, (CELL["prompt_len"] // BS,), jnp.int32)
-    if name == "prefill":
-        ids = on(dev, (1, CELL["prompt_len"]), jnp.int32)
-        args = (params, ids, ids, table_row)
-    elif name == "prefill_chunk":
-        ids = on(dev, (1, BS), jnp.int32)
-        args = (params, ids, ids, on(dev, (), jnp.int32), table_row,
-                on(dev, (1,), jnp.int32))
-    else:
-        tok = (on(dev, (CELL["slots"], 4), jnp.int32) if name == "verify"
-               else row)
-        args = (params, stacked,
-                on(dev, (CELL["slots"], CELL["blocks_per_slot"]), jnp.int32),
-                tok, row, row, row) + ((row,) if name == "verify" else ())
-    compiled = jax.jit(_served_program(model, name),
-                       donate_argnums=0).lower(pool, *args).compile()
+    compiled, pool = served(name, quant)
     text = compiled.as_text()
     if name != "prefill_chunk":             # (its attention is XLA's)
         assert "tpu_custom_call" in text    # the Pallas kernels
     layer_slice = CELL["blocks"] * BS * H * D * (1 if quant else 2)
-    big = [m.group(0) for m in re.finditer(
-               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
-           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
-               [int(x) for x in m.group(2).split(",")]) >= layer_slice]
+    big = _copies_of(text, layer_slice)
     assert not big, big
     if name in ("decode", "verify"):
         # the layer scan and nothing else: the benchmark's readers pair a
@@ -365,6 +388,29 @@ def test_served_programs_leave_the_pool_where_it_lies(chip, gpt2_small,
         re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
     assert aliased == set(range(len(pool))), aliased
     assert compiled.memory_analysis().temp_size_in_bytes < 256e6
+
+
+def test_decode_program_hands_ids_without_a_copy(served):
+    """``jit_decode`` at the serving cells' shape returns each slot's
+    greedy id, ``s32[64]``, beside the ``f32[64, 50257]`` logits (PR 36:
+    the host fetches 256 bytes a step, and the 12.9 MB only while a row
+    samples), and the argmax that makes it costs no ``copy`` operation of
+    the logits (none of 12.9 MB or more) nor of a pool (the test above).
+    What the compiler does do: the head writes the logits to fast memory
+    (``S(1)``), where the reduce reads them, and one asynchronous
+    ``copy-start`` / ``copy-done`` puts them in the output buffer beside
+    it: 0.011 ms a step on the chip (``benchmark/records/pr36``)."""
+    text = served("decode", False)[0].as_text()
+    entry = text[text.index("ENTRY"):]
+    result = re.search(r"ROOT [^\n]* = \(([^\n]*?)\) tuple\(", entry).group(1)
+    slots, vocab = CELL["slots"], 50257
+    assert re.search(rf"s32\[{slots}\]", result), result
+    assert re.search(rf"f32\[{slots},{vocab}\]", result), result
+    copied = _copies_of(text, 4 * slots * vocab)
+    assert not copied, copied
+    moved = [ln for ln in text.splitlines()
+             if f"f32[{slots},{vocab}]" in ln and "copy-start(" in ln]
+    assert len(moved) <= 1, moved
 
 
 # ---------------------------------------------------------------------------
