@@ -296,6 +296,9 @@ class _NoopSpan:
     def __exit__(self, *exc):
         return False
 
+    def note(self, **args):
+        pass
+
 
 _NOOP = _NoopSpan()
 
@@ -342,6 +345,15 @@ class _LiveSpan:
             self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
+
+    def note(self, **args):
+        """Arguments known only once the span is under way (which
+        launch of its program a scheduler span held): into the ring's
+        args and onto the live annotation, so a capture holds them
+        too."""
+        self._args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()

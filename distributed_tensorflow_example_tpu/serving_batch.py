@@ -398,11 +398,24 @@ _NOOP_CM = _NoopCM()
 SCHED_PHASES = ("housekeeping", "admit", "secure_blocks", "build_feats",
                 "dispatch", "wait_logits", "sample_emit")
 
+#: what ``sched_admit`` holds of an admission that runs a program, in
+#: order: the program's launch (its operands' host-to-device copies
+#: inside), every blocking read of what it returns up to the adoption
+#: of the pool, and what follows (prefix insert, counters, the first
+#: token's emit). One span each on lane ``scheduler``, named as here,
+#: and the seconds counter a phase has; what ``sched_admit`` holds
+#: outside them (queue, selection, prefix lookup, blocks, operands) is
+#: its self time and has no span
+ADMIT_CHILDREN = ("admit_launch", "admit_read", "admit_emit")
+
 
 class _Phase:
     """One scheduler phase: its span (entered inside, so the counter
     covers the span) and its seconds counter, which counts with the
-    recorder off too."""
+    recorder off too. One clock, ``time.perf_counter``: the thread's
+    CPU clock (``time.thread_time``) is a system call on the serving
+    cells' host, 6-20 us a reading in ticks of 10 ms, too dear and too
+    coarse for a phase (``benchmark/records/pr37/call4``, ``call7``)."""
 
     __slots__ = ("_counter", "_span", "_t0")
 
@@ -415,6 +428,9 @@ class _Phase:
         self._t0 = time.perf_counter()
         self._span.__enter__()
         return self
+
+    def note(self, **args):
+        self._span.note(**args)
 
     def __exit__(self, *exc):
         self._span.__exit__(*exc)
@@ -1426,12 +1442,19 @@ class GenerationEngine:
         # phase (SCHED_PHASES tile it): wait_logits is the chip's
         # share, the rest is the host's: what an operator without a
         # profiler reads to see which of the two sets the pace
+        # ADMIT_CHILDREN split the admit phase where it runs a program
+        names = {**{f"sched_{ph}": ph for ph in SCHED_PHASES},
+                 **{ch: ch for ch in ADMIT_CHILDREN}}
         self._c_phase = {
-            f"sched_{ph}": reg.counter(
+            name: reg.counter(
                 f"serving_sched_{ph}_seconds_total",
                 f"scheduler-thread seconds in phase {ph!r} of the "
-                "working iterations (the phases tile an iteration)")
-            for ph in SCHED_PHASES}
+                "working iterations (the sched_* phases tile an "
+                "iteration; the admit_* ones lie inside admit)")
+            for name, ph in names.items()}
+        #: launches of each device program so far: the ``seq`` a
+        #: launch's span carries (:meth:`_launch`)
+        self._launches: dict[str, int] = {}
         # generation by diffusion over blocks (zeros for artifacts that
         # decode one token a step)
         self._c_block_steps = reg.counter(
@@ -1456,15 +1479,6 @@ class GenerationEngine:
             "serving_moe_max_expert_load_ratio",
             "last block step: the fullest expert's rows over the mean "
             "an expert gets")
-        # chosen when a program is traced, so counted once, at load
-        for tiles in self.moe_tiles.values():
-            for tile in tiles.values():
-                reg.counter(
-                    "serving_moe_ragged_dot_tiled_"
-                    f"{tile.replace(',', 'x')}_total",
-                    "grouped matmuls a layer, over the loaded programs, "
-                    "traced with this tile m x k x n (xla: XLA's own)"
-                ).inc()
         #: (row, expert) pairs one row sends through a per-request-state
         #: artifact's expert layers
         self._moe_pairs_a_row = (
@@ -2414,13 +2428,33 @@ class GenerationEngine:
                             self.blocks, self.block_size,
                             registry=self.registry)
 
-    def _phase(self, span_name: str) -> _Phase:
+    @scheduler_thread
+    def _launch(self, program: str, call, *operands, on):
+        """Launch device program ``program``: ``call(*operands)``. The
+        one place this engine starts a program (``prefill``,
+        ``prefill_chunk``, ``decode``, ``verify``, ``block_step``,
+        ``zero_slot``, ``copy``; a capture shows each as
+        ``jit_<program>``), so that the span around the launch, ``on``,
+        says which launch it was: ``seq``, how many times this engine
+        launched ``program`` before, counted here (a re-dispatch after
+        a fault counts too: it executes). The device runs an engine's
+        programs in the order of their launches, so a reader pairs the
+        k-th ``jit_<program>`` of a capture with the k-th ``seq`` in it
+        whatever the capture's two clocks say
+        (``benchmark/readers/launch_pairs.py``)."""
+        seq = self._launches.get(program, 0)
+        self._launches[program] = seq + 1
+        on.note(program=program, seq=seq)
+        return call(*operands)
+
+    def _phase(self, span_name: str, **args) -> _Phase:
         """``with self._phase(span_name="sched_admit"):`` — the phase's
-        span on the scheduler lane and its seconds counter around one
-        block (the keyword is what graftlint TRC01 reads the name by)."""
+        span on the scheduler lane (with ``args``) and its seconds
+        counter around one block (the keyword is what graftlint TRC01
+        reads the name by)."""
         return _Phase(self._c_phase[span_name],
                       span(span_name, process=self.process,
-                           lane="scheduler"))
+                           lane="scheduler", **args))
 
     @scheduler_thread
     def _apply_cancellations(self) -> None:
@@ -2621,28 +2655,31 @@ class GenerationEngine:
                   request_id=req.request_id, prompt_tokens=p,
                   **req.trace):
             faults.inject("engine.prefill", detail=req.request_id)
-            out = self.sw.prefill({
-                "input_ids": ids, "prompt_mask": mask,
-                "slot": np.int32(index), **self._pool})
+            with self._phase(span_name="admit_launch") as launch:
+                out = self._launch("prefill", self.sw.prefill, {
+                    "input_ids": ids, "prompt_mask": mask,
+                    "slot": np.int32(index), **self._pool}, on=launch)
             # materialize BEFORE adopting the returned pool: on an
             # async backend a device-side fault surfaces at this block,
             # and self._pool must still name the donated (now deleted)
             # inputs so _pool_alive() escalates to the engine-fatal
             # rebuild instead of quarantining over a poisoned pool
-            tok0, logits0 = self._fetch_first(req, out, "prefill")
-            pad0 = int(np.asarray(out["pad"])[0])
-            self._pool = {k: v for k, v in out.items()
-                          if k.startswith("cache_")}
-        with self.registry.atomic():
-            self._c_admissions.inc()
-            self._c_prefills.inc()
-        self._admit_counter += 1
-        slot = _Slot(req, index, pad=pad0,
-                     pos=self.prompt_len, rng=req.sampler(),
-                     seq=self._admit_counter)
-        slot.t_prefill_done = time.perf_counter()
-        self._emit(slot, tok0 if logits0 is None
-                   else self._pick(slot, logits0))
+            with self._admit_read("prefill"):
+                tok0, logits0 = self._fetch_first(req, out, "prefill")
+                pad0 = int(np.asarray(out["pad"])[0])
+                self._pool = {k: v for k, v in out.items()
+                              if k.startswith("cache_")}
+        with self._phase(span_name="admit_emit"):
+            with self.registry.atomic():
+                self._c_admissions.inc()
+                self._c_prefills.inc()
+            self._admit_counter += 1
+            slot = _Slot(req, index, pad=pad0,
+                         pos=self.prompt_len, rng=req.sampler(),
+                         seq=self._admit_counter)
+            slot.t_prefill_done = time.perf_counter()
+            self._emit(slot, tok0 if logits0 is None
+                       else self._pick(slot, logits0))
 
     @scheduler_thread
     def _admit_paged(self, req: GenRequest, index: int) -> bool:
@@ -2751,21 +2788,25 @@ class GenerationEngine:
                       request_id=req.request_id, prompt_tokens=p,
                       **req.trace):
                 faults.inject("engine.prefill", detail=req.request_id)
-                out = self.sw.prefill({
-                    "input_ids": ids, "prompt_mask": mask,
-                    "table_row": table_row, **self._pool})
+                with self._phase(span_name="admit_launch") as launch:
+                    out = self._launch("prefill", self.sw.prefill, {
+                        "input_ids": ids, "prompt_mask": mask,
+                        "table_row": table_row, **self._pool}, on=launch)
                 # materialize BEFORE adopting the returned pool (see
                 # _admit_slab): an async device fault must leave
                 # self._pool naming the donated inputs so the outer
                 # handler's _pool_alive() probe escalates correctly
-                if self.block:
-                    # no first token: the prompt's whole blocks are in
-                    # the pool, its remainder opens the first block
-                    out["cache_k"].block_until_ready()
-                else:
-                    tok0, logits0 = self._fetch_first(req, out, "prefill")
-                self._pool = {k: v for k, v in out.items()
-                              if k.startswith("cache_")}
+                with self._admit_read("prefill"):
+                    if self.block:
+                        # no first token: the prompt's whole blocks are
+                        # in the pool, its remainder opens the first
+                        # block
+                        out["cache_k"].block_until_ready()
+                    else:
+                        tok0, logits0 = self._fetch_first(req, out,
+                                                          "prefill")
+                    self._pool = {k: v for k, v in out.items()
+                                  if k.startswith("cache_")}
         except Exception:
             # quarantine path (the outer _admit handler fails the
             # request): the block run allocated above must go back to
@@ -2773,34 +2814,37 @@ class GenerationEngine:
             # A pool-consuming fault still escalates there.
             self.blocks.release(run)
             raise
-        with self.registry.atomic():
-            self._c_admissions.inc()
-            self._c_prefills.inc()
+        with self._phase(span_name="admit_emit"):
+            with self.registry.atomic():
+                self._c_admissions.inc()
+                self._c_prefills.inc()
+                if self.prefix_cache is not None:
+                    self.prefix_cache.record_miss()
+            self._tables[index, :needed] = run
             if self.prefix_cache is not None:
-                self.prefix_cache.record_miss()
-        self._tables[index, :needed] = run
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(tokens, run)
-        self._admit_counter += 1
-        slot = _Slot(req, index, pad=0, pos=p, rng=req.sampler(),
-                     seq=self._admit_counter)
-        slot.drafter = self._drafter_for(req)
-        slot.t_prefill_done = time.perf_counter()
-        if self.block:
-            start = p - p % self._lanes
-            slot.open_block(start, [int(t) for t in tokens[start:]],
-                            self._lanes, int(self.block["mask_id"]))
-            self._live[index] = slot
-            return True
-        self._emit(slot, tok0 if logits0 is None
-                   else self._pick(slot, logits0))
+                self.prefix_cache.insert(tokens, run)
+            self._admit_counter += 1
+            slot = _Slot(req, index, pad=0, pos=p, rng=req.sampler(),
+                         seq=self._admit_counter)
+            slot.drafter = self._drafter_for(req)
+            slot.t_prefill_done = time.perf_counter()
+            if self.block:
+                start = p - p % self._lanes
+                slot.open_block(start, [int(t) for t in tokens[start:]],
+                                self._lanes, int(self.block["mask_id"]))
+                self._live[index] = slot
+            else:
+                self._emit(slot, tok0 if logits0 is None
+                           else self._pick(slot, logits0))
         return True
 
     @scheduler_thread
     def _zero_slot_state(self, index: int) -> None:
         """Zero slot ``index``'s rows of every ``per: "slot"`` array
         (in place; the pool is donated to the call)."""
-        self._pool = self.sw.zero_slot(self._pool, index)
+        with self._phase(span_name="admit_launch") as launch:
+            self._pool = self._launch("zero_slot", self.sw.zero_slot,
+                                      self._pool, index, on=launch)
 
     @scheduler_thread
     def _prefill_chunk_step(self) -> None:
@@ -2864,15 +2908,19 @@ class GenerationEngine:
                                  slot=np.int32(slot.index))
                 else:
                     feats["chunk_mask"] = mask
-                out = self.sw.prefill_chunk(feats)
+                with self._phase(span_name="admit_launch") as launch:
+                    out = self._launch("prefill_chunk",
+                                       self.sw.prefill_chunk, feats,
+                                       on=launch)
                 # materialize BEFORE adopting the returned pool (the
                 # _admit_slab convention): an async device fault must
                 # leave self._pool naming the donated inputs so
                 # _pool_alive() escalates correctly
-                tok0, logits0 = self._fetch_first(
-                    req, out, "prefill_chunk")
-                self._pool = {k: v for k, v in out.items()
-                              if k.startswith("cache_")}
+                with self._admit_read("prefill_chunk"):
+                    tok0, logits0 = self._fetch_first(
+                        req, out, "prefill_chunk")
+                    self._pool = {k: v for k, v in out.items()
+                                  if k.startswith("cache_")}
         except Exception as e:
             if not self._pool_alive():
                 raise          # donated pool consumed: engine-fatal
@@ -2884,29 +2932,31 @@ class GenerationEngine:
                 f"starting token {start} ({type(e).__name__}: {e}); "
                 "its neighbors were not disturbed"))
             return
-        # the SPLIT estimator: chunk wall time feeds the prefill EMA,
-        # never the decode-step EMA Retry-After reads
-        self._retry.observe_prefill(time.perf_counter() - t0)
-        with self.registry.atomic():
-            self._c_prefill_chunks.inc()
-            self._c_prefill_chunk_tokens.inc(n)
-            if self.state:
-                self._c_moe_rows.inc(n * self._moe_pairs_a_row)
-        slot.chunk_done = start + n
-        if slot.chunk_done < p:
-            return
-        # prompt fully resident: same tail as the monolithic cold path
-        slot.pos = p
-        slot.t_prefill_done = time.perf_counter()
-        del self._prefilling[slot.index]
-        self._g_prefilling_slots.set(len(self._prefilling))
-        if self.prefix_cache is not None:
-            self.prefix_cache.insert(
-                tokens, [int(b) for b in row[:needed]])
-        self._emit(slot, tok0 if logits0 is None
-                   else self._pick(slot, logits0))
-        with self._cond:
-            self._g_live_slots.set(len(self._live))
+        with self._phase(span_name="admit_emit"):
+            # the SPLIT estimator: chunk wall time feeds the prefill
+            # EMA, never the decode-step EMA Retry-After reads
+            self._retry.observe_prefill(time.perf_counter() - t0)
+            with self.registry.atomic():
+                self._c_prefill_chunks.inc()
+                self._c_prefill_chunk_tokens.inc(n)
+                if self.state:
+                    self._c_moe_rows.inc(n * self._moe_pairs_a_row)
+            slot.chunk_done = start + n
+            if slot.chunk_done < p:
+                return
+            # prompt fully resident: same tail as the monolithic cold
+            # path
+            slot.pos = p
+            slot.t_prefill_done = time.perf_counter()
+            del self._prefilling[slot.index]
+            self._g_prefilling_slots.set(len(self._prefilling))
+            if self.prefix_cache is not None:
+                self.prefix_cache.insert(
+                    tokens, [int(b) for b in row[:needed]])
+            self._emit(slot, tok0 if logits0 is None
+                       else self._pick(slot, logits0))
+            with self._cond:
+                self._g_live_slots.set(len(self._live))
 
     @scheduler_thread
     def _update_pressure(self) -> None:
@@ -3096,12 +3146,13 @@ class GenerationEngine:
                           lane="scheduler",
                           request_id=slot.req.request_id,
                           slot=slot.index, block=pb,
-                          **slot.req.trace):
+                          **slot.req.trace) as cow:
                     if self.blocks.free_count < 1 \
                             and self.prefix_cache is not None:
                         self.prefix_cache.evict(1)
                     nb = self.blocks.alloc(1)[0]
-                    self._pool = self._copy_block(self._pool, pb, nb)
+                    self._pool = self._launch("copy", self._copy_block,
+                                              self._pool, pb, nb, on=cow)
                     self._tables[slot.index, bi] = nb
                     self.blocks.release([pb])
                 self._c_cow.inc()
@@ -3145,6 +3196,13 @@ class GenerationEngine:
         if req.temperature <= 0.0 and self._hands_ids(program):
             return int(np.asarray(out["ids"])[0]), None
         return None, np.asarray(out["logits"])[0]
+
+    def _admit_read(self, program: str) -> _Phase:
+        """The ``admit_read`` phase: around every blocking read of what
+        the launch of ``program`` just made returns, up to the adoption
+        of the pool."""
+        return self._phase(span_name="admit_read", program=program,
+                           seq=self._launches[program] - 1)
 
     @scheduler_thread
     def _needs_logits(self) -> bool:
@@ -3377,11 +3435,11 @@ class GenerationEngine:
                 **self._pool}
 
     @scheduler_thread
-    def _dispatch_decode(self, feats: dict, *, call=None,
+    def _dispatch_decode(self, feats: dict, *, program: str = "decode",
                          rebuild=None,
                          span_name: str = "decode_step",
                          describe=None, fetch=None):
-        """One shared dispatch (normal decode step, or — ``call``/
+        """One shared dispatch (normal decode step, or — ``program``/
         ``rebuild`` overridden — the K-token verify program) under the
         bounded re-dispatch protocol: a first failure that left the
         donated pool intact is retried once (transient faults heal
@@ -3400,8 +3458,8 @@ class GenerationEngine:
         and ``host_bytes`` among them) and ``fetch(out)`` the host's
         copy of what the dispatch returns (default: the logits); the
         one-token step and the block step pass both."""
-        if call is None:
-            call = self.sw.decode
+        # looked up a dispatch: a test may shadow the generator's method
+        call = getattr(self.sw, program)
         if rebuild is None:
             rebuild = self._build_step_feats
         if describe is None:
@@ -3422,9 +3480,9 @@ class GenerationEngine:
                                        attempt=attempt)
                 args = describe(feats)
                 with span(span_name, process=self.process,
-                          lane="scheduler", **args):
+                          lane="scheduler", **args) as step:
                     with self._phase(span_name="sched_dispatch"):
-                        out = call(feats)
+                        out = self._launch(program, call, feats, on=step)
                     # blocks on the result BEFORE adopting the returned
                     # pool: an async device fault surfaces here, and
                     # self._pool must still name the donated (deleted)
@@ -3617,7 +3675,7 @@ class GenerationEngine:
         t0 = time.perf_counter()
         if use_verify:
             got = self._dispatch_decode(
-                feats, call=self.sw.verify,
+                feats, program="verify",
                 rebuild=self._build_verify_feats,
                 span_name="verify_step")
         elif self.state:
@@ -3728,7 +3786,7 @@ class GenerationEngine:
             feats = self._build_block_feats()
         t0 = time.perf_counter()
         got = self._dispatch_decode(
-            feats, call=self.sw.block_step,
+            feats, program="block_step",
             rebuild=self._build_block_feats, span_name="block_step",
             describe=self._describe_block, fetch=_fetch_block)
         if got is None:
@@ -4006,7 +4064,7 @@ class GenerationEngine:
             # decode steps' live rows held, and this engine's compilations
             "sched_phase_seconds": {
                 ph: round(c(f"serving_sched_{ph}_seconds_total"), 6)
-                for ph in SCHED_PHASES},
+                for ph in SCHED_PHASES + ADMIT_CHILDREN},
             "decode_kv_bytes": c("serving_decode_kv_bytes_total"),
             # shared steps by what the host fetched, and its bytes
             "decode_ids_steps": c("serving_decode_ids_steps_total"),

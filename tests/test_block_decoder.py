@@ -541,8 +541,6 @@ def test_artifact_keeps_the_tiles_and_the_engine_shows_them(artifact,
     try:
         assert len(eng.generate(list(range(1, 40)), max_new=6)) == 6
         assert eng.stats()["moe_tiles"] == tiled
-        assert eng.registry.snapshot()[
-            "serving_moe_ragged_dot_tiled_128x128x128_total"]["value"] == 6
     finally:
         eng.close()
 
