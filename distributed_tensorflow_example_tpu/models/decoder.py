@@ -87,7 +87,7 @@ from ..config import TrainConfig
 from ..ops import dsa as dsa_ops
 from ..ops import kda as kda_ops
 from ..ops import mla as mla_ops
-from ..ops.moe import moe_dropless
+from ..ops.moe import moe_dropless, pair_bound
 from .base import DefaultRulesMixin, register_model, resolve_dtype
 
 
@@ -936,8 +936,11 @@ class BlockDecoder(DefaultRulesMixin):
         overwrites them before any read. ``attention``: the selected
         attention of a ``full_attention`` layer, ``"auto"`` (the Pallas
         kernel on a TPU where the shapes allow), ``"pallas"`` or ``"xla"``
-        (``ops/mla.mla_masked_prefill_attention``). Returns the state and
-        ``ids`` [1]: the greedy token after the chunk's last token."""
+        (``ops/mla.mla_masked_prefill_attention``). Returns the state,
+        ``ids`` [1]: the greedy token after the chunk's last token, and,
+        where the expert layers run over a bound of the chunk's pairs
+        (``ops/moe.pair_bound``), ``moe_whole``: how many of them passed
+        it and ran at the whole width."""
         c = self.cfg
         cw = input_ids.shape[1]
         latent = state["cache_latent"]
@@ -951,6 +954,12 @@ class BlockDecoder(DefaultRulesMixin):
         valid = jnp.arange(cw) < n_valid
         h = self._embed(params, input_ids[0])
         expert_rows = jnp.zeros((), jnp.int32)
+        # the expert layers whose held pairs passed the bound they run
+        # over (ops/moe.pair_bound): counted where there is one
+        pairs = cw * c.experts_per_token
+        bound = pair_bound(pairs, c.held, c.experts)
+        bounded = bound < pairs
+        moe_whole = jnp.zeros((), jnp.int32)
         kda_at, mla_at = c.state_rows()
         sparse_at, window_at = (c.rows_of("mla_sparse"),
                                 c.rows_of("mla_window"))
@@ -1028,6 +1037,8 @@ class BlockDecoder(DefaultRulesMixin):
             h, rows = self._ffn_of(i, lp, h)
             if rows is not None:
                 expert_rows += jnp.sum(rows > 0).astype(jnp.int32)
+                if bounded:
+                    moe_whole += (jnp.sum(rows) > bound).astype(jnp.int32)
         # the head over the last token's row only (tests ask for all)
         last = jnp.maximum(n_valid - 1, 0)
         ids, logits = self._greedy(
@@ -1037,6 +1048,8 @@ class BlockDecoder(DefaultRulesMixin):
             ids = lax.dynamic_slice_in_dim(ids, last, 1)
         out = {"ids": ids, "expert_rows": expert_rows,
                **self._state_out(latent, s_all, conv_all, index, rings)}
+        if bounded:
+            out["moe_whole"] = moe_whole
         if with_logits:
             out["logits"] = logits
         return out

@@ -346,8 +346,9 @@ _RAGGED_ROWS = 128
 #: what a tile may hold of the chip's VMEM, counted as both operand tiles
 #: twice (they are double-buffered) and the float32 result tile three
 #: times. Compiled for a described v5e, every tile up to 15.25 MiB by
-#: this count passed and every one from 16 MiB on was refused; the
-#: largest the sweep ran, 128 x 2048 x 768, counts 8.1 MiB.
+#: this count passed and every one from 16 MiB on was refused (PR 38:
+#: again at dots3's widths, 13.25 passed and 16.5 was refused); the
+#: largest the sweeps ran, 128 x 5120 x 512, counts 13.25 MiB.
 _RAGGED_VMEM_BUDGET = 14 * 2**20
 
 
@@ -355,8 +356,9 @@ def ragged_tiling(pairs: int, k: int, n: int, dtype) -> str | None:
     """The tile ``"m,k,n"`` of one ``lax.ragged_dot`` of ``pairs`` rows
     over groups of ``[k, n]`` weights, or ``None`` for XLA's
     own choice: a rule on the shapes the call observes, set from the
-    chip sweep on record (``experiments/flash_sweep.py ragged``;
-    ``benchmark/records/pr28/ragged_sweep.jsonl``; DESIGN §24).
+    chip sweeps on record (``experiments/flash_sweep.py ragged``;
+    ``benchmark/records/pr28/ragged_sweep.jsonl``, ``pr32/``,
+    ``pr38/ragged_dots3_sweep.jsonl``; DESIGN §24, §26).
 
     XLA's grouped matmul visits every (row tile, group) pair in which
     the group owns a row of the tile and multiplies the tile WHOLE; its
@@ -370,18 +372,35 @@ def ragged_tiling(pairs: int, k: int, n: int, dtype) -> str | None:
     not once a column tile; 64 rows gain nothing more. It was within
     2.5 % of the best tile in all twelve shapes and 2.0 to 3.3 times
     faster than XLA's own. The number of groups does not enter: the tile
-    that is best at 8 rows a group is best at 256. ``None`` wherever no
-    sweep was read or XLA refuses the tile: operands that are not
-    bfloat16, ``k`` or ``n`` not a multiple of 128 or over 4096,
-    ``pairs`` not a multiple of the tile's rows, a tile over
-    ``_RAGGED_VMEM_BUDGET``."""
+    that is best at 8 rows a group is best at 256.
+
+    Where ``k`` or ``n`` passes 4,096 the whole matrix is over the
+    budget (dots3-note-prev's [5120, 1536] is 36 MB) and ``n`` is cut:
+    128 rows by the whole ``k`` by the widest ``n / d`` that is a
+    multiple of 128 and fits. Read at 32 groups of [5120, 1536] and
+    [1536, 5120], 1,024 grouped rows in 8,192, 2,048 and 1,024:
+    ``128,5120,512`` 0.77-0.78 ms and ``128,1536,1280`` 0.80 against
+    1.74-1.77 under XLA's own tile and 0.61 of weight read; a cut of
+    ``k`` (a float32 tile read and written again a step) 0.87-0.98;
+    rows past the last group cost nothing. Up to 8,192 the rule is the
+    budget's count, compiled for a described v5e and not timed.
+
+    ``None`` wherever no sweep was read or XLA refuses the tile:
+    operands that are not bfloat16, ``k`` or ``n`` not a multiple of
+    128 or over 8,192, ``pairs`` not a multiple of the tile's rows, no
+    tile under ``_RAGGED_VMEM_BUDGET``."""
     tm = _RAGGED_ROWS
-    vmem = 2 * 2 * (tm * k + k * n) + 3 * 4 * tm * n
     if (jnp.dtype(dtype) != jnp.bfloat16 or k % 128 or n % 128
-            or max(k, n) > 4096 or pairs % tm
-            or vmem > _RAGGED_VMEM_BUDGET):
+            or max(k, n) > 8192 or pairs % tm):
         return None
-    return f"{tm},{k},{n}"
+    # up to 4,096 the whole n or XLA's own; past it the widest cut of n
+    cuts = range(1, n // 128 + 1) if max(k, n) > 4096 else (1,)
+    for d in cuts:
+        tn = n // d
+        vmem = 2 * 2 * (tm * k + k * tn) + 3 * 4 * tm * tn
+        if n % d == 0 and tn % 128 == 0 and vmem <= _RAGGED_VMEM_BUDGET:
+            return f"{tm},{k},{tn}"
+    return None
 
 
 def ragged_dot_tiled(a: jax.Array, w: jax.Array, rows: jax.Array,
@@ -395,21 +414,54 @@ def ragged_dot_tiled(a: jax.Array, w: jax.Array, rows: jax.Array,
                               preferred_element_type=jnp.float32)
 
 
-_TILE_LOG: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+def pair_bound(pairs: int, held: int, experts: int) -> int:
+    """How many rows of the sorted (row, expert) pairs
+    :func:`moe_dropless` runs its grouped matmuls over: twice what the
+    ``held`` of ``experts`` published experts expect of ``pairs`` under
+    even routing, in whole tiles, where that is at most half of
+    ``pairs``; else ``pairs`` (a chip that holds half the experts or
+    more, a one-token step of a few hundred pairs: the parent's
+    program). Arithmetic on shapes alone; a layer whose held pairs
+    outnumber the bound runs at the whole width (DESIGN §26)."""
+    bound = _RAGGED_ROWS * math.ceil(
+        2 * pairs * held / experts / _RAGGED_ROWS)
+    return bound if 2 * bound <= pairs else pairs
+
+
+def _combine_bounded(out: jax.Array, at: jax.Array, w: jax.Array,
+                     top_k: int) -> jax.Array:
+    """[T, H]: the rows ``out`` of the pairs ``at`` (``pair = row *
+    top_k + pick``), each times its pick's weight, added up by row: every
+    pair gathers its row of ``out``, or one zero row where it is not
+    among ``at``. Of the four forms timed at dots3's chunk
+    (``benchmark/records/pr38/layer_bench.py``) the fastest that is
+    exact: a scatter-add of the rows walks them one at a time (+1.5 ms
+    a layer), a one-hot matmul at the highest precision is +0.2."""
+    where = jnp.full((w.size,), out.shape[0], at.dtype).at[at].set(
+        jnp.arange(out.shape[0], dtype=at.dtype))
+    padded = jnp.concatenate([out, jnp.zeros_like(out[:1])])
+    return jnp.sum(padded[where].reshape(*w.shape, -1) * w[..., None],
+                   axis=1)
+
+
+_TILE_LOG: contextvars.ContextVar[tuple | None] = contextvars.ContextVar(
     "moe_tile_log", default=None)
 
 
 @contextlib.contextmanager
-def tile_log():
+def tile_log(rows: dict | None = None):
     """While a program is traced under this, collect the tile each of
     :func:`moe_dropless`'s grouped matmuls was given: ``{"gate": "m,k,n"
-    | "xla", "up": ..., "down": ...}``. A compiled program carries a
-    tile always or never, so this is the evidence that the rule
+    | "xla", "up": ..., "down": ...}`` and, into ``rows`` where one is
+    passed, the rows they run over: ``{"pairs": T x k, "bound":``
+    :func:`pair_bound```}``. A compiled program carries a tile and a
+    bound always or never, so this is the evidence that the rules
     engaged (``serving.export_generator`` keeps it in ``export.json``)."""
     tiles: dict[str, str] = {}
-    # a ContextVar's set, not a metric's: the exporter's own dict, written
+    # a ContextVar's set, not a metric's: the exporter's own dicts, written
     # while it traces and never under a compiled call
-    token = _TILE_LOG.set(tiles)  # graftlint: disable=JIT01
+    token = _TILE_LOG.set(  # graftlint: disable=JIT01
+        (tiles, {} if rows is None else rows))
     try:
         yield tiles
     finally:
@@ -461,6 +513,7 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     another layer: it drops past a capacity and trains.)
     """
     t, _ = x.shape
+    pairs = t * top_k
     e_held = experts["gate"].shape[0]
     with jax.named_scope("moe_route"):
         logits = jnp.dot(
@@ -479,27 +532,48 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
         order = jnp.argsort(key, stable=True)
         rows = jnp.bincount(key, length=e_held + 1)[:e_held].astype(
             jnp.int32)
-        live = (jnp.arange(t * top_k) < jnp.sum(rows))[:, None]
-    with jax.named_scope("moe_experts"):
-        xs = x.astype(dtype)[order // top_k]                # [T * k, H]
+        live = (jnp.arange(pairs) < jnp.sum(rows))[:, None]
+    bound = pair_bound(pairs, e_held, router.shape[1])
+    tiles, rows_log = _TILE_LOG.get() or (None, None)
+    if rows_log is not None:
+        rows_log.update(pairs=pairs, bound=bound)
 
-        log = _TILE_LOG.get()
+    def grouped(a, name, live):
+        w_e = experts[name].astype(dtype)
+        tile = ragged_tiling(a.shape[0], *w_e.shape[1:], dtype)
+        # the tile the program runs: its whole-width fallback logs none
+        if tiles is not None and a.shape[0] == bound:
+            tiles[name] = tile or "xla"
+        # rows past the last group belong to no held expert: what
+        # the grouped matmul leaves there is not a result
+        return jnp.where(live, ragged_dot_tiled(a, w_e, rows, tile), 0.0)
 
-        def grouped(a, name):
-            w_e = experts[name].astype(dtype)
-            tile = ragged_tiling(a.shape[0], *w_e.shape[1:], dtype)
-            if log is not None:
-                log[name] = tile or "xla"
-            # rows past the last group belong to no held expert: what
-            # the grouped matmul leaves there is not a result
-            return jnp.where(live, ragged_dot_tiled(a, w_e, rows, tile),
-                             0.0)
+    def ffn(at, live):
+        """The held experts' outputs for the pairs ``at`` of the sorted
+        order: [len(at), H] float32, zero where ``live`` is not."""
+        xs = x.astype(dtype)[at // top_k]
+        act = (jax.nn.silu(grouped(xs, "gate", live))
+               * grouped(xs, "up", live))
+        return grouped(act.astype(dtype), "down", live)
 
-        act = jax.nn.silu(grouped(xs, "gate")) * grouped(xs, "up")
-        out = grouped(act.astype(dtype), "down")            # [T * k, H]
-    with jax.named_scope("moe_combine"):
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(t * top_k, dtype=order.dtype))
-        y = jnp.sum(out[inverse].reshape(t, top_k, -1)
-                    * w[..., None], axis=1)
-    return y, rows
+    def whole():
+        with jax.named_scope("moe_experts"):
+            out = ffn(order, live)
+        with jax.named_scope("moe_combine"):
+            inverse = jnp.zeros_like(order).at[order].set(
+                jnp.arange(pairs, dtype=order.dtype))
+            return jnp.sum(out[inverse].reshape(t, top_k, -1)
+                           * w[..., None], axis=1)
+
+    def bounded():
+        at = order[:bound]
+        with jax.named_scope("moe_experts"):
+            out = ffn(at, live[:bound])
+        with jax.named_scope("moe_combine"):
+            return _combine_bounded(out, at, w, top_k)
+
+    if bound == pairs:
+        return whole(), rows
+    # dropless for any routing: a layer whose held pairs outnumber the
+    # bound runs at the whole width
+    return lax.cond(jnp.sum(rows) <= bound, bounded, whole), rows

@@ -979,7 +979,7 @@ def _export_block_generator(model, params, out_dir: str, *,
                   "commit": spec((slots,), np.int32),
                   "block_tables": spec((slots, blocks_per_slot), np.int32),
                   **pool_specs}
-    weights, param_count, param_bytes, moe_tiles = _trace_with_params(
+    weights, param_count, param_bytes, moe = _trace_with_params(
         ((_PREFILL, prefill_fn, prefill_specs),
          (_BLOCK_STEP, step_fn, step_specs)), params, platforms, out_dir)
     meta = {
@@ -1016,7 +1016,7 @@ def _export_block_generator(model, params, out_dir: str, *,
                       "experts": int(c.experts),
                       "experts_held": int(c.held),
                       "experts_per_token": int(c.experts_per_token),
-                      "moe_tiles": moe_tiles},
+                      **moe},
         },
     }
     artifact = os.path.join(out_dir, _BLOCK_STEP)
@@ -1030,7 +1030,9 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
     """Export each ``(file name, fn(params, feats), feature specs)``:
     weights baked under ``BAKE_LIMIT_BYTES``, else saved once under
     ``params/`` and taken as every program's first argument. Returns
-    ``(weights, param_count, param_bytes, moe_tiles)``."""
+    ``(weights, param_count, param_bytes, moe)``: ``moe`` by program
+    what its expert layers were traced with (``ops/moe.tile_log``), to
+    keep under ``export.json``'s ``moe_tiles`` and ``moe_rows``."""
     leaves = jax.tree_util.tree_leaves(params)
     param_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in leaves)
@@ -1040,11 +1042,13 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
         os.makedirs(out_dir, exist_ok=True)
     p_specs = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), params)
-    moe_tiles = {}
+    moe = {"moe_tiles": {}, "moe_rows": {}}
     for name, fn, specs in fns:
         # the tile each of the expert layer's grouped matmuls was traced
-        # with (every layer has the same shapes): fixed once compiled
-        with tile_log() as tiles:
+        # with and the rows they run over, of the pairs (every layer has
+        # the same shapes): fixed once compiled
+        rows = {}
+        with tile_log(rows) as tiles:
             if as_args:
                 exp = jax_export.export(
                     jax.jit(fn), platforms=list(platforms))(p_specs, specs)
@@ -1052,15 +1056,15 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
                 exp = jax_export.export(
                     jax.jit(lambda feats, fn=fn: fn(params, feats)),
                     platforms=list(platforms))(specs)
-        moe_tiles[name.removesuffix(".stablehlo")] = tiles
+        program = name.removesuffix(".stablehlo")
+        moe["moe_tiles"][program], moe["moe_rows"][program] = tiles, rows
         if chief:
             with open(os.path.join(out_dir, name), "wb") as f:
                 f.write(exp.serialize())
     if as_args and chief:
         save_params(os.path.join(out_dir, _PARAMS_DIR), params)
     return ("checkpoint" if as_args else "baked",
-            sum(int(np.prod(x.shape)) for x in leaves), param_bytes,
-            moe_tiles)
+            sum(int(np.prod(x.shape)) for x in leaves), param_bytes, moe)
 
 
 def _export_state_generator(model, params, out_dir: str, *,
@@ -1159,7 +1163,7 @@ def _export_state_generator(model, params, out_dir: str, *,
                     "alive": spec((slots,), np.int32),
                     "block_tables": spec((slots, blocks_per_slot), np.int32),
                     **state_specs}
-    weights, param_count, param_bytes, moe_tiles = _trace_with_params(
+    weights, param_count, param_bytes, moe = _trace_with_params(
         ((_PREFILL_CHUNK, chunk_fn, chunk_specs),
          (_DECODE, decode_fn, decode_specs)), params, platforms, out_dir)
     pool_shape = specs["cache_latent"]["shape"]
@@ -1203,7 +1207,7 @@ def _export_state_generator(model, params, out_dir: str, *,
                       # layer sees (0: the model has no such layer)
                       "index_topk": int(c.index_topk),
                       "window": int(c.window),
-                      "moe_tiles": moe_tiles},
+                      **moe},
         },
     }
     artifact = os.path.join(out_dir, _DECODE)

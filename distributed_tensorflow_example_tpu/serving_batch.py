@@ -969,6 +969,16 @@ def _fetch_ids(out: dict) -> np.ndarray:
     return np.asarray(out["ids"])
 
 
+def _fetch_together(*arrays) -> list:
+    """The host's copies of several results of one program in ONE round
+    trip: every copy is started before the first is waited for (a
+    blocking read of its own each is ~0.5 ms more a result on the
+    chip's host)."""
+    for a in arrays:
+        a.copy_to_host_async()
+    return [np.asarray(a) for a in arrays]
+
+
 def _fetch_block(out: dict) -> tuple:
     """What a block step hands the host: ids and confidences [slots, B]
     and the two routing scalars, never logits."""
@@ -1479,11 +1489,21 @@ class GenerationEngine:
             "serving_moe_max_expert_load_ratio",
             "last block step: the fullest expert's rows over the mean "
             "an expert gets")
-        #: (row, expert) pairs one row sends through a per-request-state
-        #: artifact's expert layers
-        self._moe_pairs_a_row = (
-            self.state["ffns"].count("moe")
-            * int(self.state["experts_per_token"]) if self.state else 0)
+        self._c_moe_bounded = reg.counter(
+            "serving_moe_bounded_layers_total",
+            "expert layers of chunk programs that ran over the bound of "
+            "their (row, expert) pairs (ops/moe.pair_bound; export.json "
+            "moe_rows)")
+        self._c_moe_whole = reg.counter(
+            "serving_moe_whole_layers_total",
+            "expert layers of such programs whose held pairs passed the "
+            "bound and ran at the whole width")
+        #: a per-request-state artifact's expert layers, and the (row,
+        #: expert) pairs one row sends through them
+        self._moe_layers = (self.state["ffns"].count("moe")
+                            if self.state else 0)
+        self._moe_pairs_a_row = self._moe_layers * (
+            int(self.state["experts_per_token"]) if self.state else 0)
         # held experts that received a row in the LAST block step: the
         # next step's span carries it (a step's routing is known only
         # when it returns)
@@ -2917,6 +2937,12 @@ class GenerationEngine:
                 # leave self._pool naming the donated inputs so
                 # _pool_alive() escalates correctly
                 with self._admit_read("prefill_chunk"):
+                    # a program whose expert layers run over a bound
+                    # says how many passed it: brought in the id's read
+                    whole = out.get("moe_whole")
+                    if whole is not None:
+                        out["ids"], whole = _fetch_together(
+                            out["ids"], whole)
                     tok0, logits0 = self._fetch_first(
                         req, out, "prefill_chunk")
                     self._pool = {k: v for k, v in out.items()
@@ -2941,6 +2967,9 @@ class GenerationEngine:
                 self._c_prefill_chunk_tokens.inc(n)
                 if self.state:
                     self._c_moe_rows.inc(n * self._moe_pairs_a_row)
+                if whole is not None:
+                    self._c_moe_whole.inc(int(whole))
+                    self._c_moe_bounded.inc(self._moe_layers - int(whole))
             slot.chunk_done = start + n
             if slot.chunk_done < p:
                 return
@@ -4077,6 +4106,9 @@ class GenerationEngine:
             "tokens_committed": c("serving_tokens_committed_total"),
             "moe_rows": c("serving_moe_rows_total"),
             "moe_tiles": self.moe_tiles,
+            # chunk programs' expert layers by the rows they ran over
+            "moe_bounded_layers": c("serving_moe_bounded_layers_total"),
+            "moe_whole_layers": c("serving_moe_whole_layers_total"),
             # what each program's paged decode attention was traced with
             "attn_schedule": self.sw.attn_schedule,
             # a kind a layer (None otherwise): which layers mix and feed

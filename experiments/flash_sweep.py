@@ -61,6 +61,11 @@ another's device memory):
                      4 and 32 rows a group in 1,024 and 8,192 rows (half
                      of a step's pairs name experts held elsewhere and
                      sort past the last group, as in the served step).
+  ragged OUT dots3   the same at the widths ``dots3-serve-longctx`` runs
+                     (PR 38): 32 groups of [5120, 1536] and [1536, 5120],
+                     1,024 grouped rows in 8,192 (the chunk's pairs),
+                     2,048 (its bounded buffer) and 1,024, under the
+                     tiles that split K or N (``DOTS3_TILES``).
   paged [OUT [PARENT_PY]]
                      the one-token paged attention kernels alone (PR 34):
                      ``paged_decode_attn`` by NAME at 16 to 256 rows of
@@ -502,14 +507,36 @@ def measure_kernels(row: dict, *, iters: int = 5) -> dict:
 # the expert layer's grouped matmul alone (PR 28): device ms by tile
 # ---------------------------------------------------------------------------
 
-def ragged_rows(wide: bool = False) -> list[dict]:
+#: ``ragged OUT dots3``: the tiles compiled for a described v5e before
+#: the call (K or N split: the whole [5120, 1536] matrix is 36 MB)
+DOTS3_TILES = {
+    (5120, 1536): ("128,5120,512", "128,5120,384", "128,5120,256",
+                   "128,2560,768", "128,2560,512", "128,1280,1536",
+                   "128,1024,1536"),
+    (1536, 5120): ("128,1536,1280", "128,1536,1024", "128,1536,640",
+                   "128,1536,512", "128,768,2560", "256,1536,1024"),
+}
+
+
+def ragged_rows(wide: str | None = None) -> list[dict]:
     """What ``ragged`` measures, in order: ``m`` pairs over 128 groups of
     ``[k, n]`` weights, ``skew`` (group sizes from a Dirichlet(0.3) draw
     in place of a uniform one), ``tiling`` (None = XLA's own, first for
     each shape: the others are compared with its result). ``wide``: the
-    other configuration's widths, ``m`` rows of which ``grouped`` lie in
-    a group."""
+    other configurations' widths, ``m`` rows of which ``grouped`` lie in
+    a group (``"wide"`` Kimi's, ``"dots3"`` dots3-note-prev's 32 held
+    experts)."""
     rows = []
+    if wide == "dots3":
+        for (k, n), tiles in DOTS3_TILES.items():
+            for m, grouped in ((8192, 1024), (2048, 1024), (1024, 1024)):
+                rows += [dict(m=m, k=k, n=n, groups=32, skew=False,
+                              grouped=grouped, tiling=t)
+                         for t in (None, *tiles)]
+                rows += [dict(m=m, k=k, n=n, groups=32, skew=True,
+                              grouped=grouped, tiling=t)
+                         for t in (None, *tiles[:2], tiles[3])]
+        return rows
     if wide:
         for k, n in ((2304, 1024), (1024, 2304)):
             for m, grouped in ((1024, 512), (8192, 4096)):
@@ -747,7 +774,7 @@ def _load_parent(path: str):
 
 
 def kernels(out_path: str | None, mode: str = "kernels",
-            wide: bool = False, parent_py: str | None = None) -> None:
+            wide: str | None = None, parent_py: str | None = None) -> None:
     import jax
 
     on_tpu = jax.devices()[0].platform == "tpu"
@@ -840,7 +867,7 @@ def main() -> None:
                         exist_ok=True)
         third = sys.argv[3] if len(sys.argv) > 3 else None
         kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1],
-                wide=third == "wide",
+                wide=third if third in ("wide", "dots3") else None,
                 parent_py=third if sys.argv[1] == "paged" else None)
         return
     if sys.argv[1:2] == ["--trace"]:

@@ -28,7 +28,7 @@ from distributed_tensorflow_example_tpu.models.decoder import (  # noqa: E402
     BlockDecoder, DecoderBlockConfig)
 from distributed_tensorflow_example_tpu.ops import moe as moe_mod   # noqa: E402
 from distributed_tensorflow_example_tpu.ops.moe import (  # noqa: E402
-    moe_dropless, ragged_tiling)
+    moe_dropless, pair_bound, ragged_tiling)
 from distributed_tensorflow_example_tpu.serving_batch import (  # noqa: E402
     GenerationEngine)
 
@@ -310,18 +310,18 @@ def test_dropless_layer_equals_the_dense_loop(skew):
         assert int(rows[0]) > 0.9 * x.shape[0]
 
 
-def _sigmoid_shares():
+def _sigmoid_shares(t: int = 40):
     """dots3-note-prev's expert layer at test widths, whole and as 8
     shares of 2 of its 16 experts: sigmoid scores, a selection bias, the
     picked scores renormalised, ONE shared expert every share computes
-    alike (counted once). Its own plain reference."""
+    alike (counted once). Its own plain reference. ``t`` rows."""
     dots3 = load_module(os.path.join(ROOT, "benchmark", "reference",
                                      "dots3-note-prev.py"))
     sizes = json.load(open(os.path.join(
         ROOT, "benchmark", "configs", "dots3-note-prev.json")))[
             "rehearsal"]["sizes"]
     k = jax.random.split(jax.random.key(2), 9)
-    t, h, e, f = 40, sizes["hidden_size"], 16, sizes["moe_intermediate_size"]
+    h, e, f = sizes["hidden_size"], 16, sizes["moe_intermediate_size"]
     x = jax.random.normal(k[0], (t, h))
     mp = {"router": jax.random.normal(k[1], (h, e)) * 0.2,
           "router_bias": jax.random.normal(k[2], (e,)) * 0.02,
@@ -368,8 +368,18 @@ def _softmax_shares():
             held(first), x, "f32"))
 
 
-@pytest.mark.parametrize("case", [_softmax_shares, _sigmoid_shares],
-                         ids=["softmax_4_shares", "sigmoid_8_shares"])
+def _sigmoid_shares_bounded():
+    """The same 8 shares at 256 rows: each share's grouped matmuls run
+    over ``pair_bound`` rows of its pairs, not over all of them."""
+    case = _sigmoid_shares(256)
+    pairs = 256 * case[5]["top_k"]
+    assert pair_bound(pairs, 2, 16) == pairs // 4
+    return case
+
+
+@pytest.mark.parametrize(
+    "case", [_softmax_shares, _sigmoid_shares, _sigmoid_shares_bounded],
+    ids=["softmax_4_shares", "sigmoid_8_shares", "sigmoid_8_shares_bounded"])
 def test_shares_of_the_experts_add_up_to_the_whole_layer(case):
     """The guide's share test. Each share routes over all the experts and
     computes its own experts' part; the parts, with what every share
@@ -387,6 +397,99 @@ def test_shares_of_the_experts_add_up_to_the_whole_layer(case):
         assert rows.shape == (2,)
         total = total + part
     np.testing.assert_allclose(total, whole, rtol=1e-5, atol=2e-5)
+
+
+# ---- (b'') the pair buffer bounded by the held share ----------------------
+
+def _routed_case(routing: str):
+    """128 rows x top-2 of 16 experts, experts 4 and 5 held (1 in 8): 256
+    pairs, ``pair_bound`` 128. The router is the identity on the first 16
+    features, so a row's logits are written into it: ``uniform`` draws
+    them, the others pick per row a pair of experts (the first held pick
+    4, the second 5; absent picks 2 and 3). Returns ``(x, router,
+    experts, cfg, held pairs)``."""
+    t, e, h, f = 128, 16, 64, 32
+    k = jax.random.split(jax.random.key(5), 5)
+    if routing == "uniform":
+        logits, n_held = jax.random.normal(k[0], (t, e)), None
+    else:
+        both, one = {"all_held": (t, 0), "at_bound": (64, 0),
+                     "one_over": (64, 1), "none_held": (0, 0)}[routing]
+        first = np.where(np.arange(t) < both + one, 4, 2)
+        second = np.where(np.arange(t) < both, 5, 3)
+        logits = (4.0 * jax.nn.one_hot(first, e)
+                  + 3.0 * jax.nn.one_hot(second, e))
+        n_held = 2 * both + one
+    x = jnp.concatenate([logits, jax.random.normal(k[1], (t, h - e))], 1)
+    router = jnp.zeros((h, e)).at[:e].set(jnp.eye(e))
+    experts = {n: jax.random.normal(kk, s) * 0.2 for n, kk, s in (
+        ("gate", k[2], (2, h, f)), ("up", k[3], (2, h, f)),
+        ("down", k[4], (2, f, h)))}
+    cfg = dict(CFG, num_experts=e, num_experts_per_tok=2, experts_held=2,
+               first_expert=4)
+    return x, router, experts, cfg, n_held
+
+
+@pytest.mark.parametrize("routing", ["uniform", "all_held", "at_bound",
+                                     "one_over", "none_held"])
+def test_bounded_layer_is_the_dense_loop_and_the_whole_width(routing,
+                                                             monkeypatch):
+    """The layer over the first ``pair_bound`` rows of the sorted pairs
+    (near-uniform routing; exactly as many held pairs as the bound; none
+    at all) and its whole-width fallback (one held pair over the bound;
+    a router that sends EVERY pick to a held expert) against the dense
+    loop and against the layer with no bound: the same output, the same
+    ``rows``. Dropless for any routing. float32: 1e-5."""
+    x, router, experts, cfg, n_held = _routed_case(routing)
+
+    def layer(x):                               # a trace of its own a call
+        return moe_dropless(x, router, experts, top_k=2, first_expert=4,
+                            dtype=jnp.float32)
+
+    log = {}
+    with moe_mod.tile_log(log):
+        y, rows = jax.jit(layer)(x)
+    assert log == {"pairs": 256, "bound": 128}
+    want = ref.experts(ref._sizes(cfg), {"router": router, **experts}, x,
+                       "f32")
+    np.testing.assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+    monkeypatch.setattr(moe_mod, "pair_bound", lambda pairs, *_: pairs)
+    whole, whole_rows = jax.jit(lambda x: layer(x))(x)
+    np.testing.assert_allclose(y, whole, rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(rows, whole_rows)
+    if n_held is None:
+        assert 0 < int(rows.sum()) < 128        # the bounded branch's case
+    else:
+        assert int(rows.sum()) == n_held
+    if routing in ("all_held", "one_over"):     # the fallback IS that layer
+        assert np.asarray(y).tobytes() == np.asarray(whole).tobytes()
+    if routing == "none_held":
+        assert not np.asarray(y).any()
+
+
+@pytest.mark.parametrize("pairs,held,experts,want", [
+    # dots3-note-prev's chunk: 1,024 rows x 8 picks, 32 of 256 held
+    (8192, 32, 256, 2048),
+    # ... and everything else that is served runs the parent's program:
+    (192, 32, 256, 192),        # dots3's one-token step, 24 slots
+    (8192, 128, 256, 8192),     # Kimi's chunk: 1 in 2 held
+    (1024, 128, 256, 1024),     # Kimi's step, 128 slots
+    (2048, 128, 128, 2048),     # SDAR's block step: every expert held
+    (32768, 128, 128, 32768),   # SDAR's prefill
+    (64, 4, 8, 64),             # the tiny decoders'
+    # the rule's edges: twice the expected rows in whole tiles, engaged
+    # where that is at most half of the pairs
+    (4096, 32, 256, 1024),
+    (1024, 32, 256, 256),
+    (512, 32, 256, 128),
+    (384, 32, 256, 128),
+    (256, 32, 256, 128),
+    (255, 32, 256, 255),
+    (8192, 64, 256, 4096),      # 1 in 4 held: exactly half
+    (8192, 65, 256, 8192),
+])
+def test_pair_bound_is_a_rule_on_shapes(pairs, held, experts, want):
+    assert pair_bound(pairs, held, experts) == want
 
 
 # ---- (b') the grouped matmuls' tile ---------------------------------------
@@ -412,8 +515,21 @@ BF16 = jnp.bfloat16
     # outside what was swept: XLA's own, exactly the parent's program
     (2048, 2000, 768, BF16, None),          # K not a multiple of 128
     (2048, 2048, 800, BF16, None),          # N not a multiple of 128
-    (2048, 8192, 768, BF16, None),          # over 4,096
-    (2048, 768, 4224, BF16, None),
+    # past 4,096 the matrix is over the budget: the whole K, N cut to the
+    # widest part that fits (compiled for a described v5e, not timed) ...
+    (2048, 8192, 768, BF16, "128,8192,256"),
+    (2048, 768, 4224, BF16, "128,768,1408"),
+    # ... read on the chip at what dots3-serve-longctx runs: 32 held
+    # experts of [5120, 1536], a chunk's 8,192 pairs and their bound
+    (8192, 5120, 1536, BF16, "128,5120,512"),
+    (8192, 1536, 5120, BF16, "128,1536,1280"),
+    (2048, 5120, 1536, BF16, "128,5120,512"),
+    (2048, 1536, 5120, BF16, "128,1536,1280"),
+    (192, 5120, 1536, BF16, None),          # its step: 24 slots x 8 picks
+    (192, 1536, 5120, BF16, None),
+    (2048, 5120, 1536, jnp.float32, None),
+    (2048, 8320, 768, BF16, None),          # over 8,192
+    (2048, 5120, 5120, BF16, "128,5120,512"),
     (2048, 2048, 768, jnp.float32, None),   # only bfloat16 was read
     (64, 2048, 768, BF16, None),            # fewer pairs than a tile
     (2000, 2048, 768, BF16, None),          # XLA wants m % tile rows == 0

@@ -627,6 +627,125 @@ def test_spans_say_what_was_selected(artifact):
     eng.close()
 
 
+# ---- (e') a chunk whose expert layers run over a bound of the pairs ------
+
+WIDE_CHUNK = 128        # x top-2 of 16 experts, 2 held: 256 pairs, bound 128
+
+
+def _wide(all_held: bool = False):
+    """The tiny layers with 16 experts of which this chip holds 2 (1 in
+    8, the cell's share), so that a 128-row chunk's expert layers run
+    over ``pair_bound`` = 128 of their 256 pairs; ``all_held``: a
+    selection bias that sends EVERY pick to the two held experts (256
+    held pairs: every layer falls back to the whole width)."""
+    import dataclasses
+    from distributed_tensorflow_example_tpu.models.decoder import (
+        BlockDecoder, DecoderBlockConfig)
+    model = BlockDecoder(dataclasses.replace(
+        DecoderBlockConfig.dots3_note_tiny(), experts=16, experts_held=2),
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    params = model.init(jax.random.key(38))
+    if all_held:
+        for lp in params["layers"].values():
+            if "moe" in lp:
+                lp["moe"]["router_bias"] = lp["moe"]["router_bias"].at[
+                    :2].add(100.0)
+    return model, params
+
+
+def _wide_chunk(model, params, toks):
+    """One 128-row chunk of ``toks`` through ``prefill_chunk``: its output
+    (logits at every row)."""
+    specs = model.state_specs(slots=SLOTS, num_blocks=1 + SLOTS * NB,
+                              block_size=BS)
+    state = {k: jnp.zeros(v["shape"], v["dtype"]) for k, v in specs.items()}
+    blocks = 1 + np.arange(NB, dtype=np.int32)
+    ids = np.zeros((1, WIDE_CHUNK), np.int32)
+    ids[0, :len(toks)] = toks
+    return jax.jit(lambda st, ids: model.prefill_chunk(
+        params, st, ids, len(toks), 0, 0, blocks, blocks,
+        with_logits=True))(state, ids)
+
+
+@pytest.mark.parametrize("all_held", [False, True],
+                         ids=["bounded", "overflow"])
+def test_a_bounded_chunk_is_the_whole_width_chunk(all_held, monkeypatch):
+    """The chunk program of a chip that holds 1 in 8 experts: the same
+    logits with the bound and without it, and ``moe_whole`` says how many
+    of its four expert layers passed the bound (none under seeded
+    routing; all four when every pick is a held expert). A program
+    without a bound returns no such scalar (Kimi's, the cell's decode
+    step, this file's other chunks)."""
+    from distributed_tensorflow_example_tpu.models import decoder as dec_mod
+    from distributed_tensorflow_example_tpu.ops import moe as moe_mod
+    model, params = _wide(all_held)
+    toks = np.random.RandomState(3).randint(0, 384, 100)
+    got = _wide_chunk(model, params, toks)
+    assert int(got["moe_whole"]) == (4 if all_held else 0)
+    for mod in (dec_mod, moe_mod):
+        monkeypatch.setattr(mod, "pair_bound", lambda pairs, *_: pairs)
+    want = _wide_chunk(model, params, toks)
+    assert "moe_whole" not in want
+    np.testing.assert_allclose(got["logits"][:100], want["logits"][:100],
+                               rtol=1e-5, atol=1e-5)
+    assert int(got["ids"][0]) == int(want["ids"][0])
+    assert int(got["expert_rows"]) == int(want["expert_rows"])
+
+
+def test_engine_counts_bounded_and_whole_layers(tmp_path):
+    """``export.json`` names the rows each program's expert layers were
+    traced over; ``/stats`` counts, a chunk program, its expert layers
+    that ran over the bound and those that fell back, from the scalar
+    the program returns beside the id."""
+    lens = [100, 128, 200]
+    chunks = sum(-(-n // WIDE_CHUNK) for n in lens)
+    rs = np.random.RandomState(4)
+    prompts = [rs.randint(0, 384, n).tolist() for n in lens]
+    tokens = []
+    for all_held in (False, True):
+        model, params = _wide(all_held)
+        out = str(tmp_path / f"wide{int(all_held)}")
+        serving.export_generator(
+            model, params, out, ragged=True, stepwise=True, paged=True,
+            slots=SLOTS, block_size=BS, prompt_len=256, max_new_tokens=8,
+            prefill_chunk=WIDE_CHUNK, platforms=("cpu",))
+        st = json.load(open(os.path.join(out, "export.json")))[
+            "stepwise"]["state"]
+        assert st["moe_rows"] == {
+            "prefill_chunk": {"pairs": 256, "bound": 128},
+            "decode": {"pairs": 2 * SLOTS, "bound": 2 * SLOTS}}
+        assert set(st["moe_tiles"]["prefill_chunk"].values()) == {"xla"}
+        eng = GenerationEngine(serving.load_stepwise(out)).start()
+        try:
+            tokens.append([eng.submit(p, max_new=4).result(timeout=300)
+                           for p in prompts])
+            stats = eng.stats()
+        finally:
+            eng.close()
+        assert stats["prefill_chunks"] == chunks
+        assert (stats["moe_bounded_layers"], stats["moe_whole_layers"]) == (
+            (0, 4 * chunks) if all_held else (4 * chunks, 0))
+    assert all(len(t) == 4 for run in tokens for t in run)
+
+
+def test_a_program_without_a_bound_counts_nothing(artifact):
+    """This file's artifact (64 pairs a chunk: no bound): its chunk
+    program returns no ``moe_whole`` and both counters stay at zero."""
+    meta = json.load(open(os.path.join(artifact, "export.json")))
+    assert meta["stepwise"]["state"]["moe_rows"] == {
+        "prefill_chunk": {"pairs": 2 * CHUNK, "bound": 2 * CHUNK},
+        "decode": {"pairs": 2 * SLOTS, "bound": 2 * SLOTS}}
+    eng = GenerationEngine(serving.load_stepwise(artifact)).start()
+    try:
+        assert len(eng.submit(list(range(1, 41)), max_new=3).result(
+            timeout=300)) == 3
+        st = eng.stats()
+    finally:
+        eng.close()
+    assert st["prefill_chunks"] == 2
+    assert (st["moe_bounded_layers"], st["moe_whole_layers"]) == (0, 0)
+
+
 # ---- (f) the other served programs are the parent's ---------------------
 
 #: sha256 (16 hex digits) of each program exported at the parent commit

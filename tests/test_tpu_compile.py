@@ -687,3 +687,102 @@ def test_selecting_programs_keep_their_three_caches_where_they_lie(chip,
     if name == "decode":
         assert f"{slots},{width}" in sorts      # one row a slot
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def _reached(text: str) -> dict:
+    """Per computation of a compiled program's text, the lines of every
+    computation it reaches (its own, its fusions', its branches')."""
+    own, calls, name = {}, {}, None
+    for ln in text.splitlines():
+        m = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{$", ln)
+        if m:
+            name = m.group(1)
+            own[name], calls[name] = [], set()
+        elif name and ln == "}":
+            name = None
+        elif name:
+            own[name].append(ln)
+            calls[name].update(re.findall(
+                r"(?:calls|to_apply|body|condition|true_computation|"
+                r"false_computation)=(%[\w.\-]+)", ln))
+            for group in re.findall(r"branch_computations=\{([^}]*)\}", ln):
+                calls[name].update(group.split(", "))
+
+    def reach(n, seen):
+        if n not in seen and n in own:
+            seen.add(n)
+            for c in calls[n]:
+                reach(c, seen)
+        return seen
+
+    return {n: [ln for c in reach(n, set()) for ln in own[c]] for n in own}
+
+
+def test_dots3_chunk_expert_layer_runs_over_the_bound(chip):
+    """dots3-note-prev's chunk expert layer at the cell's shapes (1,024
+    rows x top-8 of 256 sigmoid-routed experts, 32 held, [5120, 1536] and
+    [1536, 5120] bfloat16): every grouped matmul carries the tile
+    ``ops/moe.ragged_tiling`` gives past 4,096 (N cut: the whole matrix
+    is 36 MB); the program is a conditional one of whose branches runs
+    its grouped matmuls, gathers and selects over the 2,048 rows of
+    ``pair_bound`` and keeps ONE ``[8192, ..]`` tensor, the combine's
+    gather (every pair its row or the zero row), while the other (the
+    whole-width fallback) is the layer over all 8,192; its temporaries
+    are the parent's program's (XLA's own tile, every ``T x k`` row) to
+    1 %: the fallback reserves them."""
+    from distributed_tensorflow_example_tpu.ops import moe as moe_mod
+    dev = chip[0]
+    t, h, f, e, held = 1024, 5120, 1536, 256, 32
+    experts = {"gate": on(dev, (held, h, f)), "up": on(dev, (held, h, f)),
+               "down": on(dev, (held, f, h))}
+
+    def compiled():
+        def layer(x, router, experts, bias):    # a trace of its own a call
+            return moe_mod.moe_dropless(
+                x, router, experts, top_k=8, scores="sigmoid",
+                select_bias=bias, scale=2.5)
+        return jax.jit(layer).lower(
+            on(dev, (t, h), jnp.float32), on(dev, (h, e)), experts,
+            on(dev, (e,), jnp.float32)).compile()
+
+    got = compiled()
+    text = got.as_text()
+    gate, down = "128,5120,512", "128,1536,1280"
+    tiles = re.findall(r'ragged_dot_tiling="([^"]*)"', text)
+    assert sorted(tiles) == sorted([gate, gate, down] * 2), tiles
+    reached = _reached(text)
+    cond = [ln for ln in text.splitlines() if " conditional(" in ln]
+    assert len(cond) == 1, cond
+    branches = re.findall(r"(?:true_computation|false_computation)="
+                          r"(%[\w.\-]+)", cond[0]) or re.search(
+        r"branch_computations=\{([^}]*)\}", cond[0]).group(1).split(", ")
+    assert len(branches) == 2
+
+    def wide(branch):
+        """The [8192, ..] tensors a branch defines, by element type and
+        width (the mask ``pred[8192,1]`` left out)."""
+        return sorted({m.group(1) + m.group(2) for ln in reached[branch]
+                       for m in [re.search(
+                           r" = (\w+)\[8192,(\d{2,})\]", ln)] if m})
+
+    bounded, whole = sorted(branches, key=lambda b: len(wide(b)))
+    assert wide(bounded) == ["f325120"], wide(bounded)
+    assert {"bf165120", "f321536", "bf161536", "f325120"} <= set(wide(whole))
+    for shape in ("bf16[2048,5120]", "f32[2048,1536]", "bf16[2048,1536]",
+                  "f32[2048,5120]"):
+        assert any(shape in ln for ln in reached[bounded]), shape
+        assert not any(shape in ln for ln in reached[whole]), shape
+    mp = pytest.MonkeyPatch()
+    mp.setattr(moe_mod, "ragged_tiling", lambda *a: None)
+    mp.setattr(moe_mod, "pair_bound", lambda pairs, *_: pairs)
+    try:
+        parent = compiled()
+    finally:
+        mp.undo()
+    # the tile XLA chooses for itself is in the compiled text: 512 rows
+    assert set(re.findall(r'ragged_dot_tiling="([^"]*)"',
+                          parent.as_text())) == {"512,512,512"}
+    # the fallback branch reserves what the parent's layer did (336.3
+    # MB) and the conditional 0.6 MB more: no saving, no cost
+    assert (got.memory_analysis().temp_size_in_bytes
+            < 1.01 * parent.memory_analysis().temp_size_in_bytes)
