@@ -62,6 +62,18 @@ Registered as ``dots3_note`` (dots3-note-prev's layers) and
 ``dots3_note_tiny``; the equations are in
 ``benchmark/reference/dots3-note-prev.py``.
 
+A description that names ``layer_types`` and no indexer (``index_topk``
+0) mixes by GROUPED-QUERY attention in both kinds (``ops/gqa.py``):
+``full_attention`` layers (``gqa_full``: ``heads`` query heads over
+``kv_heads`` KV heads, rotary positions on the leading ``rotary_dim``
+values of a head at YaRN's frequencies, ``rope_scaling``) keep every
+row's K and V in a paged pool, ``sliding_attention`` layers
+(``gqa_window``: ``swa_heads`` query heads over the same KV heads, plain
+rotary positions at ``swa_rope_theta``) keep the last ``window`` rows in
+a ring a slot; both gate each head's output (``head_gate``). Registered
+as ``laguna`` (Laguna-S-2.1's layers) and ``laguna_tiny``; the equations
+are in ``benchmark/reference/laguna-s-2.1.py``.
+
 Equations (per layer, pre-norm, no bias anywhere)::
 
     h = x + W_o . Attn(rope(rms_d(W_q n)), rope(rms_d(W_k n)), W_v n),
@@ -85,6 +97,7 @@ from jax import lax
 
 from ..config import TrainConfig
 from ..ops import dsa as dsa_ops
+from ..ops import gqa as gqa_ops
 from ..ops import kda as kda_ops
 from ..ops import mla as mla_ops
 from ..ops.moe import moe_dropless, pair_bound
@@ -163,6 +176,43 @@ class DecoderBlockConfig:
     swa_qk_rope_dim: int = 0
     swa_v_head_dim: int = 0
     swa_rope_theta: float = 0.0
+    # ---- grouped-query layers by name (layer_types without an indexer) --
+    #: values of a full layer's head that are rotated (0 = all of them)
+    rotary_dim: int = 0
+    #: YaRN in the full layers: (factor, original context, beta_fast,
+    #: beta_slow, attention factor); () = plain rotary at ``rope_theta``
+    rope_scaling: tuple = ()
+
+    @classmethod
+    def laguna_s_2_1(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=100352, hidden=3072, layers=48, heads=48,
+                   kv_heads=8, head_dim=128, norm_eps=1e-6, qk_norm=False,
+                   rope_theta=5e5, experts=256, experts_per_token=10,
+                   expert_width=1024, block_length=1, mask_id=0,
+                   max_len=1048576,
+                   layer_types=("full_attention",
+                                *("sliding_attention",) * 3) * 12,
+                   kv_lora_rank=0, head_gate=True, window=512, swa_heads=72,
+                   swa_rope_theta=1e4, rotary_dim=64,
+                   rope_scaling=(128.0, 8192, 32.0, 1.0,
+                                 1.4852030263919618),
+                   dense_layers=1, dense_width=12288, shared_experts=1,
+                   router_scores="softmax", routed_scale=2.5)
+
+    @classmethod
+    def laguna_tiny(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=512, hidden=64, layers=5, heads=4, kv_heads=2,
+                   head_dim=16, norm_eps=1e-6, qk_norm=False, rope_theta=5e5,
+                   experts=8, experts_per_token=3, expert_width=32,
+                   block_length=1, mask_id=0, max_len=4096,
+                   layer_types=("full_attention", "sliding_attention",
+                                "sliding_attention", "sliding_attention",
+                                "full_attention"),
+                   kv_lora_rank=0, head_gate=True, window=9, swa_heads=6,
+                   swa_rope_theta=1e4, rotary_dim=8,
+                   rope_scaling=(16.0, 32, 32.0, 1.0, 1.2),
+                   dense_layers=1, dense_width=128, shared_experts=1,
+                   router_scores="softmax", routed_scale=2.5)
 
     @classmethod
     def dots3_note_prev(cls) -> "DecoderBlockConfig":
@@ -253,13 +303,29 @@ class DecoderBlockConfig:
 
     def mixer(self, i: int) -> str:
         """Layer ``i``'s mixer (``i`` from 0): ``gqa``, ``kda``, ``mla``,
-        ``mla_sparse`` or ``mla_window``."""
+        ``mla_sparse`` or ``mla_window``, ``gqa_full`` or ``gqa_window``.
+        A named layer's kind follows from the description: latent
+        attention where it has an indexer over a latent
+        (``index_topk``, ``kv_lora_rank``), grouped-query attention
+        where it has neither."""
         if self.layer_types:
-            return {"full_attention": "mla_sparse",
-                    "sliding_attention": "mla_window"}[self.layer_types[i]]
+            full = self.layer_types[i] == "full_attention"
+            if self.typed_mla:
+                return "mla_sparse" if full else "mla_window"
+            return "gqa_full" if full else "gqa_window"
         if not self.linear_attn:
             return "gqa"
         return "mla" if i + 1 in self.full_attn_layers else "kda"
+
+    @property
+    def typed_mla(self) -> bool:
+        """Named layers that mix by latent attention under an indexer."""
+        return bool(self.layer_types and self.index_topk
+                    and self.kv_lora_rank)
+
+    def heads_of(self, kind: str) -> int:
+        """Query heads of a grouped-query layer of ``kind``."""
+        return self.swa_heads if kind == "gqa_window" else self.heads
 
     def geometry(self, kind: str) -> "MlaGeometry":
         """The sizes of an MLA layer of ``kind``."""
@@ -326,8 +392,13 @@ class MlaGeometry:
         return (self.nope + self.pe) ** -0.5
 
 
+#: a grouped-query description's caches (state_specs): the paged K/V
+#: pool of its full layers, the rings of its window layers
+_KV_ARRAYS = ("cache_k", "cache_v", "cache_window_k", "cache_window_v")
+
 #: the parameter group a layer's mixer lies under, where not its kind
-_GROUP = {"gqa": "attn", "mla_sparse": "mla", "mla_window": "mla"}
+_GROUP = {"gqa": "attn", "mla_sparse": "mla", "mla_window": "mla",
+          "gqa_full": "attn", "gqa_window": "attn"}
 
 
 def _rms(x, scale, eps: float):
@@ -337,16 +408,28 @@ def _rms(x, scale, eps: float):
     return x * inv * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, theta: float):
-    """Rotary positions, rotate-half over the whole head: ``x`` [T, H, D]
-    float32, ``pos`` [T] int32."""
-    d = x.shape[-1]
-    inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+def _rope(x, pos, theta: float, *, width: int = 0, inv_freq=None,
+          factor: float = 1.0):
+    """Rotary positions, rotate-half over the leading ``width`` values of
+    a head (0: the whole head): ``x`` [T, H, D] float32, ``pos`` [T]
+    int32. ``inv_freq`` [width / 2]: a table of inverse frequencies in
+    the place of ``theta``'s own; ``factor`` multiplies cos and sin
+    (YaRN's attention factor)."""
+    d = width or x.shape[-1]
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     ang = pos.astype(jnp.float32)[:, None] * inv_freq[None, :]  # [T, D/2]
     cos = jnp.concatenate([jnp.cos(ang)] * 2, axis=-1)[:, None, :]
     sin = jnp.concatenate([jnp.sin(ang)] * 2, axis=-1)[:, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    if factor != 1.0:
+        cos, sin = cos * factor, sin * factor
+    if d == x.shape[-1]:
+        x1, x2 = x[..., :d // 2], x[..., d // 2:]
+        return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
+    xr, x1, x2 = x[..., :d], x[..., :d // 2], x[..., d // 2:d]
+    return jnp.concatenate(
+        [xr * cos + jnp.concatenate([-x2, x1], axis=-1) * sin, x[..., d:]],
+        axis=-1)
 
 
 class BlockDecoder(DefaultRulesMixin):
@@ -354,9 +437,12 @@ class BlockDecoder(DefaultRulesMixin):
 
     def __init__(self, cfg: DecoderBlockConfig, dtype=jnp.bfloat16,
                  param_dtype=jnp.bfloat16):
-        if cfg.heads % cfg.kv_heads:
-            raise ValueError(f"{cfg.heads} query heads do not divide over "
-                             f"{cfg.kv_heads} KV heads")
+        named_gqa = cfg.layer_types and not cfg.typed_mla
+        for heads in (cfg.heads, cfg.swa_heads) if named_gqa else (
+                cfg.heads,):
+            if not heads or heads % cfg.kv_heads:
+                raise ValueError(f"{heads} query heads do not divide over "
+                                 f"{cfg.kv_heads} KV heads")
         b = cfg.block_length
         if b < 1 or b & (b - 1):
             raise ValueError(f"block_length must be a power of two, got {b}")
@@ -369,7 +455,7 @@ class BlockDecoder(DefaultRulesMixin):
                 "a kind a layer (linear_attn or layer_types) and one token "
                 "a step (block_length = 1) come together: the "
                 "block-diffusion forwards run grouped-query attention "
-                "only, the one-token forwards KDA and MLA only; got "
+                "only, under one head count and no window; got "
                 f"linear_attn={cfg.linear_attn}, layer_types of "
                 f"{len(cfg.layer_types)}, block_length={b}")
         if cfg.layer_types:
@@ -377,9 +463,26 @@ class BlockDecoder(DefaultRulesMixin):
                 raise ValueError(
                     f"layer_types names {len(cfg.layer_types)} layers of "
                     f"{cfg.layers} (and excludes linear_attn)")
-            if not (cfg.window and cfg.index_topk and cfg.index_heads):
-                raise ValueError("layer_types needs window, index_topk and "
-                                 "index_heads")
+            if not cfg.window:
+                raise ValueError("layer_types needs window (the rows a "
+                                 "sliding_attention layer sees)")
+            if bool(cfg.index_topk) != bool(cfg.index_heads):
+                raise ValueError(
+                    "an indexer needs index_topk and index_heads (latent "
+                    "attention under a selection); without either the "
+                    f"named layers are grouped-query; got index_topk="
+                    f"{cfg.index_topk}, index_heads={cfg.index_heads}")
+            if cfg.index_topk and not cfg.kv_lora_rank:
+                raise ValueError(
+                    "a selection over K/V heads is not built: an indexer "
+                    "(index_topk) needs the latent cache (kv_lora_rank)")
+            if cfg.rotary_dim % 2 or cfg.rotary_dim > cfg.head_dim:
+                raise ValueError(f"rotary_dim {cfg.rotary_dim} is not an "
+                                 f"even part of a head of {cfg.head_dim}")
+            if cfg.rope_scaling and len(cfg.rope_scaling) != 5:
+                raise ValueError(
+                    "rope_scaling is (factor, original context, beta_fast,"
+                    f" beta_slow, attention factor), got {cfg.rope_scaling}")
         if cfg.router_scores not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_scores {cfg.router_scores!r}")
         if cfg.first_vocab + cfg.vocab > cfg.vocab_size:
@@ -427,6 +530,16 @@ class BlockDecoder(DefaultRulesMixin):
                     "down": glorot(*lead, width, c.hidden)}
 
         def mixer(kind):
+            if kind in ("gqa_full", "gqa_window"):
+                hd = c.heads_of(kind) * c.head_dim
+                ap = {"wq": glorot(c.hidden, hd), "wk": glorot(c.hidden, kd),
+                      "wv": glorot(c.hidden, kd), "wo": glorot(hd, c.hidden)}
+                if c.head_gate:
+                    ap["wg"] = glorot(c.hidden, c.heads_of(kind))
+                if c.qk_norm:
+                    ap.update(q_norm=ones(c.head_dim),
+                              k_norm=ones(c.head_dim))
+                return ap
             if kind == "gqa":
                 return {"wq": glorot(c.hidden, qd),
                         "wk": glorot(c.hidden, kd),
@@ -685,6 +798,18 @@ class BlockDecoder(DefaultRulesMixin):
         a request takes the slot, carried from chunk to chunk to the
         decode steps, and left as it lies at release."""
         c = self.cfg
+        if c.layer_types and not c.typed_mla:
+            full, win = c.layers_of("gqa_full"), c.layers_of("gqa_window")
+            dt = str(jnp.dtype(self.dtype))
+            row = c.kv_heads * c.head_dim   # a token's KV heads, side by side
+            pool = {"shape": [len(full), num_blocks, block_size, row],
+                    "dtype": dt, "layers": full, "per": "block"}
+            # the last `window` rows of a slot, row p % ring holding
+            # position p: bounded, whatever the context
+            ring = {"shape": [len(win), slots, c.ring, row],
+                    "dtype": dt, "layers": win, "per": "slot"}
+            return {"cache_k": pool, "cache_v": dict(pool),
+                    "cache_window_k": ring, "cache_window_v": dict(ring)}
         if c.layer_types:
             full, win = c.layers_of("mla_sparse"), c.layers_of("mla_window")
             dt = str(jnp.dtype(self.dtype))
@@ -870,12 +995,66 @@ class BlockDecoder(DefaultRulesMixin):
                           w_vb.astype(self.dtype),
                           preferred_element_type=jnp.float32)
 
+    def _gate_heads(self, mp, n, ctx):
+        """``g_h . ctx_h`` with ``g = sigmoid(W_g n)`` (a method so that a
+        planted fault can drop the gate)."""
+        return ctx * jax.nn.sigmoid(self._mm(n, mp["wg"]))[..., None]
+
     def _typed_out(self, mp, n, ctx):
         """``W_o concat_h(g_h . ctx_h)``: ``ctx`` [T, H, v] float32."""
         t = n.shape[0]
         if self.cfg.head_gate:
-            ctx = ctx * jax.nn.sigmoid(self._mm(n, mp["wg"]))[..., None]
+            ctx = self._gate_heads(mp, n, ctx)
         return self._mm(ctx.reshape(t, -1), mp["wo"])
+
+    def _full_rope(self, x, pos):
+        """Rotary positions of a grouped-query full layer: the leading
+        ``rotary_dim`` values of a head, at YaRN's frequencies where the
+        description scales them (a method so that a planted fault can
+        rotate the whole head at the plain ones)."""
+        c = self.cfg
+        if not c.rope_scaling:
+            return _rope(x, pos, c.rope_theta, width=c.rotary_dim)
+        factor, original, fast, slow, attn = c.rope_scaling
+        return _rope(x, pos, c.rope_theta, width=c.rotary_dim,
+                     inv_freq=jnp.asarray(gqa_ops.yarn_inv_freq(
+                         c.rotary_dim or c.head_dim, c.rope_theta, factor,
+                         original, fast, slow)), factor=attn)
+
+    def _gqa_qkv(self, ap, n, pos, kind: str):
+        """A grouped-query layer's inputs from the normed rows ``n``
+        [T, h]: q [T, H, D] (H the kind's own), k and v [T, KVH x D] as
+        the caches keep them, in ``dtype``, q and k rotated."""
+        c = self.cfg
+        t = n.shape[0]
+        q = self._mm(n, ap["wq"]).reshape(t, -1, c.head_dim)
+        k = self._mm(n, ap["wk"]).reshape(t, c.kv_heads, c.head_dim)
+        v = self._mm(n, ap["wv"])
+        if c.qk_norm:
+            q = _rms(q, ap["q_norm"], c.norm_eps)
+            k = _rms(k, ap["k_norm"], c.norm_eps)
+        if kind == "gqa_full":
+            q, k = self._full_rope(q, pos), self._full_rope(k, pos)
+        else:
+            q = _rope(q, pos, c.swa_rope_theta)
+            k = _rope(k, pos, c.swa_rope_theta)
+        return (self._heads_in(q.astype(self.dtype)),
+                k.reshape(t, -1).astype(self.dtype), v.astype(self.dtype))
+
+    def _heads_in(self, q):
+        """The query heads in the order the attention groups them: head
+        ``j`` reads KV head ``j // (H / KVH)`` (a method, with
+        :meth:`_heads_out`, so that a planted fault can deal them
+        otherwise)."""
+        return q
+
+    def _heads_out(self, ctx):
+        return ctx
+
+    def _routed_scale(self) -> float:
+        """What the renormalised picks' weights sum to (a method so that
+        a planted fault can leave it off)."""
+        return self.cfg.routed_scale
 
     def _chunk_index(self, index, j, blocks, keys):
         """The index pool with a chunk's keys in its blocks (a method so
@@ -912,7 +1091,7 @@ class BlockDecoder(DefaultRulesMixin):
             m, mp["router"], mp, top_k=c.experts_per_token,
             first_expert=c.first_expert, dtype=self.dtype,
             router_dtype=self.router_dtype, scores=c.router_scores,
-            select_bias=mp.get("router_bias"), scale=c.routed_scale)
+            select_bias=mp.get("router_bias"), scale=self._routed_scale())
         if "shared" in mp:
             y = y + gated(mp["shared"], m)
         return h + y, rows
@@ -943,11 +1122,12 @@ class BlockDecoder(DefaultRulesMixin):
         it and ran at the whole width."""
         c = self.cfg
         cw = input_ids.shape[1]
-        latent = state["cache_latent"]
-        # the arrays of the other kinds this model has (state_specs)
+        # the arrays of the kinds this model has (state_specs)
+        latent = state.get("cache_latent")
         s_all, conv_all = state.get("cache_state"), state.get("cache_conv")
         index, rings = state.get("cache_index"), state.get("cache_window")
-        n_mla, nb, bs, r = latent.shape
+        kv = {k: state[k] for k in _KV_ARRAYS if k in state}
+        n_mla, nb, bs, r = (kv["cache_k"] if kv else latent).shape
         flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
         table_row = jnp.asarray(table_row, jnp.int32)
         chunk_blocks = jnp.asarray(chunk_blocks, jnp.int32)
@@ -963,11 +1143,39 @@ class BlockDecoder(DefaultRulesMixin):
         kda_at, mla_at = c.state_rows()
         sparse_at, window_at = (c.rows_of("mla_sparse"),
                                 c.rows_of("mla_window"))
+        gfull_at, gwin_at = c.rows_of("gqa_full"), c.rows_of("gqa_window")
         pos = start + jnp.arange(cw, dtype=jnp.int32)
         for i in range(c.layers):
             lp = params["layers"][str(i)]
             n = _rms(h, lp["attn_norm"], c.norm_eps)
-            if i in sparse_at:
+            if i in gfull_at:
+                j, ap = gfull_at[i], lp["attn"]
+                with jax.named_scope("gqa_full"):
+                    q, k, v = self._gqa_qkv(ap, n, pos, "gqa_full")
+                    for name, rows in (("cache_k", k), ("cache_v", v)):
+                        kv[name] = kv[name].at[j, chunk_blocks].set(
+                            rows.reshape(cw // bs, bs, r))
+                    ctx = gqa_ops.gqa_prefill_attention(
+                        q, kv["cache_k"].reshape(flat),
+                        kv["cache_v"].reshape(flat), table_row + j * nb,
+                        start, key_tile=min(1024, cw), impl=attention)
+                    h = h + self._typed_out(ap, n, self._heads_out(ctx))
+            elif i in gwin_at:
+                j, ap = gwin_at[i], lp["attn"]
+                with jax.named_scope("gqa_window"):
+                    q, k, v = self._gqa_qkv(ap, n, pos, "gqa_window")
+                    ctx = gqa_ops.gqa_window_prefill_attention(
+                        q, k, v, kv["cache_window_k"][j, slot],
+                        kv["cache_window_v"][j, slot], start,
+                        window=self._window(), block_size=bs,
+                        impl=attention)
+                    for name, rows in (("cache_window_k", k),
+                                       ("cache_window_v", v)):
+                        kv[name] = kv[name].at[j, slot].set(
+                            mla_ops.ring_after_chunk(
+                                kv[name][j, slot], rows, start, n_valid))
+                    h = h + self._typed_out(ap, n, self._heads_out(ctx))
+            elif i in sparse_at:
                 j, mp = sparse_at[i], lp["mla"]
                 g = c.geometry("mla_sparse")
                 with jax.named_scope("mla_sparse"):
@@ -1046,7 +1254,7 @@ class BlockDecoder(DefaultRulesMixin):
                 h, last, 1), with_logits)
         if with_logits:
             ids = lax.dynamic_slice_in_dim(ids, last, 1)
-        out = {"ids": ids, "expert_rows": expert_rows,
+        out = {"ids": ids, "expert_rows": expert_rows, **kv,
                **self._state_out(latent, s_all, conv_all, index, rings)}
         if bounded:
             out["moe_whole"] = moe_whole
@@ -1082,10 +1290,11 @@ class BlockDecoder(DefaultRulesMixin):
         numbers of :meth:`block_step`."""
         c = self.cfg
         s = tok.shape[0]
-        latent = state["cache_latent"]
+        latent = state.get("cache_latent")
         s_all, conv_all = state.get("cache_state"), state.get("cache_conv")
         index, rings = state.get("cache_index"), state.get("cache_window")
-        n_mla, nb, bs, r = latent.shape
+        kv = {k: state[k] for k in _KV_ARRAYS if k in state}
+        n_mla, nb, bs, r = (kv["cache_k"] if kv else latent).shape
         flat = (n_mla * nb, bs, r)      # a layer's blocks, nb further on
         bt = jnp.asarray(block_tables, jnp.int32)
         live = jnp.asarray(alive) != 0
@@ -1098,11 +1307,38 @@ class BlockDecoder(DefaultRulesMixin):
         kda_at, mla_at = c.state_rows()
         sparse_at, window_at = (c.rows_of("mla_sparse"),
                                 c.rows_of("mla_window"))
+        gfull_at, gwin_at = c.rows_of("gqa_full"), c.rows_of("gqa_window")
         scale = (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
         for i in range(c.layers):
             lp = params["layers"][str(i)]
             n = _rms(h, lp["attn_norm"], c.norm_eps)
-            if i in sparse_at:
+            if i in gfull_at:
+                j, ap = gfull_at[i], lp["attn"]
+                with jax.named_scope("gqa_full"):
+                    q, k, v = self._gqa_qkv(ap, n, pos, "gqa_full")
+                    kv["cache_k"] = kv["cache_k"].at[j, pbid, off].set(k)
+                    kv["cache_v"] = kv["cache_v"].at[j, pbid, off].set(v)
+                    ctx = gqa_ops.paged_gqa_decode_attention(
+                        q, kv["cache_k"].reshape(flat),
+                        kv["cache_v"].reshape(flat),
+                        block_tables=bt + j * nb, pos=pos, impl=attention)
+                    h = h + self._typed_out(ap, n, self._heads_out(ctx))
+            elif i in gwin_at:
+                j, ap = gwin_at[i], lp["attn"]
+                with jax.named_scope("gqa_window"):
+                    q, k, v = self._gqa_qkv(ap, n, pos, "gqa_window")
+                    at = pos % c.ring
+                    mine = jnp.arange(s)
+                    # a row that is not alive keeps its ring to the bit
+                    for name, rows in (("cache_window_k", k),
+                                       ("cache_window_v", v)):
+                        kv[name] = kv[name].at[j, mine, at].set(jnp.where(
+                            live[:, None], rows, kv[name][j, mine, at]))
+                    ctx = gqa_ops.gqa_window_decode_attention(
+                        q, kv["cache_window_k"][j], kv["cache_window_v"][j],
+                        pos, window=self._window())
+                    h = h + self._typed_out(ap, n, self._heads_out(ctx))
+            elif i in sparse_at:
                 j, mp = sparse_at[i], lp["mla"]
                 g = c.geometry("mla_sparse")
                 with jax.named_scope("mla_sparse"):
@@ -1189,6 +1425,7 @@ class BlockDecoder(DefaultRulesMixin):
         mean_load = s * c.experts_per_token / c.experts
         out = {"ids": ids, "expert_rows": expert_rows,
                "max_expert_load": fullest.astype(jnp.float32) / mean_load,
+               **kv,
                **self._state_out(latent, s_all, conv_all, index, rings)}
         if with_logits:
             out["logits"] = logits
@@ -1237,4 +1474,18 @@ def _make_dots3_note(config: TrainConfig) -> BlockDecoder:
 def _make_dots3_note_tiny(config: TrainConfig) -> BlockDecoder:
     model = _make(config, DecoderBlockConfig.dots3_note_tiny())
     model.name = "dots3_note"
+    return model
+
+
+@register_model("laguna")
+def _make_laguna(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.laguna_s_2_1())
+    model.name = "laguna"
+    return model
+
+
+@register_model("laguna_tiny")
+def _make_laguna_tiny(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.laguna_tiny())
+    model.name = "laguna"
     return model

@@ -374,10 +374,12 @@ def ragged_tiling(pairs: int, k: int, n: int, dtype) -> str | None:
     faster than XLA's own. The number of groups does not enter: the tile
     that is best at 8 rows a group is best at 256.
 
-    Where ``k`` or ``n`` passes 4,096 the whole matrix is over the
-    budget (dots3-note-prev's [5120, 1536] is 36 MB) and ``n`` is cut:
-    128 rows by the whole ``k`` by the widest ``n / d`` that is a
-    multiple of 128 and fits. Read at 32 groups of [5120, 1536] and
+    Where the whole matrix is over the budget (dots3-note-prev's
+    [5120, 1536] is 36 MB double-buffered, Laguna-S-2.1's [3072, 1024]
+    15.7) ``n`` is cut: 128 rows by the whole ``k`` by the widest
+    ``n / d`` that is a multiple of 128 and fits (``128,3072,512`` and
+    ``128,1024,1536`` for Laguna's: ``benchmark/records/pr41/
+    ragged_laguna_sweep.jsonl``). Read at 32 groups of [5120, 1536] and
     [1536, 5120], 1,024 grouped rows in 8,192, 2,048 and 1,024:
     ``128,5120,512`` 0.77-0.78 ms and ``128,1536,1280`` 0.80 against
     1.74-1.77 under XLA's own tile and 0.61 of weight read; a cut of
@@ -393,9 +395,8 @@ def ragged_tiling(pairs: int, k: int, n: int, dtype) -> str | None:
     if (jnp.dtype(dtype) != jnp.bfloat16 or k % 128 or n % 128
             or max(k, n) > 8192 or pairs % tm):
         return None
-    # up to 4,096 the whole n or XLA's own; past it the widest cut of n
-    cuts = range(1, n // 128 + 1) if max(k, n) > 4096 else (1,)
-    for d in cuts:
+    # the whole n where it fits the budget, else the widest cut of it
+    for d in range(1, n // 128 + 1):
         tn = n // d
         vmem = 2 * 2 * (tm * k + k * tn) + 3 * 4 * tm * tn
         if n % d == 0 and tn % 128 == 0 and vmem <= _RAGGED_VMEM_BUDGET:
@@ -492,11 +493,12 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     [Eh, F, H]}``, the held experts, whose global ids are
     ``first_expert .. first_expert + Eh - 1``. Routing is over all E:
     float32 softmax of the float32 router product, the ``top_k``
-    largest, renormalised to sum to 1. ``scores="sigmoid"`` is the other
-    published router: float32 sigmoid scores, the ``top_k`` largest of
-    ``score + select_bias`` (a bias [E] that picks and does not weigh),
-    the picked scores renormalised to sum to ``scale``
-    (``routed_scaling_factor``). The (row, expert) pairs whose
+    largest, renormalised to sum to ``scale``
+    (``routed_scaling_factor``; 1 unless a description says otherwise).
+    ``scores="sigmoid"`` is the other published router: float32 sigmoid
+    scores, the ``top_k`` largest of ``score + select_bias`` (a bias [E]
+    that picks and does not weigh), the picked scores renormalised to
+    sum to ``scale`` too. The (row, expert) pairs whose
     expert is held are sorted by expert and run through one grouped
     matmul per projection (``lax.ragged_dot``, ``ragged-dot-*`` in a
     capture, at the tile :func:`ragged_tiling` gives for its shape);
@@ -523,6 +525,8 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
         if scores == "softmax":
             w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
             w = w / jnp.sum(w, axis=-1, keepdims=True)
+            if scale != 1.0:
+                w = w * scale
         else:
             w, idx = sigmoid_top_k(logits, select_bias, top_k, scale)
         local = (idx - first_expert).reshape(-1)            # [T * k]

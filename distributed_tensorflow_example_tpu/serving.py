@@ -1032,7 +1032,9 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
     ``params/`` and taken as every program's first argument. Returns
     ``(weights, param_count, param_bytes, moe)``: ``moe`` by program
     what its expert layers were traced with (``ops/moe.tile_log``), to
-    keep under ``export.json``'s ``moe_tiles`` and ``moe_rows``."""
+    keep under ``export.json``'s ``moe_tiles`` and ``moe_rows``; and,
+    where a program's one-token attention logged one
+    (``decode_attention.schedule_log``), ``attn_schedule`` by program."""
     leaves = jax.tree_util.tree_leaves(params)
     param_bytes = sum(int(np.prod(x.shape)) * x.dtype.itemsize
                       for x in leaves)
@@ -1048,7 +1050,7 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
         # with and the rows they run over, of the pairs (every layer has
         # the same shapes): fixed once compiled
         rows = {}
-        with tile_log(rows) as tiles:
+        with tile_log(rows) as tiles, schedule_log() as seen:
             if as_args:
                 exp = jax_export.export(
                     jax.jit(fn), platforms=list(platforms))(p_specs, specs)
@@ -1058,6 +1060,9 @@ def _trace_with_params(fns, params, platforms, out_dir: str):
                     platforms=list(platforms))(specs)
         program = name.removesuffix(".stablehlo")
         moe["moe_tiles"][program], moe["moe_rows"][program] = tiles, rows
+        if seen:
+            moe.setdefault("attn_schedule", {})[program] = (
+                seen[0] if len(seen) == 1 else seen)
         if chief:
             with open(os.path.join(out_dir, name), "wb") as f:
                 f.write(exp.serialize())
@@ -1085,10 +1090,12 @@ def _export_state_generator(model, params, out_dir: str, *,
     ``state_specs`` names, by layer kind, recorded under
     ``stepwise.state``: ``per: "block"`` arrays lie behind the block
     tables (the latent pool ``[L_mla, N, Bs, R]``; the index-key pool
-    ``[L_full, N, Bs, D]`` of a model that selects), ``per: "slot"`` arrays
-    hold one row a slot (the recurrent state ``[L_kda, slots, H, d, d]``
-    float32 and the convolutions' tails; a window layer's ring
-    ``[L_win, slots, ring, R]``). All of them are the donated
+    ``[L_full, N, Bs, D]`` of a model that selects; the K and V pools
+    ``[L_full, N, Bs, KVH x D]`` of grouped-query full layers), ``per:
+    "slot"`` arrays hold one row a slot (the recurrent state ``[L_kda,
+    slots, H, d, d]`` float32 and the convolutions' tails; a window
+    layer's ring ``[L_win, slots, ring, R]``, or its K and V rings). All
+    of them are the donated
     ``cache_*`` operands of both programs, updated in place. Weights as in
     :func:`_export_block_generator`."""
     c = model.cfg
@@ -1166,7 +1173,10 @@ def _export_state_generator(model, params, out_dir: str, *,
     weights, param_count, param_bytes, moe = _trace_with_params(
         ((_PREFILL_CHUNK, chunk_fn, chunk_specs),
          (_DECODE, decode_fn, decode_specs)), params, platforms, out_dir)
-    pool_shape = specs["cache_latent"]["shape"]
+    # the first of the arrays behind the block tables
+    pool_shape = next(v["shape"] for v in specs.values()
+                      if v["per"] == "block")
+    attn_schedule = moe.pop("attn_schedule", None)
     meta = {
         "model": getattr(model, "name", type(model).__name__),
         "kind": "generator", "batch_polymorphic": False,
@@ -1210,6 +1220,10 @@ def _export_state_generator(model, params, out_dir: str, *,
                       **moe},
         },
     }
+    if attn_schedule:
+        # what each program's one-token attention was traced with, where
+        # GPT-2's artifacts keep theirs
+        meta["stepwise"]["decode"] = {"attn_schedule": attn_schedule}
     artifact = os.path.join(out_dir, _DECODE)
     if jax.process_index() == 0:
         with open(os.path.join(out_dir, _META), "w") as f:

@@ -1801,7 +1801,15 @@ class GenerationEngine:
             "bytes of the paged latent pool of a per-request-state "
             "artifact (0 otherwise)")
         self._g_latent_pool_bytes.set(
-            int(self._pool["cache_latent"].nbytes) if self.state else 0)
+            int(self._pool["cache_latent"].nbytes)
+            if self.state and "cache_latent" in self._pool else 0)
+        self._g_kv_pool_bytes = reg.gauge(
+            "serving_kv_pool_bytes",
+            "bytes of the paged K and V pools of a per-request-state "
+            "artifact whose full layers keep K/V heads (0 otherwise)")
+        self._g_kv_pool_bytes.set(sum(
+            int(self._pool[k].nbytes) for k in ("cache_k", "cache_v")
+            if self.state and k in self._pool))
         self._g_index_pool_bytes = reg.gauge(
             "serving_index_pool_bytes",
             "bytes of the paged index-key pool of an artifact whose "
@@ -1810,10 +1818,11 @@ class GenerationEngine:
             "serving_window_cache_bytes",
             "bytes of the window layers' rings, every slot's (0 for "
             "artifacts without window layers)")
+        # (a grouped-query artifact keeps a ring for K and one for V)
         for gauge, name in ((self._g_index_pool_bytes, "cache_index"),
                             (self._g_window_cache_bytes, "cache_window")):
-            gauge.set(int(self._pool[name].nbytes)
-                      if name in self._pool else 0)
+            gauge.set(sum(int(v.nbytes) for k, v in self._pool.items()
+                          if k == name or k.startswith(name + "_")))
         #: bytes of each array the engine holds for the artifact
         self._pool_bytes = {k: int(v.nbytes) for k, v in self._pool.items()}
         #: a selecting artifact (``state.index_topk`` > 0): per layer
@@ -1831,6 +1840,20 @@ class GenerationEngine:
                          "latent": of("cache_latent"),
                          "index": of("cache_index"),
                          "ring": of("cache_window")}
+        #: a grouped-query artifact with window layers (``state.window``
+        #: > 0 and no indexer): the full layers, the window, the window
+        #: layers and the bytes of one ring row (K and V), from which the
+        #: chunk and decode spans say what the contexts held and what
+        #: the window layers read
+        self._gqa: dict | None = None
+        if self.state and "cache_window_k" in self._pool:
+            rings = [v for k, v in self._pool.items()
+                     if k.startswith("cache_window_")]
+            self._gqa = {"window": int(self.state["window"]),
+                         "full": int(self._pool["cache_k"].shape[0]),
+                         "ring": (int(rings[0].shape[0]), sum(
+                             int(a.shape[-1]) * a.dtype.itemsize
+                             for a in rings))}
         self._c_dsa_selected = reg.counter(
             "serving_dsa_selected_rows_total",
             "rows the full-attention layers attended to after selection, "
@@ -3591,6 +3614,16 @@ class GenerationEngine:
         layers; ``kv_bytes``: the selected latent rows as stored;
         ``window_bytes``: ring rows the window layers read."""
         d = self._dsa
+        if d is None and self._gqa is not None:
+            # grouped-query full layers attend to every row: no
+            # selection, the contexts' K/V rows as stored (a chunk's rows
+            # share one context) and the window layers' ring rows
+            g = self._gqa
+            n_win, ring_b = g["ring"]
+            return {"context_rows": int(contexts.sum()) * g["full"],
+                    "kv_bytes": int(keys) * self._kv_token_bytes,
+                    "window_bytes": int(np.minimum(
+                        contexts, g["window"]).sum()) * n_win * ring_b}
         if d is None:
             return {}
         (n_full, lat_b), (_, idx_b), (n_win, ring_b) = (
@@ -3620,8 +3653,9 @@ class GenerationEngine:
                 "expert_rows": self._expert_rows_last,
                 # an id a slot and the two routing scalars
                 "host_bytes": 4 * self.slots + 8}
-        if self._dsa is not None:
+        if self._dsa is not None or self._gqa is not None:
             # what the SELECTED rows hold replaces what the contexts hold
+            # (a grouped-query artifact selects nothing: the same bytes)
             contexts = feats["pos"][feats["alive"] != 0] + 1
             args.update(self._describe_selection(contexts, contexts.sum()))
         return args
@@ -4120,6 +4154,7 @@ class GenerationEngine:
                       if self.state else None),
             "state_bytes": c("serving_state_bytes"),
             "latent_pool_bytes": c("serving_latent_pool_bytes"),
+            "kv_pool_bytes": c("serving_kv_pool_bytes"),
             "index_pool_bytes": c("serving_index_pool_bytes"),
             "window_cache_bytes": c("serving_window_cache_bytes"),
             "dsa_selected_rows": c("serving_dsa_selected_rows_total"),

@@ -527,6 +527,26 @@ def ragged_rows(wide: str | None = None) -> list[dict]:
     a group (``"wide"`` Kimi's, ``"dots3"`` dots3-note-prev's 32 held
     experts)."""
     rows = []
+    if wide == "laguna":
+        # Laguna-S-2.1's 32 held experts of [3072, 1024]: a chunk's
+        # 10,240 pairs and their bound of 2,560 (1,280 held under even
+        # routing), a decode step's 240 (30 held; no tile divides it)
+        for k, n in ((3072, 1024), (1024, 3072)):
+            tiles = [f"128,{k},{n}", f"256,{k},{n}", f"128,{k},{n // 2}",
+                     f"256,{k},{n // 2}", f"128,{k},{n // 4}",
+                     f"64,{k},{n}", f"128,{k // 2},{n}"]
+            if n % 384 == 0:
+                tiles.append(f"128,{k},{n // 3}")
+            for m, grouped in ((10240, 1280), (2560, 1280), (256, 32)):
+                rows += [dict(m=m, k=k, n=n, groups=32, skew=False,
+                              grouped=grouped, tiling=t)
+                         for t in (None, *tiles)]
+            rows += [dict(m=2560, k=k, n=n, groups=32, skew=True,
+                          grouped=1280, tiling=t)
+                     for t in (None, tiles[0], tiles[2])]
+            rows += [dict(m=240, k=k, n=n, groups=32, skew=False,
+                          grouped=30, tiling=None)]
+        return rows
     if wide == "dots3":
         for (k, n), tiles in DOTS3_TILES.items():
             for m, grouped in ((8192, 1024), (2048, 1024), (1024, 1024)):
@@ -867,7 +887,7 @@ def main() -> None:
                         exist_ok=True)
         third = sys.argv[3] if len(sys.argv) > 3 else None
         kernels(sys.argv[2] if len(sys.argv) > 2 else None, sys.argv[1],
-                wide=third if third in ("wide", "dots3") else None,
+                wide=third if third in ("wide", "dots3", "laguna") else None,
                 parent_py=third if sys.argv[1] == "paged" else None)
         return
     if sys.argv[1:2] == ["--trace"]:

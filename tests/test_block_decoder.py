@@ -533,7 +533,15 @@ BF16 = jnp.bfloat16
     (2048, 2048, 768, jnp.float32, None),   # only bfloat16 was read
     (64, 2048, 768, BF16, None),            # fewer pairs than a tile
     (2000, 2048, 768, BF16, None),          # XLA wants m % tile rows == 0
-    (2048, 4096, 4096, BF16, None),         # 128 x 4096 x 4096: VMEM
+    # wherever the whole matrix is over the budget, at any width, N is
+    # cut (PR 41): read on the chip at what laguna-serve-mixed runs, 32
+    # held experts of [3072, 1024] (15.7 MB double-buffered), a chunk's
+    # 10,240 pairs and their bound; its step's 240 pairs keep XLA's own
+    (10240, 3072, 1024, BF16, "128,3072,512"),
+    (2560, 3072, 1024, BF16, "128,3072,512"),
+    (2560, 1024, 3072, BF16, "128,1024,1536"),
+    (240, 3072, 1024, BF16, None),
+    (2048, 4096, 4096, BF16, "128,4096,512"),   # by the rule, not timed
     (24, 64, 32, BF16, None),               # sdar_moe_tiny's
 ])
 def test_ragged_tiling_is_a_rule_on_shapes(pairs, k, n, dtype, want):

@@ -786,3 +786,88 @@ def test_dots3_chunk_expert_layer_runs_over_the_bound(chip):
     # MB) and the conditional 0.6 MB more: no saving, no cost
     assert (got.memory_analysis().temp_size_in_bytes
             < 1.01 * parent.memory_analysis().temp_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# grouped-query attention over a K/V pool and rings: chunk program and step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["decode", "prefill_chunk"])
+def test_grouped_query_programs_keep_pool_and_rings_where_they_lie(chip,
+                                                                   name):
+    """``laguna``'s two served programs at the ``laguna-serve-mixed``
+    cell's shape (9 layers, 32 held experts, an eighth of the vocabulary;
+    24 slots, 3,073 blocks of 128 tokens, 1,024-token chunks over up to
+    16,384 rows), the state donated: (a) the K and V pools and the K and
+    V rings are aliased to outputs; no ``copy`` of a step is as large as
+    one layer's rings (the first form of the ring attention, a product by
+    KV head, cost twelve a step: 25 MB each), none of a chunk a twentieth
+    of a pool;
+    (b) the one-token attention of the full layers is the Pallas kernel
+    ``paged_gqa_attn`` (3 calls), the chunk's two attentions
+    ``gqa_chunk_attn`` (3 causal + 6 band), and no ``[heads, 1024,
+    1024]`` score tile is written to memory; (c) temporaries under 1 GB
+    beside 6.40 GB of weights."""
+    from distributed_tensorflow_example_tpu.config import TrainConfig
+    from distributed_tensorflow_example_tpu.models import get_model
+    gqa_mod = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.gqa")
+    dev = chip[0]
+    model = get_model("laguna", TrainConfig(
+        model="laguna", dtype="bfloat16", param_dtype="bfloat16",
+        num_layers=9))
+    model.cfg.experts_held, model.cfg.vocab_held = 32, 12544
+    slots, bs, chunk, prompt, new = 24, 128, 1024, 15360, 1024
+    nb = (prompt + new) // bs
+    params = jax.tree_util.tree_map(
+        lambda x: on(dev, x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 1e9 - 6.40) < 0.05
+    specs = model.state_specs(slots=slots, num_blocks=1 + slots * nb,
+                              block_size=bs)
+    state = {k: on(dev, tuple(v["shape"]), jnp.dtype(v["dtype"]))
+             for k, v in specs.items()}
+    i32 = functools.partial(on, dev, dtype=jnp.int32)
+    if name == "decode":
+        fn = lambda st, p, bt, tok, pos, alive: model.decode_step(  # noqa: E731
+            p, st, bt, tok, pos, alive, attention="pallas")
+        args = (i32((slots, nb)), i32((slots,)), i32((slots,)),
+                i32((slots,)))
+    else:
+        fn = lambda st, p, ids, n, start, slot, row, cb: (  # noqa: E731
+            model.prefill_chunk(p, st, ids, n, start, slot, row, cb,
+                                attention="pallas"))
+        args = (i32((1, chunk)), i32(()), i32(()), i32(()),
+                i32((-(-prompt // chunk) * chunk // bs,)),
+                i32((chunk // bs,)))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(gqa_mod, "_interpret", lambda: False)
+    try:
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            state, params, *args).compile()
+    finally:
+        mp.undo()
+    text = compiled.as_text()
+    kernel = "paged_gqa_attn" if name == "decode" else "gqa_chunk_attn"
+    calls = re.findall(rf"(?m)^\s*%{kernel}[.\d]* = \S+ custom-call\(", text)
+    assert len(calls) == (3 if name == "decode" else 9), calls
+    if name == "prefill_chunk":
+        assert not re.search(r"f32\[(?:48|72),1024,(?:512|1024)\]", text)
+    # a step: nothing as large as one layer's rings; a chunk (whose
+    # 72-head activations are 38 MB in float32, laid out head-major by
+    # XLA on either side of the kernel): nothing a twentieth of a pool
+    pool = int(np.prod(specs["cache_k"]["shape"])) * 2
+    limit = (int(np.prod(specs["cache_window_k"]["shape"][1:])) * 2
+             if name == "decode" else pool // 20)
+    big = [m.group(0) for m in re.finditer(
+               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+               [int(x) for x in m.group(2).split(",")]) >= limit]
+    assert not big, big
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    assert aliased == set(range(len(state))), aliased
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
