@@ -74,6 +74,27 @@ a ring a slot; both gate each head's output (``head_gate``). Registered
 as ``laguna`` (Laguna-S-2.1's layers) and ``laguna_tiny``; the equations
 are in ``benchmark/reference/laguna-s-2.1.py``.
 
+A description with a latent (``kv_lora_rank``), one token a step, NO
+``linear_attn`` and NO ``layer_types`` mixes by DENSE latent attention
+in every layer (``mla_dense``): dots3's query low-rank pair and rotary
+positions on ``q_pe`` and on the shared ``k_pe`` before the row is
+cached (at YaRN's frequencies, and the softmax scale times ``mscale^2``,
+where ``rope_scaling`` / ``rope_mscale_all_dim`` say so), every earlier
+row attended to: EXPANDED in a chunk (``ops/mla.mla_chunk_attention``:
+K and V of a head made from a tile of latent rows) and ABSORBED in a
+step (Kimi's ``mla_decode_attention``). Its whole per-request state is
+the paged latent pool. Its sigmoid router may choose by groups
+(``expert_groups``, ``top_expert_groups``). Registered as ``axk1``
+(A.X-K1's layers) and ``axk1_tiny``; the equations are in
+``benchmark/reference/a.x-k1.py``.
+
+How a layer's kind follows from the description
+(:meth:`DecoderBlockConfig.mixer`): ``layer_types`` names it with an
+indexer over a latent (``mla_sparse`` / ``mla_window``) or without
+(``gqa_full`` / ``gqa_window``); ``linear_attn`` makes it ``kda``, or
+``mla`` on ``full_attn_layers``; a latent with neither is ``mla_dense``;
+none of these is the block-diffusion ``gqa``.
+
 Equations (per layer, pre-norm, no bias anywhere)::
 
     h = x + W_o . Attn(rope(rms_d(W_q n)), rope(rms_d(W_k n)), W_v n),
@@ -101,6 +122,7 @@ from ..ops import gqa as gqa_ops
 from ..ops import kda as kda_ops
 from ..ops import mla as mla_ops
 from ..ops.moe import moe_dropless, pair_bound
+from ..ops.pallas.decode_attention import _log_schedule
 from .base import DefaultRulesMixin, register_model, resolve_dtype
 
 
@@ -182,6 +204,43 @@ class DecoderBlockConfig:
     #: YaRN in the full layers: (factor, original context, beta_fast,
     #: beta_slow, attention factor); () = plain rotary at ``rope_theta``
     rope_scaling: tuple = ()
+    # ---- dense latent attention in every layer (a latent, one token a
+    # step, neither linear_attn nor layer_types) ----
+    #: YaRN's ``mscale_all_dim`` of a latent layer: the softmax scale
+    #: times ``(0.1 m ln factor + 1)^2``; 0 = the plain scale
+    rope_mscale_all_dim: float = 0.0
+    #: the sigmoid router chooses among the ``top_expert_groups`` best of
+    #: ``expert_groups`` groups of experts (1 / 1: over all of them)
+    expert_groups: int = 1
+    top_expert_groups: int = 1
+
+    @classmethod
+    def a_x_k1(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=163840, hidden=7168, layers=61, heads=64,
+                   kv_heads=64, head_dim=128, norm_eps=1e-6, qk_norm=False,
+                   rope_theta=1e4, experts=192, experts_per_token=8,
+                   expert_width=2048, block_length=1, mask_id=0,
+                   max_len=131072, mla_rope=True, q_lora_rank=1536,
+                   kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64,
+                   v_head_dim=128,
+                   rope_scaling=(32.0, 4096, 32.0, 1.0, 1.0),
+                   rope_mscale_all_dim=1.0, dense_layers=1,
+                   dense_width=18432, shared_experts=1,
+                   router_scores="sigmoid", routed_scale=2.5,
+                   expert_groups=8, top_expert_groups=4)
+
+    @classmethod
+    def axk1_tiny(cls) -> "DecoderBlockConfig":
+        return cls(vocab_size=512, hidden=64, layers=5, heads=4, kv_heads=4,
+                   head_dim=16, norm_eps=1e-6, qk_norm=False, rope_theta=1e4,
+                   experts=16, experts_per_token=4, expert_width=32,
+                   block_length=1, mask_id=0, max_len=4096, mla_rope=True,
+                   q_lora_rank=24, kv_lora_rank=32, qk_nope_dim=16,
+                   qk_rope_dim=8, v_head_dim=16,
+                   rope_scaling=(8.0, 32, 32.0, 1.0, 1.0),
+                   rope_mscale_all_dim=1.0, dense_layers=1, dense_width=128,
+                   shared_experts=1, router_scores="sigmoid",
+                   routed_scale=2.5, expert_groups=4, top_expert_groups=2)
 
     @classmethod
     def laguna_s_2_1(cls) -> "DecoderBlockConfig":
@@ -299,29 +358,40 @@ class DecoderBlockConfig:
     def stateful(self) -> bool:
         """A kind a layer: served by a chunk program and a one-token
         step over the arrays :meth:`BlockDecoder.state_specs` names."""
-        return self.linear_attn or bool(self.layer_types)
+        return (self.linear_attn or bool(self.layer_types)
+                or self.dense_latent)
 
     def mixer(self, i: int) -> str:
         """Layer ``i``'s mixer (``i`` from 0): ``gqa``, ``kda``, ``mla``,
-        ``mla_sparse`` or ``mla_window``, ``gqa_full`` or ``gqa_window``.
-        A named layer's kind follows from the description: latent
-        attention where it has an indexer over a latent
-        (``index_topk``, ``kv_lora_rank``), grouped-query attention
-        where it has neither."""
+        ``mla_sparse`` or ``mla_window``, ``gqa_full`` or ``gqa_window``,
+        ``mla_dense``. The kind follows from the description: a named
+        layer is latent attention where it has an indexer over a latent
+        (:attr:`selecting_latent`) and grouped-query attention where it
+        has neither; without names, ``linear_attn`` makes it KDA (MLA
+        without positions on ``full_attn_layers``), a latent alone at
+        one token a step (:attr:`dense_latent`) dense latent attention,
+        and nothing at all the block-diffusion ``gqa``."""
         if self.layer_types:
             full = self.layer_types[i] == "full_attention"
-            if self.typed_mla:
+            if self.selecting_latent:
                 return "mla_sparse" if full else "mla_window"
             return "gqa_full" if full else "gqa_window"
-        if not self.linear_attn:
-            return "gqa"
-        return "mla" if i + 1 in self.full_attn_layers else "kda"
+        if self.linear_attn:
+            return "mla" if i + 1 in self.full_attn_layers else "kda"
+        return "mla_dense" if self.dense_latent else "gqa"
 
     @property
-    def typed_mla(self) -> bool:
+    def selecting_latent(self) -> bool:
         """Named layers that mix by latent attention under an indexer."""
         return bool(self.layer_types and self.index_topk
                     and self.kv_lora_rank)
+
+    @property
+    def dense_latent(self) -> bool:
+        """Every layer attends to the whole of a latent cache: a latent,
+        one token a step, no recurrent kind and no named layers."""
+        return bool(self.block_length == 1 and self.kv_lora_rank
+                    and not self.linear_attn and not self.layer_types)
 
     def heads_of(self, kind: str) -> int:
         """Query heads of a grouped-query layer of ``kind``."""
@@ -334,9 +404,15 @@ class DecoderBlockConfig:
                                self.swa_kv_lora_rank, self.swa_qk_nope_dim,
                                self.swa_qk_rope_dim, self.swa_v_head_dim,
                                self.swa_rope_theta)
-        return MlaGeometry(self.heads, self.q_lora_rank, self.kv_lora_rank,
-                           self.qk_nope_dim, self.qk_rope_dim,
-                           self.v_head_dim, self.rope_theta)
+        g = MlaGeometry(self.heads, self.q_lora_rank, self.kv_lora_rank,
+                        self.qk_nope_dim, self.qk_rope_dim,
+                        self.v_head_dim, self.rope_theta)
+        if kind != "mla_dense" or not self.rope_scaling:
+            return g
+        return dataclasses.replace(
+            g, yarn=self.rope_scaling,
+            mscale=0.1 * self.rope_mscale_all_dim * math.log(
+                self.rope_scaling[0]) + 1.0)
 
     def layers_of(self, kind: str) -> list[int]:
         return [i for i in range(self.layers) if self.mixer(i) == kind]
@@ -382,6 +458,11 @@ class MlaGeometry:
     pe: int
     v: int
     theta: float
+    #: YaRN on the rope values: ``DecoderBlockConfig.rope_scaling``
+    yarn: tuple = ()
+    #: YaRN's ``mscale(factor, mscale_all_dim)``; the scores carry it
+    #: twice (q and k)
+    mscale: float = 1.0
 
     @property
     def row(self) -> int:
@@ -389,7 +470,8 @@ class MlaGeometry:
 
     @property
     def scale(self) -> float:
-        return (self.nope + self.pe) ** -0.5
+        # (x 1.0 where nothing scales the context: the plain scale, exactly)
+        return (self.nope + self.pe) ** -0.5 * self.mscale ** 2
 
 
 #: a grouped-query description's caches (state_specs): the paged K/V
@@ -398,7 +480,7 @@ _KV_ARRAYS = ("cache_k", "cache_v", "cache_window_k", "cache_window_v")
 
 #: the parameter group a layer's mixer lies under, where not its kind
 _GROUP = {"gqa": "attn", "mla_sparse": "mla", "mla_window": "mla",
-          "gqa_full": "attn", "gqa_window": "attn"}
+          "mla_dense": "mla", "gqa_full": "attn", "gqa_window": "attn"}
 
 
 def _rms(x, scale, eps: float):
@@ -437,7 +519,7 @@ class BlockDecoder(DefaultRulesMixin):
 
     def __init__(self, cfg: DecoderBlockConfig, dtype=jnp.bfloat16,
                  param_dtype=jnp.bfloat16):
-        named_gqa = cfg.layer_types and not cfg.typed_mla
+        named_gqa = cfg.layer_types and not cfg.selecting_latent
         for heads in (cfg.heads, cfg.swa_heads) if named_gqa else (
                 cfg.heads,):
             if not heads or heads % cfg.kv_heads:
@@ -452,8 +534,9 @@ class BlockDecoder(DefaultRulesMixin):
                 f" are not among the {cfg.experts} the router knows")
         if cfg.stateful != (b == 1):
             raise ValueError(
-                "a kind a layer (linear_attn or layer_types) and one token "
-                "a step (block_length = 1) come together: the "
+                "a kind a layer (linear_attn, layer_types or a latent "
+                "alone) and one token a step (block_length = 1) come "
+                "together: the "
                 "block-diffusion forwards run grouped-query attention "
                 "only, under one head count and no window; got "
                 f"linear_attn={cfg.linear_attn}, layer_types of "
@@ -479,10 +562,23 @@ class BlockDecoder(DefaultRulesMixin):
             if cfg.rotary_dim % 2 or cfg.rotary_dim > cfg.head_dim:
                 raise ValueError(f"rotary_dim {cfg.rotary_dim} is not an "
                                  f"even part of a head of {cfg.head_dim}")
-            if cfg.rope_scaling and len(cfg.rope_scaling) != 5:
-                raise ValueError(
-                    "rope_scaling is (factor, original context, beta_fast,"
-                    f" beta_slow, attention factor), got {cfg.rope_scaling}")
+        if cfg.dense_latent and not cfg.q_lora_rank:
+            raise ValueError(
+                "dense latent attention takes its queries from a low-rank "
+                "pair (q_lora_rank); one query matrix is linear_attn's "
+                "MLA on full_attn_layers")
+        if cfg.rope_scaling and len(cfg.rope_scaling) != 5:
+            raise ValueError(
+                "rope_scaling is (factor, original context, beta_fast,"
+                f" beta_slow, attention factor), got {cfg.rope_scaling}")
+        if cfg.expert_groups > 1 and (
+                cfg.router_scores != "sigmoid"
+                or cfg.experts % cfg.expert_groups
+                or not 0 < cfg.top_expert_groups <= cfg.expert_groups):
+            raise ValueError(
+                f"a group limit ({cfg.top_expert_groups} of "
+                f"{cfg.expert_groups} groups) needs the sigmoid router "
+                f"and {cfg.experts} experts in whole groups")
         if cfg.router_scores not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router_scores {cfg.router_scores!r}")
         if cfg.first_vocab + cfg.vocab > cfg.vocab_size:
@@ -547,7 +643,7 @@ class BlockDecoder(DefaultRulesMixin):
                         "wo": glorot(qd, c.hidden),
                         "q_norm": ones(c.head_dim),
                         "k_norm": ones(c.head_dim)}
-            if kind in ("mla_sparse", "mla_window"):
+            if kind in ("mla_sparse", "mla_window", "mla_dense"):
                 g = c.geometry(kind)
                 mp = {"wqa": glorot(c.hidden, g.q_rank),
                       "q_norm": ones(g.q_rank),
@@ -557,6 +653,8 @@ class BlockDecoder(DefaultRulesMixin):
                       "wkvb": glorot(g.rank, g.heads * (g.nope + g.v)),
                       "wg": glorot(c.hidden, g.heads),
                       "wo": glorot(g.heads * g.v, c.hidden)}
+                if not c.head_gate:
+                    del mp["wg"]
                 if kind == "mla_sparse":
                     mp["index"] = {
                         "wq": glorot(g.q_rank,
@@ -798,7 +896,7 @@ class BlockDecoder(DefaultRulesMixin):
         a request takes the slot, carried from chunk to chunk to the
         decode steps, and left as it lies at release."""
         c = self.cfg
-        if c.layer_types and not c.typed_mla:
+        if c.layer_types and not c.selecting_latent:
             full, win = c.layers_of("gqa_full"), c.layers_of("gqa_window")
             dt = str(jnp.dtype(self.dtype))
             row = c.kv_heads * c.head_dim   # a token's KV heads, side by side
@@ -830,6 +928,14 @@ class BlockDecoder(DefaultRulesMixin):
                               c.geometry("mla_window").row],
                     "dtype": dt, "layers": win, "per": "slot"},
             }
+        if c.dense_latent:
+            # the latent pool is the whole of a request's state
+            every = c.layers_of("mla_dense")
+            return {"cache_latent": {
+                "shape": [len(every), num_blocks, block_size,
+                          c.geometry("mla_dense").row],
+                "dtype": str(jnp.dtype(self.dtype)), "layers": every,
+                "per": "block"}}
         kda, mla = c.layers_of("kda"), c.layers_of("mla")
         return {
             "cache_latent": {
@@ -923,9 +1029,21 @@ class BlockDecoder(DefaultRulesMixin):
         q = self._mm(cq, mp["wqb"]).reshape(t, g.heads, g.nope + g.pe)
         if c.mla_rope:
             q = jnp.concatenate(
-                [q[..., :g.nope], _rope(q[..., g.nope:], pos, g.theta)],
+                [q[..., :g.nope], self._latent_rope(q[..., g.nope:], pos, g)],
                 axis=-1)
         return cq, q
+
+    def _latent_rope(self, x, pos, g: MlaGeometry):
+        """Rotary positions on the rope values of a latent layer's heads
+        ``x`` [T, H, pe]: at YaRN's frequencies where the geometry scales
+        them (a method so that a planted fault can leave the scaling
+        off)."""
+        if not g.yarn:
+            return _rope(x, pos, g.theta)
+        factor, original, fast, slow, attn = g.yarn
+        return _rope(x, pos, g.theta, inv_freq=jnp.asarray(
+            gqa_ops.yarn_inv_freq(g.pe, g.theta, factor, original, fast,
+                                  slow)), factor=attn)
 
     def _typed_latent(self, mp, n, pos, g: MlaGeometry):
         """The row a token keeps in a typed MLA layer: ``[RMSNorm(c[:rank])
@@ -937,15 +1055,26 @@ class BlockDecoder(DefaultRulesMixin):
             lat = lat * math.sqrt(c.hidden / g.rank)
         k_pe = ckv[:, g.rank:]
         if c.mla_rope:
-            k_pe = self._rope_k(k_pe, pos, g.theta)
+            k_pe = (self._rope_k(k_pe, pos, g.theta, g) if g.yarn
+                    else self._rope_k(k_pe, pos, g.theta))
         return jnp.concatenate(
             [lat, k_pe, jnp.zeros((n.shape[0], g.row - g.rank - g.pe))],
             axis=-1).astype(self.dtype)
 
-    def _rope_k(self, k_pe, pos, theta):
+    def _rope_k(self, k_pe, pos, theta, g: MlaGeometry | None = None):
         """Rotary positions on the one ``k_pe`` a token's heads share
-        (a method so that a planted fault can leave it off)."""
+        (a method so that a planted fault can leave it off); ``g``: the
+        geometry whose YaRN table replaces ``theta``'s own."""
+        if g is not None:
+            return self._latent_rope(k_pe[:, None, :], pos, g)[:, 0]
         return _rope(k_pe[:, None, :], pos, theta)[:, 0]
+
+    def _step_pool(self, before, after):
+        """The latent pool a step's absorbed attention reads: the one
+        with the step's own rows written (a method so that a planted
+        fault can hand it the one before)."""
+        del before
+        return after
 
     def _index_inputs(self, ip, n, cq, pos):
         """The indexer's three inputs for rows ``n`` [T, h]: queries
@@ -1051,6 +1180,11 @@ class BlockDecoder(DefaultRulesMixin):
     def _heads_out(self, ctx):
         return ctx
 
+    def _expert_groups(self) -> int:
+        """Groups the sigmoid router's choice is limited by (a method so
+        that a planted fault can drop the limit)."""
+        return self.cfg.expert_groups
+
     def _routed_scale(self) -> float:
         """What the renormalised picks' weights sum to (a method so that
         a planted fault can leave it off)."""
@@ -1072,9 +1206,12 @@ class BlockDecoder(DefaultRulesMixin):
         it)."""
         return dsa_ops.top_k_mask(scores, k, live=live)
 
-    def _ffn_of(self, i, lp, h):
+    def _ffn_of(self, i, lp, h, routed: list | None = None, counted=None):
         """Layer ``i``'s FFN on the residual stream; the held experts
-        that received a row, per expert (None for a dense layer)."""
+        that received a row, per expert (None for a dense layer).
+        ``routed``: a list that takes, for a sparse layer, how many of
+        the rows ``counted`` ([T] bool) picked at least one held
+        expert."""
         c = self.cfg
         m = _rms(h, lp["ffn_norm"], c.norm_eps)
 
@@ -1087,11 +1224,15 @@ class BlockDecoder(DefaultRulesMixin):
         if "mlp" in lp:
             return h + gated(lp["mlp"], m), None
         mp = lp["moe"]
-        y, rows = moe_dropless(
+        y, rows, *count = moe_dropless(
             m, mp["router"], mp, top_k=c.experts_per_token,
             first_expert=c.first_expert, dtype=self.dtype,
             router_dtype=self.router_dtype, scores=c.router_scores,
-            select_bias=mp.get("router_bias"), scale=self._routed_scale())
+            select_bias=mp.get("router_bias"), scale=self._routed_scale(),
+            groups=self._expert_groups(), top_groups=c.top_expert_groups,
+            count_routed=None if routed is None else counted)
+        if count:
+            routed.append(count[0])
         if "shared" in mp:
             y = y + gated(mp["shared"], m)
         return h + y, rows
@@ -1144,6 +1285,10 @@ class BlockDecoder(DefaultRulesMixin):
         sparse_at, window_at = (c.rows_of("mla_sparse"),
                                 c.rows_of("mla_window"))
         gfull_at, gwin_at = c.rows_of("gqa_full"), c.rows_of("gqa_window")
+        dense_at = c.rows_of("mla_dense")
+        # under a group limit: the prompt's rows that picked a held expert,
+        # by layer
+        routed = [] if c.expert_groups > 1 else None
         pos = start + jnp.arange(cw, dtype=jnp.int32)
         for i in range(c.layers):
             lp = params["layers"][str(i)]
@@ -1211,6 +1356,21 @@ class BlockDecoder(DefaultRulesMixin):
                     rings = rings.at[j, slot].set(mla_ops.ring_after_chunk(
                         rings[j, slot], lat, start, n_valid))
                     h = h + self._typed_out(mp, n, ctx)
+            elif i in dense_at:
+                j, mp = dense_at[i], lp["mla"]
+                g = c.geometry("mla_dense")
+                with jax.named_scope("mla_dense"):
+                    _, q = self._typed_q(mp, n, pos, g)
+                    latent = latent.at[j, chunk_blocks].set(
+                        self._typed_latent(mp, n, pos, g).reshape(
+                            cw // bs, bs, r))
+                    # expanded: a head's K and V from a tile of rows
+                    ctx = mla_ops.mla_chunk_attention(
+                        q, latent.reshape(flat), table_row + j * nb, start,
+                        mp["wkvb"].reshape(g.rank, g.heads, -1),
+                        rank=g.rank, nope=g.nope, pe=g.pe, v_dim=g.v,
+                        scale=g.scale, impl=attention)
+                    h = h + self._typed_out(mp, n, ctx)
             elif i in kda_at:
                 j, kp = kda_at[i], lp["kda"]
                 with jax.named_scope("kda"):
@@ -1242,7 +1402,7 @@ class BlockDecoder(DefaultRulesMixin):
                         pe=c.qk_rope_dim, v_dim=c.v_head_dim,
                         scale=(c.qk_nope_dim + c.qk_rope_dim) ** -0.5)
                     h = h + self._mm(ctx.reshape(cw, -1), mp["wo"])
-            h, rows = self._ffn_of(i, lp, h)
+            h, rows = self._ffn_of(i, lp, h, routed, valid)
             if rows is not None:
                 expert_rows += jnp.sum(rows > 0).astype(jnp.int32)
                 if bounded:
@@ -1258,6 +1418,8 @@ class BlockDecoder(DefaultRulesMixin):
                **self._state_out(latent, s_all, conv_all, index, rings)}
         if bounded:
             out["moe_whole"] = moe_whole
+        if routed:
+            out["routed_rows"] = sum(routed)
         if with_logits:
             out["logits"] = logits
         return out
@@ -1308,6 +1470,8 @@ class BlockDecoder(DefaultRulesMixin):
         sparse_at, window_at = (c.rows_of("mla_sparse"),
                                 c.rows_of("mla_window"))
         gfull_at, gwin_at = c.rows_of("gqa_full"), c.rows_of("gqa_window")
+        dense_at = c.rows_of("mla_dense")
+        routed = [] if c.expert_groups > 1 else None
         scale = (c.qk_nope_dim + c.qk_rope_dim) ** -0.5
         for i in range(c.layers):
             lp = params["layers"][str(i)]
@@ -1372,6 +1536,23 @@ class BlockDecoder(DefaultRulesMixin):
                         q_abs, rings[j], pos, window=self._window(),
                         rank=g.rank)
                     h = h + self._typed_out(mp, n, self._unabsorb(ctx, w_vb))
+            elif i in dense_at:
+                j, mp = dense_at[i], lp["mla"]
+                g = c.geometry("mla_dense")
+                with jax.named_scope("mla_dense"):
+                    _, q = self._typed_q(mp, n, pos, g)
+                    before = latent
+                    latent = latent.at[j, pbid, off].set(
+                        self._typed_latent(mp, n, pos, g))
+                    # absorbed: the query meets the latent rows themselves
+                    q_abs, w_vb = self._absorb(mp, q, g)
+                    _log_schedule(mla_ops.decode_schedule(
+                        s, g.heads, g.rank, bs, bt.shape[1], attention))
+                    ctx = mla_ops.mla_decode_attention(
+                        q_abs, self._step_pool(before, latent).reshape(flat),
+                        block_tables=bt + j * nb, last=pos, rank=g.rank,
+                        impl=attention)
+                    h = h + self._typed_out(mp, n, self._unabsorb(ctx, w_vb))
             elif i in kda_at:
                 j, kp = kda_at[i], lp["kda"]
                 with jax.named_scope("kda"):
@@ -1417,7 +1598,7 @@ class BlockDecoder(DefaultRulesMixin):
                         wkvb[..., c.qk_nope_dim:].astype(self.dtype),
                         preferred_element_type=jnp.float32)
                     h = h + self._mm(ctx.reshape(s, -1), mp["wo"])
-            h, rows = self._ffn_of(i, lp, h)
+            h, rows = self._ffn_of(i, lp, h, routed, live)
             if rows is not None:
                 expert_rows += jnp.sum(rows > 0).astype(jnp.int32)
                 fullest = jnp.maximum(fullest, jnp.max(rows))
@@ -1427,6 +1608,8 @@ class BlockDecoder(DefaultRulesMixin):
                "max_expert_load": fullest.astype(jnp.float32) / mean_load,
                **kv,
                **self._state_out(latent, s_all, conv_all, index, rings)}
+        if routed:
+            out["routed_rows"] = sum(routed)
         if with_logits:
             out["logits"] = logits
         return out
@@ -1488,4 +1671,18 @@ def _make_laguna(config: TrainConfig) -> BlockDecoder:
 def _make_laguna_tiny(config: TrainConfig) -> BlockDecoder:
     model = _make(config, DecoderBlockConfig.laguna_tiny())
     model.name = "laguna"
+    return model
+
+
+@register_model("axk1")
+def _make_axk1(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.a_x_k1())
+    model.name = "axk1"
+    return model
+
+
+@register_model("axk1_tiny")
+def _make_axk1_tiny(config: TrainConfig) -> BlockDecoder:
+    model = _make(config, DecoderBlockConfig.axk1_tiny())
+    model.name = "axk1"
     return model

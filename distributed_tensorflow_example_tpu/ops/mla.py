@@ -16,9 +16,9 @@ slot):
 
 - every earlier row, in a paged pool ``[N, Bs, R]`` behind block tables (a
   layer's blocks ``N`` apart in the flat view):
-  :func:`mla_prefill_attention` (per-head K and V made from the latent a
-  tile of rows at a time, ``[k_nope_h ; v_h] = W_kvb c_kv``, an online
-  softmax over the tiles up to the chunk's own) and
+  :func:`mla_prefill_attention` / :func:`mla_chunk_attention` (per-head
+  K and V from the latent, ``[k_nope_h ; v_h] = W_kvb c_kv``, a tile of
+  rows at a time: an XLA loop / the kernel ``mla_chunk_attn``) and
   :func:`mla_decode_attention` (absorbed: ``W_kvb`` folded into the query
   and the output, each live row read once for all heads; on the chip the
   Pallas kernel ``paged_latent_attn`` walks a slot's blocks,
@@ -564,3 +564,176 @@ def mla_window_decode_attention(q: jax.Array, ring: jax.Array, pos, *,
         return jnp.einsum("bhk,bkc->bhc", p.astype(ring.dtype),
                           ring[..., :rank],
                           preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# a chunk against every earlier row, dense: the scores of a tile kept in
+# the core (below everything the other decoders' programs were traced
+# from: a Mosaic kernel's serialized body carries its source lines)
+# ---------------------------------------------------------------------------
+
+def chunk_tile_friendly(t: int, block_size: int, rank: int, nope: int,
+                        pe: int, v_dim: int, table_blocks: int,
+                        tile: int = 1024) -> bool:
+    """Shapes the TPU compiler takes for ``mla_chunk_attn``: a chunk and
+    a table of whole key tiles (``tile`` latent rows a grid step),
+    128-row blocks, lane-aligned head parts."""
+    return (block_size == 128 and tile % 128 == 0 and t % tile == 0
+            and rank % 128 == 0 and nope % 128 == 0 and pe % 64 == 0
+            and v_dim % 128 == 0 and table_blocks % (tile // 128) == 0)
+
+
+def _chunk_kernel(table_ref, at_ref, q_ref, w_ref, *rest, blocks: int,
+                  tile: int, rank: int, nope: int, pe: int):
+    """Grid (H, W / tile): one head's queries (the whole chunk) against a
+    tile of ``blocks`` latent blocks a step; K and V of the head are made
+    from the tile's latent rows here, an online softmax runs over the
+    tiles. ``at_ref``: the chunk's first position and the tiles it can
+    see. A tile wholly before the chunk needs no mask; one that holds
+    rows of the chunk is masked from positions; one past the chunk names
+    the last live one again (no DMA) and computes nothing."""
+    lat_refs, (o_ref, m_ref, l_ref, acc_ref) = rest[:blocks], rest[blocks:]
+    j = pl.program_id(1)
+    start, live_tiles = at_ref[0], at_ref[1]
+    k0 = j * tile
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def attend(masked: bool):
+        lat = jnp.concatenate([r[0] for r in lat_refs], axis=0)  # [tile, R]
+        kv = jnp.dot(lat[:, :rank], w_ref[0],
+                     preferred_element_type=jnp.float32).astype(lat.dtype)
+        q = q_ref[0]                                        # [T, nope + pe]
+        dims = (((1,), (1,)), ((), ()))
+        s = (lax.dot_general(q[:, :nope], kv[:, :nope], dims,
+                             preferred_element_type=jnp.float32)
+             + lax.dot_general(q[:, nope:], lat[:, rank:rank + pe], dims,
+                               preferred_element_type=jnp.float32))
+        if masked:
+            qp = start + lax.broadcasted_iota(jnp.int32, (q.shape[0], 1), 0)
+            kp = k0 + lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+            live = kp <= qp
+            s = jnp.where(live, s, NEG_INF)
+        m_prev = m_ref[...]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(live, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(lat.dtype), kv[:, nope:],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
+
+    @pl.when(k0 + tile <= start)
+    def _before():
+        attend(False)
+
+    @pl.when((k0 + tile > start) & (j < live_tiles))
+    def _own():
+        attend(True)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        # every row attends to itself at least, so l > 0
+        o_ref[0] = acc_ref[...] / l_ref[...]
+
+
+def _chunk_dispatch(q, pool, table_row, start, w_kvb, *, rank: int,
+                    nope: int, pe: int, v_dim: int, scale: float,
+                    tile: int):
+    t, h, _ = q.shape
+    bs, r = pool.shape[1], pool.shape[2]
+    dtype = pool.dtype
+    g = tile // bs
+    tiles = table_row.shape[0] // g
+    qh = (q.astype(jnp.float32) * scale).astype(dtype).transpose(1, 0, 2)
+    wh = w_kvb.astype(dtype).transpose(1, 0, 2)         # [H, rank, nope+v]
+    start = jnp.asarray(start, jnp.int32)
+    at = jnp.stack([start, (start + t + tile - 1) // tile])
+
+    def head_map(hh, jj, table, at_s):
+        return (hh, 0, 0)
+
+    def lat_map(hh, jj, table, at_s, *, k):
+        return (table[jnp.minimum(jj, at_s[1] - 1) * g + k], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,              # table_row, (start, live tiles)
+        grid=(h, tiles),
+        in_specs=[pl.BlockSpec((1, t, nope + pe), head_map),
+                  pl.BlockSpec((1, rank, nope + v_dim), head_map)] + [
+            pl.BlockSpec((1, bs, r), functools.partial(lat_map, k=k))
+            for k in range(g)],
+        out_specs=pl.BlockSpec((1, t, v_dim), head_map),
+        scratch_shapes=[pltpu.VMEM((t, 1), jnp.float32),
+                        pltpu.VMEM((t, 1), jnp.float32),
+                        pltpu.VMEM((t, v_dim), jnp.float32)])
+    out = pl.pallas_call(
+        functools.partial(_chunk_kernel, blocks=g, tile=tile, rank=rank,
+                          nope=nope, pe=pe),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((h, t, v_dim), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=48 * 1024 * 1024),
+        name="mla_chunk_attn",
+        interpret=_interpret(),
+    )(jnp.asarray(table_row, jnp.int32), at, qh, wh, *([pool] * g))
+    return out.transpose(1, 0, 2)
+
+
+def mla_chunk_attention(q: jax.Array, pool: jax.Array, table_row, start,
+                        w_kvb: jax.Array, *, rank: int, nope: int, pe: int,
+                        v_dim: int, scale: float, key_tile: int = 1024,
+                        impl: str = "auto") -> jax.Array:
+    """:func:`mla_prefill_attention` (same arguments, same result: a
+    chunk's queries against every earlier row and its own, causal, K and
+    V expanded from the latent) with the scores of a tile kept in the
+    core. ``impl``: ``"auto"`` takes the kernel (``mla_chunk_attn`` in a
+    capture) on a TPU where :func:`chunk_tile_friendly` holds,
+    ``"pallas"`` forces it (interpreted off the TPU), ``"xla"`` runs
+    :func:`mla_prefill_attention`'s tile loop. ``key_tile``: latent rows
+    a grid step."""
+    t = q.shape[0]
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"unknown chunk attention impl {impl!r}")
+    table_row = jnp.asarray(table_row, jnp.int32)
+    tile = min(key_tile, t)
+    friendly = chunk_tile_friendly(t, pool.shape[1], rank, nope, pe, v_dim,
+                                   table_row.shape[0], tile)
+    if impl == "pallas" and not friendly:
+        raise ValueError(
+            "the chunk attention kernel needs 128-row blocks, a chunk and "
+            f"a table of whole {tile}-row tiles and lane-aligned head "
+            f"parts, got T={t} block_size={pool.shape[1]} rank={rank} "
+            f"nope={nope} pe={pe} v={v_dim} table={table_row.shape[0]}")
+    if friendly and (impl == "pallas" or (
+            impl == "auto" and jax.default_backend() == "tpu")):
+        with jax.named_scope("mla_chunk_attn"):
+            return _chunk_dispatch(q, pool, table_row, start, w_kvb,
+                                   rank=rank, nope=nope, pe=pe, v_dim=v_dim,
+                                   scale=scale, tile=tile)
+    return mla_prefill_attention(q, pool, table_row, start, w_kvb, rank=rank,
+                                 nope=nope, pe=pe, v_dim=v_dim, scale=scale)
+
+
+def decode_schedule(rows: int, heads: int, rank: int, block_size: int,
+                    blocks_per_slot: int, impl: str = "auto") -> dict:
+    """What :func:`mla_decode_attention` runs for these shapes, as an
+    exporter keeps it (``export.json`` ``stepwise.decode.attn_schedule``):
+    the kernel with its blocks a grid step and its grid, or the
+    gather."""
+    if latent_tile_friendly(block_size, heads, rank, blocks_per_slot) and (
+            impl == "pallas" or (impl == "auto"
+                                 and jax.default_backend() == "tpu")):
+        return {"kernel": "paged_latent_attn",
+                "blocks_per_step": _BLOCKS_A_STEP,
+                "grid": [rows, blocks_per_slot // _BLOCKS_A_STEP],
+                "heads": heads}
+    return {"kernel": "xla", "heads": heads}

@@ -469,22 +469,47 @@ def tile_log(rows: dict | None = None):
         _TILE_LOG.reset(token)
 
 
-def sigmoid_top_k(logits, select_bias, top_k: int, scale: float):
+def sigmoid_top_k(logits, select_bias, top_k: int, scale: float,
+                  groups: int = 1, top_groups: int = 1):
     """The sigmoid router's choice: per row the ``top_k`` largest of
     ``sigmoid(logits) + select_bias`` and their weights, the picked
-    scores (the bias left out) renormalised to sum to ``scale``."""
+    scores (the bias left out) renormalised to sum to ``scale``.
+
+    ``groups`` > 1 limits the choice by groups (DeepSeek-V3's router):
+    the experts lie in ``groups`` runs of ``E / groups``, a group's score
+    is the sum of its two largest biased scores, and only experts of the
+    ``top_groups`` best groups can be picked. One group is the plain
+    top-k, and traces nothing more than it."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     pick = s if select_bias is None else s + select_bias.astype(jnp.float32)
+    if groups > 1:
+        pick = _keep_groups(pick, groups, top_groups)
     _, idx = lax.top_k(pick, top_k)
     w = jnp.take_along_axis(s, idx, axis=-1)
     return w * (scale / jnp.sum(w, axis=-1, keepdims=True)), idx
+
+
+def _keep_groups(pick, groups: int, top_groups: int):
+    """``pick`` [T, E] with the experts outside each row's ``top_groups``
+    best groups at ``-inf`` (a group's score: its two largest)."""
+    t, e = pick.shape
+    if e % groups or not 0 < top_groups <= groups or e // groups < 2:
+        raise ValueError(f"{e} experts do not lie in {groups} groups of "
+                         f"two or more, {top_groups} of them kept")
+    by_group = pick.reshape(t, groups, e // groups)
+    score = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)     # [T, G]
+    _, kept = lax.top_k(score, top_groups)
+    keep = jnp.zeros((t, groups), bool).at[
+        jnp.arange(t)[:, None], kept].set(True)
+    return jnp.where(keep[:, :, None], by_group, -jnp.inf).reshape(t, e)
 
 
 def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
                  top_k: int, first_expert: int = 0,
                  dtype=jnp.bfloat16, router_dtype=jnp.float32,
                  scores: str = "softmax", select_bias=None,
-                 scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+                 scale: float = 1.0, groups: int = 1, top_groups: int = 1,
+                 count_routed=None) -> tuple:
     """A gated (SiLU) expert FFN that never drops a token, told which
     experts it holds.
 
@@ -498,7 +523,9 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
     ``scores="sigmoid"`` is the other published router: float32 sigmoid
     scores, the ``top_k`` largest of ``score + select_bias`` (a bias [E]
     that picks and does not weigh), the picked scores renormalised to
-    sum to ``scale`` too. The (row, expert) pairs whose
+    sum to ``scale`` too, the choice limited to the ``top_groups`` best
+    of ``groups`` groups of experts where ``groups`` > 1
+    (:func:`sigmoid_top_k`). The (row, expert) pairs whose
     expert is held are sorted by expert and run through one grouped
     matmul per projection (``lax.ragged_dot``, ``ragged-dot-*`` in a
     capture, at the tile :func:`ragged_tiling` gives for its shape);
@@ -511,8 +538,13 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
 
     Returns ``(y [T, H] float32, rows [Eh] int32)``: the held experts'
     part of the layer's output, and how many rows each held expert
-    received. (The encoder's capacity dispatch, :func:`moe_ffn`, is
-    another layer: it drops past a capacity and trains.)
+    received; with ``count_routed`` ([T] bool: the rows that count, a
+    chunk's padding and a step's dead slots left out) a third, how many
+    of those rows picked at least one held expert (int32: under a group
+    limit a row whose kept groups lie on other chips brings this chip
+    nothing). (The encoder's
+    capacity dispatch, :func:`moe_ffn`, is another layer: it drops past
+    a capacity and trains.)
     """
     t, _ = x.shape
     pairs = t * top_k
@@ -528,7 +560,8 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
             if scale != 1.0:
                 w = w * scale
         else:
-            w, idx = sigmoid_top_k(logits, select_bias, top_k, scale)
+            w, idx = sigmoid_top_k(logits, select_bias, top_k, scale,
+                                   groups, top_groups)
         local = (idx - first_expert).reshape(-1)            # [T * k]
         held = (local >= 0) & (local < e_held)
         # pairs of absent experts sort past the last group
@@ -576,8 +609,11 @@ def moe_dropless(x: jax.Array, router: jax.Array, experts: Params, *,
         with jax.named_scope("moe_combine"):
             return _combine_bounded(out, at, w, top_k)
 
-    if bound == pairs:
-        return whole(), rows
     # dropless for any routing: a layer whose held pairs outnumber the
     # bound runs at the whole width
-    return lax.cond(jnp.sum(rows) <= bound, bounded, whole), rows
+    y = whole() if bound == pairs else lax.cond(
+        jnp.sum(rows) <= bound, bounded, whole)
+    if count_routed is None:
+        return y, rows
+    return y, rows, jnp.sum(jnp.any(held.reshape(t, top_k), axis=-1)
+                            & count_routed).astype(jnp.int32)

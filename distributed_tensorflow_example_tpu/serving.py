@@ -1080,7 +1080,8 @@ def _export_state_generator(model, params, out_dir: str, *,
                             eos_id, pad_id: int,
                             platforms: Sequence[str]) -> str:
     """The artifact of a decoder with a kind a layer (``models/decoder.py``,
-    ``linear_attn`` or ``layer_types``): ``prefill_chunk.stablehlo`` (``prefill_chunk``
+    ``linear_attn``, ``layer_types`` or a latent alone):
+    ``prefill_chunk.stablehlo`` (``prefill_chunk``
     tokens of one prompt from the state the chunks before left) and
     ``decode.stablehlo`` (one token of every slot), greedy ids out of
     both, never logits. No monolithic program and no whole-prompt
@@ -1089,7 +1090,8 @@ def _export_state_generator(model, params, out_dir: str, *,
     What the server keeps between dispatches is what the model's
     ``state_specs`` names, by layer kind, recorded under
     ``stepwise.state``: ``per: "block"`` arrays lie behind the block
-    tables (the latent pool ``[L_mla, N, Bs, R]``; the index-key pool
+    tables (the latent pool ``[L_mla, N, Bs, R]``, which is ALL a
+    dense-latent model keeps; the index-key pool
     ``[L_full, N, Bs, D]`` of a model that selects; the K and V pools
     ``[L_full, N, Bs, KVH x D]`` of grouped-query full layers), ``per:
     "slot"`` arrays hold one row a slot (the recurrent state ``[L_kda,
@@ -1217,6 +1219,11 @@ def _export_state_generator(model, params, out_dir: str, *,
                       # layer sees (0: the model has no such layer)
                       "index_topk": int(c.index_topk),
                       "window": int(c.window),
+                      # groups the router's choice is limited by (1:
+                      # over all experts): such programs hand the host
+                      # ``routed_rows`` beside ``expert_rows``
+                      "expert_groups": int(c.expert_groups),
+                      "top_expert_groups": int(c.top_expert_groups),
                       **moe},
         },
     }
@@ -1457,9 +1464,9 @@ class StepwiseGenerator:
         self._prefill = (split(self._prefill_exp.call, "prefill")
                          if self._prefill_exp is not None else None)
         self._zero = None
-        if self.state:
-            per_slot = [k for k, v in self.state["specs"].items()
-                        if v["per"] == "slot"]
+        per_slot = [k for k, v in (self.state or {}).get(
+            "specs", {}).items() if v["per"] == "slot"]
+        if per_slot:
 
             def zero_slot(pool, slot):
                 return {k: (v.at[:, slot].set(0) if k in per_slot else v)
@@ -1527,7 +1534,9 @@ class StepwiseGenerator:
         array zeroed (in place: the pool is donated): what a request
         that takes the slot starts from."""
         if self._zero is None:
-            raise ValueError("this artifact keeps no per-slot state")
+            raise ValueError("this artifact keeps no per-slot state "
+                             "(nothing to zero: a row behind the block "
+                             "tables is written before it is read)")
         return self._zero(pool, np.int32(slot))
 
     def decode(self, feats: dict) -> dict:

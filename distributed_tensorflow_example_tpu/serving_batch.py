@@ -1566,6 +1566,24 @@ class GenerationEngine:
             "bound and ran at the whole width")
         #: a per-request-state artifact's expert layers, and the (row,
         #: expert) pairs one row sends through them
+        self._c_moe_routed_rows = reg.counter(
+            "serving_moe_routed_rows_total",
+            "rows that picked at least one held expert, summed over "
+            "expert layers, as the programs of an artifact whose router "
+            "is limited by groups report them (state.expert_groups > 1; "
+            "of serving_moe_rows_total / experts_per_token rows)")
+        #: the router chooses among groups of experts: both programs
+        #: hand the host ``routed_rows`` beside ``expert_rows``
+        self._group_routed = bool(
+            self.state and int(self.state.get("expert_groups", 1)) > 1)
+        #: ``expert_rows`` / ``routed_rows`` of the last step and of the
+        #: last chunk the host has read: the next span of each kind
+        #: carries them (a program's routing is known when it returns)
+        self._routed_rows_last = 0
+        self._chunk_rows_last = (0, 0)
+        #: (``expert_rows``, ``routed_rows``) of chunks nobody read yet:
+        #: they ride in the next read, as ``_whole_due``
+        self._routed_due: list = []
         self._moe_layers = (self.state["ffns"].count("moe")
                             if self.state else 0)
         self._moe_pairs_a_row = self._moe_layers * (
@@ -1933,6 +1951,12 @@ class GenerationEngine:
                          "ring": (int(rings[0].shape[0]), sum(
                              int(a.shape[-1]) * a.dtype.itemsize
                              for a in rings))}
+        #: a dense-latent artifact (every layer attends to the whole of
+        #: the latent pool, which is all it keeps): its layers, from
+        #: which the chunk and decode spans say what the contexts held
+        self._dense_latent = (
+            len(self.state["mixers"]) if self.state and set(
+                self.state["mixers"]) == {"mla_dense"} else 0)
         self._c_dsa_selected = reg.counter(
             "serving_dsa_selected_rows_total",
             "rows the full-attention layers attended to after selection, "
@@ -2905,9 +2929,11 @@ class GenerationEngine:
             # interleaved with the shared decode step, and the final
             # chunk's logits become the first sample point
             self._tables[index, :needed] = run
-            if self.state:
+            if self._state_slot_bytes:
                 # the slot's recurrent rows still hold the request that
-                # left it: this one starts from zeros
+                # left it: this one starts from zeros (an artifact whose
+                # whole state lies behind the block tables has none: no
+                # launch)
                 self._zero_slot_state(index)
             with self.registry.atomic():
                 self._c_admissions.inc()
@@ -3094,6 +3120,7 @@ class GenerationEngine:
                   start=start, chunk_tokens=n, tokens=n, prompt_tokens=p,
                   **self._describe_selection(
                       start + 1 + np.arange(n), start + n),
+                  **self._describe_chunk_routing(),
                   **where, **req.trace)
         cm.__enter__()
         try:
@@ -3117,9 +3144,7 @@ class GenerationEngine:
         """Block on what chunk ``rec`` handed the host for its
         request's first token (and every ``moe_whole`` due, its own
         among them, in the same round trip), and close its span."""
-        whole = rec.out.get("moe_whole")
-        if whole is not None:
-            self._whole_due.append(whole)
+        self._leave_due(rec)
         try:
             with self._admit_read("prefill_chunk", rec.seq):
                 rec.first = self._read(self._fetch_first, rec.slot.req,
@@ -3127,6 +3152,18 @@ class GenerationEngine:
             rec.read_t = time.perf_counter()
         finally:
             rec.close_span()
+
+    def _leave_due(self, rec: _Chunk) -> None:
+        """The routing scalars chunk ``rec`` returned, left for the next
+        read to bring (:meth:`_fetch_with_due`): ``moe_whole`` of a
+        program whose expert layers run over a bound, ``expert_rows``
+        and ``routed_rows`` of one whose router chooses by groups."""
+        whole = rec.out.get("moe_whole")
+        if whole is not None:
+            self._whole_due.append(whole)
+        if self._group_routed:
+            self._routed_due.append((rec.out["expert_rows"],
+                                     rec.out["routed_rows"]))
 
     @scheduler_thread
     def _prove_chunk(self, rec: _Chunk) -> None:
@@ -3151,9 +3188,7 @@ class GenerationEngine:
             if rec.first is None:
                 # never read: done for all the host will know of it
                 rec.close_span()
-                whole = rec.out.get("moe_whole")
-                if whole is not None:
-                    self._whole_due.append(whole)
+                self._leave_due(rec)
             else:
                 # the SPLIT estimator: a chunk's own time feeds the
                 # prefill EMA, never the decode-step EMA Retry-After
@@ -3251,6 +3286,7 @@ class GenerationEngine:
             rec.close_span()
         self._due = self._behind = None
         self._whole_due = []
+        self._routed_due = []
 
     @scheduler_thread
     def _update_pressure(self) -> None:
@@ -3894,6 +3930,13 @@ class GenerationEngine:
                     "kv_bytes": int(keys) * self._kv_token_bytes,
                     "window_bytes": int(np.minimum(
                         contexts, g["window"]).sum()) * n_win * ring_b}
+        if d is None and self._dense_latent:
+            # every layer attends to every row: the contexts' latent
+            # rows as stored, all layers (a chunk's rows share one
+            # context)
+            return {"context_rows": int(contexts.sum())
+                    * self._dense_latent,
+                    "kv_bytes": int(keys) * self._kv_token_bytes}
         if d is None:
             return {}
         (n_full, lat_b), (_, idx_b), (n_win, ring_b) = (
@@ -3909,6 +3952,16 @@ class GenerationEngine:
                 "window_bytes": int(np.minimum(
                     contexts, d["window"]).sum()) * n_win * ring_b}
 
+    def _describe_chunk_routing(self) -> dict:
+        """A chunk span's routing arguments where the router chooses by
+        groups ({} elsewhere): ``expert_rows`` and ``routed_rows`` of the
+        last chunk the host has read (this chunk's are known when it
+        returns, and ride in a later read)."""
+        if not self._group_routed:
+            return {}
+        held, rows = self._chunk_rows_last
+        return {"expert_rows": held, "routed_rows": rows}
+
     def _describe_state_decode(self, feats: dict) -> dict:
         """A decode step's span arguments for a per-request-state
         artifact: ``kv_bytes`` the latent rows the live contexts hold,
@@ -3923,34 +3976,53 @@ class GenerationEngine:
                 "expert_rows": self._expert_rows_last,
                 # an id a slot and the two routing scalars
                 "host_bytes": 4 * self.slots + 8}
-        if self._dsa is not None or self._gqa is not None:
+        if (self._dsa is not None or self._gqa is not None
+                or self._dense_latent):
             # what the SELECTED rows hold replaces what the contexts hold
-            # (a grouped-query artifact selects nothing: the same bytes)
+            # (a grouped-query or dense-latent artifact selects nothing:
+            # the same bytes)
             contexts = feats["pos"][feats["alive"] != 0] + 1
             args.update(self._describe_selection(contexts, contexts.sum()))
+        if self._group_routed:
+            # rows of the step before that picked a held expert, summed
+            # over expert layers
+            args["routed_rows"] = self._routed_rows_last
+            args["host_bytes"] += 4
         return args
 
     def _fetch_state_step(self, out: dict) -> np.ndarray:
         """What such a decode step hands the host: the greedy ids
         [slots] (never logits) and the two routing scalars, and with
         them what the chunks nobody read left due; one round trip."""
-        ids, rows, load = self._fetch_with_due(
-            out["ids"], out["expert_rows"], out["max_expert_load"])
+        ids, rows, load, *routed = self._fetch_with_due(
+            out["ids"], out["expert_rows"], out["max_expert_load"],
+            *([out["routed_rows"]] if self._group_routed else ()))
         self._expert_rows_last = int(rows)
         self._g_moe_load.set(float(load))
+        if routed:
+            self._routed_rows_last = int(routed[0])
+            self._c_moe_routed_rows.inc(int(routed[0]))
         return ids
 
     def _fetch_with_due(self, *arrays) -> list:
         """The host's copies of ``arrays`` and, in the same round trip,
         of every ``moe_whole`` scalar a chunk left due (counted here:
-        how many of its expert layers ran at the whole width)."""
+        how many of its expert layers ran at the whole width) and of
+        the chunks' ``expert_rows`` / ``routed_rows`` where the router
+        chooses by groups (:meth:`_leave_due`)."""
         due, self._whole_due = self._whole_due, []
-        if not due and len(arrays) == 1:
+        routed, self._routed_due = self._routed_due, []
+        if not due and not routed and len(arrays) == 1:
             return [np.asarray(arrays[0])]      # a lone result: a plain read
-        got = _fetch_together(*arrays, *due)
-        for whole in got[len(arrays):]:
+        got = _fetch_together(*arrays, *due,
+                              *(x for pair in routed for x in pair))
+        n = len(arrays) + len(due)
+        for whole in got[len(arrays):n]:
             self._c_moe_whole.inc(int(whole))
             self._c_moe_bounded.inc(self._moe_layers - int(whole))
+        for held, rows in zip(got[n::2], got[n + 1::2]):
+            self._chunk_rows_last = (int(held), int(rows))
+            self._c_moe_routed_rows.inc(int(rows))
         return got[:len(arrays)]
 
     @scheduler_thread
@@ -4438,6 +4510,7 @@ class GenerationEngine:
             # chunk programs' expert layers by the rows they ran over
             "moe_bounded_layers": c("serving_moe_bounded_layers_total"),
             "moe_whole_layers": c("serving_moe_whole_layers_total"),
+            "moe_routed_rows": c("serving_moe_routed_rows_total"),
             # what each program's paged decode attention was traced with
             "attn_schedule": self.sw.attn_schedule,
             # a kind a layer (None otherwise): which layers mix and feed

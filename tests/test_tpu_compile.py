@@ -871,3 +871,90 @@ def test_grouped_query_programs_keep_pool_and_rings_where_they_lie(chip,
         re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
     assert aliased == set(range(len(state))), aliased
     assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+# ---------------------------------------------------------------------------
+# dense latent attention over a pool that is the whole state: chunk and step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("name", ["decode", "prefill_chunk"])
+def test_dense_latent_programs_keep_the_pool_where_it_lies(chip, name):
+    """``axk1``'s two served programs at the ``axk1-serve-longctx`` cell's
+    shape (5 layers, 12 held experts of 192, an eighth of the vocabulary;
+    24 slots, 5,569 blocks of 128 tokens, 1,024-token chunks over up to
+    29,696 rows), the state donated: (a) the ONE state array, the 4.56 GB
+    latent pool, is aliased to its output, and no ``copy`` is a 200th of
+    it in a step or a 100th in a chunk (whose largest are the kernel's own
+    operands laid head-major, 25 and 34 MB); (b) the step's absorbed
+    attention is the Pallas kernel ``paged_latent_attn`` (5 calls), the
+    chunk's expanded attention ``mla_chunk_attn`` (5 calls); no ``[64,
+    1024, ctx]`` float32 score tensor and no transposed latent block
+    ``[.., 640, 128]`` reach memory; (c) the chunk's expert layers run
+    under a ``conditional`` over the bound of their pairs at tiles that
+    cut N (``128,7168,256`` / ``128,2048,1024``); (d) temporaries under
+    1 GB beside 6.98 GB of weights."""
+    from distributed_tensorflow_example_tpu.config import TrainConfig
+    from distributed_tensorflow_example_tpu.models import get_model
+    mla_mod = importlib.import_module(
+        "distributed_tensorflow_example_tpu.ops.mla")
+    dev = chip[0]
+    model = get_model("axk1", TrainConfig(
+        model="axk1", dtype="bfloat16", param_dtype="bfloat16",
+        num_layers=5))
+    model.cfg.experts_held, model.cfg.vocab_held = 12, 20480
+    slots, bs, chunk, prompt, new = 24, 128, 1024, 28672, 1024
+    nb = (prompt + new) // bs
+    params = jax.tree_util.tree_map(
+        lambda x: on(dev, x.shape, x.dtype),
+        jax.eval_shape(model.init, jax.random.key(0)))
+    weights = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+                  for x in jax.tree_util.tree_leaves(params))
+    assert abs(weights / 1e9 - 6.98) < 0.01
+    specs = model.state_specs(slots=slots, num_blocks=1 + slots * nb,
+                              block_size=bs)
+    assert list(specs) == ["cache_latent"]
+    state = {k: on(dev, tuple(v["shape"]), jnp.dtype(v["dtype"]))
+             for k, v in specs.items()}
+    i32 = functools.partial(on, dev, dtype=jnp.int32)
+    if name == "decode":
+        fn = lambda st, p, bt, tok, pos, alive: model.decode_step(  # noqa: E731
+            p, st, bt, tok, pos, alive, attention="pallas")
+        args = (i32((slots, nb)), i32((slots,)), i32((slots,)),
+                i32((slots,)))
+    else:
+        fn = lambda st, p, ids, n, start, slot, row, cb: (  # noqa: E731
+            model.prefill_chunk(p, st, ids, n, start, slot, row, cb,
+                                attention="pallas"))
+        args = (i32((1, chunk)), i32(()), i32(()), i32(()),
+                i32((-(-prompt // chunk) * chunk // bs,)),
+                i32((chunk // bs,)))
+    mp = pytest.MonkeyPatch()
+    mp.setattr(mla_mod, "_interpret", lambda: False)
+    try:
+        compiled = jax.jit(fn, donate_argnums=0).lower(
+            state, params, *args).compile()
+    finally:
+        mp.undo()
+    text = compiled.as_text()
+    kernel = "paged_latent_attn" if name == "decode" else "mla_chunk_attn"
+    calls = re.findall(rf"(?m)^\s*%{kernel}[.\d]* = \S+ custom-call\(", text)
+    assert len(calls) == 5, calls
+    assert not re.search(r"f32\[64,1024,(?:512|1024|\d{4,})\]", text)
+    assert not re.search(r"\[(?:\d+,)*640,128\]", text)
+    pool = int(np.prod(specs["cache_latent"]["shape"])) * 2
+    assert abs(pool / 1e9 - 4.56) < 0.01
+    limit = pool // (200 if name == "decode" else 100)
+    big = [m.group(0) for m in re.finditer(
+               r"(\w+)\[([\d,]+)\]\S* copy\(", text)
+           if _ITEMSIZE.get(m.group(1), 4) * np.prod(
+               [int(x) for x in m.group(2).split(",")]) >= limit]
+    assert not big, big
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)",
+        re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1))}
+    assert aliased == {0}, aliased
+    if name == "prefill_chunk":
+        assert text.count("conditional(") == 4
+        assert set(re.findall(r'ragged_dot_tiling="?([\d,]+)', text)) == {
+            "128,7168,256", "128,2048,1024"}
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
