@@ -1543,6 +1543,15 @@ class StepwiseGenerator:
         pool, rest = self._split(feats)
         return self._decode(pool, rest)
 
+    @staticmethod
+    def committed(array):
+        """``array`` COMMITTED to the pool's device, as everything a
+        program returns is: a step fed the ids the step before it left
+        on the device and a step fed the host's own are then ONE
+        compiled program (an uncommitted operand keys the jit cache
+        apart from a committed one: see :meth:`make_pool`)."""
+        return jax.device_put(array, jax.devices()[0])
+
     def block_step(self, feats: dict) -> dict:
         """One batched block step of a block-diffusion artifact (``tok``
         [slots, B], ``pos``/``alive``/``commit`` [slots],
