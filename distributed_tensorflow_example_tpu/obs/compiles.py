@@ -21,6 +21,7 @@ program compiling inside the ramp, both by accident).
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 
@@ -76,3 +77,18 @@ def count_on_this_thread(registry: Registry, process: str) -> None:
             jax.monitoring.register_event_duration_secs_listener(
                 _on_duration)
             _listening = True
+
+
+@contextlib.contextmanager
+def counting(registry: Registry, process: str):
+    """:func:`count_on_this_thread` for the length of a ``with`` block:
+    the calling thread's compilations inside it are ``registry``'s, and
+    afterwards whoever's they were before (a caller that compiles a
+    component's programs on its own thread before the component's thread
+    exists)."""
+    before = getattr(_thread, "sink", None)
+    count_on_this_thread(registry, process)
+    try:
+        yield
+    finally:
+        _thread.sink = before

@@ -895,6 +895,23 @@ def load_params(directory: str):
     return tree
 
 
+def prefill_widths(prompt_len: int, block_size: int) -> list[int]:
+    """The widths a whole-prompt prefill is exported at, widest first:
+    ``prompt_len``, and its half and its quarter where they are a whole
+    number of pool blocks (a prefill writes K/V in whole blocks). The
+    engine admits a prompt through the narrowest that holds it: the rows
+    past a prompt are computed and never read, so the mean prompt of a
+    lognormal mix pays for about its own length and not for the
+    longest's. A rule on the two shapes alone."""
+    return [prompt_len] + [prompt_len // d for d in (2, 4)
+                           if prompt_len % (d * block_size) == 0]
+
+
+def _prefill_file(width: int, prompt_len: int) -> str:
+    """The widest program keeps the name every artifact has."""
+    return _PREFILL if width == prompt_len else f"prefill_{width}.stablehlo"
+
+
 def _export_block_generator(model, params, out_dir: str, *,
                             prompt_len: int, max_new_tokens: int,
                             slots: int, block_size: int,
@@ -903,10 +920,13 @@ def _export_block_generator(model, params, out_dir: str, *,
                             platforms: Sequence[str]) -> str:
     """The artifact of a block-diffusion decoder: ``prefill.stablehlo``
     (one prompt under the block-causal mask, K/V written in whole pool
-    blocks) and ``block_step.stablehlo`` (B lanes a slot; ids and
-    confidences out, never logits), over a pool [L, N, Bs, KVH * D] in the
-    model's compute dtype (a token's heads side by side: the layout the
-    attention kernel reads in place, ``ops/pallas/decode_attention``).
+    blocks; the same program again at each narrower width of
+    :func:`prefill_widths`, ``prefill_<width>.stablehlo``, listed under
+    ``stepwise.prefill_widths``) and ``block_step.stablehlo`` (B lanes a
+    slot; ids and confidences out, never logits), over a pool
+    [L, N, Bs, KVH * D] in the model's compute dtype (a token's heads side
+    by side: the layout the attention kernel reads in place,
+    ``ops/pallas/decode_attention``).
 
     Weights: under ``BAKE_LIMIT_BYTES`` they are constants of both
     programs, as in every other artifact. Above it they are saved once,
@@ -969,10 +989,14 @@ def _export_block_generator(model, params, out_dir: str, *,
 
     pool_specs = {"cache_k": spec(pool_shape, cache_dtype),
                   "cache_v": spec(pool_shape, cache_dtype)}
-    prefill_specs = {"input_ids": spec((1, prompt_len), np.int32),
-                     "prompt_mask": spec((1, prompt_len), np.int32),
-                     "table_row": spec((prompt_blocks,), np.int32),
-                     **pool_specs}
+    widths = prefill_widths(prompt_len, block_size)
+
+    def prefill_specs(width):
+        return {"input_ids": spec((1, width), np.int32),
+                "prompt_mask": spec((1, width), np.int32),
+                "table_row": spec((-(-width // block_size),), np.int32),
+                **pool_specs}
+
     step_specs = {"tok": spec((slots, lanes), np.int32),
                   "pos": spec((slots,), np.int32),
                   "alive": spec((slots,), np.int32),
@@ -980,7 +1004,8 @@ def _export_block_generator(model, params, out_dir: str, *,
                   "block_tables": spec((slots, blocks_per_slot), np.int32),
                   **pool_specs}
     weights, param_count, param_bytes, moe = _trace_with_params(
-        ((_PREFILL, prefill_fn, prefill_specs),
+        (*((_prefill_file(w, prompt_len), prefill_fn, prefill_specs(w))
+           for w in widths),
          (_BLOCK_STEP, step_fn, step_specs)), params, platforms, out_dir)
     meta = {
         "model": getattr(model, "name", type(model).__name__),
@@ -1007,6 +1032,8 @@ def _export_block_generator(model, params, out_dir: str, *,
             "prompt_blocks": prompt_blocks, "layout": "left_aligned",
             "block_bytes": block_bytes, "spec_tokens": 0,
             "prefill_chunk": 0,
+            # the whole-prompt prefill's exported widths, widest first
+            "prefill_widths": widths,
             # generation by diffusion over blocks: what the engine's
             # slot state and transfer rule need of the model
             "block": {"length": lanes, "mask_id": int(c.mask_id),
@@ -1422,10 +1449,17 @@ class StepwiseGenerator:
         self.params = None
         if self.meta.get("weights") == "checkpoint":
             self.params = load_params(os.path.join(directory, _PARAMS_DIR))
-        self._prefill_exp = None
-        if not self.state:
-            with open(os.path.join(directory, _PREFILL), "rb") as f:
-                self._prefill_exp = jax_export.deserialize(f.read())
+        #: the whole-prompt prefill's exported widths, widest first (an
+        #: artifact that does not list them has one, ``prompt_len``; a
+        #: per-request-state artifact none)
+        prompt_len = int(step_meta["prompt_len"])
+        self.prefill_widths: tuple[int, ...] = () if self.state else tuple(
+            int(w) for w in step_meta.get("prefill_widths", [prompt_len]))
+        prefill_exps = {}
+        for w in self.prefill_widths:
+            with open(os.path.join(
+                    directory, _prefill_file(w, prompt_len)), "rb") as f:
+                prefill_exps[w] = jax_export.deserialize(f.read())
         with open(os.path.join(
                 directory, _BLOCK_STEP if self.block else _DECODE),
                 "rb") as f:
@@ -1444,7 +1478,8 @@ class StepwiseGenerator:
         # each wrapper carries its program's name, so the executed
         # programs read jit_prefill / jit_decode / jit_verify /
         # jit_prefill_chunk in a profiler capture and in compile events
-        # (the exported artifacts are untouched)
+        # (the exported artifacts are untouched); a prefill of any width
+        # is jit_prefill
         def split(call, name):
             if self.params is not None:
                 return split_with_params(call, name)
@@ -1461,8 +1496,9 @@ class StepwiseGenerator:
             jitted = jax.jit(fn, donate_argnums=(0,))
             return lambda pool, rest: jitted(pool, self.params, rest)
 
-        self._prefill = (split(self._prefill_exp.call, "prefill")
-                         if self._prefill_exp is not None else None)
+        self._prefills = {w: split(exp.call, "prefill")
+                          for w, exp in prefill_exps.items()}
+        self._prefill = self._prefills.get(prompt_len)      # the widest
         self._zero = None
         per_slot = [k for k, v in (self.state or {}).get(
             "specs", {}).items() if v["per"] == "slot"]
@@ -1522,12 +1558,14 @@ class StepwiseGenerator:
         return pool, rest
 
     def prefill(self, feats: dict) -> dict:
-        if self._prefill is None:
+        """The whole-prompt prefill at the width of ``input_ids``, one of
+        :attr:`prefill_widths`."""
+        if not self._prefills:
             raise ValueError("this artifact holds no whole-prompt prefill "
                              "program: its prompts go through "
                              "prefill_chunk")
         pool, rest = self._split(feats)
-        return self._prefill(pool, rest)
+        return self._prefills[rest["input_ids"].shape[1]](pool, rest)
 
     def zero_slot(self, pool: dict, slot: int) -> dict:
         """The pool with slot ``slot``'s rows of every ``per: "slot"``

@@ -1419,6 +1419,26 @@ class GenerationEngine:
         reg = self.registry
         self._c_prefills = reg.counter(
             "serving_prefills_total", "prefill program dispatches")
+        #: the whole-prompt prefill's widths, narrowest first: a prompt
+        #: is admitted through the narrowest that holds it
+        #: (serving.prefill_widths; one width, ``prompt_len``, in an
+        #: artifact that lists none)
+        self.prefill_widths: tuple[int, ...] = tuple(sorted(
+            stepwise.prefill_widths))
+        self._c_prefills_by_width = {
+            w: reg.counter(
+                f"serving_prefills_width_{w}_total",
+                f"whole-prompt prefill dispatches of the program "
+                f"{w} tokens wide")
+            for w in self.prefill_widths}
+        self._c_prefill_rows = reg.counter(
+            "serving_prefill_rows_total",
+            "rows the whole-prompt prefill dispatches computed (the sum "
+            "of their programs' widths)")
+        self._c_prefill_tokens = reg.counter(
+            "serving_prefill_tokens_total",
+            "prompt tokens the whole-prompt prefill dispatches carried "
+            "(of serving_prefill_rows_total: the rest was padding)")
         self._c_decode_steps = reg.counter(
             "serving_decode_steps_total", "shared decode dispatches")
         self._c_decode_slot_steps = reg.counter(
@@ -1909,6 +1929,8 @@ class GenerationEngine:
                 2 * int(np.prod([shape[0], *shape[2:]])) * np.dtype(
                     m["cache_dtype"]).itemsize))
             self._copy_block = self._make_block_copy()
+            if len(self.prefill_widths) > 1:
+                self._pool = self._compile_prefills(self._pool)
         else:
             self.prefix_cache = None
         # bytes one cached token costs at this artifact's kv dtype
@@ -2449,6 +2471,34 @@ class GenerationEngine:
         return round(self._retry.estimate(
             self._steps_to_free_hint, queue_ahead=len(self._queue),
             slots=self.slots), 2)
+
+    def _compile_prefills(self, pool: dict) -> dict:
+        """Compile every width of the whole-prompt prefill at load (from
+        ``__init__``, on the caller's thread, counted as this engine's
+        compilations): no single warm request reaches more than one
+        width, and a width compiled at first use would put its compile
+        (seconds) into some request's latency. One launch a width on an
+        all-zero table row (through :meth:`_launch`, as every program:
+        the launches count in ``seq``): what it writes lands in the null
+        block, which no program reads. Returns the pool they left."""
+        t0 = time.perf_counter()
+        with obs_compiles.counting(self.registry, self.process):
+            for w in self.prefill_widths:
+                with span("prefill", process=self.process, lane="load",
+                          prompt_tokens=0, width=w) as launch:
+                    out = self._launch("prefill", self.sw.prefill, {
+                        "input_ids": np.zeros((1, w), np.int32),
+                        "prompt_mask": np.zeros((1, w), np.int32),
+                        "table_row": np.zeros(
+                            (-(-w // self.block_size),), np.int32),
+                        **pool}, on=launch)
+                pool = {k: v for k, v in out.items()
+                        if k.startswith("cache_")}
+            for array in pool.values():
+                array.block_until_ready()
+        log.info("prefill widths %s compiled in %.2f s",
+                 list(self.prefill_widths), time.perf_counter() - t0)
+        return pool
 
     # ---- scheduler thread --------------------------------------------
     def start(self) -> "GenerationEngine":
@@ -2991,17 +3041,20 @@ class GenerationEngine:
             self._prefilling[index] = slot
             self._g_prefilling_slots.set(len(self._prefilling))
             return True
-        table_row = np.zeros((self.prompt_blocks,), np.int32)
+        # the narrowest exported width that holds the prompt: the rows
+        # past it are computed and never read
+        width = min(w for w in self.prefill_widths if w >= p)
+        table_row = np.zeros((-(-width // self.block_size),), np.int32)
         table_row[:needed] = run
-        ids = np.zeros((1, self.prompt_len), np.int32)
-        mask = np.zeros((1, self.prompt_len), np.int32)
+        ids = np.zeros((1, width), np.int32)
+        mask = np.zeros((1, width), np.int32)
         ids[0, :p] = tokens
         mask[0, :p] = 1
         try:
             with span("prefill", process=self.process,
                       lane=f"slot{index}",
                       request_id=req.request_id, prompt_tokens=p,
-                      **req.trace):
+                      width=width, **req.trace):
                 faults.inject("engine.prefill", detail=req.request_id)
                 with self._phase(span_name="admit_launch") as launch:
                     out = self._launch("prefill", self.sw.prefill, {
@@ -3033,6 +3086,9 @@ class GenerationEngine:
             with self.registry.atomic():
                 self._c_admissions.inc()
                 self._c_prefills.inc()
+                self._c_prefills_by_width[width].inc()
+                self._c_prefill_rows.inc(width)
+                self._c_prefill_tokens.inc(p)
                 if self.prefix_cache is not None:
                     self.prefix_cache.record_miss()
             self._tables[index, :needed] = run
@@ -4666,6 +4722,7 @@ class GenerationEngine:
         decode_steps = c("serving_decode_steps_total")
         shared = (c("serving_decode_slot_steps_total") / decode_steps
                   if decode_steps else 0.0)
+        prefill_rows = c("serving_prefill_rows_total")
         out = {
             "slots": self.slots,
             "kv_cache_dtype": self.kv_cache_dtype,
@@ -4704,6 +4761,14 @@ class GenerationEngine:
             "shed_batch": c("serving_shed_batch_total"),
             "shed_best_effort": c("serving_shed_best_effort_total"),
             "shed_infeasible": c("serving_shed_infeasible_total"),
+            # whole-prompt prefills by the width of the program that ran
+            # them, and the share of their rows that was padding
+            "prefills_by_width": {
+                w: c(f"serving_prefills_width_{w}_total")
+                for w in self.prefill_widths},
+            "prefill_pad_share": round(
+                1.0 - c("serving_prefill_tokens_total") / prefill_rows,
+                4) if prefill_rows else 0.0,
             "prefill_chunk_tokens": self.prefill_chunk_tokens,
             "prefill_chunks": c("serving_prefill_chunks_total"),
             "chunks_behind_step": c("serving_chunks_behind_step_total"),

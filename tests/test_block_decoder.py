@@ -278,6 +278,201 @@ def test_what_is_refused_for_this_artifact(f32, artifact, tmp_path):
         serving.load_servable(artifact)({"input_ids": np.zeros((1, 32))})
 
 
+# ---- (a2) the prefill's widths: export, load, admission ------------------
+
+W_NARROW, W_WIDE = 16, 32         # the artifact's widths (block_size 16)
+
+
+@pytest.mark.parametrize("prompt_len,block_size,want", [
+    (4096, 128, [4096, 2048, 1024]),     # the served cell
+    (32, 16, [32, 16]),                  # the rehearsal: 8 is no whole block
+    (48, 16, [48]),                      # 24 and 12 are none either
+    (16, 16, [16])])
+def test_prefill_widths_is_a_rule_on_shapes(prompt_len, block_size, want):
+    assert serving.prefill_widths(prompt_len, block_size) == want
+
+
+def _width_for(p):
+    return min(w for w in (W_NARROW, W_WIDE) if w >= p)
+
+
+@pytest.fixture(scope="module")
+def wide_engine(artifact):
+    """An engine held to the widest program, as the parent admits: the
+    test narrows what the engine sees of the artifact, not the program."""
+    eng = GenerationEngine(serving.load_stepwise(artifact), max_queue=32)
+    eng.prefill_widths = (W_WIDE,)
+    eng.start()
+    yield eng
+    eng.close()
+
+
+@pytest.mark.parametrize("p", [1, W_NARROW - 1, W_NARROW, W_NARROW + 1,
+                               W_WIDE])
+def test_narrowest_width_leaves_the_pool_and_the_tokens_of_the_widest(
+        artifact, engine, wide_engine, p):
+    """What a prompt leaves in its pool blocks through the narrowest
+    program that holds it is what it leaves through ``prompt_len`` (XLA
+    attention, float32: to rounding, the reductions run over other
+    lengths), nothing but the null block is written beside them, and an
+    engine run generates the same tokens through either."""
+    sw = serving.load_stepwise(artifact)
+    prompt = np.random.RandomState(40 + p).randint(0, 500, p)
+    run = [3, 5][:-(-p // 16)]
+
+    def leaves(width):
+        ids = np.zeros((1, width), np.int32)
+        ids[0, :p] = prompt
+        row = np.zeros((width // 16,), np.int32)
+        row[:len(run)] = run
+        out = sw.prefill({"input_ids": ids,
+                          "prompt_mask": np.ones_like(ids),
+                          "table_row": row, **sw.make_pool()})
+        return np.asarray(out["cache_k"]), np.asarray(out["cache_v"])
+
+    narrow, wide = leaves(_width_for(p)), leaves(W_WIDE)
+    for a, b in zip(narrow, wide):
+        assert np.abs(b[:, run]).max() > 0
+        np.testing.assert_allclose(a[:, run], b[:, run], rtol=1e-5,
+                                   atol=1e-6)
+        rest = [i for i in range(1, a.shape[1]) if i not in run]
+        assert not a[:, rest].any() and not b[:, rest].any()
+    before = engine.stats()["prefills_by_width"]
+    got = engine.generate(prompt.tolist(), max_new=9)
+    after = engine.stats()["prefills_by_width"]
+    assert got == wide_engine.generate(prompt.tolist(), max_new=9)
+    assert {w: after[w] - before[w] for w in after} == {
+        w: int(w == _width_for(p)) for w in (W_NARROW, W_WIDE)}
+
+
+def test_engine_admits_through_the_narrowest_width_and_stats_say_so(
+        artifact):
+    """``/stats`` and the ``prefill`` span name the width each prompt
+    took; the pad share is the rows no prompt token filled."""
+    from distributed_tensorflow_example_tpu.obs.trace import (
+        TraceRecorder, recorder, set_recorder)
+    old = recorder()
+    rec = set_recorder(TraceRecorder(max_events=1 << 14))
+    rec.start()
+    eng = GenerationEngine(serving.load_stepwise(artifact)).start()
+    try:
+        assert eng.prefill_widths == (W_NARROW, W_WIDE)
+        lens = [5, 16, 17, 32, 1]
+        for n in lens:
+            eng.generate(list(range(1, n + 1)), max_new=5)
+        st = eng.stats()
+    finally:
+        eng.close()
+        rec.stop()
+        set_recorder(old)
+    assert st["prefills_by_width"] == {W_NARROW: 3, W_WIDE: 2}
+    assert st["prefills"] == 5
+    rows = 3 * W_NARROW + 2 * W_WIDE
+    assert st["prefill_pad_share"] == round(1 - sum(lens) / rows, 4)
+    snap = eng.metrics_snapshot()
+    assert snap["serving_prefill_rows_total"]["value"] == rows
+    assert snap["serving_prefill_tokens_total"]["value"] == sum(lens)
+    spans = [it[5] for it in rec.drain() if it[2] == "prefill"]
+    # the two launches at load (no prompt) come first
+    assert [(a["prompt_tokens"], a["width"]) for a in spans] == [
+        (0, W_NARROW), (0, W_WIDE), *((n, _width_for(n)) for n in lens)]
+
+
+def test_every_width_is_compiled_before_the_engine_is_up(artifact):
+    """Both widths are compiled when the constructor returns (counted as
+    the engine's compilations), and a run that uses every width after the
+    first request compiles nothing: no jit cache of the artifact's
+    programs misses again."""
+    sw = serving.load_stepwise(artifact)
+    assert sorted(sw._prefills) == [W_NARROW, W_WIDE]
+    assert all(f._cache_size() == 0 for f in sw._prefills.values())
+    eng = GenerationEngine(sw)
+    try:
+        assert all(f._cache_size() == 1 for f in sw._prefills.values())
+        assert eng.stats()["jit_compiles"] >= 2
+        eng.start()
+        eng.generate(list(range(1, 31)), max_new=8)    # the warm request
+        compiled = eng.stats()["jit_compiles"]
+        for n in (1, 15, 16, 17, 32, 9, 24):
+            eng.generate(list(range(1, n + 1)), max_new=8)
+        st = eng.stats()
+        assert st["jit_compiles"] == compiled
+        assert all(n > 0 for n in st["prefills_by_width"].values())
+        assert all(f._cache_size() == 1 for f in sw._prefills.values())
+        assert sw._decode._cache_size() == 1
+    finally:
+        eng.close()
+
+
+def test_artifact_without_the_widths_key_loads_with_one_width(artifact,
+                                                              tmp_path):
+    """An artifact exported before the widths were (no
+    ``stepwise.prefill_widths``) has one, ``prompt_len``: nothing is
+    compiled at load, every prompt takes it, the tokens are the same."""
+    import shutil
+    d = str(tmp_path / "old")
+    shutil.copytree(artifact, d)
+    path = os.path.join(d, "export.json")
+    meta = json.load(open(path))
+    assert meta["stepwise"].pop("prefill_widths") == [W_WIDE, W_NARROW]
+    os.remove(os.path.join(d, f"prefill_{W_NARROW}.stablehlo"))
+    with open(path, "w") as f:
+        json.dump(meta, f)
+    sw = serving.load_stepwise(d)
+    assert sw.prefill_widths == (W_WIDE,)
+    eng = GenerationEngine(sw)
+    assert sw._prefill._cache_size() == 0          # compiled at first use
+    eng.start()
+    new = GenerationEngine(serving.load_stepwise(artifact)).start()
+    try:
+        for n in (3, 16, 32):
+            prompt = np.random.RandomState(n).randint(0, 500, n).tolist()
+            assert eng.generate(prompt, max_new=7) == new.generate(
+                prompt, max_new=7)
+        assert eng.stats()["prefills_by_width"] == {W_WIDE: 3}
+    finally:
+        eng.close()
+        new.close()
+
+
+def test_block_artifact_holds_one_program_a_width(artifact):
+    meta = json.load(open(os.path.join(artifact, "export.json")))
+    assert meta["stepwise"]["prefill_widths"] == [W_WIDE, W_NARROW]
+    assert sorted(f for f in os.listdir(artifact)
+                  if f.endswith(".stablehlo")) == [
+        "block_step.stablehlo", "prefill.stablehlo",
+        f"prefill_{W_NARROW}.stablehlo"]
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_gpt_artifacts_keep_one_prefill_program(tmp_path, paged):
+    """GPT-2's exporters are not touched: one ``prefill*.stablehlo``, no
+    widths key, and the engine admits every prompt through it."""
+    gpt = get_model("gpt_tiny", TrainConfig(model="gpt_tiny"))
+    d = str(tmp_path / "gpt")
+    serving.export_generator(gpt, gpt.init(jax.random.key(0)), d,
+                             ragged=True, stepwise=True, paged=paged,
+                             slots=2, block_size=16, prompt_len=32,
+                             max_new_tokens=8, platforms=("cpu",))
+    assert [f for f in sorted(os.listdir(d)) if f.startswith("prefill")] == [
+        "prefill.stablehlo"]
+    meta = json.load(open(os.path.join(d, "export.json")))
+    assert "prefill_widths" not in meta["stepwise"]
+    sw = serving.load_stepwise(d)
+    assert sw.prefill_widths == (32,)
+    eng = GenerationEngine(sw, prefix_cache=False)
+    assert sw._prefill._cache_size() == 0          # compiled at first use
+    eng.start()
+    try:
+        for n in (3, 16, 17):
+            assert len(eng.generate(list(range(1, n + 1)), max_new=4)) == 4
+        assert eng.stats()["prefills_by_width"] == {
+            32: 3 if paged else 0}
+        assert sw._prefill._cache_size() == 1
+    finally:
+        eng.close()
+
+
 # ---- (b) the expert layer that holds a share --------------------------
 
 def _moe_case(skew: float):
@@ -649,9 +844,11 @@ def test_artifact_keeps_the_tiles_and_the_engine_shows_them(artifact,
                              slots=16, block_size=16, prompt_len=256,
                              max_new_tokens=8, platforms=("cpu",))
     names = ("gate", "up", "down")
+    # every width of the prefill is a program of its own
     tiled = {p: dict.fromkeys(names, "128,128,128")
-             for p in ("prefill", "block_step")}
-    own = {p: dict.fromkeys(names, "xla") for p in tiled}
+             for p in ("prefill", "prefill_128", "prefill_64", "block_step")}
+    own = {p: dict.fromkeys(names, "xla")
+           for p in ("prefill", "prefill_16", "block_step")}
     for d_, want in ((d, tiled), (artifact, own)):
         meta = json.load(open(os.path.join(d_, "export.json")))
         assert meta["stepwise"]["block"]["moe_tiles"] == want

@@ -594,5 +594,10 @@ def test_gpt_and_sdar_programs_are_the_parents(name, tmp_path):
     got = {f"{name}/{f}": hashlib.sha256(_program_text(
                os.path.join(out, f)).encode()).hexdigest()[:16]
            for f in sorted(os.listdir(out)) if f.endswith(".stablehlo")}
-    assert got == {k: v for k, v in PARENT_PROGRAMS.items()
-                   if k.startswith(name + "/")}
+    # PR 46: a block artifact holds its prefill again at each narrower
+    # width (here 16); what the parent exported is still what it was
+    assert [k for k in got if k not in PARENT_PROGRAMS] == (
+        ["sdar/prefill_16.stablehlo"] if name == "sdar" else [])
+    assert {k: v for k, v in got.items() if k in PARENT_PROGRAMS} == {
+        k: v for k, v in PARENT_PROGRAMS.items()
+        if k.startswith(name + "/")}
