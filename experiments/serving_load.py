@@ -215,13 +215,16 @@ def build_export(out_dir: str, *, prompt_len: int, max_new: int,
                  block_size: int = 16, num_blocks=None,
                  weight_quant: str = "off",
                  kv_cache_dtype: str = "auto", pool_bytes=None,
-                 spec_tokens: int = 0, prefill_chunk: int = 0):
+                 spec_tokens: int = 0, prefill_chunk: int = 0,
+                 repeating: bool = False):
     """Seeded GPT stepwise export (ragged monolithic artifact too, so
     the off path serves the same mixed prompt lengths). ``platforms``
     includes "tpu" when bench.py runs the serving row on chip;
     ``paged=True`` exports the block-paged stepwise pair instead of
     the slab pool. ``weight_quant``/``kv_cache_dtype``/``pool_bytes``
-    pass straight through to ``export_generator`` (the int8 legs)."""
+    pass straight through to ``export_generator`` (the int8 legs).
+    ``repeating=True`` serves :func:`repeating_params` of the seeded
+    init: the speculative legs' fixture."""
     import jax
     from distributed_tensorflow_example_tpu.config import TrainConfig
     from distributed_tensorflow_example_tpu.models import get_model
@@ -229,6 +232,8 @@ def build_export(out_dir: str, *, prompt_len: int, max_new: int,
 
     model = get_model(model_name, TrainConfig(model=model_name))
     params = model.init(jax.random.key(seed))
+    if repeating:
+        params = repeating_params(params)
     export_generator(model, params, out_dir, prompt_len=prompt_len,
                      max_new_tokens=max_new, batch_size=1, ragged=True,
                      stepwise=True, slots=slots, paged=paged,
@@ -239,6 +244,38 @@ def build_export(out_dir: str, *, prompt_len: int, max_new: int,
                      prefill_chunk=prefill_chunk,
                      platforms=tuple(platforms))
     return model.cfg.vocab_size
+
+
+#: every BREAK_EVERY-th position of :func:`repeating_params` emits its
+#: own index instead of repeating the last token
+BREAK_EVERY = 8
+
+
+def repeating_params(params):
+    """GPT params whose greedy continuation repeats BY CONSTRUCTION,
+    whatever an untrained init happens to draw: the blocks' output
+    projections are zeroed, so the residual stream is ``wte[token] +
+    wpe[position]``, and ``wpe`` is zero but for every
+    ``BREAK_EVERY``-th row, which holds four times the embedding of
+    the token whose id is that position. The argmax under the tied head
+    is then the last token itself (a run the prompt-lookup drafter
+    mines: every draft inside a run is accepted), except after a break
+    position, where it is the position's id (the draft that continues
+    the run is REJECTED, so the rewind path runs). int8 weights keep
+    both argmaxes: the margins are a whole embedding's norm."""
+    import jax
+    import jax.numpy as jnp
+    out = jax.tree_util.tree_map(lambda x: x, params)
+    for name, layer in out.items():
+        if name.startswith("layer_"):
+            for proj in (layer["attn"]["o"], layer["ffn"]["out"]):
+                proj["kernel"] = jnp.zeros_like(proj["kernel"])
+                proj["bias"] = jnp.zeros_like(proj["bias"])
+    wte, wpe = out["wte"]["table"], out["wpe"]["table"]
+    breaks = jnp.arange(BREAK_EVERY - 1, wpe.shape[0], BREAK_EVERY)
+    out["wpe"]["table"] = jnp.zeros_like(wpe).at[breaks].set(
+        4.0 * wte[breaks])
+    return out
 
 
 def make_requests(clients: int, requests: int, *, prompt_len: int,
@@ -290,10 +327,11 @@ def make_repetitive_requests(clients: int, requests: int, *,
                              seed: int, period: int = 3):
     """The speculative-decoding workload: every prompt is one seeded
     ``period``-token pattern tiled to a seeded length, so the
-    prompt-lookup drafter's suffix n-grams recur from token one — and
-    greedy decode of a fixed model drifts into its own repetitive
-    fixed points, which the drafter then mines from the GENERATED
-    context too. Same [client][request] -> (prompt, max_new) shape as
+    prompt-lookup drafter's suffix n-grams recur from token one. That
+    the GENERATED context repeats too is the export's doing
+    (``build_export(repeating=True)``), not an untrained model's: under
+    a seeded init most continuations never repeat and accept nothing.
+    Same [client][request] -> (prompt, max_new) shape as
     :func:`make_requests`."""
     rs = np.random.RandomState(seed)
     pattern = rs.randint(0, vocab, (period,)).astype(np.int32)
@@ -1465,7 +1503,7 @@ def main(argv=None) -> int:
                              num_blocks=1 + 4 * args.slots
                              * -(-(args.prompt_len + spec_max_new)
                                  // args.block_size),
-                             spec_tokens=spec_k)
+                             spec_tokens=spec_k, repeating=True)
                 rep = make_repetitive_requests(
                     args.clients, args.requests,
                     prompt_len=args.prompt_len, max_new=spec_max_new,

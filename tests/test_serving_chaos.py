@@ -426,7 +426,8 @@ def test_spec_seams_inert_when_silent(tmp_path):
 
     d = str(tmp_path / "spec")
     vocab = build_export(d, prompt_len=8, max_new=16, slots=4, seed=0,
-                         paged=True, block_size=4, spec_tokens=4)
+                         paged=True, block_size=4, spec_tokens=4,
+                         repeating=True)
     matrix = make_repetitive_requests(1, 4, prompt_len=8, max_new=12,
                                       vocab=vocab, seed=0)
     prompts = [p for row in matrix for p, _ in row]

@@ -46,7 +46,7 @@ def spec_dir(tmp_path_factory):
     d = str(tmp_path_factory.mktemp("spec"))
     vocab = build_export(d, prompt_len=PROMPT_LEN, max_new=MAX_NEW,
                          slots=SLOTS, seed=0, paged=True, block_size=4,
-                         spec_tokens=4)
+                         spec_tokens=4, repeating=True)
     return d, vocab
 
 
@@ -60,7 +60,7 @@ def spec_int8_dir(tmp_path_factory):
     vocab = build_export(d, prompt_len=PROMPT_LEN, max_new=MAX_NEW,
                          slots=SLOTS, seed=0, paged=True, block_size=4,
                          weight_quant="int8", kv_cache_dtype="int8",
-                         spec_tokens=4)
+                         spec_tokens=4, repeating=True)
     return d, vocab
 
 
@@ -230,7 +230,8 @@ def test_engine_spec_off_is_bitwise_noop(spec_dir, tmp_path):
     d, vocab = spec_dir
     plain = str(tmp_path / "plain")
     build_export(plain, prompt_len=PROMPT_LEN, max_new=MAX_NEW,
-                 slots=SLOTS, seed=0, paged=True, block_size=4)
+                 slots=SLOTS, seed=0, paged=True, block_size=4,
+                 repeating=True)
     prompts = ragged_prompts(vocab, n=SLOTS)
 
     def run_preloaded(dir_):
