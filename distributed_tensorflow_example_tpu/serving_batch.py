@@ -199,12 +199,15 @@ Round 10 — block-paged pool + shared-prefix reuse: with a PAGED
 stepwise artifact (``export_generator(..., paged=True)``) the engine
 swaps the ``slots × T`` slab reservation for a shared pool of
 ``block_size``-token physical blocks plus per-slot block tables
-(:class:`BlockPool`: refcounted, allocate-on-write during decode,
-retirement returns blocks, block 0 reserved as the never-read null
-target). Admission consults a :class:`PrefixCache` (token-prefix hash
-at block granularity, LRU): a hit mounts the cached blocks by
-reference and teacher-forces only the uncached suffix through the
-SHARED decode step — zero prefill dispatches for a repeated prefix —
+(:class:`~.serving_cache.BlockPool`: refcounted, allocate-on-write
+during decode, retirement returns blocks, block 0 reserved as the
+never-read null target). The tables, the pool and every decision about
+them live in :mod:`.serving_cache`; the engine holds one
+:class:`~.serving_cache.PagedCache` (``self.cache``) and calls its
+verbs. Admission consults a :class:`~.serving_cache.PrefixCache`
+(token-prefix hash at block granularity, LRU): a hit mounts the cached
+blocks by reference and teacher-forces only the uncached suffix through
+the SHARED decode step — zero prefill dispatches for a repeated prefix —
 and a write into a still-shared block copies it first (copy-on-write),
 so divergence can never corrupt a neighbor or the cache. Admission and
 429 are driven by BLOCK exhaustion, not slot count: concurrency is
@@ -217,7 +220,7 @@ import dataclasses
 import functools
 import threading
 import time
-from collections import OrderedDict, deque
+from collections import deque
 # the stdlib Future is the right primitive (set_result/set_exception/
 # result(timeout)); NOTE concurrent.futures.TimeoutError only became
 # the builtin TimeoutError alias in 3.11 — on 3.10 they are distinct
@@ -236,6 +239,7 @@ from .obs.registry import SERVING_LATENCY_BUCKETS, Registry
 from .obs.trace import add_span, span
 from .runtime import faults
 from .serving import ServableModel, StepwiseGenerator
+from .serving_cache import BlocksExhaustedError, PagedCache
 from .utils.logging import get_logger
 
 log = get_logger("serving")
@@ -270,12 +274,6 @@ class DrainingError(Exception):
     def __init__(self, msg: str, retry_after: float = 1.0):
         super().__init__(msg)
         self.retry_after = retry_after
-
-
-class BlocksExhaustedError(Exception):
-    """The paged cache pool has no free physical block left (even after
-    prefix-cache eviction). The one request that needed the block fails
-    loudly; the engine keeps serving its neighbors."""
 
 
 class RequestCancelledError(Exception):
@@ -496,201 +494,6 @@ def _sanitized_class(cls: type) -> type:
         sub = type(cls.__name__ + "ThreadSanitized", (cls,), ns)
         _SANITIZED_CLASSES[cls] = sub
     return sub
-
-
-class BlockPool:
-    """Host-side refcounted allocator over the physical blocks of a
-    paged KV-cache pool.
-
-    Block 0 is the reserved NULL block: never allocated, the target of
-    unused/dead block-table entries — whole-block prefill spill and the
-    gated dead-row write land there and are never read (the attention
-    mask excludes every logical slot past ``pos``). A block returns to
-    the free list exactly when its LAST reference drops: slot tables
-    and prefix-cache entries each hold one reference, so a shared
-    prefix block outlives any single request that mounted it.
-    Single-threaded by design — only the scheduler thread touches it.
-    """
-
-    def __init__(self, num_blocks: int):
-        if num_blocks < 2:
-            raise ValueError(f"num_blocks must be >= 2 (the reserved "
-                             f"null block + at least one usable), got "
-                             f"{num_blocks}")
-        self.num_blocks = num_blocks
-        self._ref = [0] * num_blocks
-        # LIFO free list: recently retired blocks are remounted first;
-        # deterministic allocation order (tests rely on it), and holes
-        # from mixed-length retirement are served like any other block
-        # — physical contiguity is irrelevant, the table indirection IS
-        # the defragmenter
-        self._free = list(range(num_blocks - 1, 0, -1))
-        #: high-water mark of blocks in use — the bytes_resident_peak
-        #: observable (per-dtype residency for the bench rows)
-        self.peak_in_use = 0
-
-    @classmethod
-    def from_bytes(cls, pool_bytes: int, block_bytes: int) -> "BlockPool":
-        """Size the pool IN BYTES: as many usable blocks as
-        ``block_bytes``-sized K/V payloads fit the budget, plus the
-        reserved null block — the sizing rule under which an int8
-        cache (half the payload bytes) genuinely doubles the block
-        count at fixed HBM. Mirrors ``export_generator``'s
-        ``pool_bytes`` math."""
-        if block_bytes < 1:
-            raise ValueError(f"block_bytes must be >= 1, got "
-                             f"{block_bytes}")
-        return cls(1 + pool_bytes // block_bytes)
-
-    @property
-    def usable(self) -> int:
-        return self.num_blocks - 1
-
-    @property
-    def free_count(self) -> int:
-        return len(self._free)
-
-    @property
-    def in_use(self) -> int:
-        return self.usable - len(self._free)
-
-    def alloc(self, n: int) -> list[int]:
-        """``n`` fresh blocks, refcount 1 each — all-or-nothing (a
-        caller never holds a partial run)."""
-        faults.inject("pool.alloc", detail=f"n={n}")
-        if n > len(self._free):
-            raise BlocksExhaustedError(
-                f"need {n} cache block(s), {len(self._free)} free "
-                f"(pool of {self.usable} usable blocks)")
-        out = [self._free.pop() for _ in range(n)]
-        for b in out:
-            self._ref[b] = 1
-        self.peak_in_use = max(self.peak_in_use, self.in_use)
-        return out
-
-    def retain(self, blocks) -> None:
-        for b in blocks:
-            if self._ref[b] <= 0:
-                raise AssertionError(f"retain of free block {b}")
-            self._ref[b] += 1
-
-    def release(self, blocks) -> None:
-        for b in blocks:
-            self._ref[b] -= 1
-            if self._ref[b] < 0:
-                raise AssertionError(f"double release of block {b}")
-            if self._ref[b] == 0:
-                self._free.append(b)
-
-    def refcount(self, block: int) -> int:
-        return self._ref[block]
-
-
-class PrefixCache:
-    """Block-granularity prefix reuse: hash of a token prefix -> the
-    physical blocks whose K/V bytes ARE that prefix's.
-
-    Entries exist at every full-block boundary of an admitted cold
-    prompt (key = its first ``j * block_size`` tokens, value = its
-    first ``j`` blocks) plus one EXACT whole-prompt entry when the
-    prompt ends mid-block (value includes the partial tail block). The
-    left-aligned paged layout makes the cached bytes position-
-    independent facts of the token prefix — token i always sits at
-    logical slot i — so a hit mounts the blocks by reference (retain),
-    no copy. Each entry holds one refcount per block; LRU eviction
-    releases entries until the allocator can serve again, and a block
-    still mounted by a live slot simply survives its cache eviction.
-    """
-
-    def __init__(self, pool: BlockPool, block_size: int, *,
-                 registry: Registry | None = None):
-        self.pool = pool
-        self.block_size = block_size
-        # key -> (blocks tuple, covered token count); insertion order
-        # doubles as LRU (move_to_end on touch)
-        self._entries: OrderedDict[bytes, tuple[tuple[int, ...], int]] \
-            = OrderedDict()
-        # registry-backed counters (the engine hands in ITS registry so
-        # /stats, /metrics and the engine counters stay one source of
-        # truth; standalone unit tests get a private one)
-        self.registry = registry if registry is not None else Registry()
-        self._c_hits = self.registry.counter(
-            "serving_prefix_cache_hits_total",
-            "admissions served (fully or partially) from cached blocks")
-        self._c_misses = self.registry.counter(
-            "serving_prefix_cache_misses_total",
-            "admissions with no cached prefix (cold prefill)")
-
-    @property
-    def hits(self) -> int:
-        return self._c_hits.value
-
-    @property
-    def misses(self) -> int:
-        return self._c_misses.value
-
-    def record_hit(self) -> None:
-        self._c_hits.inc()
-
-    def record_miss(self) -> None:
-        self._c_misses.inc()
-
-    @staticmethod
-    def _key(tokens: np.ndarray) -> bytes:
-        return np.ascontiguousarray(tokens, np.int32).tobytes()
-
-    def __len__(self) -> int:
-        return len(self._entries)
-
-    def lookup(self, tokens: np.ndarray, *,
-               record: bool = True) -> tuple[int, tuple[int, ...]]:
-        """Longest cached prefix of ``tokens``: ``(n_tokens_hit,
-        blocks)`` — the exact whole-prompt entry wins, else the longest
-        full-block chain; ``(0, ())`` on a miss. Mounting (refcounting)
-        is the caller's move. ``record=False`` skips the hit/miss
-        counters — for probes that may not lead to an admission (a
-        block-pressure deferral retries the same request every step,
-        and one admission must count once)."""
-        bs = self.block_size
-        p = int(tokens.size)
-        probes = [p] + [j * bs for j in range(p // bs, 0, -1)
-                        if j * bs != p]
-        for n in probes:
-            key = self._key(tokens[:n])
-            e = self._entries.get(key)
-            if e is not None:
-                self._entries.move_to_end(key)
-                if record:
-                    self._c_hits.inc()
-                return n, e[0]
-        if record:
-            self._c_misses.inc()
-        return 0, ()
-
-    def insert(self, tokens: np.ndarray, blocks) -> None:
-        """Record a cold prompt's block run: one entry per full-block
-        boundary plus the exact whole-prompt entry. Re-inserting a
-        known key only touches its LRU position."""
-        bs = self.block_size
-        p = int(tokens.size)
-        ends = sorted({*(j * bs for j in range(1, p // bs + 1)), p})
-        for n in ends:
-            nb = -(-n // bs)
-            key = self._key(tokens[:n])
-            if key in self._entries:
-                self._entries.move_to_end(key)
-                continue
-            ref = tuple(int(b) for b in blocks[:nb])
-            self.pool.retain(ref)
-            self._entries[key] = (ref, n)
-
-    def evict(self, need_free: int) -> None:
-        """Release LRU entries until ``need_free`` blocks are free (or
-        the cache is empty — blocks still mounted by live slots stay
-        resident past their entry's eviction)."""
-        while self.pool.free_count < need_free and self._entries:
-            _, (blocks, _) = self._entries.popitem(last=False)
-            self.pool.release(blocks)
 
 
 class NgramDrafter:
@@ -1252,10 +1055,10 @@ class _StepAhead:
         self.t_free = t_free
 
 
-@scheduler_owned("_pool", "_live", "_free", "_admitting", "_tables",
-                 "blocks", "prefix_cache", "_slot_freed_t", "_retry",
-                 "_steps_to_free_hint", "_admit_counter", "_prefilling",
-                 "_behind", "_due", "_step_ahead")
+@scheduler_owned("_pool", "_live", "_free", "_admitting", "cache",
+                 "_slot_freed_t", "_retry", "_steps_to_free_hint",
+                 "_admit_counter", "_prefilling", "_behind", "_due",
+                 "_step_ahead")
 class GenerationEngine:
     """The continuous-batching scheduler (see module docstring).
 
@@ -1900,7 +1703,6 @@ class GenerationEngine:
             self.num_blocks = int(m["num_blocks"])
             self.blocks_per_slot = int(m["blocks_per_slot"])
             self.prompt_blocks = int(m["prompt_blocks"])
-            self.blocks = BlockPool(self.num_blocks)
             self._g_blocks_free = reg.gauge(
                 "serving_blocks_free", "free physical cache blocks")
             self._g_bytes_resident = reg.gauge(
@@ -1913,14 +1715,13 @@ class GenerationEngine:
             self._g_prefix_entries = reg.gauge(
                 "serving_prefix_cache_entries",
                 "live prefix-cache entries")
-            self.prefix_cache = (PrefixCache(self.blocks,
-                                             self.block_size,
-                                             registry=reg)
-                                 if prefix_cache else None)
-            # per-slot block tables, host-owned (the decode program
-            # takes them as a per-step operand; 0 = the null block)
-            self._tables = np.zeros((self.slots, self.blocks_per_slot),
-                                    np.int32)
+            #: the host half of the paged cache (block tables, block
+            #: pool, prefix cache); None for a slab artifact. The table
+            #: is read here only as the device programs' operand
+            self.cache: PagedCache | None = PagedCache(
+                slots=self.slots, blocks_per_slot=self.blocks_per_slot,
+                num_blocks=self.num_blocks, block_size=self.block_size,
+                prefix_cache=prefix_cache, registry=reg)
             shape = m["pool_shape"]                # [L, N, Bs, ...]
             # per-block residency incl. int8 scale rows: recorded at
             # export since round 12; the fallback recomputes the K/V
@@ -1932,7 +1733,7 @@ class GenerationEngine:
             if len(self.prefill_widths) > 1:
                 self._pool = self._compile_prefills(self._pool)
         else:
-            self.prefix_cache = None
+            self.cache = None
         # bytes one cached token costs at this artifact's kv dtype
         # (K+V payload + int8 scale rows), taken from the pool this
         # engine holds (every cache_* array is [L, rows, tokens, ...]):
@@ -2649,17 +2450,7 @@ class GenerationEngine:
                 self._drop_chunks(wait=False)
                 self._pool = self.sw.make_pool()
                 if self.paged:
-                    # the rebuilt pool is empty: every table entry and
-                    # cached prefix names bytes that no longer exist
-                    # (hit/miss counters live in the engine registry,
-                    # so the rebuilt PrefixCache keeps counting where
-                    # the dead one stopped)
-                    self._tables[:] = 0
-                    self.blocks = BlockPool(self.num_blocks)
-                    if self.prefix_cache is not None:
-                        self.prefix_cache = PrefixCache(
-                            self.blocks, self.block_size,
-                            registry=self.registry)
+                    self.cache.reset()
 
     @scheduler_thread
     def _iterate(self) -> None:
@@ -2879,10 +2670,10 @@ class GenerationEngine:
         with self.registry.atomic():
             self._c_admissions.inc()
             self._c_requests_failed.inc()
-            if self.paged and self.prefix_cache is not None:
+            if self.paged:
                 # an admission outcome counts hit or miss exactly once;
                 # a failed admission never mounted cached blocks
-                self.prefix_cache.record_miss()
+                self.cache.count(hit=False)
         with self._cond:
             self._free.append(index)
             self._inflight_ids.discard(req.request_id)
@@ -2950,13 +2741,10 @@ class GenerationEngine:
         the request (re-queued at the head, slot index returned)."""
         tokens = np.asarray(req.prompt, np.int32)
         p = int(tokens.size)
-        # record=False: this probe repeats every step while the request
+        # uncounted: this probe repeats every step while the request
         # is deferred under block pressure — hits/misses are counted
         # below, exactly once per ADMISSION OUTCOME
-        n_hit, hit_blocks = ((self.prefix_cache.lookup(tokens,
-                                                       record=False))
-                             if self.prefix_cache is not None
-                             else (0, ()))
+        n_hit, hit_blocks = self.cache.lookup(tokens)
         if n_hit:
             # Cache hit: mount the cached blocks by reference and feed
             # the remaining KNOWN tokens through the shared decode step
@@ -2968,11 +2756,10 @@ class GenerationEngine:
                       lane=f"slot{index}",
                       request_id=req.request_id, prompt_tokens=p,
                       cached_tokens=start, **req.trace):
-                self.blocks.retain(hit_blocks)
-                self._tables[index, :len(hit_blocks)] = hit_blocks
+                self.cache.mount(index, hit_blocks)
             with self.registry.atomic():
                 self._c_admissions.inc()
-                self.prefix_cache.record_hit()
+                self.cache.count(hit=True)
                 self._c_tokens_saved.inc(start)
             self._admit_counter += 1
             slot = _Slot(req, index, pad=0, pos=start,
@@ -2993,10 +2780,7 @@ class GenerationEngine:
         # entries under pressure) and run the paged prefill program.
         needed = -(-p // self.block_size)
         try:
-            if self.blocks.free_count < needed \
-                    and self.prefix_cache is not None:
-                self.prefix_cache.evict(needed)
-            run = self.blocks.alloc(needed)
+            run = self.cache.reserve(needed)
         except BlocksExhaustedError as e:
             if self._live or self._prefilling:
                 # retirement will free blocks — try again next boundary
@@ -3023,7 +2807,7 @@ class GenerationEngine:
             # feeds one chunk per iteration (_prefill_chunk_step),
             # interleaved with the shared decode step, and the final
             # chunk's logits become the first sample point
-            self._tables[index, :needed] = run
+            self.cache.bind(index, run)
             if self._state_slot_bytes:
                 # the slot's recurrent rows still hold the request that
                 # left it: this one starts from zeros (an artifact whose
@@ -3032,8 +2816,7 @@ class GenerationEngine:
                 self._zero_slot_state(index)
             with self.registry.atomic():
                 self._c_admissions.inc()
-                if self.prefix_cache is not None:
-                    self.prefix_cache.record_miss()
+                self.cache.count(hit=False)
             self._admit_counter += 1
             slot = _Slot(req, index, pad=0, pos=0, rng=req.sampler(),
                          seq=self._admit_counter)
@@ -3080,7 +2863,7 @@ class GenerationEngine:
             # request): the block run allocated above must go back to
             # the pool first — a failed admission must not leak HBM.
             # A pool-consuming fault still escalates there.
-            self.blocks.release(run)
+            self.cache.give_back(run)
             raise
         with self._phase(span_name="admit_emit"):
             with self.registry.atomic():
@@ -3089,11 +2872,9 @@ class GenerationEngine:
                 self._c_prefills_by_width[width].inc()
                 self._c_prefill_rows.inc(width)
                 self._c_prefill_tokens.inc(p)
-                if self.prefix_cache is not None:
-                    self.prefix_cache.record_miss()
-            self._tables[index, :needed] = run
-            if self.prefix_cache is not None:
-                self.prefix_cache.insert(tokens, run)
+                self.cache.count(hit=False)
+            self.cache.bind(index, run)
+            self.cache.publish(index, tokens)
             self._admit_counter += 1
             slot = _Slot(req, index, pad=0, pos=p, rng=req.sampler(),
                          seq=self._admit_counter)
@@ -3196,7 +2977,7 @@ class GenerationEngine:
         # table row; lanes past the prompt's allocated run write the
         # reserved null block (never read — the paged convention)
         needed = -(-p // bs)
-        row = self._tables[slot.index]
+        row = self.cache.tables[slot.index]
         cb = np.zeros((cw // bs,), np.int32)
         for j in range(cw // bs):
             bi = start // bs + j
@@ -3305,15 +3086,11 @@ class GenerationEngine:
             # prompt fully resident: same tail as the monolithic cold
             # path
             tokens = np.asarray(slot.req.prompt, np.int32)
-            slot.pos = p = int(tokens.size)
+            slot.pos = int(tokens.size)
             slot.t_prefill_done = time.perf_counter()
             del self._prefilling[slot.index]
             self._g_prefilling_slots.set(len(self._prefilling))
-            if self.prefix_cache is not None:
-                needed = -(-p // self.block_size)
-                self.prefix_cache.insert(
-                    tokens,
-                    [int(b) for b in self._tables[slot.index, :needed]])
+            self.cache.publish(slot.index, tokens)
             tok0, logits0 = rec.first
             self._emit(slot, tok0 if logits0 is None
                        else self._pick(slot, logits0))
@@ -3406,18 +3183,28 @@ class GenerationEngine:
                 return None
             if s.emitted + 1 < s.req.max_new:
                 rows[i] = s
-        if not rows or not self._write_blocks_ahead(rows.values()):
+        if not rows:
             return None
         pos = np.zeros((self.slots,), np.int32)
         alive = np.zeros((self.slots,), np.int32)
         for i, s in rows.items():
             pos[i] = s.pos + 1
             alive[i] = 1
+        # allocation alone: a block someone else reads would be copied
+        # by a program, which the step in turn launches on a pool this
+        # moment does not hold
+        try:
+            if not self.cache.secure_all(rows, pos):
+                return None
+        except Exception as e:      # an injected pool.alloc fault
+            log.warning("no block for the step ahead (%s): the next "
+                        "step is launched in turn", e)
+            return None
         return _StepAhead(
             {"tok": out["ids"], "pos": pos, "alive": alive,
              # the host's tables as they are now: rows are retired and
              # admitted in them before this step is read
-             "block_tables": self._tables.copy(), **pool},
+             "block_tables": self.cache.tables.copy(), **pool},
             rows, pool, time.perf_counter())
 
     @scheduler_thread
@@ -3447,35 +3234,6 @@ class GenerationEngine:
         self._step_ahead = nxt
         self._c_steps_behind.inc()
         return _pool_of(nxt.out)
-
-    @scheduler_thread
-    def _write_blocks_ahead(self, rows) -> bool:
-        """Every row's write block for ``pos + 1``, before the step ahead
-        is launched: all of them, or none and False. Allocate-on-write
-        alone (:meth:`_ensure_write_block`'s first half): a block
-        someone else reads would be copied by a program, which the step
-        in turn launches on a pool this moment does not hold."""
-        bs, need = self.block_size, []
-        for s in rows:
-            bi = (s.pos + 1) // bs
-            if bi >= self._tables.shape[1]:
-                return False
-            pb = int(self._tables[s.index, bi])
-            if pb == 0:
-                need.append((s.index, bi))
-            elif self.blocks.refcount(pb) > 1:
-                return False
-        if len(need) > self.blocks.free_count:
-            return False
-        try:
-            got = self.blocks.alloc(len(need)) if need else []
-        except Exception as e:      # an injected pool.alloc fault
-            log.warning("no block for the step ahead (%s): the next "
-                        "step is launched in turn", e)
-            return False
-        for (index, bi), block in zip(need, got):
-            self._tables[index, bi] = block
-        return True
 
     @scheduler_thread
     def _drop_chunks(self, wait: bool) -> None:
@@ -3627,18 +3385,6 @@ class GenerationEngine:
                 retry_after=ra))
 
     @scheduler_thread
-    def _release_slot_blocks(self, index: int) -> None:
-        """Retirement/failure: drop this slot's table references (a
-        block shared with the prefix cache or another slot survives —
-        freed only at its LAST release) and reset the row to the null
-        block."""
-        row = self._tables[index]
-        ids = [int(b) for b in row if b]
-        if ids:
-            self.blocks.release(ids)
-        row[:] = 0
-
-    @scheduler_thread
     def _fail_slot(self, slot: _Slot, err: Exception,
                    counter=None) -> None:
         """Retire ONE live (or mid-chunked-prefill) request with
@@ -3648,7 +3394,7 @@ class GenerationEngine:
         ``counter`` picks which retirement counter advances (default:
         requests_failed)."""
         if self.paged:
-            self._release_slot_blocks(slot.index)
+            self.cache.release(slot.index)
         if slot.index in self._prefilling \
                 and self._prefilling[slot.index] is slot:
             del self._prefilling[slot.index]
@@ -3677,65 +3423,20 @@ class GenerationEngine:
         slot.req.future.set_exception(err)
 
     @scheduler_thread
-    def _ensure_write_block(self, slot: _Slot, n: int = 1) -> None:
-        """Before a decode step writes at ``slot.pos`` (or a verify
-        dispatch writes the span ``pos..pos+n-1``): allocate-on-write
-        when a target table entry is still the null block, and
-        copy-on-write when a target block is shared (prefix cache or
-        another slot still references it) — a divergence must never
-        mutate bytes someone else reads. Only the FIRST block of a
-        verify span can be shared (anything past the slot's own write
-        frontier was never cached), but every block gets the same
-        check — the invariant, not the current topology, is what the
-        code states."""
-        bs = self.block_size
-        for bi in range(slot.pos // bs, (slot.pos + n - 1) // bs + 1):
-            pb = int(self._tables[slot.index, bi])
-            if pb == 0:
-                if self.blocks.free_count < 1 \
-                        and self.prefix_cache is not None:
-                    self.prefix_cache.evict(1)
-                self._tables[slot.index, bi] = self.blocks.alloc(1)[0]
-            elif self.blocks.refcount(pb) > 1:
-                # cow spans live on the scheduler lane (they interleave
-                # with the slot's long decode window, and slot lanes
-                # must stay non-overlapping); the request id keeps
-                # correlation
-                with span("cow_copy", process=self.process,
-                          lane="scheduler",
-                          request_id=slot.req.request_id,
-                          slot=slot.index, block=pb,
-                          **slot.req.trace) as cow:
-                    if self.blocks.free_count < 1 \
-                            and self.prefix_cache is not None:
-                        self.prefix_cache.evict(1)
-                    nb = self.blocks.alloc(1)[0]
-                    self._pool = self._launch("copy", self._copy_block,
-                                              self._pool, pb, nb, on=cow)
-                    self._tables[slot.index, bi] = nb
-                    self.blocks.release([pb])
-                self._c_cow.inc()
-
-    @scheduler_thread
-    def _release_trailing_blocks(self, slot: _Slot,
-                                 span_end: int) -> None:
-        """After a draft rejection rewound ``slot.pos``: any block the
-        verify span secured PAST the next write position holds only
-        rejected-lane bytes nothing will ever read — its (fresh,
-        refcount-1) ref returns to the pool and the table entry goes
-        back to the null block. The block containing the next write
-        position is kept: the next dispatch writes into it. No-op when
-        the rejection stayed inside one block — the left-aligned paged
-        layout means a rewind releases nothing unless the span crossed
-        a block boundary."""
-        bs = self.block_size
-        row = self._tables[slot.index]
-        last = min(span_end // bs, row.size - 1)
-        for bi in range(slot.pos // bs + 1, last + 1):
-            pb = int(row[bi])
-            if pb:
-                self.blocks.release([pb])
-                row[bi] = 0
+    def _cow_copy(self, slot: _Slot, src: int, dst: int) -> None:
+        """Copy physical block ``src`` to ``dst`` on the device: the
+        copy-on-write of ``slot``'s write into a block someone else
+        reads (:meth:`PagedCache.secure` calls it with the new block
+        in hand, before the table names it)."""
+        # cow spans live on the scheduler lane (they interleave with
+        # the slot's long decode window, and slot lanes must stay
+        # non-overlapping); the request id keeps correlation
+        with span("cow_copy", process=self.process, lane="scheduler",
+                  request_id=slot.req.request_id, slot=slot.index,
+                  block=src, **slot.req.trace) as cow:
+            self._pool = self._launch("copy", self._copy_block,
+                                      self._pool, src, dst, on=cow)
+        self._c_cow.inc()
 
     def _hands_ids(self, program: str) -> bool:
         """Whether ``program``'s outputs hold each row's greedy id: a
@@ -3917,7 +3618,7 @@ class GenerationEngine:
         with span("retire", process=self.process, lane=lane,
                   request_id=req.request_id, **req.trace):
             if self.paged:
-                self._release_slot_blocks(slot.index)
+                self.cache.release(slot.index)
             with self._cond:
                 self._free.append(slot.index)
                 self._g_live_slots.set(len(self._live))
@@ -3968,7 +3669,7 @@ class GenerationEngine:
             # compiled program for both
             feats["tok"] = self.sw.committed(tok)
         if self.paged:
-            feats["block_tables"] = self._tables
+            feats["block_tables"] = self.cache.tables
         return feats
 
     @scheduler_thread
@@ -3995,7 +3696,7 @@ class GenerationEngine:
             pad[i] = s.pad
             alive[i] = 1
         return {"tok": tok, "pos": pos, "pad": pad, "alive": alive,
-                "n_tok": n_tok, "block_tables": self._tables,
+                "n_tok": n_tok, "block_tables": self.cache.tables,
                 **self._pool}
 
     @scheduler_thread
@@ -4407,19 +4108,23 @@ class GenerationEngine:
         neighbors still step; a SPEC row that cannot get its draft span
         drops the drafts first (degrading to the normal step is
         strictly better than dying for an optimization)."""
+        def copy(src, dst):     # made once a step: `s` is the loop's row
+            self._cow_copy(s, src, dst)
+
+        secure, rewind = self.cache.secure, self.cache.rewind
         for s in list(self._live.values()):
             try:
                 try:
                     # a block step writes all its lanes
-                    self._ensure_write_block(
-                        s, self._lanes or 1 + len(s.draft))
+                    secure(s.index, s.pos,
+                           self._lanes or 1 + len(s.draft), copy)
                 except BlocksExhaustedError:
                     if not s.draft:
                         raise
                     span_end = s.pos + len(s.draft)
                     s.draft = []
-                    self._release_trailing_blocks(s, span_end)
-                    self._ensure_write_block(s, 1)
+                    rewind(s.index, s.pos, span_end)
+                    secure(s.index, s.pos, 1, copy)
             except BlocksExhaustedError as e:
                 self._fail_slot(s, BlocksExhaustedError(
                     f"out of cache blocks mid-decode after "
@@ -4453,7 +4158,7 @@ class GenerationEngine:
             alive[i] = 1
             commit[i] = not any(s.blk_masked)
         return {"tok": tok, "pos": pos, "alive": alive, "commit": commit,
-                "block_tables": self._tables, **self._pool}
+                "block_tables": self.cache.tables, **self._pool}
 
     def _describe_block(self, feats: dict) -> dict:
         """A block step's span arguments. ``kv_bytes``: what the live
@@ -4601,18 +4306,14 @@ class GenerationEngine:
                 if not s.forced:
                     s.t_forced_done = time.perf_counter()
                 continue
-            if s.pending_insert is not None and \
-                    self.prefix_cache is not None:
+            if s.pending_insert is not None:
                 # the whole prompt is now resident in this slot's
-                # blocks: cache it. Inserting shares the tail block,
+                # blocks: cache it. Publishing shares the tail block,
                 # so this slot's NEXT write copy-on-writes it — the
                 # cached bytes stay pure, same as the cold path.
                 # (_propose_drafts never drafts under a pending
                 # insert, so the shared tail holds prompt bytes only.)
-                tokens = s.pending_insert
-                nb = -(-int(tokens.size) // self.block_size)
-                self.prefix_cache.insert(
-                    tokens, [int(b) for b in self._tables[s.index, :nb]])
+                self.cache.publish(s.index, s.pending_insert)
                 s.pending_insert = None
             row = got[i]            # an id, [V], or [K, V] on verify
             if s.draft:
@@ -4655,7 +4356,7 @@ class GenerationEngine:
                         break               # EOS / stop / max_new
                 self._c_spec_emitted.inc(n_emitted)
                 if not retired:
-                    self._release_trailing_blocks(s, span_end)
+                    self.cache.rewind(s.index, s.pos, span_end)
                 continue
             s.pos += 1
             advance += 1
@@ -4697,14 +4398,13 @@ class GenerationEngine:
                 if proposed else 0.0)
         if self.paged:
             with self.registry.atomic():
-                free = self.blocks.free_count
+                free, used, peak, entries = self.cache.occupancy()
                 self._g_blocks_free.set(free)
-                self._g_bytes_resident.set(
-                    (self.blocks.usable - free) * self._block_bytes)
+                self._g_bytes_resident.set(used * self._block_bytes)
                 self._g_bytes_resident_peak.set(
-                    self.blocks.peak_in_use * self._block_bytes)
-                if self.prefix_cache is not None:
-                    self._g_prefix_entries.set(len(self.prefix_cache))
+                    peak * self._block_bytes)
+                if entries is not None:
+                    self._g_prefix_entries.set(entries)
         return self.registry.snapshot()
 
     @snapshot_view
@@ -4837,23 +4537,25 @@ class GenerationEngine:
             # block-level observability: residency is ACTUAL tokens,
             # not slots × worst-case depth — the paged pool's whole
             # point, so it must be visible at /stats
+            cache = self.cache
+            cached = cache.prefix is not None
             out.update({
                 "paged": True,
                 "block_size": self.block_size,
                 "pool_shape": list(self.sw.step_meta["pool_shape"]),
-                "blocks_total": self.blocks.usable,
+                "blocks_total": cache.pool.usable,
                 "blocks_free": c("serving_blocks_free"),
                 "bytes_resident": c("serving_bytes_resident"),
                 "bytes_resident_peak": c("serving_bytes_resident_peak"),
                 "prefix_cache_hits": (
                     c("serving_prefix_cache_hits_total")
-                    if self.prefix_cache is not None else 0),
+                    if cached else 0),
                 "prefix_cache_misses": (
                     c("serving_prefix_cache_misses_total")
-                    if self.prefix_cache is not None else 0),
+                    if cached else 0),
                 "prefix_cache_entries": (
                     c("serving_prefix_cache_entries")
-                    if self.prefix_cache is not None else 0),
+                    if cached else 0),
                 "prefill_tokens_saved": c(
                     "serving_prefill_tokens_saved_total"),
                 "cow_copies": c("serving_cow_copies_total"),

@@ -507,7 +507,8 @@ def scenario_spec_verify_fault(d: str, seed: int, vocab: int):
         build_export(ds, prompt_len=PROMPT_LEN, max_new=10,
                      slots=SLOTS, seed=seed, paged=True,
                      block_size=BLOCK,
-                     num_blocks=1 + 4 * SLOTS * _bps(), spec_tokens=4)
+                     num_blocks=1 + 4 * SLOTS * _bps(), spec_tokens=4,
+                     repeating=True)
 
         def run(spec: int, wrap: bool = False):
             eng = fresh_engine(ds, spec_tokens=spec)

@@ -270,7 +270,7 @@ def test_what_is_refused_for_this_artifact(f32, artifact, tmp_path):
         with pytest.raises(ValueError, match="diffusion over blocks"):
             GenerationEngine(sw, **bad)
     eng = GenerationEngine(sw)              # prefix cache: off, not refused
-    assert eng.prefix_cache is None and eng.block["length"] == LANES
+    assert eng.cache.prefix is None and eng.block["length"] == LANES
     with pytest.raises(ValueError, match="greedily"):
         eng.submit([1, 2, 3], temperature=0.7)
     eng.close()

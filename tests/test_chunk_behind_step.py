@@ -369,7 +369,7 @@ def _the_rest_is_undisturbed(eng, prompts, handles, failed, err, match=None):
                 h.result(timeout=1)
         else:
             assert h.result(timeout=1) == w
-    assert eng.blocks.in_use == 0 and not eng._live and not eng._prefilling
+    assert eng.cache.pool.in_use == 0 and not eng._live and not eng._prefilling
     assert eng._behind is None and eng._due is None
 
 
@@ -394,8 +394,8 @@ def test_the_prefill_seam_fails_one_request_behind_a_step(artifacts):
 def test_a_request_gone_while_its_chunk_runs_leaves_no_trace(artifacts, how):
     eng, prompts, handles = _disturbed(artifacts, shed_policy="off")
     gone = eng._behind.slot
-    held = [int(b) for b in eng._tables[gone.index] if b]
-    free = eng.blocks.free_count
+    held = [int(b) for b in eng.cache.tables[gone.index] if b]
+    free = eng.cache.pool.free_count
     if how == "cancel":
         assert eng.cancel(gone.req.request_id)
     else:
@@ -408,7 +408,7 @@ def test_a_request_gone_while_its_chunk_runs_leaves_no_trace(artifacts, how):
     # the slot and its blocks went back at the boundary, with the chunk
     # still on the device, unread
     assert eng._prefilling.get(gone.index) is not gone
-    assert eng.blocks.free_count == free + len(held) and eng._behind
+    assert eng.cache.pool.free_count == free + len(held) and eng._behind
     assert gone.req.future.done()
     eng._iterate()
     assert eng.stats()["prefill_chunks"] == chunks + 1   # it ran; dropped

@@ -720,7 +720,7 @@ def test_ownership_markers_are_declared_metadata():
         GenerationEngine as GE
 
     owned = set(GE.__scheduler_owned__)
-    assert {"_live", "_pool", "blocks", "prefix_cache"} <= owned
+    assert {"_live", "_pool", "cache"} <= owned
     assert GE._admit.__scheduler_thread__
     assert GE._shared_step.__scheduler_thread__
     assert GE.stats.__snapshot_view__

@@ -482,7 +482,7 @@ def test_engine_generates_what_the_reference_generates(artifact, f32):
     eng = GenerationEngine(serving.load_stepwise(artifact)).start()
     try:
         assert eng.prefill_chunk_tokens == CHUNK
-        assert eng.prefix_cache is None
+        assert eng.cache.prefix is None
         handles = [eng.submit(p, max_new=k)
                    for p, k in zip(prompts, new)]
         got = [h.result(timeout=300) for h in handles]
@@ -515,7 +515,7 @@ def test_what_the_artifact_refuses_is_said(artifact, f32):
     with pytest.raises(ValueError, match="chunk"):
         GenerationEngine(sw, prefill_chunk_tokens=16)
     eng = GenerationEngine(sw, prefix_cache=True)
-    assert eng.prefix_cache is None
+    assert eng.cache.prefix is None
     with pytest.raises(ValueError, match="greedy"):
         eng.submit([1, 2, 3], temperature=0.7)
     for bad in (dict(spec_tokens=2), dict(temperature=0.5),

@@ -31,8 +31,9 @@ from distributed_tensorflow_example_tpu.ops.pallas.decode_attention import (
 from distributed_tensorflow_example_tpu.serving import (export_generator,
                                                         load_stepwise)
 from distributed_tensorflow_example_tpu.serving_batch import (
-    BlockPool, BlocksExhaustedError, GenerationEngine, PrefixCache,
-    RetryAfterEstimator)
+    GenerationEngine, RetryAfterEstimator)
+from distributed_tensorflow_example_tpu.serving_cache import (
+    BlockPool, BlocksExhaustedError, PrefixCache)
 from distributed_tensorflow_example_tpu.serving_http import PredictServer
 
 # the module, not the same-named function ops.pallas re-exports
@@ -901,28 +902,28 @@ def test_shared_block_freed_only_at_last_release(paged_dir, tiny_model):
     eng = GenerationEngine(load_stepwise(paged_dir))
     eng.submit(sysp, max_new=1)
     _drain(eng)                                  # cold: caches the block
-    free_with_cache = eng.blocks.free_count
+    free_with_cache = eng.cache.pool.free_count
     blk = None
-    for (blocks, n) in eng.prefix_cache._entries.values():
+    for (blocks, n) in eng.cache.prefix._entries.values():
         if n == BLOCK:
             blk = blocks[0]
     assert blk is not None
-    assert eng.blocks.refcount(blk) == 1                 # cache only
+    assert eng.cache.pool.refcount(blk) == 1                 # cache only
     # two hit admissions mount it (no steps run yet)
     a = np.concatenate([sysp, rs.randint(0, 1000, (1,)).astype(np.int32)])
     b = np.concatenate([sysp, rs.randint(0, 1000, (2,)).astype(np.int32)])
     fa, fb = eng.submit(a), eng.submit(b)
     eng._admit()
-    assert eng.blocks.refcount(blk) == 3
-    eng.prefix_cache.evict(10 ** 9)                      # drop ALL entries
-    assert eng.blocks.refcount(blk) == 2                 # slots still hold
-    assert eng.blocks.free_count < eng.blocks.usable
+    assert eng.cache.pool.refcount(blk) == 3
+    eng.cache.prefix.evict(10 ** 9)                      # drop ALL entries
+    assert eng.cache.pool.refcount(blk) == 2                 # slots still hold
+    assert eng.cache.pool.free_count < eng.cache.pool.usable
     _drain(eng)                                          # both retire
     # the retired slots re-inserted their (partial-hit) prompts, so
     # the cache again holds blk — drop it to see the LAST release free
-    eng.prefix_cache.evict(10 ** 9)
-    assert eng.blocks.refcount(blk) == 0                 # last release
-    assert eng.blocks.free_count == eng.blocks.usable
+    eng.cache.prefix.evict(10 ** 9)
+    assert eng.cache.pool.refcount(blk) == 0                 # last release
+    assert eng.cache.pool.free_count == eng.cache.pool.usable
     assert fa.result(timeout=5) == _oracle(m, params, a)
     assert fb.result(timeout=5) == _oracle(m, params, b)
     eng.close()
@@ -979,7 +980,7 @@ def test_http_paged_end_to_end_parity_and_stats(paged_dir):
             assert results[i] == want, f"request {i} diverged"
 
     with PredictServer(paged_dir, prefix_cache=False) as srv:
-        assert srv.engine.prefix_cache is None
+        assert srv.engine.cache.prefix is None
         got = post(srv.port, srv.name,
                    {"inputs": {"input_ids": [prompts[0].tolist()]}}
                    )["generations"][0]

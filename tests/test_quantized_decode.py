@@ -41,8 +41,9 @@ from distributed_tensorflow_example_tpu.serving import (ServableModel,
                                                         export_generator,
                                                         load_stepwise,
                                                         validate_quant_meta)
-from distributed_tensorflow_example_tpu.serving_batch import (
-    BlockPool, GenerationEngine)
+from distributed_tensorflow_example_tpu.serving_batch import \
+    GenerationEngine
+from distributed_tensorflow_example_tpu.serving_cache import BlockPool
 from distributed_tensorflow_example_tpu.serving_http import PredictServer
 
 sys.path.insert(0, os.path.join(

@@ -248,8 +248,8 @@ def test_an_iteration_that_admits_nothing_emits_no_child(ring, paged_dir):
     assert admit() == ["sched_admit"]                   # a hit: no program
     assert eng.stats()["prefix_cache_hits"] == 1
     # no free block left: the next cold prompt is deferred, no child
-    eng.blocks.alloc(eng.blocks.free_count)
-    eng.prefix_cache = None
+    eng.cache.pool.alloc(eng.cache.pool.free_count)
+    eng.cache.prefix = None
     eng.submit(_prompts(1, seed=6)[0], max_new=MAX_NEW)
     assert admit() == ["sched_admit"]
     assert eng.stats()["prefills"] == 1 and len(eng._queue) == 1

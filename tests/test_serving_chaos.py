@@ -614,7 +614,7 @@ def test_cancel_during_block_pressure_deferral_not_lost(chaos_dir):
     instead of silently admitting the request later."""
     d, vocab = chaos_dir
     eng = _engine(d)
-    orig_alloc = eng.blocks.alloc
+    orig_alloc = eng.cache.pool.alloc
     state = {"armed": True}
 
     def alloc(n):
@@ -636,14 +636,14 @@ def test_cancel_during_block_pressure_deferral_not_lost(chaos_dir):
                               .astype(np.int32), max_new=16)
         _wait(lambda: eng.stats()["live_slots"] == 1,
               what="neighbor going live")
-        eng.blocks.alloc = alloc
+        eng.cache.pool.alloc = alloc
         victim = eng.submit(np.array([3, 1, 4], np.int32), max_new=16)
         with pytest.raises(RequestCancelledError):
             victim.req.future.result(timeout=60)
         assert eng.stats()["cancelled"] == 1
         assert len(neighbor.result(timeout=120)) == 16  # undisturbed
     finally:
-        eng.blocks.alloc = orig_alloc
+        eng.cache.pool.alloc = orig_alloc
         eng.close()
 
 

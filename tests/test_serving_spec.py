@@ -94,7 +94,7 @@ def run_engine(d, prompts, *, spec: int, max_new: int = MAX_NEW, **kw):
                    for p in prompts]
         outs = [h.result(timeout=300) for h in handles]
         stats = eng.stats()
-        assert eng.blocks.in_use == 0, "blocks leaked past retirement"
+        assert eng.cache.pool.in_use == 0, "blocks leaked past retirement"
         return outs, stats, [h.timings for h in handles]
     finally:
         eng.close()

@@ -105,7 +105,7 @@ def _served(eng, prompts, new, **kw):
 
 
 def _settled(eng):
-    assert eng.blocks.in_use == 0 and not eng._live and not eng._prefilling
+    assert eng.cache.pool.in_use == 0 and not eng._live and not eng._prefilling
     assert eng._behind is None and eng._due is None
     assert eng._step_ahead is None and eng._pool_alive()
 
@@ -183,8 +183,8 @@ def test_a_request_gone_between_the_two_reads_leaves_no_trace(
     flying = eng._step_ahead
     gone = eng._live[max(flying.rows)]
     assert flying.rows[gone.index] is gone
-    held = [int(b) for b in eng._tables[gone.index] if b]
-    free = eng.blocks.free_count
+    held = [int(b) for b in eng.cache.tables[gone.index] if b]
+    free = eng.cache.pool.free_count
     if how == "cancel":
         assert eng.cancel(gone.req.request_id)
     else:
@@ -195,7 +195,7 @@ def test_a_request_gone_between_the_two_reads_leaves_no_trace(
     # the slot and its blocks went back at the boundary with the step
     # that computes the row still unread; its id was dropped at the read
     assert eng._live.get(gone.index) is not gone and gone.req.future.done()
-    assert eng.blocks.free_count >= free + len(held) - 1
+    assert eng.cache.pool.free_count >= free + len(held) - 1
     assert eng.stats()["step_ahead_dead_rows"] == dead + 1
     _drive(eng)
     want = _served(_engine(artifacts, kind, in_turn=True), prompts,
@@ -227,7 +227,7 @@ def test_a_row_crosses_a_block_boundary_in_the_step_ahead(artifacts, kind):
             if pos % BS == 0:
                 # the block the step in flight writes is the row's own
                 block = int(flying.feats["block_tables"][i, pos // BS])
-                assert block and eng.blocks.refcount(block) == 1
+                assert block and eng.cache.pool.refcount(block) == 1
                 crossed.append((i, pos))
     assert len({i for i, _ in crossed}) == 2 and len(crossed) >= 3
     want = _served(_engine(artifacts, kind, in_turn=True), prompts,
@@ -277,7 +277,7 @@ def test_b_without_a_block_for_the_next_row_the_step_is_launched_in_turn(
     eng = _engine(artifacts)
     handle = eng.submit(prompts[0], max_new=NEW)
     _drive(eng, done=lambda: eng._step_ahead is not None)
-    spare = eng.blocks.alloc(eng.blocks.free_count)
+    spare = eng.cache.pool.alloc(eng.cache.pool.free_count)
     fell = []
     while _busy(eng):
         before = eng.stats()["steps_behind_step"]
@@ -286,7 +286,7 @@ def test_b_without_a_block_for_the_next_row_the_step_is_launched_in_turn(
             # nothing was launched ahead, and nobody was failed for it
             assert eng.stats()["steps_behind_step"] == before
             fell.append(next(iter(eng._live.values())).pos)
-            eng.blocks.release(spare)
+            eng.cache.pool.release(spare)
             spare = None
     assert fell == [BS]         # the row's next write opens a block
     assert handle.result(timeout=1) == _served(
@@ -546,7 +546,7 @@ def test_e_an_allocation_fault_at_the_step_ahead_leaves_the_step_to_its_turn(
     finally:
         faults.install(None)
     assert eng._step_ahead is None and slot().pos == BS
-    assert int(eng._tables[slot().index, 1]) == 0
+    assert int(eng.cache.tables[slot().index, 1]) == 0
     _drive(eng)
     assert handle.result(timeout=1) == _served(
         _engine(artifacts, "axk1_tiny", in_turn=True), prompts, (NEW,))[0]

@@ -552,7 +552,7 @@ class Thr01(Rule):
         """Mutation of an owned field whose attribute node itself sits
         in Load context: ``self._live.clear()`` (mutator call),
         ``self._live[k] = v`` / ``del self._live[k]`` (item write), and
-        ``self.blocks.x = v`` (write-through) all load `self.<field>`
+        ``self.cache.x = v`` (write-through) all load `self.<field>`
         first — ctx alone cannot see them. Returns a short description
         of the mutation, or None for a genuine read."""
         p = parents.get(n)
